@@ -103,6 +103,36 @@ def _build_roles(num_executors: int, master_node: str | None, eval_node: bool) -
     return roles
 
 
+def _chip_fight(node_envs: Sequence[dict], num_compute: int) -> str | None:
+    """Why these node processes would fight for one host's TPU chips, or None.
+
+    A process that initialises the TPU backend claims every chip it can see,
+    and a second claimant then fails (or hangs): ``num_compute`` compute
+    processes on one host need a disjoint chip slice each
+    (``TPU_VISIBLE_CHIPS``, from ``tpu_info.chip_visibility_env``).  Roles are
+    handed out in registration order, so any launched process may turn out to
+    compute and every one of them is held to the rule.  Read from the
+    environment alone — the driver must not touch a backend to find out; a
+    process whose env leaves the platform to jax's auto-detection is not
+    judged here (a losing claim then fails that node with libtpu's error).
+    """
+    if num_compute < 2:
+        return None
+    seen: set[str] = set()
+    for i, env in enumerate(node_envs):
+        if env.get("JAX_PLATFORMS", "").split(",")[0].strip() != "tpu":
+            continue
+        chips = {c for c in env.get("TPU_VISIBLE_CHIPS", "").split(",") if c}
+        if not chips:
+            return (f"process {i} is aimed at the TPU (JAX_PLATFORMS="
+                    f"{env['JAX_PLATFORMS']}) with no TPU_VISIBLE_CHIPS")
+        if chips & seen:
+            return (f"process {i} is given chips {sorted(chips & seen)} that "
+                    "another process already holds")
+        seen |= chips
+    return None
+
+
 class _PartitionLedger:
     """Driver-side record of every (epoch, partition) a ``train()`` call must
     deliver: queued on its home slot, in flight on an executor, done, or
@@ -2437,21 +2467,23 @@ class TPUCluster:
         ``device_summary``, in executor-id order) — the driver-side
         replacement for the reference's per-executor randomized GPU picking
         (``gpu_info.py``; SURVEY.md §5.2 disposition).  Returns one
-        ``HostAssignment`` per node; evaluators report their chips too but
-        own no data-plane role."""
+        ``HostAssignment`` per node; the evaluator sidecar and ingest
+        workers hold no accelerator and report zero chips."""
         from tensorflowonspark_tpu import tpu_info
 
         infos = self.coordinator.cluster_info()
         pending = [m["executor_id"] for m in infos
                    if (m.get("device") or {}).get("num_devices") is None]
         if pending:
-            # jax_distributed nodes register a placeholder and report real
-            # device facts only after jax.distributed.initialize — a plan
-            # built from placeholders would be silently all-zero
+            # nodes register a placeholder and report real device facts only
+            # once they know their role (and, in a jax_distributed job, after
+            # jax.distributed.initialize) — a plan built from placeholders
+            # would be silently all-zero
             raise RuntimeError(
                 f"chip plan unavailable: nodes {pending} have not reported "
-                "device facts yet (distributed nodes report after their "
-                "jax.distributed bootstrap); retry once the job is running")
+                "device facts yet (a node claims its accelerator after "
+                "registration, once it knows its role); retry once the job "
+                "is running")
         counts = [int((m.get("device") or {}).get("num_devices") or 0)
                   for m in infos]
         return tpu_info.plan_topology(counts)
@@ -2496,7 +2528,10 @@ def run(
     ``env`` applies to every node; ``per_node_env`` (one dict per executor)
     layers per-process overrides on top — the carrier for disjoint
     accelerator slices (``tpu_info.chip_visibility_env``) when several node
-    processes share a host.
+    processes share a host.  One process owns a TPU chip: several compute
+    executors aimed at one host's TPU (``JAX_PLATFORMS=tpu``) without a
+    disjoint ``TPU_VISIBLE_CHIPS`` each are refused here instead of being
+    left to fight for the claim.
 
     ``reservation_timeout``/``feed_timeout`` default from the
     ``TOS_RESERVATION_TIMEOUT``/``TOS_FEED_TIMEOUT`` env vars when not given
@@ -2573,6 +2608,28 @@ def run(
     # so node_main's role-aware dispatch (not the config) decides which
     # process actually runs the service loop
     roles.extend(("ingest", i) for i in range(ingest_workers))
+    # Default to SubprocessLauncher: children run the lean ``node_entry``
+    # module directly (~0.5s to a live node), where multiprocessing-spawn
+    # re-imports the driver's __main__ machinery in every child (~3s under
+    # pytest), and the env is in place before the child interpreter starts
+    # (libtpu reads its chip-visibility variables when it loads).
+    launcher = launcher or SubprocessLauncher()
+    node_envs = [{**(env or {}),
+                  **(per_node_env[i] if per_node_env is not None
+                     and i < len(per_node_env) else {})}
+                 for i in range(total_procs)]
+    if isinstance(launcher, (SubprocessLauncher, LocalLauncher)):
+        # one host: refuse N compute processes aimed at the same TPU chips
+        # rather than letting them fight for the claim
+        fight = _chip_fight(
+            [{**os.environ, **launcher.env, **e} for e in node_envs],
+            sum(1 for name, _ in roles if name not in ("evaluator", "ingest")))
+        if fight:
+            raise ValueError(
+                f"{fight}: one process owns a TPU chip.  Run ONE executor "
+                "per host and shard over its chips with ctx.make_mesh, or "
+                "give each executor its own chips: per_node_env=["
+                "tpu_info.chip_visibility_env([i]) for i in range(n)]")
     authkey = secrets.token_bytes(16)
     if log_dir:
         os.makedirs(log_dir, exist_ok=True)
@@ -2605,19 +2662,12 @@ def run(
             log_dir=log_dir,
             tensorboard=tensorboard,
             jax_distributed=jax_distributed,
-            env={**(env or {}),
-                 **(per_node_env[i] if per_node_env is not None
-                    and i < len(per_node_env) else {})},
+            env=node_envs[i],
             launch_index=i,
             ingest_opts=dict(ingest_opts) if ingest_opts else None,
         )
         for i in range(total_procs)
     ]
-    # Default to SubprocessLauncher: children run the lean ``node_entry``
-    # module directly (~0.5s to a live node), where multiprocessing-spawn
-    # re-imports the driver's __main__ machinery in every child (~3s under
-    # pytest), and OS-level env lands before any site hook can import jax.
-    launcher = launcher or SubprocessLauncher()
     launcher.launch(configs, log_dir or None)
     try:
         cluster_info = coordinator.await_registrations(reservation_timeout)

@@ -35,7 +35,7 @@ from tensorflowonspark_tpu.utils.locks import tos_named_condition, tos_named_loc
 import time
 from typing import Any
 
-from tensorflowonspark_tpu import faultinject, telemetry
+from tensorflowonspark_tpu import faultinject, telemetry, tpu_info
 from tensorflowonspark_tpu.telemetry import trace as ttrace
 from tensorflowonspark_tpu.telemetry.registry import percentile_of
 
@@ -53,6 +53,15 @@ _TRACE_EVENT_CAP = 1024
 # tick (driver); 240 entries at ~1-2s cadence cover several minutes of
 # window, far past any sensible `cluster.stats(window=...)`.
 _STATS_HISTORY_CAP = 240
+# Extra heartbeat silence allowed to a node whose ``device`` block is still
+# the registration placeholder (``tpu_info.CLAIM_PENDING``): it is claiming
+# its accelerator.  TPU backend initialisation keeps the interpreter lock
+# (measured on a v5e: other threads stalled 4.5 s of a 7 s one-chip init; a
+# four-chip init takes 15-19 s), so the heartbeat thread cannot beat through
+# it — and a node killed mid-init leaves the chip unusable for whoever comes
+# next.  Ends the moment the node reports real device facts (or that it
+# holds none).
+_CLAIM_ALLOWANCE_SECS = 120.0
 # Write-ahead journal snapshot cadence: after this many appended records the
 # stats thread folds the full control-plane state into <journal>.snap and
 # truncates the tail, so crash recovery replays O(delta) records.
@@ -1077,7 +1086,11 @@ class CoordinatorServer:
             return []
         now = time.monotonic()
         with self._lock:
-            return [i for i, t in self._last_seen.items() if now - t > heartbeat_timeout]
+            claiming = {m["executor_id"] for m in self._nodes
+                        if m.get("device") == tpu_info.CLAIM_PENDING}
+            return [i for i, t in self._last_seen.items()
+                    if now - t > heartbeat_timeout + (
+                        _CLAIM_ALLOWANCE_SECS if i in claiming else 0.0)]
 
     def forget(self, executor_ids: list[int]) -> None:
         """Stop liveness-tracking nodes WITHOUT recording an error (used for

@@ -12,7 +12,7 @@ launcher backend owns process placement (SURVEY.md §7.1-4):
   with its own environment.  Required for per-process accelerator
   visibility (``TPU_VISIBLE_CHIPS`` / ``JAX_NUM_CPU_DEVICES``) and for
   ``jax.distributed`` runs, where env must be in place *before* the child
-  interpreter starts (site hooks may import jax at startup).
+  interpreter starts.
 - ``TPUPodLauncher`` — placement across the hosts of a TPU pod slice; one
   node process per TPU-VM host, spawned over a pluggable transport
   (default: ``ssh``; ``transport='local'`` runs every "host" on this
@@ -116,9 +116,11 @@ class LocalLauncher(_RespawnMixin):
     (the same closure-shipping contract Spark gave the reference).
 
     Env caveat: ``config.env`` is applied inside ``node_main`` — after the
-    child interpreter (and any site hooks) started.  Vars that must be seen
-    at interpreter startup (``JAX_PLATFORMS`` under a sitecustomize that
-    imports jax, ``TPU_VISIBLE_CHIPS``) need ``SubprocessLauncher``.
+    child interpreter started and, because spawn re-imports the driver's
+    ``__main__``, possibly after jax was imported (``node.
+    _apply_jax_env_config`` repairs the JAX config vars).  Vars a native
+    library reads when it loads (``TPU_VISIBLE_CHIPS``) need
+    ``SubprocessLauncher``.
     """
 
     def __init__(self, env: dict[str, str] | None = None):
@@ -236,11 +238,11 @@ def _pythonpath_env() -> dict[str, str]:
 class SubprocessLauncher(_RespawnMixin):
     """Spawn node processes as fresh OS subprocesses with per-node env.
 
-    Each child runs ``python -m tensorflowonspark_tpu.launcher`` and reads
+    Each child runs ``python -m tensorflowonspark_tpu.node_entry`` and reads
     its cloudpickled ``NodeConfig`` from stdin.  ``config.env`` is merged
-    into the *OS-level* environment of the child, so interpreter-startup
-    consumers (PJRT plugins registered from sitecustomize, libtpu chip
-    visibility) see it — the property ``LocalLauncher`` cannot provide.
+    into the *OS-level* environment of the child, so load-time consumers
+    (libtpu chip visibility, jax's import-time config snapshot) see it —
+    the property ``LocalLauncher`` cannot provide.
     """
 
     def __init__(self, env: dict[str, str] | None = None):

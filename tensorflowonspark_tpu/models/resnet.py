@@ -78,8 +78,7 @@ class ResNet(nn.Module):
     # [N,H,W,3] -> [N,H/2,W/2,12] (2x2 blocks stacked into channels) and the
     # 7x7/2 conv replaced by an equivalent-receptive-field 4x4/1 conv.  Same
     # output shape as "imagenet"; 4x more input channels feed the MXU's
-    # 128-lane tiles far better than C=3, removing most of the stem cost
-    # (PERF_NOTES.md "what would move it").  Opt-in: weights are not
+    # 128-lane tiles far better than C=3.  Opt-in: weights are not
     # interchangeable with the classic stem.
     stem: str = "imagenet"
 

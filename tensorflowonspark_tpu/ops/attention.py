@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30  # finite stand-in for -inf: keeps exp() exactly 0 without nan
 
@@ -338,7 +339,30 @@ def flash_attention(q, k, v, *, causal: bool = True,
         return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                                    block_k=block_k, kv_offset=kv_offset)
     if impl in ("pallas", "pallas_interpret"):
-        return _flash_attention_tpu(q, k, v, causal, sm_scale, kv_offset,
-                                    block_q, block_k,
-                                    impl == "pallas_interpret")
+        def kernel(q, k, v):
+            return _flash_attention_tpu(q, k, v, causal, sm_scale, kv_offset,
+                                        block_q, block_k,
+                                        impl == "pallas_interpret")
+
+        # GSPMD cannot partition a Mosaic kernel: on more than one device
+        # jax refuses to lower a bare pallas_call ("Mosaic kernels cannot be
+        # automatically partitioned").  Attention is independent per
+        # (batch, head), so under an ambient mesh (``jax.set_mesh``) run the
+        # kernel per shard over the axes the model already constrains q/k/v
+        # to: batch over (dp, fsdp), heads over tp.  Sequence stays whole
+        # here (splitting it is ring attention's job, parallel/sp.py).
+        mesh = jax.sharding.get_abstract_mesh()
+        auto = [] if mesh.empty else [a for a in mesh.axis_names
+                                      if a not in mesh.manual_axes]
+        if auto:
+            batch_axes = tuple(a for a in ("dp", "fsdp") if a in auto)
+            spec = P(batch_axes or None, None,
+                     "tp" if "tp" in auto else None, None)
+            # EVERY remaining mesh axis goes manual (the Mosaic lowering
+            # accepts nothing less); the ones the spec does not name see
+            # q/k/v replicated and compute the same shard redundantly
+            kernel = jax.shard_map(
+                kernel, in_specs=(spec, spec, spec), out_specs=spec,
+                axis_names=frozenset(auto), check_vma=False)
+        return kernel(q, k, v)
     raise ValueError(f"unknown attention impl {impl!r}")

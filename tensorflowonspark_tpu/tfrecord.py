@@ -18,8 +18,10 @@ table-driven pure-Python implementation is the fallback; the C++ extension in
 
 from __future__ import annotations
 
+import logging
 import os
 import struct
+import subprocess
 from typing import Iterable, Iterator
 
 _U64 = struct.Struct("<Q")
@@ -54,11 +56,21 @@ _native = None
 
 
 def _use_native() -> bool:
-    """Try to switch hot paths to the C++ implementation; True on success."""
+    """Try to switch hot paths to the C++ implementation; True on success.
+
+    A box without a working g++ keeps the pure-Python codec (same bytes,
+    far slower) — logged with the cause, never silent; ``NATIVE`` records
+    the outcome for callers that must not run on the slow path
+    (``chip_smoke.py``)."""
     global crc32c, _native
     try:
         from tensorflowonspark_tpu import native_bindings
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        logging.getLogger(__name__).warning(
+            "native TFRecord codec unavailable (%s: %s%s); using the "
+            "pure-Python codec", type(e).__name__, e,
+            " | " + detail.decode(errors="replace")[-400:] if detail else "")
         return False
     crc32c = native_bindings.crc32c
     _native = native_bindings

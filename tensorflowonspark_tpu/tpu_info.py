@@ -28,13 +28,14 @@ from typing import Sequence
 
 
 def is_tpu_available() -> bool:
-    """Reference parity: ``gpu_info.is_gpu_available()``."""
-    try:
-        import jax
+    """Reference parity: ``gpu_info.is_gpu_available()``.
 
-        return any(d.platform == "tpu" for d in jax.devices())
-    except Exception:
-        return False
+    Initialises the backend (and so claims the chips for this process); a
+    backend that cannot initialise raises — it is not reported as "no TPU".
+    """
+    import jax
+
+    return any(d.platform == "tpu" for d in jax.devices())
 
 
 def _forced_cpu_device_count() -> int:
@@ -54,17 +55,30 @@ def _forced_cpu_device_count() -> int:
     return int(m.group(1)) if m else 1
 
 
-def device_summary() -> dict:
-    """What this process sees; goes into the coordinator registration payload
-    so the driver's ``cluster_info`` reports real hardware per node."""
+# What a node that owns no accelerator reports: the evaluator sidecar and the
+# ingest workers never compute, so the node runtime never initialises a
+# backend for them — on a TPU host that would take the chips from the trainer.
+NO_DEVICES = {"platform": "none", "device_kind": "none", "num_devices": 0,
+              "coords": [], "process_index": 0}
+# What a node registers with when the environment does not pin its devices:
+# it will claim its accelerator (or learn it needs none) once it knows its
+# role.  The coordinator tolerates heartbeat silence while this stands —
+# backend initialisation keeps the interpreter lock.
+CLAIM_PENDING = {"platform": "pending"}
+
+
+def env_device_summary() -> dict | None:
+    """The device summary read from the environment alone, or None when the
+    environment does not pin it down.
+
+    Env-forced CPU platform and jax not loaded yet: the env already states
+    exactly what the backend would report, so synthesize it instead of paying
+    a ~3s jax import + backend init in every node process (control-plane-only
+    nodes and every CPU test node never need the backend).  Never touches a
+    backend, so it is safe before a node knows its role."""
     import os
     import sys
 
-    # Env-forced CPU platform and jax not loaded yet: synthesize the summary
-    # instead of paying a ~3s jax import + backend init in every node
-    # process — control-plane-only nodes (and every CPU test node) never
-    # need the backend, and the env already states exactly what it would
-    # report.  Once jax IS loaded (compute nodes), report live state.
     if "jax" not in sys.modules and os.environ.get(
             "JAX_PLATFORMS", "").split(",")[0] == "cpu":
         return {
@@ -74,23 +88,34 @@ def device_summary() -> dict:
             "coords": [],
             "process_index": 0,
         }
-    try:
-        import jax
+    return None
 
-        # local_devices/process_index, NOT jax.devices(): after
-        # jax.distributed.initialize the latter is pod-global, and every node
-        # would report the whole pod's chips instead of its own.
-        devices = jax.local_devices()
-        return {
-            "platform": jax.default_backend(),
-            "device_kind": devices[0].device_kind if devices else "none",
-            "num_devices": len(devices),
-            "coords": [list(getattr(d, "coords", ()) or ()) for d in devices],
-            "process_index": jax.process_index(),
-        }
-    except Exception:
-        return {"platform": "none", "device_kind": "none", "num_devices": 0,
-                "coords": [], "process_index": 0}
+
+def device_summary() -> dict:
+    """What this process sees; goes into the node's coordinator metadata so
+    the driver's ``cluster_info`` reports real hardware per node.
+
+    Unless the environment pins the answer (:func:`env_device_summary`) this
+    initialises the backend — which CLAIMS the chips for this process — and a
+    backend that cannot initialise raises instead of being reported as
+    ``platform: none``: a node that was meant to compute must not carry on
+    into ``map_fun`` without its accelerator."""
+    summary = env_device_summary()
+    if summary is not None:
+        return summary
+    import jax
+
+    # local_devices/process_index, NOT jax.devices(): after
+    # jax.distributed.initialize the latter is pod-global, and every node
+    # would report the whole pod's chips instead of its own.
+    devices = jax.local_devices()
+    return {
+        "platform": jax.default_backend(),
+        "device_kind": devices[0].device_kind if devices else "none",
+        "num_devices": len(devices),
+        "coords": [list(getattr(d, "coords", ()) or ()) for d in devices],
+        "process_index": jax.process_index(),
+    }
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,8 +184,8 @@ def chip_visibility_env(chip_ids: Sequence[int], *, platform: str = "tpu",
         return {
             "JAX_PLATFORMS": "cpu",
             # Both spellings: JAX_NUM_CPU_DEVICES is the authoritative config
-            # knob (survives plugins that rewrite XLA_FLAGS); the flag form
-            # covers older JAX versions that only read XLA_FLAGS.
+            # knob; the flag form covers older JAX versions that only read
+            # XLA_FLAGS.
             "JAX_NUM_CPU_DEVICES": str(max(1, n)),
             "XLA_FLAGS": f"--xla_force_host_platform_device_count={max(1, n)}",
             # Cross-process CPU collectives (the ICI/DCN simulation for

@@ -19,6 +19,34 @@ def test_native_builds_and_loads():
     assert tfrecord.NATIVE, "native codec failed to build/load"
 
 
+def test_library_is_keyed_on_source_and_flags(tmp_path, monkeypatch):
+    """A library is reused only for the exact source and flags it was built
+    from: the build directory travels with a copied checkout, and a stale
+    library there (whatever its file time) must never be picked up."""
+    import ctypes
+    import os
+
+    from tensorflowonspark_tpu.native import build
+
+    monkeypatch.setattr(build, "_CACHE_DIR", str(tmp_path / "cache"))
+    os.makedirs(build._CACHE_DIR)
+    src = tmp_path / "answer.cc"
+    src.write_text('extern "C" int answer() { return 1; }\n')
+    # a library built from OTHER source, newer than the source file, under
+    # the name the old file-time scheme would have reused
+    stale = tmp_path / "cache" / "libanswer.so"
+    stale.write_bytes(b"not a library")
+    first = build.build_native_lib(str(src), "libanswer.so")
+    assert first != str(stale) and ctypes.CDLL(first).answer() == 1
+    assert build.build_native_lib(str(src), "libanswer.so") == first  # reused
+    src.write_text('extern "C" int answer() { return 2; }\n')
+    os.utime(src, (0, 0))  # older than every library: file time says "reuse"
+    second = build.build_native_lib(str(src), "libanswer.so")
+    assert second != first and ctypes.CDLL(second).answer() == 2
+    assert build.build_native_lib(str(src), "libanswer.so",
+                                  ("-DX=1",)) not in (first, second)
+
+
 def test_crc_agreement():
     nb = native()
     for data in [b"", b"a", b"123456789", bytes(range(256)) * 37, b"\x00" * 4096]:

@@ -118,3 +118,38 @@ def test_bf16_inputs():
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=3e-2, rtol=3e-2)
     assert out.dtype == jnp.bfloat16
+
+
+def test_pallas_kernel_runs_per_shard_under_an_ambient_mesh():
+    """Under ``jax.set_mesh`` the kernel is shard_mapped over the batch
+    (dp, fsdp) and head (tp) axes with every other mesh axis manual too —
+    the only form jax lowers a Mosaic kernel in on more than one device
+    (chip_smoke.py checks the real lowering on four chips).  Forward and
+    gradients must still match the dense reference, also when the mesh
+    has an axis (sp) the kernel's specs do not name."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tensorflowonspark_tpu.parallel import mesh as meshlib
+
+    mesh = meshlib.make_mesh(dp=2, sp=2, tp=2)
+    q, k, v = make_qkv(b=4, s=32, h=4, d=8)
+    placed = [jax.device_put(x, NamedSharding(
+        mesh, P(("dp", "fsdp"), "sp", "tp", None))) for x in (q, k, v)]
+
+    def loss(impl):
+        def f(q, k, v):
+            out = att.flash_attention(q, k, v, causal=True, impl=impl,
+                                      block_q=16, block_k=16)
+            return jnp.sum(out ** 2), out
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    with jax.set_mesh(mesh):
+        fn = jax.jit(loss("pallas_interpret"))
+        # shardy spells a shard_map region "sdy.manual_computation"
+        assert "manual_computation" in fn.lower(*placed).as_text()
+        (_, out), grads = fn(*placed)
+    assert out.sharding.spec == P("dp", None, "tp")
+    (_, ref), ref_grads = loss("reference")(q, k, v)
+    np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+    for g, r in zip(grads, ref_grads):
+        np.testing.assert_allclose(g, r, atol=1e-4, rtol=1e-4)

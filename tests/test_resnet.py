@@ -59,21 +59,7 @@ def test_resnet50_registry_builds():
     assert model.num_classes == 10
 
 
-@pytest.fixture
-def no_persistent_cache():
-    """This jaxlib build cannot round-trip the bn-train-step executables
-    through the persistent compilation cache: reloading the fsdp variant
-    corrupts the heap (glibc "corrupted size vs. prev_size" abort that kills
-    the whole pytest process), and reloading the dp variant silently returns
-    zeroed batch_stats aux outputs.  Cold compiles are correct, so these two
-    tests opt out of the cache and pay the ~30s compile every run."""
-    prev = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    yield
-    jax.config.update("jax_compilation_cache_dir", prev)
-
-
-def test_train_step_descends_loss_fsdp_mesh(no_persistent_cache):
+def test_train_step_descends_loss_fsdp_mesh():
     mesh = meshlib.make_mesh(dp=-1, fsdp=2)
     model = tiny_resnet()
     optimizer = optax.sgd(0.05, momentum=0.9)
@@ -89,7 +75,7 @@ def test_train_step_descends_loss_fsdp_mesh(no_persistent_cache):
     assert int(jax.device_get(state.step)) == 5
 
 
-def test_batch_stats_update(no_persistent_cache):
+def test_batch_stats_update():
     mesh = meshlib.make_mesh(dp=-1)
     model = tiny_resnet()
     optimizer = optax.sgd(0.05)
