@@ -28,11 +28,12 @@ sequence itself proves the chip is handed from one process to the next:
 The driver process (this file's ``main``) never imports jax: one process owns
 the chip.  Every node is started with ``JAX_PLATFORMS=tpu`` so it cannot fall
 back to the CPU.  Any failed check raises — no phase is wrapped in try/except
-— and the exit code is non-zero with no result line.  On success the LAST
-line of stdout is one JSON object:
+— and the exit code is non-zero with no result line.  On success the last
+two lines of stdout are JSON objects: the summary (per-phase seconds, cache
+entries, ``"claim": null``), then, as the LAST line, the verdict and nothing
+else:
 
-    {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N},
-     ..., "claim": null}
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
 
 The numbers it prints (compile and step seconds, peak HBM) are evidence that
 the run happened on the named device; they are not a benchmark baseline.
@@ -683,6 +684,15 @@ def say_steps(name: str, smoke: dict) -> None:
         f"other threads {smoke['longest_thread_stall']}")
 
 
+def verdict_line(ok: bool, device: dict) -> str:
+    """The last line of stdout: exactly ``ok`` and the device as JAX reported
+    it to the node (``device_facts``) — whoever runs this script reads that
+    line and nothing else, so everything else goes on the lines before it."""
+    return json.dumps({"ok": bool(ok), "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}})
+
+
 def cache_entries(cache_dir: str) -> int:
     return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
 
@@ -896,6 +906,7 @@ def main() -> None:
     print(_TAG.strip() if opts.rehearse_cpu else "chip_smoke: all phases passed",
           flush=True)
     print(json.dumps(summary), flush=True)
+    print(verdict_line(summary["ok"], summary["device"]), flush=True)
 
 
 if __name__ == "__main__":

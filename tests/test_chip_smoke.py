@@ -212,6 +212,17 @@ def test_kernel_operand_rows_reads_custom_call_lines_only():
     assert set(chip_smoke.kernel_operand_rows(hlo)) == {64}
 
 
+def test_verdict_line_holds_ok_and_the_device_and_nothing_else():
+    """The last stdout line is read by a checker that accepts exactly these
+    keys; the summary (phases, cache, claim) goes on the line before it."""
+    facts = {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}
+    assert json.loads(chip_smoke.verdict_line(True, facts)) == {
+        "ok": True, "device": facts}
+    line = chip_smoke.verdict_line(False, {**facts, "extra": 1})
+    assert "\n" not in line
+    assert json.loads(line) == {"ok": False, "device": facts}
+
+
 @pytest.mark.slow
 def test_default_chip_smoke_fails_without_a_chip():
     """The real script, default arguments, on this chip-less box: non-zero
