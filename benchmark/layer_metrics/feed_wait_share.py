@@ -1,0 +1,13 @@
+"""Share of the (untraced) window the step loop spent inside
+``next(batches)``: waiting for the feed and for the batch to reach the device."""
+
+LAYER = "feed, batch to device"
+UNIT = "%"
+MOVES = "train_img_rate"
+
+
+def read(run: dict):
+    wait = run["spans"]["seconds"].get("feed_wait")
+    if wait is None or not run["facts"].get("window_s"):
+        return None
+    return 100.0 * wait / run["facts"]["window_s"]
