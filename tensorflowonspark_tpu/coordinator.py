@@ -1357,7 +1357,7 @@ class CoordinatorServer:
         clock offset) into its bounded stream store."""
         store = self._node_trace.setdefault(
             key, {"spans": [], "events": [], "offset": None, "rtt": None,
-                  "dropped": 0})
+                  "anchor": None, "dropped": 0})
         spans = payload.get("spans")
         if spans:
             store["spans"].extend(spans)
@@ -1369,6 +1369,8 @@ class CoordinatorServer:
         if payload.get("offset") is not None:
             store["offset"] = float(payload["offset"])
             store["rtt"] = payload.get("rtt")
+        if payload.get("anchor"):
+            store["anchor"] = list(payload["anchor"])
         if payload.get("dropped"):
             store["dropped"] = int(payload["dropped"])
 
@@ -1392,7 +1394,7 @@ class CoordinatorServer:
 
     def trace_streams(self) -> dict[str, dict]:
         """Every process's trace stream, export-ready: ``{key: {"spans",
-        "events", "clock_offset", ...}}`` (``trace_export.build_stream``
+        "events", "clock_offset", "anchor", ...}}`` (``trace_export.build_stream``
         shape).  Driver spans are drained into the store first."""
         self._drain_driver_trace()
         with self._lock:
@@ -1400,6 +1402,7 @@ class CoordinatorServer:
             for key, store in self._node_trace.items():
                 out[key] = {"schema": "tos-trace-stream-v1", "node": key,
                             "clock_offset": store["offset"],
+                            "anchor": store["anchor"],
                             "spans": list(store["spans"]),
                             "events": list(store["events"]),
                             "dropped": store["dropped"]}

@@ -202,7 +202,17 @@ class DataFeed:
         """Pop up to ``batch_size`` items; partial on EndPartition/end-of-feed.
 
         Reference hot loop ``TFNode.py:~280-340``.
+
+        Stage ``feed.collect`` is the whole call; ``feed.wait`` inside it is
+        the part spent blocked on an EMPTY queue (buffered items are popped
+        outside it), so collect − wait is the assembly of the row list.
+        ``feed.starved_polls`` counts only a WHOLE empty ``poll_interval``;
+        ``feed.wait.us`` is the reading for shorter waits.
         """
+        with telemetry.stage("feed.collect"):
+            return self._next_batch(batch_size)
+
+    def _next_batch(self, batch_size: int) -> list | dict:
         # Self-fence (ISSUE 13): "parked" means this node lost its
         # coordinator past TOS_COORDINATOR_GRACE_SECS — a replacement may
         # already own the slot, so taking NEW work risks split-brain.  Hold
@@ -232,7 +242,8 @@ class DataFeed:
                         self.done_feeding = True
                         break
                     try:
-                        item = q.get(timeout=self.poll_interval)
+                        with telemetry.stage("feed.wait"):
+                            item = q.get(timeout=self.poll_interval)
                     except queue.Empty:
                         # starvation signal: the consumer wanted data and
                         # the feed had none for a whole poll interval —

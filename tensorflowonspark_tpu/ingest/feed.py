@@ -280,7 +280,20 @@ class IngestFeed:
         at end-of-feed / stop / a completed ledger partition (shard seams
         inside a partition never truncate it) / a columnar chunk boundary.
         Calling this RELEASES the previous batch (see the zero-copy decode
-        contract in the class docstring)."""
+        contract in the class docstring).
+
+        Stage ``feed.collect`` is the whole call; ``feed.wait`` inside it is
+        the part spent blocked on the reader pipeline, so collect − wait is
+        the assembly of the row list.  ``feed.starved_polls`` counts only a
+        WHOLE empty ``poll_interval``: chunks that trickle in every few
+        milliseconds keep it at 0 while the consumer waits — ``feed.wait.us``
+        is the reading for that.  With ``readers=0`` the calling thread
+        reads inline, so ``ingest.read`` / ``ingest.decode`` nest inside
+        ``feed.wait``."""
+        with telemetry.stage("feed.collect"):
+            return self._next_batch(batch_size)
+
+    def _next_batch(self, batch_size: int) -> list | dict:
         # Self-fence (ISSUE 13): parked = coordinator unreachable past
         # TOS_COORDINATOR_GRACE_SECS — stop taking new ledger work until
         # the heartbeat loop re-admits us or gives up (same contract as
@@ -348,7 +361,8 @@ class IngestFeed:
                 self.done_feeding = True
                 break
             try:
-                item = self.pipeline.get(timeout=self.poll_interval)
+                with telemetry.stage("feed.wait"):
+                    item = self.pipeline.get(timeout=self.poll_interval)
             except queue.Empty:
                 # same starvation counter as the streaming DataFeed: an
                 # empty poll with the consumer hungry (decode behind)

@@ -36,6 +36,7 @@ feed queue; ``bench_ingest.py`` drives it raw for the scaling numbers.
 
 from __future__ import annotations
 
+import itertools
 import logging
 import queue
 import threading
@@ -327,8 +328,7 @@ class ReaderPipeline:
                     return self._sync_get(timeout)
                 raise
         try:
-            with telemetry.timed("ingest.shard_read_secs"):
-                self._read_one(path, tag)
+            self._read_one(path, tag)
         except Exception as e:  # noqa: BLE001 - same contract as the pool
             wrapped = ShardReadError(f"reading shard {path!r} failed: {e}")
             wrapped.__cause__ = e
@@ -341,18 +341,15 @@ class ReaderPipeline:
         thread): grow while the consumer starves, shrink while readers
         saturate the queue.  Sampling at pop time biases toward the moments
         that matter — when the consumer actually wants data."""
-        occupancy = self._out.qsize()
-        telemetry.gauge("ingest.prefetch_depth").set(occupancy)
         if not self._autotune:
             return
+        occupancy = self._out.qsize()
         self._occupancy_ema += _EMA_ALPHA * (occupancy / self._out.maxsize
                                              - self._occupancy_ema)
         now = time.monotonic()
         if now - self._last_tune < _TUNE_INTERVAL_SECS:
             return
         self._last_tune = now
-        telemetry.gauge("ingest.queue_occupancy").set(
-            round(self._occupancy_ema, 4))
         if (self._occupancy_ema < _TUNE_LOW and not self._work.empty()):
             # closed does NOT gate growth: it only means no more submits,
             # and the work queue may still be deep
@@ -399,8 +396,7 @@ class ReaderPipeline:
                             return
                     continue
                 try:
-                    with telemetry.timed("ingest.shard_read_secs"):
-                        self._read_one(path, tag)
+                    self._read_one(path, tag)
                 except Exception as e:  # noqa: BLE001 - re-raised consumer-side
                     wrapped = ShardReadError(f"reading shard {path!r} failed: {e}")
                     wrapped.__cause__ = e
@@ -474,31 +470,34 @@ class ReaderPipeline:
         # Columnar and bytes-copy modes keep the bytes read (their
         # decoders materialize/copy anyway).
         use_map = self.schema is None and self.zerocopy != "off"
-        if isinstance(item, ShardSpan):
-            local = resolve_uri(item.path)
-            gz = False
-            if use_map:
-                buf, spans = tfrecord.map_span_range(local, item.start,
-                                                     item.end, self.verify)
+        # stage ingest.read: open or map the work item and CRC-scan it (a
+        # gzip shard only probes here; it is read as it streams, below)
+        with telemetry.stage("ingest.read"):
+            if isinstance(item, ShardSpan):
+                local = resolve_uri(item.path)
+                gz = False
+                if use_map:
+                    buf, spans = tfrecord.map_span_range(
+                        local, item.start, item.end, self.verify)
+                else:
+                    buf, spans = tfrecord.read_span_range(
+                        local, item.start, item.end, self.verify)
             else:
-                buf, spans = tfrecord.read_span_range(local, item.start,
-                                                      item.end, self.verify)
-        else:
-            local = resolve_uri(item)
-            buf = None  # stays None for gzip shards (they stream)
-            if use_map:
-                # ONE open: gzip probe off the mapped head + CRC scan
-                buf, spans = tfrecord.map_record_spans(local, self.verify)
-                gz = buf is None
-            else:
-                with open(local, "rb") as f:
-                    gz = tfrecord._is_gzip_shard(f.read(12))
+                local = resolve_uri(item)
+                buf = None  # stays None for gzip shards (they stream)
+                if use_map:
+                    # ONE open: gzip probe off the mapped head + CRC scan
+                    buf, spans = tfrecord.map_record_spans(local, self.verify)
+                    gz = buf is None
+                else:
+                    with open(local, "rb") as f:
+                        gz = tfrecord._is_gzip_shard(f.read(12))
+                        if not gz:
+                            f.seek(0)
+                            buf = f.read()  # one read, no probe+rest concat copy
                     if not gz:
-                        f.seek(0)
-                        buf = f.read()  # one read, no probe+rest concat copy
-                if not gz:
-                    spans = tfrecord.scan_record_spans(buf, self.verify,
-                                                       name=local)
+                        spans = tfrecord.scan_record_spans(buf, self.verify,
+                                                           name=local)
         if self.schema is not None:
             nrecs, nbytes = self._read_columnar(local, buf,
                                                 None if gz else spans, gz,
@@ -519,15 +518,20 @@ class ReaderPipeline:
             nbytes = sum(length for _, length in spans)
             cr = self.chunk_records
             if decode is None:
-                records = tfrecord.record_views(buf, spans) if zc else None
+                if zc:
+                    with telemetry.stage("ingest.decode"):
+                        records = tfrecord.record_views(buf, spans)
                 for i in range(0, nrecs, cr):
-                    chunk = (records[i:i + cr] if zc else
-                             [buf[off:off + length]
-                              for off, length in spans[i:i + cr]])
+                    if zc:
+                        chunk = records[i:i + cr]
+                    else:
+                        with telemetry.stage("ingest.decode"):
+                            chunk = [buf[off:off + length]
+                                     for off, length in spans[i:i + cr]]
                     if not self._emit(chunk, tee):
                         return  # stopped with the consumer gone
             else:
-                # decode INTERLEAVED with chunk pushes: per-record decode
+                # decode INTERLEAVED with chunk pushes: one chunk's decode
                 # cost paces the queue, so the autotuner's pop-time
                 # occupancy sampling sees the decode rate, not one
                 # end-of-shard burst.  Decode callables keep their
@@ -536,32 +540,33 @@ class ReaderPipeline:
                 # copy — noise next to per-record Python decode): handing
                 # views to decoders written against bytes would crash
                 # every one of them for no measurable win.
-                chunk: list = []
-                for off, length in spans:
-                    chunk.append(decode(bytes(buf[off:off + length])))
-                    if len(chunk) >= cr:
-                        if not self._put(chunk):
-                            return
-                        chunk = []
-                if chunk and not self._put(chunk):
-                    return
+                for i in range(0, nrecs, cr):
+                    with telemetry.stage("ingest.decode"):
+                        chunk = [decode(bytes(buf[off:off + length]))
+                                 for off, length in spans[i:i + cr]]
+                    if not self._put(chunk):
+                        return
         else:
             payloads = tfrecord.read_records(local, verify=self.verify,
                                              gzipped=True)
             decode = self.decode
             nbytes = 0
             nrecs = 0
-            chunk: list = []
-            for payload in payloads:
-                nbytes += len(payload)
-                nrecs += 1
-                chunk.append(decode(payload) if decode is not None else payload)
-                if len(chunk) >= self.chunk_records:
-                    if not self._emit(chunk, tee):
-                        return  # stopped with the consumer gone
-                    chunk = []
-            if chunk and not self._emit(chunk, tee):
-                return
+            while True:
+                # a gzip shard is read as it streams: one chunk's records
+                # are pulled (inflate + CRC) under ingest.read
+                with telemetry.stage("ingest.read"):
+                    chunk = list(itertools.islice(payloads,
+                                                  self.chunk_records))
+                if not chunk:
+                    break
+                nrecs += len(chunk)
+                nbytes += sum(map(len, chunk))
+                if decode is not None:
+                    with telemetry.stage("ingest.decode"):
+                        chunk = [decode(payload) for payload in chunk]
+                if not self._emit(chunk, tee):
+                    return  # stopped with the consumer gone
         self._put(ShardDone(item, tag))
         telemetry.counter("ingest.shards_read").inc()
         telemetry.counter("ingest.records_read").inc(nrecs)
@@ -606,47 +611,48 @@ class ReaderPipeline:
         if not gz:
             for i in range(0, len(spans), cr):
                 window = spans[i:i + cr]
-                cols, counts = dfutil.decode_span_columns(
-                    buf, window, self.schema, self.binary_features)
-                if not self._emit(dfutil.ColumnChunk.from_schema(
-                        cols, counts, self.schema), tee):
+                with telemetry.stage("ingest.decode"):
+                    cols, counts = dfutil.decode_span_columns(
+                        buf, window, self.schema, self.binary_features)
+                    chunk = dfutil.ColumnChunk.from_schema(cols, counts,
+                                                           self.schema)
+                if not self._emit(chunk, tee):
                     return None, None
                 nrecs += len(window)
                 nbytes += sum(length for _, length in window)
             return nrecs, nbytes
-        batch: list = []
-        for payload in tfrecord.read_records(local, verify=self.verify,
-                                             gzipped=True):
-            batch.append(payload)
-            nbytes += len(payload)
-            if len(batch) >= cr:
+        payloads = tfrecord.read_records(local, verify=self.verify,
+                                         gzipped=True)
+        while True:
+            with telemetry.stage("ingest.read"):   # streamed: see _read_one
+                batch = list(itertools.islice(payloads, cr))
+            if not batch:
+                return nrecs, nbytes
+            with telemetry.stage("ingest.decode"):
                 cols, counts = dfutil.records_to_columns(
                     batch, self.schema, self.binary_features)
-                if not self._emit(dfutil.ColumnChunk.from_schema(
-                        cols, counts, self.schema), tee):
-                    return None, None
-                nrecs += len(batch)
-                batch = []
-        if batch:
-            cols, counts = dfutil.records_to_columns(
-                batch, self.schema, self.binary_features)
-            if not self._emit(dfutil.ColumnChunk.from_schema(
-                    cols, counts, self.schema), tee):
+                chunk = dfutil.ColumnChunk.from_schema(cols, counts,
+                                                       self.schema)
+            if not self._emit(chunk, tee):
                 return None, None
             nrecs += len(batch)
-        return nrecs, nbytes
+            nbytes += sum(map(len, batch))
 
     def _put(self, item) -> bool:
         """Bounded put that stays responsive to stop(): blocking on the full
         prefetch queue IS the backpressure, but an abandoned pipeline (stop
-        set, consumer gone) must not strand the reader thread forever."""
-        while True:
-            try:
-                self._out.put(item, timeout=0.1)
-                return True
-            except queue.Full:
-                if self._stop.is_set():
-                    return False
+        set, consumer gone) must not strand the reader thread forever.
+        Stage ``ingest.put_wait``: time spent here is the reader being
+        AHEAD of the consumer, not reading or decoding."""
+        with telemetry.stage("ingest.put_wait") as blocked:
+            while True:
+                try:
+                    self._out.put(item, timeout=0.1)
+                    return True
+                except queue.Full:
+                    if self._stop.is_set():
+                        return False
+                    blocked.tick()
 
 
 def prefetch_iterator(iterable, depth: int = 2):

@@ -84,32 +84,40 @@ class ResNet(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        x = x.astype(self.compute_dtype)
-        if self.stem == "cifar":
-            x = nn.Conv(self.width, (3, 3), use_bias=False,
-                        dtype=self.compute_dtype, name="conv_init")(x)
-        elif self.stem == "space_to_depth":
-            n, h, w, c = x.shape
-            x = x.reshape(n, h // 2, 2, w // 2, 2, c)
-            x = x.transpose(0, 1, 3, 2, 4, 5).reshape(n, h // 2, w // 2, 4 * c)
-            x = nn.Conv(self.width, (4, 4), use_bias=False,
-                        dtype=self.compute_dtype, name="conv_init")(x)
-        else:
-            x = nn.Conv(self.width, (7, 7), strides=(2, 2), use_bias=False,
-                        dtype=self.compute_dtype, name="conv_init")(x)
-        x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
-                         epsilon=1e-5, dtype=self.norm_dtype, name="bn_init")(x)
-        x = nn.relu(x)
-        if self.stem != "cifar":
-            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+        # one named scope per stage, so that a trace viewer (and a later
+        # reduction) finds where the device time goes, whatever XLA fuses
+        with jax.named_scope("stem"):
+            x = x.astype(self.compute_dtype)
+            if self.stem == "cifar":
+                x = nn.Conv(self.width, (3, 3), use_bias=False,
+                            dtype=self.compute_dtype, name="conv_init")(x)
+            elif self.stem == "space_to_depth":
+                n, h, w, c = x.shape
+                x = x.reshape(n, h // 2, 2, w // 2, 2, c)
+                x = x.transpose(0, 1, 3, 2, 4, 5).reshape(
+                    n, h // 2, w // 2, 4 * c)
+                x = nn.Conv(self.width, (4, 4), use_bias=False,
+                            dtype=self.compute_dtype, name="conv_init")(x)
+            else:
+                x = nn.Conv(self.width, (7, 7), strides=(2, 2), use_bias=False,
+                            dtype=self.compute_dtype, name="conv_init")(x)
+            x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
+                             epsilon=1e-5, dtype=self.norm_dtype,
+                             name="bn_init")(x)
+            x = nn.relu(x)
+            if self.stem != "cifar":
+                x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         for stage, size in enumerate(self.stage_sizes):
-            for block in range(size):
-                strides = 2 if stage > 0 and block == 0 else 1
-                x = BottleneckBlock(self.width * (2 ** stage), strides,
-                                    self.compute_dtype,
-                                    self.norm_dtype)(x, train=train)
-        x = jnp.mean(x, axis=(1, 2))  # global average pool
-        return nn.Dense(self.num_classes, dtype=jnp.float32, name="head")(x)
+            with jax.named_scope(f"stage{stage + 1}"):
+                for block in range(size):
+                    strides = 2 if stage > 0 and block == 0 else 1
+                    x = BottleneckBlock(self.width * (2 ** stage), strides,
+                                        self.compute_dtype,
+                                        self.norm_dtype)(x, train=train)
+        with jax.named_scope("head"):
+            x = jnp.mean(x, axis=(1, 2))  # global average pool
+            return nn.Dense(self.num_classes, dtype=jnp.float32,
+                            name="head")(x)
 
 
 def _dtypes(config: dict) -> dict:

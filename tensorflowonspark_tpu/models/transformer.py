@@ -80,23 +80,26 @@ class Attention(nn.Module):
         q, k, v = dense("q_proj")(x), dense("k_proj")(x), dense("v_proj")(x)
         if self.decode:
             return self._decode_step(x, q, k, v)
-        positions = jnp.arange(s)
-        q = apply_rope(q, positions, self.rope_theta)
-        k = apply_rope(k, positions, self.rope_theta)
-        q = constrain(q, P(BATCH, "sp", "tp", None))
-        k = constrain(k, P(BATCH, "sp", "tp", None))
-        v = constrain(v, P(BATCH, "sp", "tp", None))
-        if self.attn_impl in ("ring", "ulysses"):
-            if self.mesh is None:
-                raise ValueError("ring/ulysses attention needs mesh=")
-            from tensorflowonspark_tpu.parallel.sp import (
-                sequence_parallel_attention,
-            )
-            out = sequence_parallel_attention(self.mesh, q, k, v, causal=True,
-                                              impl=self.attn_impl)
-        else:
-            impl = None if self.attn_impl == "auto" else self.attn_impl
-            out = flash_attention(q, k, v, causal=True, impl=impl)
+        if self.attn_impl in ("ring", "ulysses") and self.mesh is None:
+            raise ValueError("ring/ulysses attention needs mesh=")
+        # named scope: rope, layout and the kernel (both halves of its
+        # VJP) carry "attention" in their op names, whatever XLA fuses
+        with jax.named_scope("attention"):
+            positions = jnp.arange(s)
+            q = apply_rope(q, positions, self.rope_theta)
+            k = apply_rope(k, positions, self.rope_theta)
+            q = constrain(q, P(BATCH, "sp", "tp", None))
+            k = constrain(k, P(BATCH, "sp", "tp", None))
+            v = constrain(v, P(BATCH, "sp", "tp", None))
+            if self.attn_impl in ("ring", "ulysses"):
+                from tensorflowonspark_tpu.parallel.sp import (
+                    sequence_parallel_attention,
+                )
+                out = sequence_parallel_attention(
+                    self.mesh, q, k, v, causal=True, impl=self.attn_impl)
+            else:
+                impl = None if self.attn_impl == "auto" else self.attn_impl
+                out = flash_attention(q, k, v, causal=True, impl=impl)
         out = nn.DenseGeneral(x.shape[-1], axis=(-2, -1), use_bias=False,
                               name="o_proj", dtype=self.compute_dtype)(out)
         return out
@@ -156,10 +159,11 @@ class SwiGLU(nn.Module):
     def __call__(self, x):
         dense = lambda n, name: nn.Dense(  # noqa: E731
             n, use_bias=False, name=name, dtype=self.compute_dtype)
-        gate = jax.nn.silu(dense(self.d_ff, "gate_proj")(x))
-        up = dense(self.d_ff, "up_proj")(x)
-        h = constrain(gate * up, P(BATCH, "sp", "tp"))
-        return dense(x.shape[-1], "down_proj")(h)
+        with jax.named_scope("mlp"):
+            gate = jax.nn.silu(dense(self.d_ff, "gate_proj")(x))
+            up = dense(self.d_ff, "up_proj")(x)
+            h = constrain(gate * up, P(BATCH, "sp", "tp"))
+            return dense(x.shape[-1], "down_proj")(h)
 
 
 class Block(nn.Module):
@@ -238,9 +242,10 @@ class Transformer(nn.Module):
         x = RMSNorm(name="final_norm")(x)
         if self.return_hidden:
             return x
-        logits = nn.Dense(self.vocab_size, use_bias=False, name="lm_head",
-                          dtype=self.compute_dtype)(x)
-        return constrain(logits.astype(jnp.float32), P(BATCH, "sp", None))
+        with jax.named_scope("lm_head_loss"):   # the loss half: make_loss_fn
+            logits = nn.Dense(self.vocab_size, use_bias=False, name="lm_head",
+                              dtype=self.compute_dtype)(x)
+            return constrain(logits.astype(jnp.float32), P(BATCH, "sp", None))
 
 
 @register("transformer")
@@ -462,9 +467,10 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
             b, s, d = h.shape
             h = h[:, :-1].reshape(b * (s - 1), d)
             targets = ids[:, 1:].reshape(-1)
-            nll = blockwise_cross_entropy(
-                h, params["lm_head"]["kernel"].astype(h.dtype), targets,
-                chunk=vocab_chunk)
+            with jax.named_scope("lm_head_loss"):
+                nll = blockwise_cross_entropy(
+                    h, params["lm_head"]["kernel"].astype(h.dtype), targets,
+                    chunk=vocab_chunk)
             return _reduce(nll.reshape(b, s - 1), batch, updates)
 
         return fused_loss_fn
@@ -473,9 +479,11 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
         ids = batch["input_ids"]
         logits, updates = model.apply({"params": params}, ids,
                                       mutable=["aux_loss"])
-        logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
-        targets = ids[:, 1:]
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        with jax.named_scope("lm_head_loss"):
+            logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
+            targets = ids[:, 1:]
+            nll = -jnp.take_along_axis(logp, targets[..., None],
+                                       axis=-1)[..., 0]
         return _reduce(nll, batch, updates)
 
     return loss_fn

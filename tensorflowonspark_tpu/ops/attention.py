@@ -284,9 +284,10 @@ def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, kv_offset,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_attention_tpu(q, k, v, causal, sm_scale, kv_offset,
                          block_q, block_k, interpret):
-    out, _ = _flash_fwd_pallas(q, k, v, causal=causal, sm_scale=sm_scale,
-                               kv_offset=kv_offset, block_q=block_q,
-                               block_k=block_k, interpret=interpret)
+    with jax.named_scope("flash_fwd"):
+        out, _ = _flash_fwd_pallas(q, k, v, causal=causal, sm_scale=sm_scale,
+                                   kv_offset=kv_offset, block_q=block_q,
+                                   block_k=block_k, interpret=interpret)
     return out
 
 
@@ -302,12 +303,13 @@ def _flash_bwd_rule(causal, sm_scale, kv_offset, block_q, block_k, interpret,
     # Memory-efficient recompute backward: VJP through the blockwise scan
     # (each block is checkpointed, so peak memory stays O(S·block_k)).
     q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: blockwise_attention(
-            q_, k_, v_, causal=causal, sm_scale=sm_scale,
-            block_k=block_k, kv_offset=kv_offset),
-        q, k, v)
-    return vjp(g)
+    with jax.named_scope("flash_bwd"):
+        _, vjp = jax.vjp(
+            lambda q_, k_, v_: blockwise_attention(
+                q_, k_, v_, causal=causal, sm_scale=sm_scale,
+                block_k=block_k, kv_offset=kv_offset),
+            q, k, v)
+        return vjp(g)
 
 
 _flash_attention_tpu.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from tensorflowonspark_tpu import telemetry
 from tensorflowonspark_tpu.parallel.mesh import batch_sharding, replicated
 
 
@@ -105,8 +106,9 @@ def make_train_step(
 
     def grads_and_metrics(params: Any, batch: Any) -> tuple[Any, dict]:
         if accum_steps == 1:
-            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, batch)
+            with jax.named_scope("loss_and_grad"):
+                (loss, aux), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, batch)
             return grads, {"loss": loss, **aux}
         micro = jax.tree.map(
             lambda x: x.reshape(accum_steps, x.shape[0] // accum_steps,
@@ -114,8 +116,9 @@ def make_train_step(
 
         def body(carry, mb):
             grads_acc, metrics_acc = carry
-            (l, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, mb)
+            with jax.named_scope("loss_and_grad"):
+                (l, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                    params, mb)
             m = {"loss": l, **aux}
             return (jax.tree.map(jnp.add, grads_acc, g),
                     jax.tree.map(jnp.add, metrics_acc, m)), None
@@ -133,8 +136,10 @@ def make_train_step(
         return grads, metrics
 
     def apply_update(state: TrainState, grads: Any) -> TrainState:
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer_update"):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.step + 1)
 
     if cross_host_grad_fn is None:
@@ -185,10 +190,14 @@ def make_bn_train_step(
     """
 
     def step(state: BNTrainState, batch: Any) -> tuple[BNTrainState, dict]:
-        grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
-        (loss, (batch_stats, aux)), grads = grad_fn(state.params, state.batch_stats, batch)
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("loss_and_grad"):
+            grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+            (loss, (batch_stats, aux)), grads = grad_fn(
+                state.params, state.batch_stats, batch)
+        with jax.named_scope("optimizer_update"):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            params = optax.apply_updates(state.params, updates)
         metrics = {"loss": loss, **aux}
         return BNTrainState(params, batch_stats, opt_state, state.step + 1), metrics
 
@@ -236,6 +245,15 @@ def make_batch_iterator(
     caller's jitted step N is still executing — the conversion/transfer cost
     disappears behind the device step instead of serializing with it.  Set
     ``prefetch=0`` for strictly synchronous delivery.
+
+    Every boundary of the producing loop is a ``telemetry.stage``:
+    ``feed.collect`` (inside ``feed.next_batch``), ``batch.convert``
+    (``to_arrays``), ``batch.put`` (``shard_batch``, with the counter
+    ``batch.h2d_bytes``) and ``batch.queue_full`` (the prefetch queue has
+    no room: the device is the bottleneck) — together the whole loop of the
+    prefetch thread, so per batch they add up to the feed's period.  The
+    consumer's side of the queue is ``batch.queue_empty`` (the step loop
+    waits for the feed).
 
     Weighting caveat (applies to the final batches of any uneven run): PAD
     rows (partial final batch) and FILLER rows (a dry host's lockstep
@@ -295,12 +313,14 @@ def _prefetch_iterator(inner, depth: int):
     def _produce() -> None:
         try:
             for item in inner:
-                while not stop.is_set():
-                    try:
-                        q.put(item, timeout=0.1)
-                        break
-                    except _queue.Full:
-                        continue
+                # blocked here = the device is the bottleneck, not the feed
+                with telemetry.stage("batch.queue_full") as blocked:
+                    while not stop.is_set():
+                        try:
+                            q.put(item, timeout=0.1)
+                            break
+                        except _queue.Full:
+                            blocked.tick()
                 if stop.is_set():
                     return
         except BaseException as e:  # noqa: BLE001 - re-raised consumer-side
@@ -318,7 +338,9 @@ def _prefetch_iterator(inner, depth: int):
     thread.start()
     try:
         while True:
-            item = q.get()
+            # blocked here = the step loop waits for the feed
+            with telemetry.stage("batch.queue_empty"):
+                item = q.get()
             if item is END:
                 break
             yield item
@@ -441,9 +463,13 @@ def _batch_iterator(
                 last_item = items[-1]
             if pad_to_batch and len(items) < batch_size:
                 items = list(items) + [items[-1]] * (batch_size - len(items))
-            batch = to_arrays(items)
+            with telemetry.stage("batch.convert"):   # rows -> host arrays
+                batch = to_arrays(items)
             if mesh is not None:
-                batch = shard_batch(mesh, batch)
+                telemetry.counter("batch.h2d_bytes").inc(
+                    sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(batch)))
+                with telemetry.stage("batch.put"):   # host -> device
+                    batch = shard_batch(mesh, batch)
             yield batch, n
             yielded += 1
     finally:

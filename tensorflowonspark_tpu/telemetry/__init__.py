@@ -23,6 +23,13 @@ The framework's observability substrate (stdlib-only):
   events (deaths/restarts/retries/resyncs/reloads/faults) feeds the run
   report's ``"flight"`` timeline and crash dumps.
 
+- **Stages** — ``stage(name)`` marks a layer boundary on a hot path (per
+  batch or chunk, never per record): busy microseconds and calls into the
+  registry (``<name>.us`` / ``<name>.calls``), a ``TraceAnnotation`` on the
+  ``jax.profiler`` timeline when jax is loaded, and a span in the ring
+  under ``TOS_TRACE=1``.  The feed path is split this way (README
+  "Observability" lists the stages).
+
 Master switch: ``TOS_METRICS`` (default on).  Disabled, every accessor
 returns a shared no-op object, so instrumentation costs one dict miss.
 
@@ -55,6 +62,7 @@ from tensorflowonspark_tpu.telemetry.report import (  # noqa: F401
     write_run_report,
 )
 from tensorflowonspark_tpu.telemetry import trace  # noqa: F401
+from tensorflowonspark_tpu.telemetry.trace import stage  # noqa: F401
 
 _lock = threading.Lock()
 _registry: MetricsRegistry | None = None

@@ -27,11 +27,29 @@ before that node died":
   injections; ``TOS_FLIGHT_EVENTS`` sizes it, 0 disables) independent of
   the trace switch, plus ``flight_snapshot()``/``dump_flight()`` so a
   chaos exit leaves a readable timeline behind.
+- **Stages** — :func:`stage` marks a *layer boundary on a hot path*
+  (per batch or per chunk, never per record).  One ``with`` block, three
+  readers: the metrics registry (``<name>.us`` busy microseconds and
+  ``<name>.calls``, always on), the ``jax.profiler`` timeline (a
+  ``TraceAnnotation`` on the thread that did the work, when jax is loaded
+  in this process) and, with ``TOS_TRACE=1``, this module's span ring
+  (unsampled, nested under the enclosing stage of the thread).
 - **Transport** — ``collect_delta()`` drains new spans/events for the
-  heartbeat piggyback (``node.py``), stamped with this process's current
-  clock-offset estimate (driver-monotonic = local-monotonic + offset, the
-  NTP-style midpoint estimate from heartbeat RTTs) so the export can
-  merge per-node streams onto one timeline (``trace_export.py``).
+  heartbeat piggyback (``node.py``), stamped with this process's clock
+  anchor and its current clock-offset estimate so the export can merge
+  per-node streams onto one timeline (``trace_export.py``).
+
+**Clocks.**  Durations and span starts are ``time.monotonic()`` seconds.
+Every ``Tracer`` takes one *anchor* at creation — ``(time.monotonic(),
+time.time_ns(), hostname)`` — and ships it with each stream, so the export
+writes absolute microseconds since the Unix epoch.  That is the clock of a
+``jax.profiler`` trace too (an xplane event starts at the ``Task
+Environment`` plane's ``profile_start_time``, CLOCK_REALTIME nanoseconds,
+plus its ``start_ns``), so ``trace.json`` lies beside the device timeline.
+The heartbeat offset (driver-monotonic = local-monotonic + offset, the
+NTP-style midpoint estimate from heartbeat RTTs) is used only to merge a
+stream from *another host*, whose wall clock may be skewed; processes of
+one host already share CLOCK_REALTIME.
 
 Disabled (the default), every accessor returns ``None`` / a shared no-op
 span, so instrumented code pays one attribute check.
@@ -42,6 +60,8 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import socket
+import sys
 import threading
 import time
 from typing import Any, NamedTuple
@@ -152,8 +172,60 @@ class _NullSpan:
     def __exit__(self, *exc) -> None:
         return None
 
+    def tick(self) -> None:     # a disabled stage (see _Stage.tick)
+        return None
+
 
 NULL_SPAN = _NullSpan()
+
+
+class _Stage:
+    """``with telemetry.stage(name):`` — see :func:`stage`."""
+
+    __slots__ = ("_name", "_us", "_calls", "_tracer", "_annotation", "_t0",
+                 "_mark", "_sid", "_parent")
+
+    def __init__(self, name: str, us, calls, tracer: "Tracer", annotation):
+        self._name = name
+        self._us = us
+        self._calls = calls
+        self._tracer = tracer
+        self._annotation = annotation
+
+    def __enter__(self) -> "_Stage":
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        tracer = self._tracer
+        if tracer.enabled:
+            local = tracer._local
+            self._parent = getattr(local, "stage", None)
+            self._sid = local.stage = tracer._new_id()
+        else:
+            self._sid = None    # decided here: exit records iff enter did
+        self._t0 = self._mark = time.monotonic()
+        return self
+
+    def tick(self) -> None:
+        """Add the time since enter (or the last tick) to ``<name>.us`` now,
+        without ending the stage.  A stage that BLOCKS in slices (a bounded
+        ``put`` retried every 100 ms) ticks once a slice, so that a reader
+        of counter deltas over a window inherits at most one slice of a wait
+        that began before its window — not the whole of it at exit."""
+        now = time.monotonic()
+        self._us.inc(int((now - self._mark) * 1e6 + 0.5))
+        self._mark = now
+
+    def __exit__(self, *exc) -> None:
+        self.tick()
+        self._calls.inc()
+        if self._sid is not None:
+            tracer = self._tracer
+            tracer._local.stage = self._parent
+            tracer.record_span(self._name,
+                               TraceContext(tracer._loop_trace, self._sid),
+                               self._parent, self._t0, self._mark - self._t0)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
 
 
 class Tracer:
@@ -195,6 +267,11 @@ class Tracer:
         #: driver itself).  Last-write-wins float: atomic attribute store.
         self.clock_offset: float | None = None
         self.clock_rtt: float | None = None
+        #: (monotonic seconds, epoch nanoseconds, host) read together once:
+        #: what turns a span's monotonic start into the profiler's clock
+        self.anchor = (time.monotonic(), time.time_ns(), socket.gethostname())
+        #: the one trace every stage of this process belongs to ("the loop")
+        self._loop_trace = self._new_id()
 
     # -- id allocation / sampling ---------------------------------------------
 
@@ -271,6 +348,16 @@ class Tracer:
             return _LiveSpan(self, name, ctx, None, tags)
         return _LiveSpan(self, name, self.derive(parent), parent[1], tags)
 
+    def stage(self, name: str, registry):
+        """See the module-level :func:`stage`; ``registry`` is the metrics
+        registry whose ``<name>.us`` / ``<name>.calls`` counters it feeds."""
+        if not registry.enabled:
+            return NULL_SPAN
+        annotate = _trace_annotation()
+        return _Stage(name, registry.counter(name + ".us"),
+                      registry.counter(name + ".calls"), self,
+                      annotate(name) if annotate is not None else None)
+
     # -- flight recorder ------------------------------------------------------
 
     def event(self, kind: str, **fields) -> None:
@@ -298,7 +385,8 @@ class Tracer:
             spans.extend(ring.tail(span_limit))
         spans.sort(key=lambda s: s["t0"])
         return {"events": list(events), "spans": spans,
-                "clock_offset": self.clock_offset}
+                "clock_offset": self.clock_offset,
+                "anchor": list(self.anchor)}
 
     # -- transport (heartbeat piggyback) --------------------------------------
 
@@ -354,6 +442,7 @@ class Tracer:
                 payload["events"] = events
         if not payload:
             return None
+        payload["anchor"] = list(self.anchor)
         if self.clock_offset is not None:
             payload["offset"] = self.clock_offset
             payload["rtt"] = self.clock_rtt
@@ -464,6 +553,45 @@ def derive(parent: TraceContext | None) -> TraceContext | None:
 def span(name: str, parent: TraceContext | None = None,
          tags: dict | None = None, root: bool = False):
     return get_tracer().span(name, parent, tags, root=root)
+
+
+_annotation_cls = None   # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` if jax is ALREADY loaded in this
+    process, else None.  Never imports jax (telemetry stays stdlib-only, and
+    a driver that must stay off the chip stays off it); the class is kept
+    once found, so the steady state is one global read."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        _annotation_cls = getattr(profiler, "TraceAnnotation", None)
+    return _annotation_cls
+
+
+def stage(name: str):
+    """Context manager for a *layer boundary on a hot path* — enter it per
+    batch or per chunk, NEVER per record.  On exit it
+
+    - adds the elapsed microseconds to the counter ``<name>.us`` and 1 to
+      ``<name>.calls`` (lock-free per-thread cells: busy time and count are
+      recorded where the work happens, with no lock, reservoir or sampling).
+      Under ``TOS_METRICS=0`` the whole stage is the shared no-op;
+    - has been a ``jax.profiler.TraceAnnotation(name)`` for the duration, if
+      jax is loaded in this process: with a profiler trace running and its
+      host tracer on, the stage is on the profiler's own timeline, on the
+      thread that did the work — the clock of the device ops;
+    - with ``TOS_TRACE=1``, is also recorded in this thread's span ring,
+      unsampled, its parent the enclosing stage of the thread and its trace
+      id one per process, so ``<log_dir>/trace.json`` shows the loop.
+
+    A stage that blocks in slices calls ``.tick()`` on the object the
+    ``with`` binds, once a slice (see :meth:`_Stage.tick`)."""
+    from tensorflowonspark_tpu import telemetry
+
+    return get_tracer().stage(name, telemetry.get_registry())
 
 
 def record_span(name: str, ctx: TraceContext | None, parent: int | None,

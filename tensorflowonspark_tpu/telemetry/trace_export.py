@@ -1,11 +1,15 @@
 """Merge per-process span streams into one Chrome-trace-format timeline.
 
-Every process records spans against its own ``time.monotonic()`` clock;
-the heartbeat transport ships each node's NTP-style clock-offset estimate
-(driver-monotonic = node-monotonic + offset, midpoint of the heartbeat
-round-trip) along with its spans.  This module folds the per-node streams
-onto the driver timeline and emits the Chrome trace event format — one
-``trace.json`` loadable in Perfetto (https://ui.perfetto.dev) or
+Every process records spans against its own ``time.monotonic()`` clock and
+ships, with its spans, the tracer's *anchor* (one ``(monotonic, epoch
+nanoseconds, host)`` reading taken at creation) and its NTP-style
+clock-offset estimate (driver-monotonic = node-monotonic + offset, midpoint
+of the heartbeat round-trip).  This module folds the per-node streams onto
+ONE absolute timeline — microseconds since the Unix epoch, the clock a
+``jax.profiler`` trace is on (``profile_start_time`` of its ``Task
+Environment`` plane + an event's ``start_ns``), so a span here and a device
+op there can be laid side by side — and emits the Chrome trace event format:
+one ``trace.json`` loadable in Perfetto (https://ui.perfetto.dev) or
 ``chrome://tracing``:
 
 - each stream becomes one "process" track (metadata ``process_name``
@@ -16,6 +20,13 @@ onto the driver timeline and emits the Chrome trace event format — one
 - flight-recorder events are instant (``ph: "i"``) events on the same
   timeline, so a chaos kill renders as a mark between the victim's last
   span and the router's retry.
+
+Which clock places a stream: its own anchor — processes of one host share
+CLOCK_REALTIME, no estimate needed.  Only a stream from ANOTHER host than
+the driver's (whose wall clock may be skewed) goes through the heartbeat
+offset onto the driver's clock, then through the driver's anchor.  A stream
+with no anchor (a file written before anchors existed) keeps
+driver-monotonic microseconds.
 
 Standalone CLI (merge + validate a run's per-node files)::
 
@@ -40,10 +51,10 @@ STREAM_SCHEMA = "tos-trace-stream-v1"
 
 
 def build_stream(key: str, spans: list, events: list,
-                 offset: float | None) -> dict:
+                 offset: float | None, anchor=None) -> dict:
     """One per-process stream document (the ``trace_<key>.json`` shape)."""
     return {"schema": STREAM_SCHEMA, "node": key,
-            "clock_offset": offset, "spans": list(spans),
+            "clock_offset": offset, "anchor": anchor, "spans": list(spans),
             "events": list(events)}
 
 
@@ -52,14 +63,31 @@ def _stream_offset(stream: dict) -> float | None:
     return float(off) if off is not None else None
 
 
+def _epoch_clock(stream: dict, driver_anchor):
+    """``monotonic seconds -> epoch microseconds`` for one stream (see the
+    module docstring for which of anchor and offset applies)."""
+    anchor = stream.get("anchor")
+    offset = _stream_offset(stream)
+    if (anchor and driver_anchor and offset is not None
+            and anchor[2] != driver_anchor[2]):
+        anchor, shift = driver_anchor, offset   # another host: driver's clock
+    elif anchor:
+        shift = 0.0
+    else:
+        return lambda t0: map_time(t0, offset) * 1e6
+    mono, epoch_ns = float(anchor[0]), int(anchor[1])
+    return lambda t0: (t0 + shift - mono) * 1e6 + epoch_ns / 1e3
+
+
 def merge_streams(streams: dict[str, dict]) -> dict:
     """``{key: stream}`` -> Chrome trace document.
 
     ``stream`` is a ``build_stream`` document (or a flight dump: same
-    ``spans``/``events``/``clock_offset`` fields).  Timestamps shift so
-    the earliest event lands at t=0.
+    ``spans``/``events``/``clock_offset``/``anchor`` fields).  Timestamps
+    are absolute: microseconds since the Unix epoch.
     """
-    raw: list[tuple[float, dict]] = []  # (driver-mono seconds, event)
+    raw: list[tuple[float, dict]] = []  # (epoch microseconds, event)
+    driver_anchor = (streams.get("driver") or {}).get("anchor")
     trace_events: list[dict] = []
     keys = sorted(streams)
     pids = {key: i + 1 for i, key in enumerate(keys)}
@@ -70,7 +98,7 @@ def merge_streams(streams: dict[str, dict]) -> dict:
     seen_events: set = set()
     for key in sorted(keys, key=lambda k: (k.startswith("flight:"), k)):
         stream = streams[key]
-        offset = _stream_offset(stream)
+        clock = _epoch_clock(stream, driver_anchor)
         pid = pids[key]
         trace_events.append({"ph": "M", "name": "process_name", "pid": pid,
                              "tid": 0, "args": {"name": key}})
@@ -79,7 +107,7 @@ def merge_streams(streams: dict[str, dict]) -> dict:
             if ident in seen_spans:
                 continue
             seen_spans.add(ident)
-            t = map_time(float(span["t0"]), offset)
+            t = clock(float(span["t0"]))
             ev = {"ph": "X", "cat": "span", "name": str(span["n"]),
                   "pid": pid, "tid": int(span.get("th") or 0) % (1 << 31),
                   "ts": t, "dur": max(0.0, float(span.get("d") or 0.0)) * 1e6,
@@ -95,18 +123,18 @@ def merge_streams(streams: dict[str, dict]) -> dict:
             if ident in seen_events:
                 continue
             seen_events.add(ident)
-            t = map_time(float(fev.get("t0", 0.0)), offset)
+            t = clock(float(fev.get("t0", 0.0)))
             args = {k: v for k, v in fev.items()
                     if k not in ("kind", "t0", "t", "node")}
             raw.append((t, {"ph": "i", "cat": "flight", "s": "g",
                             "name": str(fev.get("kind", "event")),
                             "pid": pid, "tid": 0, "ts": t, "args": args}))
-    t_base = min((t for t, _ in raw), default=0.0)
     for t, ev in sorted(raw, key=lambda p: p[0]):
-        ev["ts"] = round((t - t_base) * 1e6, 3)
+        ev["ts"] = round(t, 3)
         trace_events.append(ev)
     return {"traceEvents": trace_events, "displayTimeUnit": "ms",
-            "otherData": {"format": "tos-trace-v1", "streams": keys}}
+            "otherData": {"format": "tos-trace-v1", "streams": keys,
+                          "clock": "microseconds since the Unix epoch"}}
 
 
 def validate_chrome_trace(doc: dict) -> int:

@@ -1,0 +1,94 @@
+"""``jax.named_scope`` where the device time goes (ISSUE 23): each train
+step lowered at tiny widths carries the scope names in its HLO ``op_name``
+metadata, so a trace viewer and a later reduction find them after a
+refactor.  Metadata only: nothing here runs a step."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from tensorflowonspark_tpu.models import registry, resnet, transformer
+from tensorflowonspark_tpu.parallel import dp
+
+
+def _hlo_op_names(lowered) -> str:
+    """The lowered step as an HLO module proto: every instruction's
+    ``metadata.op_name`` (``jit(step)/loss_and_grad/...``) is in it as plain
+    bytes (``as_hlo_text`` prints no metadata)."""
+    proto = lowered.compiler_ir(dialect="hlo").as_serialized_hlo_module_proto()
+    return proto.decode("latin-1")
+
+
+def _lm_step_text(**model_overrides) -> str:
+    model = registry.build({"model": "transformer", "vocab_size": 64,
+                            "d_model": 32, "n_layers": 1, "n_heads": 2,
+                            "d_ff": 64, **model_overrides})
+    ids = jnp.zeros((2, 16), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), ids)["params"])
+    optimizer = optax.adamw(1e-3)
+    state = jax.eval_shape(lambda p: dp.TrainState.create(p, optimizer),
+                           params)
+    step = dp.make_train_step(transformer.make_loss_fn(model), optimizer)
+    return _hlo_op_names(step.lower(state, {"input_ids": ids}))
+
+
+def test_lm_step_names_attention_mlp_head_and_both_step_halves():
+    text = _lm_step_text(attn_impl="pallas_interpret")
+    for scope in ("loss_and_grad", "optimizer_update", "attention", "mlp",
+                  "lm_head_loss", "flash_fwd", "flash_bwd"):
+        assert f"/{scope}/" in text, scope
+
+
+def test_fused_head_loss_is_named_too():
+    model = registry.build({"model": "transformer", "vocab_size": 64,
+                            "d_model": 32, "n_layers": 1, "n_heads": 2,
+                            "d_ff": 64})
+    ids = jnp.zeros((2, 16), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), ids)["params"])
+    loss_fn = transformer.make_loss_fn(model, vocab_chunk=32)
+    text = _hlo_op_names(jax.jit(loss_fn).lower(params, {"input_ids": ids}))
+    assert "/lm_head_loss/" in text
+
+
+def test_gradient_accumulation_keeps_the_step_scopes():
+    model = registry.build({"model": "transformer", "vocab_size": 64,
+                            "d_model": 32, "n_layers": 1, "n_heads": 2,
+                            "d_ff": 64})
+    ids = jnp.zeros((4, 16), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), ids)["params"])
+    optimizer = optax.sgd(1e-2)
+    state = jax.eval_shape(lambda p: dp.TrainState.create(p, optimizer),
+                           params)
+    step = dp.make_train_step(transformer.make_loss_fn(model), optimizer,
+                              accum_steps=2)
+    text = _hlo_op_names(step.lower(state, {"input_ids": ids}))
+    assert "/loss_and_grad/" in text and "/optimizer_update/" in text
+
+
+@pytest.mark.parametrize("stem,stages", [("imagenet", 4), ("cifar", 3)])
+def test_resnet_step_names_every_stage(stem, stages):
+    config = ({"model": "resnet50", "num_classes": 10, "width": 8}
+              if stem == "imagenet"
+              else {"model": "resnet_cifar", "depth_blocks": 1, "width": 8})
+    model = registry.build(config)
+    size = 32
+    images = jnp.zeros((2, size, size, 3), jnp.float32)
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), images, train=False))
+    optimizer = optax.sgd(0.1, momentum=0.9)
+    state = jax.eval_shape(
+        lambda v: dp.BNTrainState.create(v["params"], v["batch_stats"],
+                                         optimizer), variables)
+    step = dp.make_bn_train_step(resnet.make_loss_fn(model), optimizer)
+    text = _hlo_op_names(step.lower(
+        state, {"image": images, "label": jnp.zeros((2,), jnp.int32)}))
+    assert len(model.stage_sizes) == stages
+    for scope in ["loss_and_grad", "optimizer_update", "stem", "head"] + [
+            f"stage{i + 1}" for i in range(stages)]:
+        assert f"/{scope}/" in text, scope
