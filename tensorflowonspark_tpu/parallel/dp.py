@@ -246,12 +246,37 @@ def make_batch_iterator(
     disappears behind the device step instead of serializing with it.  Set
     ``prefetch=0`` for strictly synchronous delivery.
 
+    The unit of conversion and placement is ONE DEVICE'S SHARD of the batch,
+    not the batch.  Where this process's devices hold more than one row
+    range of it (``mesh.batch_shards``: four on ``dp=4``, one per ``tp``
+    group on ``dp × tp``), ``to_arrays`` is called once per range, all at
+    once on threads the iterator owns (``batch-convert_N``); each thread
+    ``device_put``s its arrays to the device(s) of its range as soon as they
+    exist, and the global arrays are assembled from those under the sharding
+    ``shard_batch`` gives.  No global host array is made.  With one range
+    (one device, or ``mesh=None``) it is ``to_arrays(items)`` then
+    ``shard_batch``, inline.
+
+    That asks of ``to_arrays`` what ``shard_batch`` half asks already: every
+    leaf leads with the batch dimension, and row *i* of the output depends on
+    item *i* alone.  A converter that breaks it visibly (one that pads to the
+    longest row of ITS batch, say) is seen: if the ranges' outputs disagree
+    in tree structure, dtype or trailing shape, or a leaf's leading
+    dimension is not the range's row count, that batch and every later one
+    of this iterator is converted whole, as before, and counted in
+    ``batch.convert_whole``.  One that breaks it invisibly (values
+    normalised over the batch) must not be passed with a multi-device mesh.
+
     Every boundary of the producing loop is a ``telemetry.stage``:
-    ``feed.collect`` (inside ``feed.next_batch``), ``batch.convert``
-    (``to_arrays``), ``batch.put`` (``shard_batch``, with the counter
-    ``batch.h2d_bytes``) and ``batch.queue_full`` (the prefetch queue has
-    no room: the device is the bottleneck) — together the whole loop of the
-    prefetch thread, so per batch they add up to the feed's period.  The
+    ``feed.collect`` (inside ``feed.next_batch``), ``batch.convert`` (wall
+    time from handing the rows out until every range is converted and on its
+    way to its device; with one range, the ``to_arrays`` call), ``batch.put``
+    (the assembly; with one range, ``shard_batch``; the counter
+    ``batch.h2d_bytes`` beside it) and ``batch.queue_full`` (the prefetch
+    queue has no room: the device is the bottleneck) — together the whole
+    loop of the prefetch thread, so per batch they add up to the feed's
+    period.  ``batch.convert_slice`` is each worker's ``to_arrays`` call
+    (thread-microseconds; its calls per batch are the ranges).  The
     consumer's side of the queue is ``batch.queue_empty`` (the step loop
     waits for the feed).
 
@@ -352,6 +377,64 @@ def _prefetch_iterator(inner, depth: int):
         thread.join(timeout=30.0)
 
 
+class _Shard(NamedTuple):
+    """One device shard of a batch, converted and on its way to its devices."""
+
+    treedef: Any
+    leaves: list    # per leaf: (trailing shape, dtype)
+    nbytes: int
+    placed: list    # per leaf: one single-device array per device of the shard
+
+
+class _ShardedConvert:
+    """``to_arrays`` and ``device_put`` per device shard of a batch, each
+    shard on a thread of its own; see ``make_batch_iterator``."""
+
+    def __init__(self, mesh, to_arrays: Callable[[list], Any],
+                 total: int, owners: list[list]):
+        from concurrent.futures import ThreadPoolExecutor
+
+        self.mesh, self.to_arrays = mesh, to_arrays
+        self.total, self.owners = total, owners
+        self.pool = ThreadPoolExecutor(len(owners),
+                                       thread_name_prefix="batch-convert")
+
+    def _shard(self, items: list, devices: list) -> _Shard | None:
+        with telemetry.stage("batch.convert_slice"):
+            leaves, treedef = jax.tree.flatten(self.to_arrays(items))
+        if any(getattr(x, "shape", ())[:1] != (len(items),) for x in leaves):
+            return None
+        return _Shard(treedef, [(x.shape[1:], x.dtype) for x in leaves],
+                      sum(x.nbytes for x in leaves),
+                      [[jax.device_put(x, d) for d in devices] for x in leaves])
+
+    def convert(self, items: list) -> list[_Shard] | None:
+        """Every shard this process holds, in row order; None where
+        ``to_arrays`` shows itself not to be row-wise."""
+        rows = len(items) // len(self.owners)
+        shards = [f.result() for f in [
+            self.pool.submit(self._shard, items[j * rows:(j + 1) * rows], devices)
+            for j, devices in enumerate(self.owners)]]
+        first = shards[0]
+        if any(s is None or (s.treedef, s.leaves) != (first.treedef, first.leaves)
+               for s in shards):
+            return None
+        return shards
+
+    def assemble(self, shards: list[_Shard], rows: int):
+        """The global arrays of a batch of ``rows`` process-local rows."""
+        first = shards[0]
+        rows = rows // len(self.owners) * self.total
+        return jax.tree.unflatten(first.treedef, [
+            jax.make_array_from_single_device_arrays(
+                (rows, *trail), batch_sharding(self.mesh, len(trail)),
+                [a for s in shards for a in s.placed[i]])
+            for i, (trail, _dtype) in enumerate(first.leaves)])
+
+    def close(self) -> None:
+        self.pool.shutdown(wait=True, cancel_futures=True)
+
+
 def _batch_iterator(
     feed,
     batch_size: int,
@@ -362,7 +445,8 @@ def _batch_iterator(
     max_steps: int = -1,
     lockstep: bool | None = None,
 ):
-    from tensorflowonspark_tpu.parallel.mesh import is_multiprocess, shard_batch
+    from tensorflowonspark_tpu.parallel.mesh import (
+        batch_shards, is_multiprocess, shard_batch)
 
     if getattr(feed, "input_mapping", None):
         raise ValueError(
@@ -391,6 +475,13 @@ def _batch_iterator(
             "host must contribute the same local batch shape or the global "
             "step (batch assembly / gradient collective) diverges"
         )
+    # the unit of convert + put is one device's shard; None with one shard
+    sharded = None
+    if mesh is not None:
+        total, owners = batch_shards(mesh)
+        if len(owners) > 1:
+            sharded = _ShardedConvert(mesh, to_arrays, total, owners)
+    whole = False      # to_arrays showed itself not to be row-wise
     last_item = None   # filler source for multi-process end-of-data rounds
     exhausted = False  # feed hit end-of-feed: NEVER call next_batch again
     dry = False        # exhausted and nothing left to yield
@@ -463,9 +554,22 @@ def _batch_iterator(
                 last_item = items[-1]
             if pad_to_batch and len(items) < batch_size:
                 items = list(items) + [items[-1]] * (batch_size - len(items))
+            shards = None
             with telemetry.stage("batch.convert"):   # rows -> host arrays
-                batch = to_arrays(items)
-            if mesh is not None:
+                if (sharded is not None and not whole
+                        and len(items) % len(sharded.owners) == 0):
+                    shards = sharded.convert(items)
+                    whole = shards is None
+                if shards is None:
+                    batch = to_arrays(items)
+            if whole:
+                telemetry.counter("batch.convert_whole").inc()
+            if shards is not None:
+                telemetry.counter("batch.h2d_bytes").inc(
+                    sum(s.nbytes for s in shards))
+                with telemetry.stage("batch.put"):   # the shards, assembled
+                    batch = sharded.assemble(shards, len(items))
+            elif mesh is not None:
                 telemetry.counter("batch.h2d_bytes").inc(
                     sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(batch)))
                 with telemetry.stage("batch.put"):   # host -> device
@@ -473,6 +577,8 @@ def _batch_iterator(
             yield batch, n
             yielded += 1
     finally:
+        if sharded is not None:
+            sharded.close()
         if pending is not None and ctx is not None:
             # The caller abandoned the iterator (break / exception in its
             # train step) with a vote in flight; the unread reply would

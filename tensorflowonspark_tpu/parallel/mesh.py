@@ -92,6 +92,24 @@ def batch_sharding(mesh: Mesh, extra_dims: int = 0) -> NamedSharding:
     return NamedSharding(mesh, P(("dp", "fsdp"), *([None] * extra_dims)))
 
 
+def batch_shards(mesh: Mesh) -> tuple[int, list[list[jax.Device]]]:
+    """Who holds which rows of a batch under ``batch_sharding``.
+
+    Returns the number of row shards of the GLOBAL batch (``dp × fsdp``) and,
+    for every shard this process holds, in row order, the addressable devices
+    that hold it: the devices along ``tp``/``sp``/``ep``/``pp`` share one.
+    Shard ``j`` of the list is rows ``[j·r, (j+1)·r)`` of a process-local
+    batch of ``r × len(list)`` rows.
+    """
+    axes = spec(mesh)
+    total = axes.dp * axes.fsdp
+    owners: dict[int, list[jax.Device]] = {}
+    index_map = batch_sharding(mesh).addressable_devices_indices_map((total,))
+    for device, (rows,) in index_map.items():
+        owners.setdefault(rows.indices(total)[0], []).append(device)
+    return total, [owners[k] for k in sorted(owners)]
+
+
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 

@@ -1,6 +1,7 @@
 """SPMD data-parallel training tests on the virtual 8-device CPU mesh
 (the reference's local-cluster analogue for mesh logic, SURVEY.md §4)."""
 
+import threading
 import time
 
 import numpy as np
@@ -10,7 +11,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from tensorflowonspark_tpu.feeding import DataFeed, FeedQueues
+from tensorflowonspark_tpu import telemetry
+from tensorflowonspark_tpu.feeding import DataFeed, FeedQueues, IteratorFeed
 from tensorflowonspark_tpu.marker import EndOfFeed, EndPartition
 from tensorflowonspark_tpu.parallel.dp import (
     TrainState,
@@ -162,7 +164,6 @@ def test_batch_iterator_prefetch_propagates_errors():
 def test_batch_iterator_prefetch_abandoned_consumer_unblocks():
     """An early break must stop the producer thread promptly instead of
     leaving it blocked on the bounded queue holding the feed."""
-    import threading
 
     before = threading.active_count()
     it = make_batch_iterator(feed_with(list(range(100))), 2,
@@ -173,3 +174,192 @@ def test_batch_iterator_prefetch_abandoned_consumer_unblocks():
     while threading.active_count() > before and time.monotonic() < deadline:
         time.sleep(0.05)
     assert threading.active_count() <= before, "prefetch thread leaked"
+
+
+# -- conversion and placement per device shard (ISSUE 24) ---------------------
+
+SHARD_MESHES = {
+    "dp4": (4, {"dp": 4}, 4),
+    "dp8": (8, {"dp": 8}, 8),
+    "dp2_tp2": (4, {"dp": 2, "tp": 2}, 2),
+    "one_device": (1, {"dp": 1}, 1),
+}
+
+
+def shard_mesh(name):
+    devices, axes, slices = SHARD_MESHES[name]
+    return make_mesh(jax.devices("cpu")[:devices], **axes), slices
+
+
+def rows_to_tree(rows):
+    """Row-wise, every leaf leading with the batch dimension: what
+    ``make_batch_iterator`` asks of a converter."""
+    return {"x": np.stack([np.full((3, 2), r, np.float32) for r in rows]),
+            "y": np.asarray(rows, np.int32)}
+
+
+def expected_items(k, batch, total):
+    items = list(range(batch * k, min(total, batch * (k + 1))))
+    return items + [items[-1]] * (batch - len(items))
+
+
+def assert_same_placement(got, want):
+    """Same tree, shapes, dtypes, sharding, and every addressable shard's
+    device, index and bytes."""
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert (a.shape, a.dtype, a.sharding) == (b.shape, b.dtype, b.sharding)
+        assert a.committed == b.committed
+        assert len(a.addressable_shards) == len(b.addressable_shards)
+        for sa, sb in zip(a.addressable_shards, b.addressable_shards):
+            assert (sa.device, sa.index) == (sb.device, sb.index)
+            np.testing.assert_array_equal(np.asarray(sa.data),
+                                          np.asarray(sb.data))
+
+
+@pytest.fixture
+def counters():
+    """This test's own registry; ``counters()`` reads it."""
+    telemetry.reset(enabled=True)
+    yield lambda: telemetry.snapshot()["counters"]
+    telemetry.reset()
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+@pytest.mark.parametrize("mesh_name", ["dp4", "dp8", "dp2_tp2"])
+def test_sharded_convert_equals_shard_batch_of_the_whole(mesh_name, prefetch):
+    """41 rows in batches of 16: two full batches and a padded final one,
+    each indistinguishable from ``shard_batch(mesh, to_arrays(items))``."""
+    mesh, _ = shard_mesh(mesh_name)
+    got = list(make_batch_iterator(IteratorFeed(iter(range(41))), 16,
+                                   rows_to_tree, mesh=mesh, prefetch=prefetch))
+    assert [n for _, n in got] == [16, 16, 9]
+    for k, (batch, _n) in enumerate(got):
+        want = shard_batch(mesh, rows_to_tree(expected_items(k, 16, 41)))
+        assert_same_placement(batch, want)
+
+
+@pytest.mark.parametrize("mesh_name", [*SHARD_MESHES, None])
+def test_to_arrays_is_called_once_per_device_shard(mesh_name):
+    """Contiguous row ranges, one call each; ONE call with the whole batch
+    on a one-device mesh and without a mesh."""
+    calls, lock = [], threading.Lock()
+
+    def spy(rows):
+        with lock:
+            calls.append((list(rows), threading.current_thread().name))
+        return rows_to_tree(rows)
+
+    mesh, slices = shard_mesh(mesh_name) if mesh_name else (None, 1)
+    got = list(make_batch_iterator(IteratorFeed(iter(range(48))), 16, spy,
+                                   mesh=mesh, prefetch=0))
+    assert len(got) == 3 and len(calls) == 3 * slices
+    per = 16 // slices
+    want = [list(range(lo, lo + per)) for lo in range(0, 48, per)]
+    assert sorted(rows for rows, _ in calls) == want
+    names = {name for _, name in calls}
+    if slices == 1:
+        assert names == {threading.current_thread().name}
+    else:
+        # the pool starts a thread only when none of its own is idle
+        assert 1 <= len(names) <= slices
+        assert all(name.startswith("batch-convert") for name in names)
+
+
+def pads_to_its_longest_row(rows):
+    """Row r is r % 5 + 1 long; the batch is padded to its longest row, so
+    two slices of one batch can differ in trailing shape."""
+    width = max(r % 5 + 1 for r in rows)
+    return {"ids": np.stack([np.pad(np.full(r % 5 + 1, r, np.int32),
+                                    (0, width - (r % 5 + 1))) for r in rows])}
+
+
+def leaf_without_batch_dimension(rows):
+    return {"x": np.asarray(rows, np.float32),
+            "table": np.arange(8, dtype=np.float32)}
+
+
+@pytest.mark.parametrize("converter,items", [
+    # rows 0..3 are 1..4 long, row 4 is 5 long: the slices of the first
+    # batch [0, 1 | 2, 3] disagree (2 against 4 columns)
+    (pads_to_its_longest_row, list(range(12))),
+    (leaf_without_batch_dimension, list(range(24))),
+])
+def test_converter_that_is_not_rowwise_falls_back_whole(converter, items,
+                                                        counters):
+    mesh, _ = shard_mesh("dp4")
+    batch_size = 4 if converter is pads_to_its_longest_row else 8
+    calls = []
+
+    def spy(rows):
+        calls.append(len(rows))
+        return converter(rows)
+
+    got = list(make_batch_iterator(IteratorFeed(iter(items)), batch_size, spy,
+                                   mesh=mesh, prefetch=0))
+    steps = len(items) // batch_size
+    assert len(got) == steps
+    for k, (batch, _n) in enumerate(got):
+        rows = items[k * batch_size:(k + 1) * batch_size]
+        assert_same_placement(batch, shard_batch(mesh, converter(rows)))
+    # the first batch was tried by slices, then it and every later one whole
+    assert sorted(calls[:5]) == [batch_size // 4] * 4 + [batch_size]
+    assert calls[5:] == [batch_size] * (steps - 1)
+    c = counters()
+    assert c["batch.convert_whole"] == steps
+    assert c["batch.convert_slice.calls"] == 4
+    assert c["batch.convert.calls"] == c["batch.put.calls"] == steps
+
+
+@pytest.mark.parametrize("mesh_name", list(SHARD_MESHES))
+def test_sharded_convert_counters_keep_their_meaning(mesh_name, counters):
+    """One ``batch.convert`` and one ``batch.put`` a batch whatever the
+    slices; ``batch.convert_slice`` once a slice; the bytes the arrays'."""
+    mesh, slices = shard_mesh(mesh_name)
+    steps, nbytes = 0, 0
+    for batch, _n in make_batch_iterator(IteratorFeed(iter(range(80))), 16,
+                                         rows_to_tree, mesh=mesh, prefetch=2):
+        steps += 1
+        nbytes += sum(x.nbytes for x in jax.tree.leaves(batch))
+    c = counters()
+    assert steps == 5
+    assert c["batch.convert.calls"] == c["batch.put.calls"] == steps
+    assert c.get("batch.convert_slice.calls", 0) == (
+        slices * steps if slices > 1 else 0)
+    assert c["batch.h2d_bytes"] == nbytes == steps * 16 * (3 * 2 * 4 + 4)
+    assert c.get("batch.convert_whole", 0) == 0
+
+
+def convert_threads():
+    return [t for t in threading.enumerate()
+            if t.name.startswith("batch-convert")]
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_one_slices_exception_reaches_the_consumer(prefetch):
+    def explodes_on_row_21(rows):
+        if 21 in rows:
+            raise ValueError("conversion exploded")
+        return rows_to_tree(rows)
+
+    mesh, _ = shard_mesh("dp4")
+    it = make_batch_iterator(IteratorFeed(iter(range(64))), 16,
+                             explodes_on_row_21, mesh=mesh, prefetch=prefetch)
+    batch, n = next(it)
+    assert n == 16 and batch["y"].shape == (16,)
+    with pytest.raises(ValueError, match="conversion exploded"):
+        next(it)
+    assert convert_threads() == []
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_abandoned_iterator_leaves_no_convert_thread(prefetch):
+    mesh, _ = shard_mesh("dp4")
+    it = make_batch_iterator(IteratorFeed(iter(range(1000))), 16,
+                             rows_to_tree, mesh=mesh, prefetch=prefetch)
+    next(it)
+    alive = len(convert_threads())
+    it.close()
+    assert 1 <= alive <= 4
+    assert convert_threads() == []
+
