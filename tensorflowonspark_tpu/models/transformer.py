@@ -69,6 +69,11 @@ class Attention(nn.Module):
     compute_dtype: Any = jnp.bfloat16
     decode: bool = False          # autoregressive single-token mode (KV cache)
     max_decode_len: int = 0
+    # QK-norm (OLMoE, arXiv:2409.02060 §4.2): an RMSNorm with a learned
+    # scale over the WHOLE n_heads·d_head projection of q and of k, before
+    # the split into heads matters and before RoPE.
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x):
@@ -78,6 +83,13 @@ class Attention(nn.Module):
             (h, dh), axis=-1, use_bias=False, name=name,
             dtype=self.compute_dtype)
         q, k, v = dense("q_proj")(x), dense("k_proj")(x), dense("v_proj")(x)
+        if self.qk_norm:
+            # before the branch: the cache path computes the same model
+            with jax.named_scope("qk_norm"):
+                flat = lambda t, name: RMSNorm(  # noqa: E731
+                    self.norm_eps, name=name)(
+                        t.reshape(b, s, h * dh)).reshape(b, s, h, dh)
+                q, k = flat(q, "q_norm"), flat(k, "k_norm")
         if self.decode:
             return self._decode_step(x, q, k, v)
         if self.attn_impl in ("ring", "ulysses") and self.mesh is None:
@@ -178,23 +190,29 @@ class Block(nn.Module):
     compute_dtype: Any = jnp.bfloat16
     decode: bool = False
     max_decode_len: int = 0
+    norm_eps: float = 1e-6
+    qk_norm: bool = False
+    moe_capacity_factor: Optional[float] = 1.25   # None: dropless routing
+    moe_norm_topk_prob: bool = True
 
     @nn.compact
     def __call__(self, x):
+        norm = lambda name: RMSNorm(self.norm_eps, name=name)  # noqa: E731
         x = x + Attention(self.n_heads, self.d_head, self.rope_theta,
                           self.attn_impl, self.mesh, self.compute_dtype,
-                          self.decode, self.max_decode_len,
-                          name="attn")(RMSNorm(name="attn_norm")(x))
+                          self.decode, self.max_decode_len, self.qk_norm,
+                          self.norm_eps, name="attn")(norm("attn_norm")(x))
         x = constrain(x, P(BATCH, "sp", None))
         if self.n_experts:
             from tensorflowonspark_tpu.parallel.ep import MoEMLP
 
             ffn = MoEMLP(x.shape[-1], self.d_ff, self.n_experts,
-                         self.moe_top_k, compute_dtype=self.compute_dtype,
-                         name="moe")
+                         self.moe_top_k, self.moe_capacity_factor,
+                         compute_dtype=self.compute_dtype,
+                         norm_topk_prob=self.moe_norm_topk_prob, name="moe")
         else:
             ffn = SwiGLU(self.d_ff, self.compute_dtype, name="mlp")
-        x = x + ffn(RMSNorm(name="mlp_norm")(x))
+        x = x + ffn(norm("mlp_norm")(x))
         return constrain(x, P(BATCH, "sp", None))
 
 
@@ -224,6 +242,15 @@ class Transformer(nn.Module):
     # to O(1) per block at ~1/3 extra FLOPs — the standard long-context /
     # large-batch trade on HBM-bound TPUs.
     remat: bool = False
+    # What a published config states beyond the sizes; the defaults are the
+    # model this class built before it had the keys.
+    norm_eps: float = 1e-6   # every RMSNorm
+    qk_norm: bool = False    # see Attention
+    # MoE routing rule: a capacity factor (Switch / GShard: overflow is
+    # dropped) or None for dropless routing; whether the top-k weights are
+    # renormalised to sum to 1 (HF ``norm_topk_prob``).
+    moe_capacity_factor: Optional[float] = 1.25
+    moe_norm_topk_prob: bool = True
 
     @nn.compact
     def __call__(self, input_ids):
@@ -238,8 +265,10 @@ class Transformer(nn.Module):
             x = block_cls(self.n_heads, dh, dff, self.n_experts, self.moe_top_k,
                           self.rope_theta, self.attn_impl, self.mesh,
                           self.compute_dtype, self.decode, self.max_decode_len,
+                          self.norm_eps, self.qk_norm,
+                          self.moe_capacity_factor, self.moe_norm_topk_prob,
                           name=f"block_{i}")(x)
-        x = RMSNorm(name="final_norm")(x)
+        x = RMSNorm(self.norm_eps, name="final_norm")(x)
         if self.return_hidden:
             return x
         with jax.named_scope("lm_head_loss"):   # the loss half: make_loss_fn
@@ -250,6 +279,7 @@ class Transformer(nn.Module):
 
 @register("transformer")
 def build_transformer(config: dict) -> Transformer:
+    capacity = config.get("moe_capacity_factor", 1.25)
     return Transformer(
         vocab_size=int(config.get("vocab_size", 32000)),
         d_model=int(config.get("d_model", 512)),
@@ -263,6 +293,11 @@ def build_transformer(config: dict) -> Transformer:
         attn_impl=config.get("attn_impl", "auto"),
         compute_dtype=jnp.bfloat16 if config.get("bf16", True) else jnp.float32,
         remat=bool(config.get("remat", False)),
+        norm_eps=float(config.get("norm_eps", 1e-6)),
+        qk_norm=bool(config.get("qk_norm", False)),
+        # null / None in the config: dropless
+        moe_capacity_factor=None if capacity is None else float(capacity),
+        moe_norm_topk_prob=bool(config.get("moe_norm_topk_prob", True)),
     )
 
 
@@ -430,12 +465,17 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
     are inputs shifted left; final position predicts a discarded token).
     MoE auxiliary losses are collected from the ``aux_loss`` sow:
     ``load_balance`` leaves weighted by ``aux_loss_coef`` and ``router_z``
-    leaves (ST-MoE z-loss) by ``router_z_coef``.
+    leaves (ST-MoE z-loss) by ``router_z_coef``.  For a model with experts
+    the metrics also carry ``moe_max_load`` and ``moe_min_load``: pairs at
+    the fullest and at the emptiest expert over the mean, averaged over
+    layers (the ``moe_stats`` sow) — a collapsing router shows there first.
 
     ``vocab_chunk > 0`` fuses the lm_head matmul into a blockwise
     cross-entropy (``ops/xent.py``): the ``[B, S, V]`` logits are never
     materialized — the HBM-dominant op at large vocab.  Not for
     tensor-parallel vocab-sharded heads (use the dense path there)."""
+
+    sown = ["aux_loss", "moe_stats"] if model.n_experts else ["aux_loss"]
 
     def _reduce(nll, batch, updates):
         mask = batch.get("loss_mask")
@@ -453,7 +493,14 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
             else:
                 aux = aux + leaf
         total = loss + aux_loss_coef * aux + router_z_coef * z
-        return total, {"lm_loss": loss, "aux_loss": aux, "router_z_loss": z}
+        metrics = {"lm_loss": loss, "aux_loss": aux, "router_z_loss": z}
+        stats: dict[str, list] = {}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+                updates.get("moe_stats", {}))[0]:
+            name = [p.key for p in path if hasattr(p, "key")][-1]
+            stats.setdefault(f"moe_{name}", []).append(leaf)
+        metrics.update({k: jnp.mean(jnp.stack(v)) for k, v in stats.items()})
+        return total, metrics
 
     if vocab_chunk:
         from tensorflowonspark_tpu.ops.xent import blockwise_cross_entropy
@@ -463,7 +510,7 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
         def fused_loss_fn(params, batch):
             ids = batch["input_ids"]
             h, updates = hidden_model.apply({"params": params}, ids,
-                                            mutable=["aux_loss"])
+                                            mutable=sown)
             b, s, d = h.shape
             h = h[:, :-1].reshape(b * (s - 1), d)
             targets = ids[:, 1:].reshape(-1)
@@ -478,7 +525,7 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
     def loss_fn(params, batch):
         ids = batch["input_ids"]
         logits, updates = model.apply({"params": params}, ids,
-                                      mutable=["aux_loss"])
+                                      mutable=sown)
         with jax.named_scope("lm_head_loss"):
             logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
             targets = ids[:, 1:]

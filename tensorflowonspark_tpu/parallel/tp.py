@@ -116,4 +116,6 @@ TRANSFORMER_TP_RULES: Rules = (
     (r"experts_(up|gate)$", P("ep", None, "tp")),
     (r"experts_down$", P("ep", "tp", None)),
     (r"router/kernel$", P()),
+    # QK-norm scales span all heads (the norm is over n_heads·d_head)
+    (r"(q_norm|k_norm)/scale$", P()),
 )

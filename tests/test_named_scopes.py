@@ -43,6 +43,27 @@ def test_lm_step_names_attention_mlp_head_and_both_step_halves():
         assert f"/{scope}/" in text, scope
 
 
+@pytest.mark.parametrize("capacity", [None, 1.25],
+                         ids=["dropless", "capacity"])
+def test_moe_step_names_router_dispatch_experts_combine_and_qk_norm(capacity):
+    """ISSUE 25: the expert layer's four stages under either routing rule,
+    and QK-norm, forward and backward (the readers in
+    ``benchmark/layer_metrics/moe_*.py`` sum device time by these)."""
+    text = _lm_step_text(n_experts=4, moe_top_k=2, qk_norm=True,
+                         moe_capacity_factor=capacity, attn_impl="xla")
+    for scope in ("moe/router", "moe/dispatch", "moe/experts", "moe/combine",
+                  "qk_norm", "optimizer_update"):
+        assert f"/{scope}/" in text, scope
+        backward = [line for line in text.split("jit(step)")
+                    if f"/{scope}/" in line and "transpose(" in line]
+        assert backward or scope == "optimizer_update", scope
+
+
+def test_a_dense_step_has_no_moe_scope():
+    text = _lm_step_text(attn_impl="xla")
+    assert "/moe/" not in text and "/qk_norm/" not in text
+
+
 def test_fused_head_loss_is_named_too():
     model = registry.build({"model": "transformer", "vocab_size": 64,
                             "d_model": 32, "n_layers": 1, "n_heads": 2,
