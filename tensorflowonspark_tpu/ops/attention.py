@@ -4,10 +4,13 @@ The reference framework has no attention anywhere (SURVEY.md §5.7 — its
 models are CNNs/wide-and-deep), but long-context support is first-class in
 this build, so the hot op gets a real TPU kernel:
 
-- ``flash_attention`` — public entry.  On TPU it runs a Pallas online-softmax
-  kernel (forward) with a memory-efficient recompute backward; elsewhere it
-  lowers to ``blockwise_attention`` (a ``lax.scan`` over KV blocks with
-  per-block rematerialisation, so memory stays O(S·block) instead of O(S²)).
+- ``flash_attention`` — public entry.  On TPU it runs Pallas kernels in both
+  directions: an online-softmax forward that keeps its log-sum-exp, and a
+  backward of two passes over the same tiles (dk/dv with the q blocks
+  sequential, dq with the KV blocks sequential) that recompute each tile's
+  softmax weights from it.  Elsewhere it lowers to ``blockwise_attention``
+  (a ``lax.scan`` over KV blocks with per-block rematerialisation, so memory
+  stays O(S·block) instead of O(S²)).
 - ``chunk_attention`` / ``merge_attention`` — the (output, logsumexp)
   chunk-compute and online-softmax merge primitives that
   ``parallel/sp.py``'s ring attention composes over ICI neighbours.
@@ -120,8 +123,8 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
     """Flash-style attention as a ``lax.scan`` over KV blocks.
 
     Differentiable, runs on every backend, and with the per-block
-    ``jax.checkpoint`` memory is O(Sq·block_k) — this is both the CPU test
-    path and the recompute backward for the Pallas kernel.
+    ``jax.checkpoint`` memory is O(Sq·block_k) — ``impl="xla"``: the path off
+    the TPU, and the chunk compute of ulysses attention (``parallel/sp.py``).
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -168,15 +171,57 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU kernel (forward) — online softmax over a sequential k-block grid.
+# Pallas TPU kernels — forward (online softmax over a sequential k-block
+# grid) and backward (a dk/dv pass and a dq pass on the forward's lse).
 # ---------------------------------------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T: contract the last dim of both
+
+
+def _tile_live(q_start, k_start, *, causal: bool, kv_offset: int,
+               block_q: int, sk: int):
+    """Whether the tile of queries from ``q_start`` and keys from ``k_start``
+    (a global position) holds any visible pair: not wholly padding and, under
+    ``causal``, not wholly in the future.  All three kernels skip a dead
+    tile; a further mask (segments, a window) adds its condition here."""
+    live = k_start < kv_offset + sk
+    if causal:
+        live = jnp.logical_and(live, k_start <= q_start + block_q - 1)
+    return live
+
+
+def _tile_interior(q_start, k_start, *, causal: bool, kv_offset: int,
+                   block_k: int, sk: int):
+    """Whether EVERY pair of the tile is visible (it holds no padding and,
+    under ``causal``, lies wholly in the past): the backward kernels build
+    no mask there.  A further mask narrows this as it narrows ``_tile_live``."""
+    interior = k_start + block_k <= kv_offset + sk
+    if causal:
+        interior = jnp.logical_and(interior, k_start + block_k - 1 <= q_start)
+    return interior
+
+
+def _tile_visible(shape, q_dim: int, *, q_start, k_start, causal: bool,
+                  kv_offset: int, sk: int):
+    """The visible pairs of one live tile, queries along ``q_dim`` of
+    ``shape`` and keys along the other: the padded tail of the keys is never
+    visible, nor under ``causal`` a key after its query."""
+    kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+    mask = kpos < kv_offset + sk
+    if causal:
+        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+        mask = jnp.logical_and(mask, kpos <= qpos)
+    return mask
+
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref,
                       *, sm_scale: float, causal: bool, kv_offset: int,
-                      block_q: int, block_k: int, sq: int, sk: int):
-    # m/l scratch and the lse output are lane-replicated to 128 lanes (column
-    # 0 is authoritative) — TPU tiling requires the last dim be 128-aligned.
+                      block_q: int, block_k: int, sk: int):
+    # m/l scratch is lane-replicated to 128 lanes (column 0 is authoritative)
+    # — TPU tiling requires the last dim be 128-aligned.  The lse goes out as
+    # a ROW per (batch, head): a residual of the backward, 128 times smaller
+    # than the replicated columns and lane-dense as its dk/dv pass reads it.
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -189,23 +234,16 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     q_start = iq * block_q
     k_start = kv_offset + ik * block_k
-    # Skip blocks that are entirely in the causal future or entirely padding.
-    live = (k_start + 0) < kv_offset + sk
-    if causal:
-        live = jnp.logical_and(live, k_start <= q_start + block_q - 1)
+    mask_args = dict(causal=causal, kv_offset=kv_offset, sk=sk)
 
-    @pl.when(live)
+    @pl.when(_tile_live(q_start, k_start, block_q=block_q, **mask_args))
     def _attend():
         qb = q_ref[0].astype(jnp.float32)              # [block_q, d]
         kb = k_ref[0].astype(jnp.float32)              # [block_k, d]
         logits = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        mask = kpos < kv_offset + sk
-        if causal:
-            mask = jnp.logical_and(mask, kpos <= qpos)
+            qb, kb, _NT, preferred_element_type=jnp.float32) * sm_scale
+        mask = _tile_visible(logits.shape, 0, q_start=q_start,
+                             k_start=k_start, **mask_args)
         logits = jnp.where(mask, logits, NEG_INF)
         m_prev = m_ref[:]                               # [block_q, 128]
         m_blk = jnp.max(logits, axis=-1, keepdims=True)
@@ -224,35 +262,52 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _finalize():
         l = jnp.maximum(l_ref[:], 1e-30)
         o_ref[0] = (acc_ref[:] / l[:, 0:1]).astype(o_ref.dtype)
-        lse = m_ref[:] + jnp.log(l)
-        lse_ref[0] = jnp.where(l_ref[:] > 0.0, lse, NEG_INF)
+        lse = jnp.where(l_ref[:] > 0.0, m_ref[:] + jnp.log(l), NEG_INF)
+        lse_ref[0] = lse.T[0:1]                  # a row, as it lies in memory
 
 
-def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, kv_offset,
-                      block_q, block_k, interpret):
-    """Run the Pallas forward; returns (out, lse).  Head-major internally."""
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+def _head_major(x, interpret: bool):
+    """``[B, S, H, D]`` -> ``[B*H, S, D_p]``, the layout all three kernels
+    read: one (batch, head) per grid row, D zero-padded to the 128-lane
+    width (not under ``interpret``, which has no tiling)."""
+    b, s, h, d = x.shape
+    d_p = d if interpret else -(-d // 128) * 128
+    x = x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    return jnp.pad(x, ((0, 0), (0, 0), (0, d_p - d)))
 
-    # Head-major [B*H, S, D]; pad S to block multiples and D to the 128 lane.
-    def to_bh(x, s):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
-    qt, kt, vt = to_bh(q, sq), to_bh(k, sk), to_bh(v, sk)
+def _from_head_major(x, b: int, d: int):
+    bh, s, _ = x.shape
+    return x[:, :, :d].reshape(b, bh // b, s, d).transpose(0, 2, 1, 3)
+
+
+def _blocks(sq: int, sk: int, block_q: int, block_k: int):
+    """The tile sides and the tiled lengths of a ``[sq, sk]`` score matrix:
+    a sequence shorter than a block is one block, and every other length is
+    zero-padded up to whole blocks (``_pad_seq``) and masked as padding."""
     block_q = min(block_q, max(8, sq))
     block_k = min(block_k, max(8, sk))
-    sq_p = -(-sq // block_q) * block_q
-    sk_p = -(-sk // block_k) * block_k
-    d_p = max(128, -(-d // 128) * 128) if not interpret else d
-    qt = jnp.pad(qt, ((0, 0), (0, sq_p - sq), (0, d_p - d)))
-    kt = jnp.pad(kt, ((0, 0), (0, sk_p - sk), (0, d_p - d)))
-    vt = jnp.pad(vt, ((0, 0), (0, sk_p - sk), (0, d_p - d)))
+    return (block_q, block_k,
+            -(-sq // block_q) * block_q, -(-sk // block_k) * block_k)
 
-    grid = (b * h, sq_p // block_q, sk_p // block_k)
+
+def _pad_seq(x, s_p: int):
+    return jnp.pad(x, ((0, 0), (0, s_p - x.shape[1]), (0, 0)))
+
+
+def _flash_fwd_pallas(qt, kt, vt, *, causal, sm_scale, kv_offset,
+                      block_q, block_k, interpret):
+    """Run the Pallas forward on head-major ``[B*H, S, D_p]`` operands;
+    returns ``(out [B*H, Sq, D_p], lse [B*H, Sq] float32)``."""
+    bh, sq, d_p = qt.shape
+    sk = kt.shape[1]
+    block_q, block_k, sq_p, sk_p = _blocks(sq, sk, block_q, block_k)
+    qt, kt, vt = _pad_seq(qt, sq_p), _pad_seq(kt, sk_p), _pad_seq(vt, sk_p)
+
+    grid = (bh, sq_p // block_q, sk_p // block_k)
     kernel = functools.partial(
-        _flash_fwd_kernel, sm_scale=scale, causal=causal, kv_offset=kv_offset,
-        block_q=block_q, block_k=block_k, sq=sq, sk=sk)
+        _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
+        kv_offset=kv_offset, block_q=block_q, block_k=block_k, sk=sk)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -263,11 +318,11 @@ def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, kv_offset,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d_p), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, iq, ik: (bh, 0, iq)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq_p, d_p), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq_p, 128), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq_p, d_p), qt.dtype),
+            jax.ShapeDtypeStruct((bh, 1, sq_p), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d_p), jnp.float32),
@@ -276,40 +331,207 @@ def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, kv_offset,
         ],
         interpret=interpret,
     )(qt, kt, vt)
-    out = out[:, :sq, :d].reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    lse = lse[:, :sq, 0].reshape(b, h, sq).transpose(0, 2, 1)
-    return out, lse
+    return out[:, :sq], lse[:, 0, :sq]
+
+
+def _on_live_tile(attend, q_start, k_start, *, block_q: int, block_k: int,
+                  **mask_args):
+    """Run ``attend(visible)`` if the tile is live: with None where every
+    pair is visible, else with the tile's mask as a function of the score
+    tile's shape and the dimension its queries lie along."""
+    interior = _tile_interior(q_start, k_start, block_k=block_k, **mask_args)
+    live = _tile_live(q_start, k_start, block_q=block_q, **mask_args)
+    pl.when(interior)(lambda: attend(None))
+    pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
+        lambda: attend(functools.partial(
+            _tile_visible, q_start=q_start, k_start=k_start, **mask_args)))
+
+
+def _recompute_p(logits, visible, q_dim: int, lse):
+    """The softmax weights of one tile from the forward's log-sum-exp: no
+    running max, no rescale.  ``lse`` (a row or a column against ``logits``)
+    is at least ``NEG_INF / 2``, so a pair that is not visible gets exactly
+    0, also in a row that sees no key at all."""
+    if visible is not None:
+        logits = jnp.where(visible(logits.shape, q_dim), logits, NEG_INF)
+    return jnp.exp(logits - lse)
+
+
+def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                          dk_ref, dv_ref, dk_acc, dv_acc,
+                          *, sm_scale: float, block_q: int, block_k: int,
+                          kv_offset: int, **mask_args):
+    # One KV block against every q block (the sequential axis), on the
+    # TRANSPOSED tile [block_k, block_q]: lse and delta are then rows, lane-
+    # dense as they lie in memory, and both accumulations are plain matmuls.
+    ik = pl.program_id(1)
+    iq = pl.program_id(2)
+    nq = pl.num_programs(2)
+
+    @pl.when(iq == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def attend(visible):
+        q, do = q_ref[0], do_ref[0]                    # [block_q, d]
+        logits_t = jax.lax.dot_general(
+            k_ref[0], q, _NT, preferred_element_type=jnp.float32) * sm_scale
+        p_t = _recompute_p(logits_t, visible, 1, lse_ref[0])
+        dv_acc[:] += jnp.dot(p_t.astype(do.dtype), do,
+                             preferred_element_type=jnp.float32)
+        dp_t = jax.lax.dot_general(
+            v_ref[0], do, _NT, preferred_element_type=jnp.float32)
+        ds_t = p_t * (dp_t - delta_ref[0])
+        dk_acc[:] += jnp.dot(ds_t.astype(q.dtype), q,
+                             preferred_element_type=jnp.float32)
+
+    _on_live_tile(attend, iq * block_q, kv_offset + ik * block_k,
+                  block_q=block_q, block_k=block_k, kv_offset=kv_offset,
+                  **mask_args)
+
+    @pl.when(iq == nq - 1)
+    def _finalize():
+        dk_ref[0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                         dq_ref, dq_acc,
+                         *, sm_scale: float, block_q: int, block_k: int,
+                         kv_offset: int, **mask_args):
+    # One q block against every KV block (the sequential axis); lse and
+    # delta are columns here, replicated over 128 lanes like the forward's
+    # m and l (column 0 is read).
+    iq = pl.program_id(1)
+    ik = pl.program_id(2)
+    nk = pl.num_programs(2)
+
+    @pl.when(ik == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def attend(visible):
+        k = k_ref[0]                                   # [block_k, d]
+        logits = jax.lax.dot_general(
+            q_ref[0], k, _NT, preferred_element_type=jnp.float32) * sm_scale
+        p = _recompute_p(logits, visible, 0, lse_ref[0][:, 0:1])
+        dp = jax.lax.dot_general(
+            do_ref[0], v_ref[0], _NT, preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0][:, 0:1])
+        dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
+                             preferred_element_type=jnp.float32)
+
+    _on_live_tile(attend, iq * block_q, kv_offset + ik * block_k,
+                  block_q=block_q, block_k=block_k, kv_offset=kv_offset,
+                  **mask_args)
+
+    @pl.when(ik == nk - 1)
+    def _finalize():
+        dq_ref[0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
+
+
+def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, *, causal, sm_scale,
+                      kv_offset, block_q, block_k, interpret):
+    """Both backward passes on head-major operands; ``lse`` as
+    ``_flash_fwd_pallas`` returns it and ``delta = rowsum(dO * O)`` like it,
+    ``[B*H, Sq]`` float32.  Returns ``(dq, dk, dv)`` head-major."""
+    bh, sq, d_p = qt.shape
+    sk = kt.shape[1]
+    block_q, block_k, sq_p, sk_p = _blocks(sq, sk, block_q, block_k)
+    # a row that saw no key has lse = NEG_INF: lifted, so that exp(NEG_INF -
+    # lse) is 0 there too.  Padded q rows have do = 0 and so add nothing.
+    lse = jnp.maximum(lse, NEG_INF / 2)
+    stats = [jnp.pad(x, ((0, 0), (0, sq_p - sq))) for x in (lse, delta)]
+    rows = [x[:, None, :] for x in stats]                       # [bh, 1, sq_p]
+    cols = [jnp.broadcast_to(x[:, :, None], (bh, sq_p, 128)) for x in stats]
+    qt, do_t = _pad_seq(qt, sq_p), _pad_seq(do_t, sq_p)
+    kt, vt = _pad_seq(kt, sk_p), _pad_seq(vt, sk_p)
+
+    static = dict(sm_scale=sm_scale, causal=causal, kv_offset=kv_offset,
+                  block_q=block_q, block_k=block_k, sk=sk)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    q_block, k_block = (1, block_q, d_p), (1, block_k, d_p)
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_dkv_kernel, **static),
+        grid=(bh, sk_p // block_k, sq_p // block_q),
+        in_specs=[
+            pl.BlockSpec(q_block, lambda bh, ik, iq: (bh, iq, 0)),
+            pl.BlockSpec(q_block, lambda bh, ik, iq: (bh, iq, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, ik, iq: (bh, 0, iq)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, ik, iq: (bh, 0, iq)),
+            pl.BlockSpec(k_block, lambda bh, ik, iq: (bh, ik, 0)),
+            pl.BlockSpec(k_block, lambda bh, ik, iq: (bh, ik, 0)),
+        ],
+        out_specs=[pl.BlockSpec(k_block, lambda bh, ik, iq: (bh, ik, 0))] * 2,
+        out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
+                   jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, d_p), jnp.float32)] * 2,
+        compiler_params=params,
+        interpret=interpret,
+    )(qt, do_t, *rows, kt, vt)
+
+    dq = pl.pallas_call(
+        functools.partial(_flash_bwd_dq_kernel, **static),
+        grid=(bh, sq_p // block_q, sk_p // block_k),
+        in_specs=[
+            pl.BlockSpec(q_block, lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec(q_block, lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec((1, block_q, 128), lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec((1, block_q, 128), lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec(k_block, lambda bh, iq, ik: (bh, ik, 0)),
+            pl.BlockSpec(k_block, lambda bh, iq, ik: (bh, ik, 0)),
+        ],
+        out_specs=pl.BlockSpec(q_block, lambda bh, iq, ik: (bh, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, d_p), jnp.float32)],
+        compiler_params=params,
+        interpret=interpret,
+    )(qt, do_t, *cols, kt, vt)
+    return dq[:, :sq], dk[:, :sk], dv[:, :sk]
+
+
+def _scale(sm_scale, d: int) -> float:
+    return sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+
+
+def _flash_fwd_rule(q, k, v, causal, sm_scale, kv_offset, block_q, block_k,
+                    interpret):
+    # q, k, v stay head-major and lane-padded, as both backward kernels read
+    # them (the backward lays out only the cotangent); the output stays as
+    # the caller holds it anyway, and the kernel's log-sum-exp is kept.
+    b, _, _, d = q.shape
+    with jax.named_scope("flash_fwd"):
+        qt, kt, vt = (_head_major(x, interpret) for x in (q, k, v))
+        ot, lse = _flash_fwd_pallas(
+            qt, kt, vt, causal=causal, sm_scale=_scale(sm_scale, d),
+            kv_offset=kv_offset, block_q=block_q, block_k=block_k,
+            interpret=interpret)
+        out = _from_head_major(ot, b, d)
+        return out, (qt, kt, vt, out, lse)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_attention_tpu(q, k, v, causal, sm_scale, kv_offset,
                          block_q, block_k, interpret):
-    with jax.named_scope("flash_fwd"):
-        out, _ = _flash_fwd_pallas(q, k, v, causal=causal, sm_scale=sm_scale,
-                                   kv_offset=kv_offset, block_q=block_q,
-                                   block_k=block_k, interpret=interpret)
-    return out
-
-
-def _flash_fwd_rule(q, k, v, causal, sm_scale, kv_offset, block_q, block_k,
-                    interpret):
-    out = _flash_attention_tpu(q, k, v, causal, sm_scale, kv_offset,
-                               block_q, block_k, interpret)
-    return out, (q, k, v)
+    return _flash_fwd_rule(q, k, v, causal, sm_scale, kv_offset,
+                           block_q, block_k, interpret)[0]
 
 
 def _flash_bwd_rule(causal, sm_scale, kv_offset, block_q, block_k, interpret,
                     res, g):
-    # Memory-efficient recompute backward: VJP through the blockwise scan
-    # (each block is checkpointed, so peak memory stays O(S·block_k)).
-    q, k, v = res
+    qt, kt, vt, out, lse = res
+    b, sq, h, d = g.shape
     with jax.named_scope("flash_bwd"):
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: blockwise_attention(
-                q_, k_, v_, causal=causal, sm_scale=sm_scale,
-                block_k=block_k, kv_offset=kv_offset),
-            q, k, v)
-        return vjp(g)
+        delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1).transpose(0, 2, 1).reshape(b * h, sq)
+        grads = _flash_bwd_pallas(
+            qt, kt, vt, _head_major(g, interpret), lse, delta,
+            causal=causal, sm_scale=_scale(sm_scale, d), kv_offset=kv_offset,
+            block_q=block_q, block_k=block_k, interpret=interpret)
+        return tuple(_from_head_major(x, b, d) for x in grads)
 
 
 _flash_attention_tpu.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -59,7 +59,13 @@ def test_pallas_kernel_cross_attention_lengths():
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
 
 
-def test_pallas_backward_is_blockwise_recompute():
+def _grads(fn, q, k, v, w):
+    def loss(q, k, v):
+        return jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+
+def test_pallas_backward_matches_reference_under_a_squared_loss():
     q, k, v = make_qkv(b=1, s=32, h=2, d=8)
 
     def loss(fn):
@@ -72,6 +78,76 @@ def test_pallas_backward_is_blockwise_recompute():
                     argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g_pal, g_ref):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("case", [
+    # sq, sk, kv_offset, causal, block_q, block_k, dtype, tolerance
+    pytest.param((32, 32, 0, True, 16, 16, jnp.float32, 1e-5), id="causal"),
+    pytest.param((32, 32, 0, False, 16, 16, jnp.float32, 1e-5),
+                 id="not-causal"),
+    pytest.param((40, 72, -24, True, 16, 16, jnp.float32, 1e-5),
+                 id="sq-ne-sk-kv-offset"),
+    pytest.param((20, 52, 0, False, 16, 16, jnp.float32, 1e-5),
+                 id="cross-lengths-not-causal"),
+    pytest.param((50, 50, 0, True, 16, 16, jnp.float32, 1e-5),
+                 id="length-not-a-multiple-of-the-block"),
+    pytest.param((64, 64, 0, True, 16, 32, jnp.float32, 1e-5),
+                 id="four-q-two-kv-blocks"),
+    pytest.param((64, 64, 0, True, 32, 16, jnp.float32, 1e-5),
+                 id="two-q-four-kv-blocks"),
+    pytest.param((12, 12, 0, True, 16, 16, jnp.float32, 1e-5),
+                 id="shorter-than-a-block"),
+    pytest.param((48, 48, 0, True, 16, 16, jnp.bfloat16, 2e-2), id="bf16"),
+    pytest.param((40, 72, -24, True, 16, 16, jnp.bfloat16, 2e-2),
+                 id="bf16-sq-ne-sk-kv-offset"),
+])
+def test_pallas_backward_kernels_match_reference(case):
+    """The dk/dv and dq kernels (interpret mode) against ``jax.grad`` of the
+    dense reference in float32, under a random cotangent: skipped, interior
+    and masked tiles, padded tails on both sides, an offset KV chunk.  Errors
+    are relative to the largest entry of the reference gradient."""
+    sq, sk, kv_offset, causal, block_q, block_k, dtype, tol = case
+    q, k, v = make_qkv(b=2, s=sq, h=2, d=8, sk=sk, dtype=dtype)
+    w = jnp.asarray(np.random.RandomState(1).randn(*q.shape), jnp.float32)
+    got = _grads(lambda q, k, v: att.flash_attention(
+        q, k, v, causal=causal, kv_offset=kv_offset, block_q=block_q,
+        block_k=block_k, impl="pallas_interpret"), q, k, v, w)
+    want = _grads(lambda q, k, v: att.mha_reference(
+        q, k, v, causal=causal, kv_offset=kv_offset),
+        *(x.astype(jnp.float32) for x in (q, k, v)), w)
+    for name, a, b in zip("qkv", got, want):
+        assert a.dtype == dtype and a.shape == b.shape
+        err = jnp.max(jnp.abs(a.astype(jnp.float32) - b)) / jnp.max(jnp.abs(b))
+        assert float(err) < tol, (name, float(err))
+
+
+def test_pallas_backward_rows_that_see_no_key_get_and_give_no_gradient():
+    # a KV chunk from the future of the first rows (ring attention's
+    # offsets): those rows' output is exactly 0, their lse NEG_INF, and the
+    # backward must recompute p = 0 there, not exp(NEG_INF - NEG_INF) = 1
+    q, k, v = make_qkv(b=1, s=32, h=2, d=8, sk=16)
+    w = jnp.asarray(np.random.RandomState(1).randn(*q.shape), jnp.float32)
+    off = 8
+    got = _grads(lambda q, k, v: att.flash_attention(
+        q, k, v, causal=True, kv_offset=off, block_q=16, block_k=16,
+        impl="pallas_interpret"), q, k, v, w)
+    want = _grads(lambda q, k, v: att.chunk_attention(
+        q, k, v, causal=True, kv_offset=off)[0], q, k, v, w)
+    np.testing.assert_array_equal(got[0][:, :off], 0.0)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+
+
+def test_pallas_backward_holds_no_scan():
+    """Neither rule of the kernel's VJP goes through ``blockwise_attention``:
+    the gradient program is three ``pallas_call``s and layout, no loop."""
+    q, k, v = make_qkv(b=1, s=32, h=2, d=8)
+    jaxpr = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        att.flash_attention(q, k, v, block_q=16, block_k=16,
+                            impl="pallas_interpret")), argnums=(0, 1, 2)))(
+                                q, k, v))
+    assert jaxpr.count("pallas_call") == 3
+    assert "scan" not in jaxpr and "while" not in jaxpr
 
 
 def test_chunk_merge_equals_full_attention():
