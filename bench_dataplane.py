@@ -39,6 +39,17 @@ import threading
 import time
 
 
+def _report(conn, server, totals) -> None:
+    """A child's last act: hand the totals to the driver, then stay up until
+    the driver lets go.  The driver's ``send_eof`` is answered by a daemon
+    thread of THIS process; exiting as soon as the consumer saw EndOfFeed
+    killed that thread before its reply on a busy box (``ConnectionError:
+    socket closed mid-read`` in the feeder)."""
+    conn.send(totals)
+    conn.recv()
+    server.stop()
+
+
 def _consumer_main(conn, authkey: bytes, capacity: int, batch: int) -> None:
     """Child process: one node's data plane + a drain-everything consumer."""
     from tensorflowonspark_tpu.dataserver import DataServer
@@ -54,8 +65,7 @@ def _consumer_main(conn, authkey: bytes, capacity: int, batch: int) -> None:
         for item in feed.next_batch(batch):
             rows += 1
             nbytes += len(item)
-    conn.send((rows, nbytes))
-    server.stop()
+    _report(conn, server, (rows, nbytes))
 
 
 def _make_partition(rows: int, row_bytes: int, seed: int) -> list[bytes]:
@@ -162,6 +172,8 @@ def _run_fanout(num_nodes: int, *, row_bytes: int, rows_per_part: int,
     # the clock stops when every consumer has DRAINED its feed (end-to-end,
     # like the cluster.train measurement), not when the last send returned
     totals = [conn.recv() for conn in conns]
+    for conn in conns:
+        conn.send(None)      # lets the child go: see _report
     elapsed = time.perf_counter() - t0
     for c in clients:
         c.close()

@@ -85,6 +85,17 @@ def _pin_node(index: int) -> None:
         pass
 
 
+def _report(conn, server, totals) -> None:
+    """A child's last act: hand the totals to the driver, then stay up until
+    the driver lets go.  The driver's ``send_eof`` is answered by a daemon
+    thread of THIS process; exiting as soon as the consumer saw EndOfFeed
+    killed that thread before its reply on a busy box (``ConnectionError:
+    socket closed mid-read`` in the feeder)."""
+    conn.send(totals)
+    conn.recv()
+    server.stop()
+
+
 def _direct_consumer_main(conn, authkey: bytes, capacity: int,
                           node_index: int, readers: int | None = 0) -> None:
     """Child process: one DIRECT-mode node — DataServer (receiving shard
@@ -112,8 +123,7 @@ def _direct_consumer_main(conn, authkey: bytes, capacity: int,
         rows += len(batch)
         # C-speed drain: the clock measures the pipeline, not the consumer
         nbytes += sum(map(len, batch))
-    conn.send((rows, nbytes))
-    server.stop()
+    _report(conn, server, (rows, nbytes))
 
 
 def _streaming_consumer_main(conn, authkey: bytes, capacity: int,
@@ -134,8 +144,7 @@ def _streaming_consumer_main(conn, authkey: bytes, capacity: int,
         batch = feed.next_batch(1024)
         rows += len(batch)
         nbytes += sum(map(len, batch))
-    conn.send((rows, nbytes))
-    server.stop()
+    _report(conn, server, (rows, nbytes))
 
 
 def _run_mode(mode: str, num_nodes: int, shard_paths: list[str],
@@ -214,6 +223,8 @@ def _run_mode(mode: str, num_nodes: int, shard_paths: list[str],
     for t in threads:
         t.join()
     totals = [conn.recv() for conn in conns]
+    for conn in conns:
+        conn.send(None)      # lets the child go: see _report
     elapsed = time.perf_counter() - t0
     for c in clients:
         c.close()
@@ -383,8 +394,7 @@ def _direct_feed_consumer_main(conn, authkey: bytes, capacity: int,
             rows += len(batch["y"])  # columnar: the scalar column's length
         else:
             rows += len(batch)
-    conn.send((rows, 0))
-    server.stop()
+    _report(conn, server, (rows, 0))
 
 
 def _run_direct_items(work_items: list, num_nodes: int, expect_rows: int,
@@ -443,6 +453,8 @@ def _run_direct_items(work_items: list, num_nodes: int, expect_rows: int,
     for t in threads:
         t.join()
     totals = [conn.recv() for conn in conns]
+    for conn in conns:
+        conn.send(None)      # lets the child go: see _report
     elapsed = time.perf_counter() - t0
     for c in clients:
         c.close()
@@ -648,8 +660,7 @@ def _disagg_trainer_main(conn, authkey: bytes, capacity: int,
         rows += len(batch[count_col]) if isinstance(batch, dict) else len(batch)
     # trainer-core accounting: process CPU seconds this trainer's single
     # core spent per row is the entitlement the tier exists to free
-    conn.send((rows, time.process_time() - cpu0))
-    server.stop()
+    _report(conn, server, (rows, time.process_time() - cpu0))
 
 
 def _node_local_trainer_main(conn, authkey: bytes, capacity: int,
@@ -677,8 +688,7 @@ def _node_local_trainer_main(conn, authkey: bytes, capacity: int,
     while not feed.should_stop():
         batch = feed.next_batch(1024)
         rows += len(batch[count_col]) if isinstance(batch, dict) else len(batch)
-    conn.send((rows, time.process_time() - cpu0))
-    server.stop()
+    _report(conn, server, (rows, time.process_time() - cpu0))
 
 
 def _ingest_worker_proc_main(conn, authkey: bytes, capacity: int,
@@ -706,8 +716,7 @@ def _ingest_worker_proc_main(conn, authkey: bytes, capacity: int,
                         authkey, stop_event=None, readers=0,
                         rr_offset=node_index, **opts)
     stats = svc.run()
-    conn.send((stats["rows"], 0))
-    server.stop()
+    _report(conn, server, (stats["rows"], 0))
 
 
 def _run_tier(shard_paths: list, num_trainers: int, num_workers: int,
@@ -793,12 +802,15 @@ def _run_tier(shard_paths: list, num_trainers: int, num_workers: int,
             # theirs so EndOfFeed queues BEHIND every forwarded chunk
             for conn in wconns:
                 conn.recv()
+                conn.send(None)  # lets the child go: see _report
             eofs = [DataClient("127.0.0.1", port, authkey)
                     for port in tports]
             for c in eofs:
                 c.send_eof()
                 c.close()
         totals = [conn.recv() for conn in tconns]
+        for conn in tconns:
+            conn.send(None)      # lets the child go: see _report
         elapsed = time.perf_counter() - t0
         for c in clients:
             c.close()

@@ -4,8 +4,9 @@
 
 TPU-native: one jitted SPMD train step over a ``(dp, fsdp)`` mesh; gradient
 all-reduce and cross-replica BatchNorm fall out of GSPMD sharding.  Uses
-synthetic ImageNet-shaped data by default (the benchmark configuration —
-bench.py measures the same step); point --tfrecord-dir at real ImageNet
+synthetic ImageNet-shaped data by default (the cell
+``resnet50_train_tfrecord`` of BENCHMARK.json measures the same step, fed);
+point --tfrecord-dir at real ImageNet
 TFRecords to train on data read through the framework's TFRecord bridge.
 
   python resnet_train.py --steps 50 --batch 256

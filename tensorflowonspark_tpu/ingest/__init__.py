@@ -14,8 +14,8 @@ Pieces:
   enumeration (dir / glob / URI -> ledger partitions of paths);
 - :mod:`~tensorflowonspark_tpu.ingest.readers` — the
   :class:`ReaderPipeline`: parallel-interleaved shard readers with bounded
-  decode queues, occupancy-autotuned parallelism, and prefetch helpers
-  (:func:`prefetch_iterator`, :func:`device_prefetch`);
+  decode queues and occupancy-autotuned parallelism (host->device
+  prefetch is ``parallel.dp.make_batch_iterator(prefetch=)``);
 - :mod:`~tensorflowonspark_tpu.ingest.feed` — :class:`IngestFeed`, the
   DIRECT-mode ``DataFeed`` twin a map_fun gets from ``ctx.get_data_feed()``.
 
@@ -42,8 +42,6 @@ from tensorflowonspark_tpu.ingest.readers import (  # noqa: F401
     ReaderPipeline,
     ShardDone,
     ShardReadError,
-    device_prefetch,
-    prefetch_iterator,
 )
 from tensorflowonspark_tpu.ingest.service import (  # noqa: F401
     ChunkCache,

@@ -653,69 +653,3 @@ class ReaderPipeline:
                     if self._stop.is_set():
                         return False
                     blocked.tick()
-
-
-def prefetch_iterator(iterable, depth: int = 2):
-    """Host-side prefetch: a background thread runs the source iterator up
-    to ``depth`` items ahead of the consumer (the tf.data ``prefetch``
-    stage).  Source exceptions re-raise at the consumer, at the position
-    they would have surfaced unprefetched."""
-    if depth <= 0:
-        yield from iterable
-        return
-    buf: queue.Queue = queue.Queue(maxsize=depth)
-    DONE = object()
-    stopped = threading.Event()
-    failure: list[BaseException] = []
-
-    def _bounded_put(item) -> bool:
-        while not stopped.is_set():
-            try:
-                buf.put(item, timeout=0.1)
-                return True
-            except queue.Full:
-                continue
-        return False
-
-    def _produce() -> None:
-        try:
-            for item in iterable:
-                if not _bounded_put(item):
-                    return  # consumer abandoned the generator
-        except BaseException as e:  # noqa: BLE001 - re-raised consumer-side
-            failure.append(e)
-        finally:
-            _bounded_put(DONE)
-
-    thread = threading.Thread(target=_produce, name="ingest-prefetch",
-                              daemon=True)
-    thread.start()
-    try:
-        while True:
-            item = buf.get()
-            if item is DONE:
-                if failure:
-                    raise failure[0]
-                return
-            yield item
-    finally:
-        stopped.set()  # an abandoning consumer must not strand the producer
-
-
-def device_prefetch(batches, depth: int = 2, device=None):
-    """Prefetch-to-device double buffering: ``jax.device_put`` batch N+1
-    while the consumer computes on batch N (the host->device half of the
-    tf.data-paper pipeline; ``parallel.dp.make_batch_iterator`` applies the
-    same idea to streaming feeds).  Degrades to host-side prefetch when jax
-    is unavailable (pure-IO consumers, tests without a backend)."""
-    try:
-        import jax
-    except Exception:  # noqa: BLE001 - jax-free consumers still prefetch
-        yield from prefetch_iterator(batches, depth)
-        return
-
-    def _placed():
-        for batch in batches:
-            yield jax.device_put(batch, device)
-
-    yield from prefetch_iterator(_placed(), depth)

@@ -73,13 +73,7 @@ class ResNet(nn.Module):
     norm_dtype: Any = jnp.bfloat16
     # "imagenet": 7x7/2 stem + 3x3/2 maxpool (224px inputs);
     # "cifar": 3x3/1 stem, no pool (32px inputs — the reference's cifar10
-    # example family, ``examples/cifar10``);
-    # "space_to_depth": the MLPerf stem optimization — input rearranged
-    # [N,H,W,3] -> [N,H/2,W/2,12] (2x2 blocks stacked into channels) and the
-    # 7x7/2 conv replaced by an equivalent-receptive-field 4x4/1 conv.  Same
-    # output shape as "imagenet"; 4x more input channels feed the MXU's
-    # 128-lane tiles far better than C=3.  Opt-in: weights are not
-    # interchangeable with the classic stem.
+    # example family, ``examples/cifar10``).
     stem: str = "imagenet"
 
     @nn.compact
@@ -90,13 +84,6 @@ class ResNet(nn.Module):
             x = x.astype(self.compute_dtype)
             if self.stem == "cifar":
                 x = nn.Conv(self.width, (3, 3), use_bias=False,
-                            dtype=self.compute_dtype, name="conv_init")(x)
-            elif self.stem == "space_to_depth":
-                n, h, w, c = x.shape
-                x = x.reshape(n, h // 2, 2, w // 2, 2, c)
-                x = x.transpose(0, 1, 3, 2, 4, 5).reshape(
-                    n, h // 2, w // 2, 4 * c)
-                x = nn.Conv(self.width, (4, 4), use_bias=False,
                             dtype=self.compute_dtype, name="conv_init")(x)
             else:
                 x = nn.Conv(self.width, (7, 7), strides=(2, 2), use_bias=False,

@@ -64,8 +64,8 @@ class Attention(nn.Module):
     n_heads: int
     d_head: int
     rope_theta: float = 10000.0
-    attn_impl: str = "auto"       # auto | pallas | xla | reference | ring | ulysses
-    mesh: Optional[Any] = None    # required for ring/ulysses
+    attn_impl: str = "auto"       # auto | pallas | pallas_interpret | xla | ring
+    mesh: Optional[Any] = None    # required for ring
     compute_dtype: Any = jnp.bfloat16
     decode: bool = False          # autoregressive single-token mode (KV cache)
     max_decode_len: int = 0
@@ -92,8 +92,8 @@ class Attention(nn.Module):
                 q, k = flat(q, "q_norm"), flat(k, "k_norm")
         if self.decode:
             return self._decode_step(x, q, k, v)
-        if self.attn_impl in ("ring", "ulysses") and self.mesh is None:
-            raise ValueError("ring/ulysses attention needs mesh=")
+        if self.attn_impl == "ring" and self.mesh is None:
+            raise ValueError("ring attention needs mesh=")
         # named scope: rope, layout and the kernel (both halves of its
         # VJP) carry "attention" in their op names, whatever XLA fuses
         with jax.named_scope("attention"):
@@ -103,12 +103,12 @@ class Attention(nn.Module):
             q = constrain(q, P(BATCH, "sp", "tp", None))
             k = constrain(k, P(BATCH, "sp", "tp", None))
             v = constrain(v, P(BATCH, "sp", "tp", None))
-            if self.attn_impl in ("ring", "ulysses"):
+            if self.attn_impl == "ring":
                 from tensorflowonspark_tpu.parallel.sp import (
                     sequence_parallel_attention,
                 )
                 out = sequence_parallel_attention(
-                    self.mesh, q, k, v, causal=True, impl=self.attn_impl)
+                    self.mesh, q, k, v, causal=True)
             else:
                 impl = None if self.attn_impl == "auto" else self.attn_impl
                 out = flash_attention(q, k, v, causal=True, impl=impl)

@@ -124,7 +124,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
     Differentiable, runs on every backend, and with the per-block
     ``jax.checkpoint`` memory is O(Sq·block_k) — ``impl="xla"``: the path off
-    the TPU, and the chunk compute of ulysses attention (``parallel/sp.py``).
+    the TPU.
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -541,7 +541,7 @@ _flash_attention_tpu.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # Public entry.
 # ---------------------------------------------------------------------------
 
-Impl = Literal["pallas", "pallas_interpret", "xla", "reference"]
+Impl = Literal["pallas", "pallas_interpret", "xla"]
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -556,9 +556,6 @@ def flash_attention(q, k, v, *, causal: bool = True,
     """
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl == "reference":
-        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
-                             kv_offset=kv_offset)
     if impl == "xla":
         return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                                    block_k=block_k, kv_offset=kv_offset)

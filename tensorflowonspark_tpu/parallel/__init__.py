@@ -4,7 +4,7 @@ The tensor plane of the framework (SURVEY.md §5.8-2): XLA collectives over
 ICI emitted by jit-compiled SPMD programs — no server objects, no NCCL.
 
 Axes (mesh.AXES): ``dp`` (sync data parallel), ``fsdp`` (ZeRO-style param
-sharding), ``tp`` (Megatron tensor parallel, tp.py), ``sp`` (ring/Ulysses
+sharding), ``tp`` (Megatron tensor parallel, tp.py), ``sp`` (ring
 sequence parallel, sp.py), ``ep`` (expert parallel MoE, ep.py), ``pp``
 (GPipe pipeline, pp.py).
 """
@@ -20,7 +20,6 @@ from tensorflowonspark_tpu.parallel.mesh import (  # noqa: F401
 from tensorflowonspark_tpu.parallel.sp import (  # noqa: F401
     ring_attention,
     sequence_parallel_attention,
-    ulysses_attention,
 )
 from tensorflowonspark_tpu.parallel.tp import (  # noqa: F401
     TRANSFORMER_TP_RULES,

@@ -53,10 +53,6 @@ from jax.sharding import PartitionSpec as P
 from tensorflowonspark_tpu.parallel.tp import constrain
 
 
-def _one_hot(x, n):
-    return jax.nn.one_hot(x, n, dtype=jnp.float32)
-
-
 def _sorted_dispatch(top_idx, top_p, capacity: int, n_experts: int):
     """GShard slot assignment without one-hot tensors.
 
@@ -207,11 +203,6 @@ class MoEMLP(nn.Module):
     define it, where the capacity rule counts the first (Switch eq. 4).
     ``norm_topk_prob`` renormalises the k routing weights to sum to 1
     (HF's key of that name; OLMoE publishes ``false``).
-
-    ``dispatch`` applies to the capacity rule only: ``'sort'`` (default)
-    uses the index-based dispatch (O(n·k) bookkeeping); ``'einsum'`` keeps
-    the classic one-hot formulation (O(n·e·c) memory — fine for
-    tests/small shapes, and the parity reference for the sort path).
     """
 
     d_model: int
@@ -220,7 +211,6 @@ class MoEMLP(nn.Module):
     top_k: int = 2
     capacity_factor: Optional[float] = 1.25
     compute_dtype: jnp.dtype = jnp.float32
-    dispatch: str = "sort"        # sort | einsum
     norm_topk_prob: bool = True
 
     @nn.compact
@@ -247,7 +237,7 @@ class MoEMLP(nn.Module):
             pairs = jnp.sum(jax.nn.one_hot(top_idx, e, dtype=jnp.int32),
                             axis=(0, 1))                           # [e]
             counted = pairs if dropless else jnp.sum(
-                _one_hot(top_idx[:, 0], e), axis=0)
+                jax.nn.one_hot(top_idx[:, 0], e, dtype=jnp.float32), axis=0)
             frac_probs = jnp.mean(probs, axis=0)
             self.sow("aux_loss", "load_balance",
                      e * jnp.sum(counted / n * frac_probs))
@@ -312,16 +302,12 @@ class MoEMLP(nn.Module):
         capacity = max(1, int(math.ceil(n * self.capacity_factor
                                         * self.top_k / e)))
         with jax.named_scope("moe/dispatch"):
-            if self.dispatch == "einsum":
-                expert_in, combine = self._einsum_dispatch(
-                    xf, top_idx, top_p, capacity, cdt)
-            else:
-                slots, toks, gates, keeps = _sorted_dispatch(
-                    top_idx, top_p, capacity, e)
-                x_pairs = xf[toks].astype(cdt) * keeps[..., None].astype(cdt)
-                expert_in = (jnp.zeros((e * capacity, d), cdt)
-                             .at[slots].add(x_pairs, mode="drop")
-                             .reshape(e, capacity, d))
+            slots, toks, gates, keeps = _sorted_dispatch(
+                top_idx, top_p, capacity, e)
+            x_pairs = xf[toks].astype(cdt) * keeps[..., None].astype(cdt)
+            expert_in = (jnp.zeros((e * capacity, d), cdt)
+                         .at[slots].add(x_pairs, mode="drop")
+                         .reshape(e, capacity, d))
             # The ep constraints make GSPMD materialise the token shuffle
             # as all-to-alls over the ep axis (tokens in, outputs back).
             expert_in = constrain(expert_in, P("ep", None, None))
@@ -333,8 +319,6 @@ class MoEMLP(nn.Module):
             out = jnp.einsum("ecf,efd->ecd", h, w_down.astype(cdt))
             out = constrain(out, P("ep", None, None))
         with jax.named_scope("moe/combine"):
-            if self.dispatch == "einsum":
-                return jnp.einsum("nec,ecd->nd", combine.astype(cdt), out)
             # gather each kept pair's expert output, weight by its gate,
             # scatter-add back to its source token
             out_flat = out.reshape(e * capacity, d)
@@ -343,24 +327,3 @@ class MoEMLP(nn.Module):
                        * gates[..., None].astype(cdt)
                        * keeps[..., None].astype(cdt))
             return jnp.zeros((n, d), cdt).at[toks].add(contrib)
-
-    def _einsum_dispatch(self, xf, top_idx, top_p, capacity, cdt):
-        """Classic GShard one-hot dispatch/combine (parity reference)."""
-        e = self.n_experts
-        n = xf.shape[0]
-        counts = jnp.zeros((e,), jnp.float32)
-        dispatch = jnp.zeros((n, e, capacity), jnp.float32)
-        combine = jnp.zeros((n, e, capacity), jnp.float32)
-        for j in range(self.top_k):
-            oh = _one_hot(top_idx[:, j], e)                       # [n, e]
-            pos = jnp.cumsum(oh, axis=0) - oh + counts[None, :]   # [n, e]
-            keep = (pos < capacity).astype(jnp.float32) * oh
-            counts = counts + jnp.sum(keep, axis=0)
-            slot = _one_hot(jnp.sum(pos * oh, axis=-1).astype(jnp.int32),
-                            capacity)                             # [n, c]
-            d_j = keep[:, :, None] * slot[:, None, :]
-            dispatch = dispatch + d_j
-            combine = combine + d_j * top_p[:, j][:, None, None]
-        expert_in = jnp.einsum("nec,nd->ecd", dispatch.astype(cdt),
-                               xf.astype(cdt))
-        return expert_in, combine

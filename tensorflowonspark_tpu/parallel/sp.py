@@ -1,35 +1,27 @@
-"""Sequence/context parallelism: ring attention and Ulysses over the ``sp`` axis.
+"""Sequence/context parallelism: ring attention over the ``sp`` axis.
 
 The reference has nothing here (SURVEY.md §5.7 — it predates long-context
-work), but long sequences are first-class in this build.  Two TPU-idiomatic
-schemes, both built on the chunk/merge online-softmax primitives from
-``ops/attention.py``:
+work), but long sequences are first-class in this build.  Built on the
+chunk/merge online-softmax primitives from ``ops/attention.py``:
 
-- **Ring attention** (``ring_attention`` / ``ring_self_attention``): Q stays
-  put, KV shards rotate around the ``sp`` ring via ``jax.lax.ppermute`` over
-  ICI neighbours; each hop's partial result merges via the online-softmax
-  identity.  Memory per chip is O(S_local²-ish blockwise); the sequence can
-  exceed any single chip's HBM.
-- **Ulysses** (``ulysses_self_attention``): two ``all_to_all``s swap the
-  sharded axis seq→heads and back, so each chip computes *full-sequence*
-  attention for a head subset — cheaper collectives when heads ≥ sp and the
-  whole sequence fits per chip.
+**Ring attention** (``ring_attention``): Q stays put, KV shards rotate around
+the ``sp`` ring via ``jax.lax.ppermute`` over ICI neighbours; each hop's
+partial result merges via the online-softmax identity.  Memory per chip is
+O(S_local²-ish blockwise); the sequence can exceed any single chip's HBM.
 
-Both are meant to run *inside* ``jax.shard_map`` (the raw functions) or via
-the ``*_self_attention`` wrappers that shard_map over a standard mesh.
+``ring_attention`` is meant to run *inside* ``jax.shard_map``;
+``sequence_parallel_attention`` shard_maps it over a standard mesh.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Literal
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from tensorflowonspark_tpu.ops.attention import (
-    blockwise_attention,
     chunk_attention,
     match_vma,
     merge_attention,
@@ -83,46 +75,15 @@ def ring_attention(q, k, v, *, axis_name: str = "sp", causal: bool = True,
     return o.astype(q.dtype)
 
 
-def ulysses_attention(q, k, v, *, axis_name: str = "sp", causal: bool = True,
-                      sm_scale: float | None = None, block_k: int = 512):
-    """Ulysses (all-to-all) attention over a named axis; call inside shard_map.
-
-    Local shards ``[B, S_local, H, D]`` → all_to_all to ``[B, S, H/n, D]`` →
-    full-sequence blockwise attention per head subset → all_to_all back.
-    Requires ``H % axis_size == 0``.
-    """
-    n = jax.lax.axis_size(axis_name)
-    h = q.shape[2]
-    if h % n:
-        raise ValueError(f"ulysses needs heads ({h}) divisible by sp axis ({n})")
-    swap = functools.partial(jax.lax.all_to_all, axis_name=axis_name,
-                             split_axis=2, concat_axis=1, tiled=True)
-    unswap = functools.partial(jax.lax.all_to_all, axis_name=axis_name,
-                               split_axis=1, concat_axis=2, tiled=True)
-    out = blockwise_attention(swap(q), swap(k), swap(v), causal=causal,
-                              sm_scale=sm_scale, block_k=block_k)
-    return unswap(out)
-
-
-SpImpl = Literal["ring", "ulysses"]
-
-
 def sequence_parallel_attention(mesh, q, k, v, *, causal: bool = True,
-                                sm_scale: float | None = None,
-                                impl: SpImpl = "ring"):
-    """Shard_map wrapper: self-attention with sequence sharded over ``sp``.
+                                sm_scale: float | None = None):
+    """Shard_map wrapper: ring self-attention with sequence sharded over ``sp``.
 
     Global arrays ``[B, S, H, D]``: batch over ``(dp, fsdp)``, sequence over
     ``sp``, heads over ``tp``.  Returns the same layout.
     """
     pspec = P(("dp", "fsdp"), "sp", "tp", None)
-    fn = ring_attention if impl == "ring" else ulysses_attention
-    inner = functools.partial(fn, axis_name="sp", causal=causal,
-                              sm_scale=sm_scale)
-
-    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(pspec, pspec, pspec),
-                       out_specs=pspec)
-    def mapped(q, k, v):
-        return inner(q, k, v)
-
-    return mapped(q, k, v)
+    ring = functools.partial(ring_attention, axis_name="sp", causal=causal,
+                             sm_scale=sm_scale)
+    return jax.shard_map(ring, mesh=mesh, in_specs=(pspec, pspec, pspec),
+                         out_specs=pspec)(q, k, v)

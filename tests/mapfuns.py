@@ -666,7 +666,14 @@ def sync_coordinator_chaos(args, ctx):
     The barrier runs BEFORE the train step so a member that failed it has
     an unchanged state; a member whose barrier succeeded but whose
     all-reduce then aborted is also unchanged (the apply half never runs on
-    an aborted exchange) — reform + sync_state therefore always agree."""
+    an aborted exchange) — reform + sync_state therefore always agree.
+
+    ``step_delay`` is slept by rank r as ``r * step_delay`` after each step,
+    so rank 0 waits in the next round's barrier while the others sleep: a
+    round is in flight nearly all of the time, and a crash on ANY control
+    op (a heartbeat as well as a barrier) poisons it.  The nodes publish
+    ``coord_chaos_formed`` once the group stands, so the driver can arm the
+    kill against the rounds rather than against the boot's heartbeats."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -697,6 +704,7 @@ def sync_coordinator_chaos(args, ctx):
                                   cross_host_grad_fn=group.grad_fn())
     reforms = 0
     epochs_seen = set()
+    ctx.update_meta({"coord_chaos_formed": True})
 
     def recover(cur_state, cur_step):
         # re-form until it sticks: a reform attempted WHILE the coordinator
@@ -731,7 +739,7 @@ def sync_coordinator_chaos(args, ctx):
         if group._client.epoch is not None:
             epochs_seen.add(group._client.epoch)
         if args.get("step_delay"):
-            time.sleep(args["step_delay"])
+            time.sleep(args["step_delay"] * group.rank)
     while True:
         try:
             group.barrier(timeout=8.0)

@@ -560,11 +560,10 @@ def test_kill_coordinator_mid_sync_train_reforms_exact(tmp_path, monkeypatch,
     ttrace.collect_final()
     monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
-    arm_driver_faults("kill_coordinator:after_ops=30")
     total_steps = 12
     cluster = tcluster.run(
         mapfuns.sync_coordinator_chaos,
-        {"steps": total_steps, "step_delay": 0.1},
+        {"steps": total_steps, "step_delay": 0.3},
         num_executors=2,
         input_mode=tcluster.InputMode.STREAMING,
         launcher=SubprocessLauncher(),
@@ -573,6 +572,19 @@ def test_kill_coordinator_mid_sync_train_reforms_exact(tmp_path, monkeypatch,
         log_dir=str(tmp_path / "logs"),
         reservation_timeout=120.0,
     )
+    # Arm the kill against the ROUNDS, not against the boot: ops are counted
+    # from arming, and how many heartbeats two nodes send while they import
+    # jax depends on the box (armed from the start, op 30 fell ~2 s before
+    # the group formed: no barrier in flight, nothing to re-form).  Once the
+    # group stands, rank 0 waits in a barrier while rank 1 sleeps its
+    # step_delay, so the 6th op from here (two rounds in) crashes the
+    # coordinator with a round in flight whatever kind of op it is.
+    deadline = time.monotonic() + 120.0
+    while sum(bool(m.get("coord_chaos_formed"))
+              for m in cluster.coordinator.cluster_info()) < 2:
+        assert time.monotonic() < deadline, "the group never formed"
+        time.sleep(0.02)
+    arm_driver_faults("kill_coordinator:after_ops=6")
     # no train() feed blocks this map_fun: wait for both nodes to publish
     # (generous: the slow-convergence path stacks several bounded
     # collective backstops before the generation barrier aligns)
@@ -581,12 +593,17 @@ def test_kill_coordinator_mid_sync_train_reforms_exact(tmp_path, monkeypatch,
     while time.monotonic() < deadline:
         metas = {m["executor_id"]: m.get("coord_chaos")
                  for m in cluster.coordinator.cluster_info()}
-        if all(v is not None for v in metas.values()):
+        # BOTH nodes: between the crash and the journal's replay the
+        # coordinator knows no node, and all() of nothing is true — a poll
+        # that fell in that gap went on to shutdown(), which stops the
+        # coordinator's supervisor, and the nodes could never finish
+        if len(metas) == 2 and all(v is not None for v in metas.values()):
             break
         time.sleep(0.5)
     epoch = cluster.coordinator.epoch
     cluster.shutdown(timeout=180.0)
-    assert all(v is not None for v in metas.values()), metas
+    assert len(metas) == 2 and all(
+        v is not None for v in metas.values()), metas
     assert epoch >= 1, "the chaos kill never fired mid-run"
     for v in metas.values():
         assert v["steps"] == total_steps  # exact step accounting

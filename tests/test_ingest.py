@@ -28,7 +28,6 @@ from tensorflowonspark_tpu.ingest import (
     ShardReadError,
     ShardSpan,
     enumerate_shards,
-    prefetch_iterator,
     shards_as_partitioned,
     split_shards,
 )
@@ -162,21 +161,6 @@ def test_autotune_grows_pool_when_consumer_starves(tmp_path):
             got += len(item)
     assert got == 12 * 40
     assert max_active >= 2, "autotune never grew the reader pool"
-
-
-def test_prefetch_iterator_order_and_error():
-    assert list(prefetch_iterator(iter(range(100)), depth=4)) == list(range(100))
-
-    def explodes():
-        yield 1
-        yield 2
-        raise ValueError("source broke")
-
-    it = prefetch_iterator(explodes(), depth=2)
-    assert next(it) == 1
-    assert next(it) == 2
-    with pytest.raises(ValueError, match="source broke"):
-        next(it)
 
 
 # -- zero-copy record views (TOS_INGEST_ZEROCOPY) -----------------------------

@@ -24,9 +24,9 @@ import sys
 
 import pytest
 
+from benchmark import common
+
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_WORK = os.path.join(_REPO, ".bench_data", "benchmark")     # common.WORK_DIR
-_NOT_A_CHIP_RUN = "NOT A CHIP RUN"
 
 
 def _missing(path: str) -> list[str]:
@@ -41,15 +41,14 @@ def _missing(path: str) -> list[str]:
 @pytest.mark.parametrize("workload", ["resnet50_train_tfrecord",
                                       "olmoe_1b_7b_d1_train_4k"])
 def test_cell_rehearses_on_cpu(workload):
-    with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
-        cell = next(w for w in json.load(f)["workloads"]
-                    if w["name"] == workload)
+    cell = common.resolve_cell(workload)
     # run.py's work directory is not configurable and a DIRECT cell keeps one
     # seed's shards per (mix, configuration) there: what this run creates
     # goes again, so a checkout after tier-1 looks like one before it
-    made = [_missing(os.path.join(_WORK, "runs", workload)),
-            _missing(os.path.join(_WORK, "records",
-                                  f"{cell['traffic']}.{cell['config']}"))]
+    made = [_missing(os.path.join(common.WORK_DIR, "runs", workload)),
+            _missing(os.path.join(
+                common.WORK_DIR, "records",
+                f"{cell['traffic_name']}.{cell['config_name']}"))]
     try:
         # a session of its own: at the timeout the node goes with the driver
         proc = subprocess.Popen(
@@ -76,7 +75,7 @@ def test_cell_rehearses_on_cpu(workload):
     tail = out[-3000:] + "\n--- stderr ---\n" + err[-3000:]
     assert proc.returncode == 0, tail
     lines = out.strip().splitlines()
-    assert _NOT_A_CHIP_RUN in lines[-1], tail
+    assert "NOT A CHIP RUN" in lines[-1], tail
     result = json.loads(lines[-2])
     assert result["correct"] is True, tail
     assert result["attempted"] > 0 and result["failed"] == 0, tail

@@ -1,5 +1,7 @@
 """Attention kernels vs the dense reference (CPU; Pallas via interpret mode)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -212,20 +214,21 @@ def test_pallas_kernel_runs_per_shard_under_an_ambient_mesh():
     placed = [jax.device_put(x, NamedSharding(
         mesh, P(("dp", "fsdp"), "sp", "tp", None))) for x in (q, k, v)]
 
-    def loss(impl):
+    def loss(attend):
         def f(q, k, v):
-            out = att.flash_attention(q, k, v, causal=True, impl=impl,
-                                      block_q=16, block_k=16)
+            out = attend(q, k, v, causal=True)
             return jnp.sum(out ** 2), out
         return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
 
     with jax.set_mesh(mesh):
-        fn = jax.jit(loss("pallas_interpret"))
+        fn = jax.jit(loss(functools.partial(
+            att.flash_attention, impl="pallas_interpret",
+            block_q=16, block_k=16)))
         # shardy spells a shard_map region "sdy.manual_computation"
         assert "manual_computation" in fn.lower(*placed).as_text()
         (_, out), grads = fn(*placed)
     assert out.sharding.spec == P("dp", None, "tp")
-    (_, ref), ref_grads = loss("reference")(q, k, v)
+    (_, ref), ref_grads = loss(att.mha_reference)(q, k, v)
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
     for g, r in zip(grads, ref_grads):
         np.testing.assert_allclose(g, r, atol=1e-4, rtol=1e-4)

@@ -1,4 +1,4 @@
-"""Ring/Ulysses sequence parallelism vs dense attention (8 virtual CPU devices)."""
+"""Ring sequence parallelism vs dense attention (8 virtual CPU devices)."""
 
 import jax
 import jax.numpy as jnp
@@ -21,19 +21,7 @@ def test_ring_attention_matches_dense(causal):
     mesh = meshlib.make_mesh(dp=2, sp=4)
     q, k, v = global_qkv()
     ref = att.mha_reference(q, k, v, causal=causal)
-    out = splib.sequence_parallel_attention(mesh, q, k, v, causal=causal,
-                                            impl="ring")
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=1e-5, rtol=1e-5)
-
-
-@pytest.mark.parametrize("causal", [True, False])
-def test_ulysses_attention_matches_dense(causal):
-    mesh = meshlib.make_mesh(dp=2, sp=4)
-    q, k, v = global_qkv()
-    ref = att.mha_reference(q, k, v, causal=causal)
-    out = splib.sequence_parallel_attention(mesh, q, k, v, causal=causal,
-                                            impl="ulysses")
+    out = splib.sequence_parallel_attention(mesh, q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
 

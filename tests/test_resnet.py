@@ -128,43 +128,3 @@ def test_graft_entry_forward_tiny():
     out = jax.jit(forward)(variables["params"], variables["batch_stats"],
                            jnp.zeros((2, 32, 32, 3)))
     assert out.shape == (2, 8)
-
-
-def test_space_to_depth_stem_shapes_and_grads():
-    """Opt-in MLPerf stem: same output shape as the classic stem, trains
-    (finite loss + grads).  Numerics intentionally differ — it is a model
-    variant, not a weight-compatible rewrite."""
-    import optax
-
-    from tensorflowonspark_tpu.parallel import dp as dplib
-    from tensorflowonspark_tpu.parallel import mesh as meshlib
-
-    classic = resnet.ResNet(stage_sizes=(1, 1), num_classes=8, width=16,
-                            compute_dtype=jnp.float32, norm_dtype=jnp.float32)
-    s2d = resnet.ResNet(stage_sizes=(1, 1), num_classes=8, width=16,
-                        compute_dtype=jnp.float32, norm_dtype=jnp.float32,
-                        stem="space_to_depth")
-    x = jnp.asarray(np.random.RandomState(0).rand(2, 64, 64, 3), jnp.float32)
-    vc = classic.init(jax.random.PRNGKey(0), x, train=True)
-    vs = s2d.init(jax.random.PRNGKey(0), x, train=True)
-    out_c = classic.apply(vc, x, train=False)
-    out_s = s2d.apply(vs, x, train=False)
-    assert out_c.shape == out_s.shape == (2, 8)
-    # stem kernel really is the 4x4-on-12-channels form
-    assert vs["params"]["conv_init"]["kernel"].shape == (4, 4, 12, 16)
-
-    mesh = meshlib.make_mesh(dp=-1)
-    state = dplib.BNTrainState.create(
-        meshlib.shard_tree(mesh, vs["params"],
-                           jax.tree.map(lambda _: meshlib.replicated(mesh),
-                                        vs["params"])),
-        meshlib.shard_tree(mesh, vs["batch_stats"],
-                           jax.tree.map(lambda _: meshlib.replicated(mesh),
-                                        vs["batch_stats"])),
-        optax.sgd(0.1))
-    step = dplib.make_bn_train_step(resnet.make_loss_fn(s2d), optax.sgd(0.1))
-    batch = meshlib.shard_batch(mesh, {
-        "image": np.random.RandomState(1).rand(8, 64, 64, 3).astype(np.float32),
-        "label": (np.arange(8) % 8).astype(np.int32)})
-    state, metrics = step(state, batch)
-    assert np.isfinite(float(metrics["loss"]))

@@ -448,10 +448,14 @@ def test_canary_replica_sigkill_no_spurious_rollback_and_cohort_rejoin(
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     telemetry.reset()
+    # the canary cohort is EXECUTOR 0 (asserted below), and executor ids are
+    # handed out in registration order, not launch order: aimed through
+    # per_node_env[0] the kill hit the lone PRIMARY replica whenever the
+    # second process registered first, and a batch whose retry has no other
+    # primary to go to fails ("no healthy serving replica available")
     cluster, export = _serve_cluster(
         tmp_path, scale=2.0, elastic=True,
-        per_node_env=[{"TOS_FAULTINJECT": "kill:after_batches=3,incarnation=0"},
-                      {}])
+        env={"TOS_FAULTINJECT": "kill:after_batches=3,incarnation=0,executor=0"})
     try:
         gw = cluster.serve(export, max_batch=4, max_delay_ms=2.0,
                            listen=False, reload_poll_secs=0)

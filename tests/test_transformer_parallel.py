@@ -4,6 +4,9 @@ All on the 8-device virtual CPU platform (conftest).  float32 compute so
 parity tolerances are tight.
 """
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -108,43 +111,71 @@ def test_moe_ep_sharded_matches_replicated():
                                atol=2e-5, rtol=2e-5)
 
 
+def gshard_einsum_moe(params, x, *, top_k, capacity_factor):
+    """The classic GShard one-hot formulation of ``MoEMLP``'s capacity rule
+    (``[n, e, c]`` dispatch/combine tensors, O(n·e·c) memory): the parity
+    reference for the layer's index/sort dispatch, from the layer's params."""
+    b, s, d = x.shape
+    xf = x.reshape(-1, d)
+    n, e = xf.shape[0], params["router"]["kernel"].shape[1]
+    probs = jax.nn.softmax(xf @ params["router"]["kernel"], axis=-1)
+    top_p, top_idx = jax.lax.top_k(probs, top_k)
+    top_p = top_p / jnp.maximum(jnp.sum(top_p, -1, keepdims=True), 1e-9)
+    capacity = max(1, math.ceil(n * capacity_factor * top_k / e))
+    counts = jnp.zeros((e,), jnp.float32)
+    dispatch = combine = jnp.zeros((n, e, capacity), jnp.float32)
+    for j in range(top_k):
+        oh = jax.nn.one_hot(top_idx[:, j], e, dtype=jnp.float32)  # [n, e]
+        pos = jnp.cumsum(oh, axis=0) - oh + counts[None, :]       # [n, e]
+        keep = (pos < capacity).astype(jnp.float32) * oh
+        counts = counts + jnp.sum(keep, axis=0)
+        slot = jax.nn.one_hot(jnp.sum(pos * oh, axis=-1).astype(jnp.int32),
+                              capacity, dtype=jnp.float32)        # [n, c]
+        d_j = keep[:, :, None] * slot[:, None, :]
+        dispatch = dispatch + d_j
+        combine = combine + d_j * top_p[:, j][:, None, None]
+    expert_in = jnp.einsum("nec,nd->ecd", dispatch, xf)
+    h = (jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in,
+                                params["experts_gate"]))
+         * jnp.einsum("ecd,edf->ecf", expert_in, params["experts_up"]))
+    out = jnp.einsum("ecf,efd->ecd", h, params["experts_down"])
+    return jnp.einsum("nec,ecd->nd", combine, out).reshape(b, s, d)
+
+
 def test_moe_sort_dispatch_matches_einsum_reference():
-    """The index/sort-based dispatch (default; O(n·k) bookkeeping) must
-    reproduce the classic GShard one-hot einsum formulation exactly —
-    including which tokens overflow: slot assignment follows the same
-    priority rule (round-major, token order, kept-only carryover)."""
+    """The index/sort-based dispatch (O(n·k) bookkeeping) must reproduce
+    the classic GShard one-hot einsum formulation exactly — including which
+    tokens overflow: slot assignment follows the same priority rule
+    (round-major, token order, kept-only carryover)."""
     for cap_factor in (1.25, 0.4):  # ample capacity AND forced overflow
-        kwargs = dict(d_model=8, d_ff=16, n_experts=4, top_k=2,
-                      capacity_factor=cap_factor, compute_dtype=jnp.float32)
-        sort_layer = eplib.MoEMLP(**kwargs)
-        ein_layer = eplib.MoEMLP(**kwargs, dispatch="einsum")
+        layer = eplib.MoEMLP(d_model=8, d_ff=16, n_experts=4, top_k=2,
+                             capacity_factor=cap_factor,
+                             compute_dtype=jnp.float32)
         x = jnp.asarray(np.random.RandomState(7).randn(2, 12, 8), jnp.float32)
-        params = sort_layer.init(jax.random.PRNGKey(1), x)["params"]
-        y_sort, aux_sort = jax.jit(lambda p, v: sort_layer.apply(
-            {"params": p}, v, mutable=["aux_loss"]))(params, x)
-        y_ein, aux_ein = jax.jit(lambda p, v: ein_layer.apply(
-            {"params": p}, v, mutable=["aux_loss"]))(params, x)
+        params = layer.init(jax.random.PRNGKey(1), x)["params"]
+        y_sort = jax.jit(lambda p, v: layer.apply(
+            {"params": p}, v, mutable=["aux_loss"])[0])(params, x)
+        y_ein = jax.jit(functools.partial(
+            gshard_einsum_moe, top_k=2, capacity_factor=cap_factor))(params, x)
         np.testing.assert_allclose(np.asarray(y_sort), np.asarray(y_ein),
                                    rtol=1e-5, atol=1e-6)
-        jax.tree.map(lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-6),
-            aux_sort, aux_ein)
 
 
 def test_moe_sort_dispatch_grads_match_einsum():
-    kwargs = dict(d_model=8, d_ff=16, n_experts=2, top_k=2,
-                  capacity_factor=1.25, compute_dtype=jnp.float32)
-    sort_layer = eplib.MoEMLP(**kwargs)
-    ein_layer = eplib.MoEMLP(**kwargs, dispatch="einsum")
+    layer = eplib.MoEMLP(d_model=8, d_ff=16, n_experts=2, top_k=2,
+                         capacity_factor=1.25, compute_dtype=jnp.float32)
     x = jnp.asarray(np.random.RandomState(3).randn(1, 10, 8), jnp.float32)
-    params = sort_layer.init(jax.random.PRNGKey(2), x)["params"]
+    params = layer.init(jax.random.PRNGKey(2), x)["params"]
 
-    def loss(layer, p):
+    def loss_sort(p):
         y = layer.apply({"params": p}, x, mutable=["aux_loss"])[0]
         return jnp.sum(y * y)
 
-    g_sort = jax.grad(lambda p: loss(sort_layer, p))(params)
-    g_ein = jax.grad(lambda p: loss(ein_layer, p))(params)
+    def loss_ein(p):
+        y = gshard_einsum_moe(p, x, top_k=2, capacity_factor=1.25)
+        return jnp.sum(y * y)
+
+    g_sort, g_ein = jax.grad(loss_sort)(params), jax.grad(loss_ein)(params)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), rtol=2e-4, atol=1e-6), g_sort, g_ein)
 

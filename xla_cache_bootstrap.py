@@ -1,8 +1,8 @@
 """Persistent-XLA-cache bootstrap: the ONE place a compile-cache path is set.
 
-The tests, ``chip_smoke.py``, ``bench.py`` and ``__graft_entry__`` compile
-the same XLA programs run after run; the persistent cache turns those
-compiles into loads.  Where the cache lives is decided outside the program:
+The tests, ``chip_smoke.py``, ``benchmark/run.py`` and ``__graft_entry__``
+compile the same XLA programs run after run; the persistent cache turns
+those compiles into loads.  Where the cache lives is decided outside the program:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set by the caller is used verbatim;
 - otherwise the cache is ``<checkout>/.jax_cache`` — a fixed path, because
@@ -10,8 +10,8 @@ compiles into loads.  Where the cache lives is decided outside the program:
 
 The choice is exported through ``os.environ`` so the node processes that
 ``tos.run`` spawns inherit it (jax reads both variables at import).  jax is
-NOT imported here: the drivers of ``chip_smoke.py``/``bench.py`` must stay
-off the backend (one process owns the chip).  If the caller has already
+NOT imported here: the drivers of ``chip_smoke.py`` and ``benchmark/run.py``
+must stay off the backend (one process owns the chip).  If the caller has already
 imported jax, its config snapshot is re-asserted to match the env.
 
 Kept as a repo-root stdlib-only module so entry points can call it before
