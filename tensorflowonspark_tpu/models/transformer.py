@@ -468,7 +468,9 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
     leaves (ST-MoE z-loss) by ``router_z_coef``.  For a model with experts
     the metrics also carry ``moe_max_load`` and ``moe_min_load``: pairs at
     the fullest and at the emptiest expert over the mean, averaged over
-    layers (the ``moe_stats`` sow) — a collapsing router shows there first.
+    layers (the ``moe_stats`` sow) — a collapsing router shows there first —
+    and, under dropless routing, ``moe_executed_rows``: the rows the grouped
+    matmul's tiles compute over the routed ones (``parallel/ep.py``).
 
     ``vocab_chunk > 0`` fuses the lm_head matmul into a blockwise
     cross-entropy (``ops/xent.py``): the ``[B, S, V]`` logits are never

@@ -20,14 +20,12 @@ Given ``None`` it routes **droplessly**, as the published open MoEs do
 (OLMoE, arXiv:2409.02060): every one of the ``n·k`` (token, expert) pairs is
 computed whatever the routing, with shapes that do not depend on it.  With
 nothing dropped there is no priority to keep, so the pairs are sorted ONCE
-by expert; each expert's group is laid out from a block boundary
-(``_block_layout``), so that every block of rows belongs to one expert, and
-a scan over the blocks runs the three expert matmuls of each against its
-expert's weights — the grouped matmul in plain XLA, at most ``e`` blocks of
-padding in all, the same work whatever the routing.  Tokens go to rows and
-rows back to tokens by gathers in both directions (``_dispatch`` /
-``_combine``).  Of the XLA formulations timed on a v5e at OLMoE's shape
-(PERF.md, PR 25) this was the fastest that is not a custom call.
+by expert (``_sorted_layout``: the stable sort and its inverse) and the three
+expert matmuls run as a grouped matmul over the sorted rows, driven by the
+group sizes (``ops/grouped_matmul.py``: Pallas kernels on TPU,
+``jax.lax.ragged_dot`` elsewhere): no row is padding, and the program is the
+same whatever the routing.  Tokens go to rows and rows back to tokens by
+gathers in both directions (``_dispatch`` / ``_combine``).
 
 ``MoEMLP`` is a flax module usable standalone or inside
 ``models/transformer.py``.  Two auxiliary losses are sown into the
@@ -37,7 +35,10 @@ rows back to tokens by gathers in both directions (``_dispatch`` /
 into f32-overflow territory); ``models.transformer.make_loss_fn`` weights
 them independently.  The ``"moe_stats"`` collection gets ``max_load`` and
 ``min_load``: pairs routed to the fullest and to the emptiest expert over
-the mean — what shows a router collapsing.
+the mean — what shows a router collapsing.  Dropless routing adds
+``executed_rows``: the rows the grouped matmul's tiles compute over the
+``n·k`` routed ones (a row tile that holds rows of several experts is
+visited once for each), which is what uneven routing costs the kernel.
 """
 
 from __future__ import annotations
@@ -50,6 +51,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from tensorflowonspark_tpu.ops.grouped_matmul import (executed_rows,
+                                                       grouped_matmul)
 from tensorflowonspark_tpu.parallel.tp import constrain
 
 
@@ -91,52 +94,20 @@ def _sorted_dispatch(top_idx, top_p, capacity: int, n_experts: int):
 
 
 # ---------------------------------------------------------------------------
-# Dropless routing: one sort, groups laid out from block boundaries.
+# Dropless routing: one sort, a grouped matmul over the sorted pairs.
 # ---------------------------------------------------------------------------
 
-def _block_rows(n_pairs: int, n_experts: int) -> int:
-    """Rows per block: the mean group size rounded up to a power of two,
-    at most 512 (one block's three matmuls are then 6 GFLOP at OLMoE's
-    widths against 12 MB of weights: compute-bound on the MXU)."""
-    mean = max(1, n_pairs // n_experts)
-    return min(512, 1 << (mean - 1).bit_length())
-
-
-def _block_layout(top_idx, sizes, block: int):
-    """Index maps between the ``n·k`` pairs (token-major: pair ``t·k + j``
-    is token ``t``'s j-th choice; ``sizes[e]`` of them chose expert e) and
-    the rows of the padded layout, in which expert 0's pairs come first, in
-    token order, then expert 1's from the next multiple of ``block``, and so
-    on.  ``n·k/block + e`` blocks always hold them, so every shape is static.
-
-    Returns ``(block_expert, pair_of_row, valid, row_of_pair)``: each
-    block's expert ``[blocks]``; for every row the pair that lives there and
-    whether one does ``[blocks·block]``; for every pair its row ``[n, k]``.
-    """
+def _sorted_layout(top_idx):
+    """The ``n·k`` pairs (token-major: pair ``t·k + j`` is token ``t``'s j-th
+    choice) in expert order, token order within an expert.  Returns
+    ``(order, row_of_pair)``: the pair that sorted row r holds ``[n·k]``,
+    and its inverse, every pair's row ``[n, k]``."""
     n, k = top_idx.shape
-    e = sizes.shape[0]
-    n_blocks = n * k // block + e
-    flat = top_idx.reshape(-1)
-    order = jnp.argsort(flat, stable=True)       # the one sort
-    starts = jnp.cumsum(sizes) - sizes
-    blocks = (sizes + block - 1) // block
-    block_ends = jnp.cumsum(blocks)
-    block_starts = block_ends - blocks
-    block_expert = jnp.minimum(
-        jnp.searchsorted(block_ends, jnp.arange(n_blocks), side="right"),
-        e - 1).astype(jnp.int32)
-    row = jnp.arange(n_blocks * block)
-    row_expert = block_expert[row // block]
-    within = row - block_starts[row_expert] * block
-    valid = (within < sizes[row_expert]) & (row // block < block_ends[-1])
-    pair_of_row = order[jnp.where(valid, starts[row_expert] + within, 0)]
-    sorted_expert = flat[order]
-    row_sorted = (block_starts[sorted_expert] * block
-                  + jnp.arange(n * k) - starts[sorted_expert])
+    order = jnp.argsort(top_idx.reshape(-1), stable=True)    # the one sort
     row_of_pair = (jnp.zeros((n * k,), jnp.int32)
-                   .at[order].set(row_sorted.astype(jnp.int32))
+                   .at[order].set(jnp.arange(n * k, dtype=jnp.int32))
                    .reshape(n, k))
-    return block_expert, pair_of_row, valid, row_of_pair
+    return order, row_of_pair
 
 
 # Tokens to rows and rows back to tokens.  Both maps are known in both
@@ -144,45 +115,43 @@ def _block_layout(top_idx, sizes, block: int):
 # (autodiff would scatter-add 2048-wide rows).
 
 @jax.custom_vjp
-def _dispatch(x, pair_of_row, valid, row_of_pair):
-    """``[n, d]`` tokens -> ``[rows, d]``: row r holds its pair's token,
-    padding rows hold zeros."""
-    k = row_of_pair.shape[1]
-    return x[pair_of_row // k] * valid[:, None].astype(x.dtype)
+def _dispatch(x, order, row_of_pair):
+    """``[n, d]`` tokens -> ``[n·k, d]``: row r holds its pair's token."""
+    return x[order // row_of_pair.shape[1]]
 
 
-def _dispatch_fwd(x, pair_of_row, valid, row_of_pair):
-    return _dispatch(x, pair_of_row, valid, row_of_pair), row_of_pair
+def _dispatch_fwd(x, order, row_of_pair):
+    return _dispatch(x, order, row_of_pair), row_of_pair
 
 
 def _dispatch_bwd(row_of_pair, g):
-    return jnp.sum(g[row_of_pair], axis=1), None, None, None
+    return jnp.sum(g[row_of_pair], axis=1), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(out, weights, pair_of_row, valid, row_of_pair):
-    """``[rows, d]`` expert outputs -> ``[n, d]``: each token's k rows,
+def _combine(out, weights, order, row_of_pair):
+    """``[n·k, d]`` expert outputs -> ``[n, d]``: each token's k rows,
     weighted by its routing weights ``[n, k]``, summed."""
     return jnp.einsum("nkd,nk->nd", out[row_of_pair],
                       weights.astype(out.dtype))
 
 
-def _combine_fwd(out, weights, pair_of_row, valid, row_of_pair):
-    return (_combine(out, weights, pair_of_row, valid, row_of_pair),
-            (out, weights, pair_of_row, valid, row_of_pair))
+def _combine_fwd(out, weights, order, row_of_pair):
+    return (_combine(out, weights, order, row_of_pair),
+            (out, weights, order, row_of_pair))
 
 
 def _combine_bwd(res, dy):
-    out, weights, pair_of_row, valid, row_of_pair = res
+    out, weights, order, row_of_pair = res
     k = row_of_pair.shape[1]
-    row_weight = jnp.where(valid, weights.reshape(-1)[pair_of_row], 0.0)
-    d_out = dy[pair_of_row // k] * row_weight[:, None].astype(dy.dtype)
+    d_out = (dy[order // k]
+             * weights.reshape(-1)[order][:, None].astype(dy.dtype))
     d_weights = jnp.einsum("nkd,nd->nk", out[row_of_pair], dy,
                            preferred_element_type=jnp.float32)
-    return d_out, d_weights.astype(weights.dtype), None, None, None
+    return d_out, d_weights.astype(weights.dtype), None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -247,6 +216,10 @@ class MoEMLP(nn.Module):
             mean_pairs = n * self.top_k / e
             self.sow("moe_stats", "max_load", jnp.max(pairs) / mean_pairs)
             self.sow("moe_stats", "min_load", jnp.min(pairs) / mean_pairs)
+            if dropless:
+                self.sow("moe_stats", "executed_rows",
+                         executed_rows(pairs, n * self.top_k)
+                         / (n * self.top_k))
             # for a caller that asks (mutable=["intermediates"]): the routing
             self.sow("intermediates", "top_idx", top_idx)
 
@@ -273,28 +246,35 @@ class MoEMLP(nn.Module):
                 "which parallel/ep.py does not have; GSPMD would gather "
                 "every expert onto every rank instead.  Use ep=1 (experts "
                 "replicated or tp-sharded) or give a capacity_factor")
-        n, d = xf.shape
-        e, cdt = self.n_experts, self.compute_dtype
-        block = _block_rows(n * self.top_k, e)
+        cdt = self.compute_dtype
         with jax.named_scope("moe/dispatch"):
-            block_expert, pair_of_row, valid, row_of_pair = _block_layout(
-                top_idx, pairs, block)
-            rows = _dispatch(xf.astype(cdt), pair_of_row, valid, row_of_pair)
-            rows = rows.reshape(-1, block, d)
+            order, row_of_pair = _sorted_layout(top_idx)
+            rows = _dispatch(xf.astype(cdt), order, row_of_pair)
         with jax.named_scope("moe/experts"):
-            w_gate, w_up, w_down = (w.astype(cdt)
-                                    for w in (w_gate, w_up, w_down))
+            auto = [] if mesh.empty else [a for a in mesh.axis_names
+                                          if a not in mesh.manual_axes]
+            tp = "tp" if "tp" in auto else None
 
-            def one_block(_, block_in):
-                x_block, expert = block_in
-                h = (jax.nn.silu(x_block @ w_gate[expert])
-                     * (x_block @ w_up[expert]))
-                return None, h @ w_down[expert]
+            def ffn(rows, w_gate, w_up, w_down, sizes):
+                h = (jax.nn.silu(grouped_matmul(rows, w_gate, sizes))
+                     * grouped_matmul(rows, w_up, sizes))
+                out = grouped_matmul(h, w_down, sizes)
+                return jax.lax.psum(out, tp) if tp else out
 
-            _, out = jax.lax.scan(one_block, None, (rows, block_expert))
+            if auto:
+                # GSPMD cannot partition a Mosaic kernel (see
+                # ``flash_attention``): every rank runs the kernels on all
+                # the rows against its ``tp`` slice of each expert's width,
+                # and the slices' outputs are summed
+                ffn = jax.shard_map(
+                    ffn, in_specs=(P(), P(None, None, tp), P(None, None, tp),
+                                   P(None, tp, None), P()),
+                    out_specs=P(), axis_names=frozenset(auto),
+                    check_vma=False)
+            out = ffn(rows, *(w.astype(cdt) for w in (w_gate, w_up, w_down)),
+                      pairs)
         with jax.named_scope("moe/combine"):
-            return _combine(out.reshape(-1, d), top_p, pair_of_row, valid,
-                            row_of_pair)
+            return _combine(out, top_p, order, row_of_pair)
 
     def _capacity(self, xf, top_idx, top_p, w_gate, w_up, w_down):
         n, d = xf.shape

@@ -7,6 +7,8 @@ published widths on the chip (the configuration's ``check_train``).
 
 from __future__ import annotations
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,8 @@ import pytest
 
 from benchmark import common
 from tensorflowonspark_tpu.models import transformer as tfm
+from tensorflowonspark_tpu.ops.grouped_matmul import (executed_rows,
+                                                       grouped_matmul)
 from tensorflowonspark_tpu.parallel import ep as eplib
 from tensorflowonspark_tpu.parallel import mesh as meshlib
 from tensorflowonspark_tpu.parallel import tp as tplib
@@ -206,50 +210,159 @@ def test_prefill_then_decode_through_the_cache_matches_the_full_forward():
     assert _rel(jnp.concatenate(pieces, axis=1), full) < TOL
 
 
-@pytest.mark.parametrize("routing", ["random", "two_experts", "one_each"])
-def test_block_layout_places_every_pair_once(routing):
-    n, k, e, block = 40, 3, 8, 8
-    rng = np.random.default_rng(3)
-    if routing == "random":
-        top_idx = np.stack([rng.permutation(e)[:k] for _ in range(n)])
-    elif routing == "two_experts":      # the rest get no pair at all
-        top_idx = np.tile(np.array([[5, 2, 6]]), (n, 1))
-    else:
-        top_idx = (np.arange(n)[:, None] + np.arange(k)[None]) % e
-    sizes = np.bincount(top_idx.ravel(), minlength=e)
-    block_expert, pair_of_row, valid, row_of_pair = (
-        np.asarray(a) for a in eplib._block_layout(
-            jnp.asarray(top_idx, jnp.int32), jnp.asarray(sizes, jnp.int32),
-            block))
-    assert len(block_expert) == n * k // block + e
-    # every pair has one row, that row holds it, and the row's block is its
-    # expert's; within an expert rows follow token order
+def _routing(name, n=40, k=3, e=8):
+    """``[n, k]`` expert choices: uneven groups that are no multiple of any
+    tile, five empty groups of eight, or every group alike."""
+    if name == "random":
+        rng = np.random.default_rng(3)
+        return np.stack([rng.permutation(e)[:k] for _ in range(n)])
+    if name == "two_experts":       # the rest get no pair at all
+        return np.tile(np.array([[5, 2, 6]]), (n, 1))
+    return (np.arange(n)[:, None] + np.arange(k)[None]) % e
+
+
+ROUTINGS = ["random", "two_experts", "one_each"]
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_sort_and_its_inverse_place_every_pair_once(routing):
+    top_idx = _routing(routing)
+    n, k = top_idx.shape
+    order, row_of_pair = (np.asarray(a) for a in eplib._sorted_layout(
+        jnp.asarray(top_idx, jnp.int32)))
+    # every pair has one row and that row holds it; rows are in expert
+    # order and, within an expert, in token order
     rows = row_of_pair.ravel()
-    assert len(set(rows)) == n * k and valid[rows].all()
-    assert valid.sum() == n * k
-    np.testing.assert_array_equal(pair_of_row[rows], np.arange(n * k))
-    np.testing.assert_array_equal(block_expert[rows // block],
-                                  top_idx.ravel())
-    for expert in range(e):
-        mine = np.sort(rows[top_idx.ravel() == expert])
-        np.testing.assert_array_equal(pair_of_row[mine] // k,
-                                      np.sort(pair_of_row[mine] // k))
-        if len(mine):
-            assert mine[0] % block == 0
-            np.testing.assert_array_equal(np.diff(mine), 1)
+    assert sorted(rows) == list(range(n * k))
+    np.testing.assert_array_equal(order[rows], np.arange(n * k))
+    expert_of_row = top_idx.ravel()[order]
+    assert (np.diff(expert_of_row) >= 0).all()
+    same = np.diff(expert_of_row) == 0
+    assert (np.diff(order // k)[same] >= 0).all()
+
+
+@pytest.mark.parametrize("impl", ["pallas_interpret", "xla"])
+@pytest.mark.parametrize("routing,d,f", [
+    *((routing, 32, 48) for routing in ROUTINGS),
+    ("random", 4096, 136),      # a contraction in two tiles, odd lanes
+], ids=[*ROUTINGS, "wide"])
+def test_grouped_matmul_and_both_cotangents_match_a_loop_over_experts(
+        routing, d, f, impl):
+    """bf16 operands against a dense float32 product per expert: the
+    forward, the rows' cotangent and every expert's weight cotangent (zero
+    for an expert with no row), each to bf16's rounding of its own size."""
+    e = 8
+    sizes = np.bincount(_routing(routing, n=100).ravel(), minlength=e)
+    m = int(sizes.sum())                       # 300: no multiple of a tile
+    rng = np.random.default_rng(4)
+    rows, w, ct = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+                   for shape in ((m, d), (e, d, f), (m, f)))
+
+    def loop(rows, w):
+        rows, w = rows.astype(jnp.float32), w.astype(jnp.float32)
+        bounds = np.concatenate([[0], np.cumsum(sizes)])
+        return jnp.concatenate([rows[lo:hi] @ w[g] for g, (lo, hi)
+                                in enumerate(zip(bounds[:-1], bounds[1:]))])
+
+    def system(rows, w):
+        return grouped_matmul(rows, w, jnp.asarray(sizes, jnp.int32),
+                              impl=impl)
+
+    want, want_vjp = jax.vjp(loop, rows, w)
+    got, got_vjp = jax.vjp(system, rows, w)
+    assert got.dtype == jnp.bfloat16 and got.shape == (m, f)
+    d_rows, d_w = got_vjp(ct)
+    for a, b in zip((got, d_rows, d_w),
+                    (want, *want_vjp(ct.astype(jnp.float32)))):
+        assert a.shape == b.shape
+        assert _rel(a, b) < 2 ** -7, (routing, impl, _rel(a, b))
+    assert not np.asarray(d_w, np.float32)[sizes == 0].any()
+    assert (sizes == 0).any() == (routing == "two_experts")
+
+
+@pytest.mark.parametrize("sizes,tile_visits", [
+    ([256] * 4, 4), ([100, 0, 156, 768], 6), ([1, 1, 1, 1021], 7)])
+def test_executed_rows_counts_a_tile_once_for_every_group_in_it(
+        sizes, tile_visits):
+    """1024 rows in tiles of 256: four visits when every group ends on a
+    tile boundary, one more for each group that starts inside a tile, and
+    one for an empty group."""
+    assert int(executed_rows(jnp.asarray(sizes, jnp.int32), 1024)) == (
+        256 * tile_visits)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A DESCRIBED v5e (nothing runs, no chip needed), inside a fixture and
+    never at import: one process at a time may load the TPU library (the
+    on-chip-measurement guide)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means: not here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("pairs,d,f,dtype", [
+    (65536, 2048, 1024, jnp.bfloat16),      # the cell's step
+    (32768, 2048, 1024, jnp.bfloat16),      # its reference check
+    (65536, 2048, 1024, jnp.float32),       # the same model with bf16 off
+    (1000, 4096, 1536, jnp.bfloat16),       # a contraction in two tiles
+], ids=["cell", "check", "float32", "wide"])
+def test_kernels_fit_a_v5e_at_olmoes_widths(one_chip, pairs, d, f, dtype):
+    """All three products of the up and of the down projection are Mosaic
+    kernels the chip's compiler accepts (tiles inside VMEM), at 64 experts."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def spec(*shape, dtype=dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(rows, w_up, w_down, sizes):
+        h = grouped_matmul(rows, w_up, sizes, impl="pallas")
+        out = grouped_matmul(h, w_down, sizes, impl="pallas")
+        return jnp.sum(out.astype(jnp.float32))
+
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without a chip: keep it out of the cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            spec(pairs, d), spec(64, d, f), spec(64, f, d),
+            spec(64, dtype=jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 6
+    assert " while(" not in hlo
 
 
 def test_dropless_under_tp_matches_and_under_ep_says_what_is_missing():
     model = _system()
     params, ids = _params(skew=True), _ids(1, (4, 16))
-    ref = model.apply({"params": params}, ids)
     mesh = meshlib.make_mesh(dp=-1, tp=2)
     shardings = tplib.rule_shardings(mesh, params, tplib.TRANSFORMER_TP_RULES)
     sharded = meshlib.shard_tree(mesh, params, shardings)
+    # forward and, through the shard_map around the grouped matmuls, the
+    # gradient of every parameter leaf
+    def out_and_grads(p, x):
+        def f(p):
+            out = model.apply({"params": p}, x)
+            return jnp.sum(jnp.sin(out)), out
+        (_, out), grads = jax.value_and_grad(f, has_aux=True)(p)
+        return out, grads
+
+    ref, ref_grads = jax.jit(out_and_grads)(params, ids)
     with jax.set_mesh(mesh):
-        out = jax.jit(lambda p, x: model.apply({"params": p}, x))(sharded, ids)
+        out, grads = jax.jit(out_and_grads)(sharded, ids)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+    assert max(jax.tree.leaves(jax.tree.map(_rel, grads, ref_grads))) < TOL
     with jax.set_mesh(meshlib.make_mesh(dp=-1, ep=2)):
         with pytest.raises(NotImplementedError, match="ragged all-to-all"):
             jax.jit(lambda p, x: model.apply({"params": p}, x))(params, ids)

@@ -57,6 +57,8 @@ def test_moe_step_names_router_dispatch_experts_combine_and_qk_norm(capacity):
         backward = [line for line in text.split("jit(step)")
                     if f"/{scope}/" in line and "transpose(" in line]
         assert backward or scope == "optimizer_update", scope
+    if capacity is None:    # a grouped matmul, not a scan over blocks of rows
+        assert "/moe/experts/while" not in text
 
 
 def test_a_dense_step_has_no_moe_scope():
