@@ -74,30 +74,55 @@ class Attention(nn.Module):
     # the split into heads matters and before RoPE.
     qk_norm: bool = False
     norm_eps: float = 1e-6
+    # Grouped-query heads: K and V are projected to ``n_kv_heads`` heads
+    # (0: as many as the queries) and query head j reads head j // group.
+    n_kv_heads: int = 0
+    # Where ``qk_norm`` sits: over each head's ``d_head`` with ONE learned
+    # scale of that width for all heads (Qwen3's placement) instead of over
+    # the whole projection (OLMoE's).
+    qk_norm_per_head: bool = False
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None, block_diffusion=None):
+        """``positions`` ``[S]`` are the tokens' RoPE positions (None: their
+        places in the sequence); ``block_diffusion=(length, block)`` puts
+        that mask in place of the causal one (``ops/attention.py``).  The
+        caller hands in both: what a sequence holds is the loss's business
+        (``make_block_diffusion_loss_fn``)."""
         b, s, _ = x.shape
         h, dh = self.n_heads, self.d_head
-        dense = lambda name: nn.DenseGeneral(  # noqa: E731
-            (h, dh), axis=-1, use_bias=False, name=name,
+        h_kv = self.n_kv_heads or h
+        dense = lambda name, heads: nn.DenseGeneral(  # noqa: E731
+            (heads, dh), axis=-1, use_bias=False, name=name,
             dtype=self.compute_dtype)
-        q, k, v = dense("q_proj")(x), dense("k_proj")(x), dense("v_proj")(x)
+        q, k, v = (dense("q_proj", h)(x), dense("k_proj", h_kv)(x),
+                   dense("v_proj", h_kv)(x))
         if self.qk_norm:
             # before the branch: the cache path computes the same model
             with jax.named_scope("qk_norm"):
-                flat = lambda t, name: RMSNorm(  # noqa: E731
-                    self.norm_eps, name=name)(
-                        t.reshape(b, s, h * dh)).reshape(b, s, h, dh)
-                q, k = flat(q, "q_norm"), flat(k, "k_norm")
+                if self.qk_norm_per_head:
+                    norm = lambda t, name: RMSNorm(  # noqa: E731
+                        self.norm_eps, name=name)(t)
+                else:
+                    norm = lambda t, name: RMSNorm(  # noqa: E731
+                        self.norm_eps, name=name)(
+                            t.reshape(b, s, -1)).reshape(t.shape)
+                q, k = norm(q, "q_norm"), norm(k, "k_norm")
         if self.decode:
+            if h_kv != h or positions is not None or block_diffusion:
+                raise NotImplementedError(
+                    "the cache path holds one K/V head per query head, at "
+                    "the tokens' own places, under the causal mask")
             return self._decode_step(x, q, k, v)
-        if self.attn_impl == "ring" and self.mesh is None:
-            raise ValueError("ring attention needs mesh=")
+        if self.attn_impl == "ring" and (
+                self.mesh is None or h_kv != h or block_diffusion):
+            raise ValueError("ring attention needs mesh=, as many K/V heads "
+                             "as query heads and the causal mask")
         # named scope: rope, layout and the kernel (both halves of its
         # VJP) carry "attention" in their op names, whatever XLA fuses
         with jax.named_scope("attention"):
-            positions = jnp.arange(s)
+            if positions is None:
+                positions = jnp.arange(s)
             q = apply_rope(q, positions, self.rope_theta)
             k = apply_rope(k, positions, self.rope_theta)
             q = constrain(q, P(BATCH, "sp", "tp", None))
@@ -111,7 +136,9 @@ class Attention(nn.Module):
                     self.mesh, q, k, v, causal=True)
             else:
                 impl = None if self.attn_impl == "auto" else self.attn_impl
-                out = flash_attention(q, k, v, causal=True, impl=impl)
+                out = flash_attention(q, k, v, causal=not block_diffusion,
+                                      impl=impl,
+                                      block_diffusion=block_diffusion)
         out = nn.DenseGeneral(x.shape[-1], axis=(-2, -1), use_bias=False,
                               name="o_proj", dtype=self.compute_dtype)(out)
         return out
@@ -194,14 +221,20 @@ class Block(nn.Module):
     qk_norm: bool = False
     moe_capacity_factor: Optional[float] = 1.25   # None: dropless routing
     moe_norm_topk_prob: bool = True
+    n_kv_heads: int = 0
+    qk_norm_per_head: bool = False
+    moe_held: Optional[tuple] = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None, block_diffusion=None):
         norm = lambda name: RMSNorm(self.norm_eps, name=name)  # noqa: E731
         x = x + Attention(self.n_heads, self.d_head, self.rope_theta,
                           self.attn_impl, self.mesh, self.compute_dtype,
                           self.decode, self.max_decode_len, self.qk_norm,
-                          self.norm_eps, name="attn")(norm("attn_norm")(x))
+                          self.norm_eps, self.n_kv_heads,
+                          self.qk_norm_per_head, name="attn")(
+                              norm("attn_norm")(x), positions,
+                              block_diffusion)
         x = constrain(x, P(BATCH, "sp", None))
         if self.n_experts:
             from tensorflowonspark_tpu.parallel.ep import MoEMLP
@@ -209,7 +242,8 @@ class Block(nn.Module):
             ffn = MoEMLP(x.shape[-1], self.d_ff, self.n_experts,
                          self.moe_top_k, self.moe_capacity_factor,
                          compute_dtype=self.compute_dtype,
-                         norm_topk_prob=self.moe_norm_topk_prob, name="moe")
+                         norm_topk_prob=self.moe_norm_topk_prob,
+                         held=self.moe_held, name="moe")
         else:
             ffn = SwiGLU(self.d_ff, self.compute_dtype, name="mlp")
         x = x + ffn(norm("mlp_norm")(x))
@@ -251,23 +285,35 @@ class Transformer(nn.Module):
     # renormalised to sum to 1 (HF ``norm_topk_prob``).
     moe_capacity_factor: Optional[float] = 1.25
     moe_norm_topk_prob: bool = True
+    # Grouped-query heads and the placement of QK-norm (see Attention); the
+    # range of each layer's ``n_experts`` experts that this chip holds (see
+    # ``parallel/ep.py``; None: all of them).
+    n_kv_heads: int = 0
+    qk_norm_per_head: bool = False
+    moe_held: Optional[tuple] = None
 
     @nn.compact
-    def __call__(self, input_ids):
+    def __call__(self, input_ids, positions=None, block_diffusion=None):
+        """``positions`` and ``block_diffusion`` go to every layer's
+        attention as they are (see ``Attention``)."""
         dh = self.d_head or self.d_model // self.n_heads
         dff = self.d_ff or 4 * self.d_model
         emb = nn.Embed(self.vocab_size, self.d_model, name="embed",
                        dtype=self.compute_dtype)
         x = emb(input_ids)
         x = constrain(x, P(BATCH, "sp", None))
-        block_cls = nn.remat(Block) if self.remat else Block
+        # the mask is a tuple of sizes: static under remat
+        block_cls = (nn.remat(Block, static_argnums=(3,)) if self.remat
+                     else Block)
         for i in range(self.n_layers):
             x = block_cls(self.n_heads, dh, dff, self.n_experts, self.moe_top_k,
                           self.rope_theta, self.attn_impl, self.mesh,
                           self.compute_dtype, self.decode, self.max_decode_len,
                           self.norm_eps, self.qk_norm,
                           self.moe_capacity_factor, self.moe_norm_topk_prob,
-                          name=f"block_{i}")(x)
+                          self.n_kv_heads, self.qk_norm_per_head,
+                          self.moe_held, name=f"block_{i}")(
+                              x, positions, block_diffusion)
         x = RMSNorm(self.norm_eps, name="final_norm")(x)
         if self.return_hidden:
             return x
@@ -280,6 +326,7 @@ class Transformer(nn.Module):
 @register("transformer")
 def build_transformer(config: dict) -> Transformer:
     capacity = config.get("moe_capacity_factor", 1.25)
+    held = config.get("moe_held")
     return Transformer(
         vocab_size=int(config.get("vocab_size", 32000)),
         d_model=int(config.get("d_model", 512)),
@@ -298,6 +345,9 @@ def build_transformer(config: dict) -> Transformer:
         # null / None in the config: dropless
         moe_capacity_factor=None if capacity is None else float(capacity),
         moe_norm_topk_prob=bool(config.get("moe_norm_topk_prob", True)),
+        n_kv_heads=int(config.get("n_kv_heads", 0)),
+        qk_norm_per_head=bool(config.get("qk_norm_per_head", False)),
+        moe_held=None if held is None else tuple(int(x) for x in held),
     )
 
 
@@ -459,6 +509,34 @@ def greedy_generate(model: Transformer, params, prompt_ids, max_new_tokens: int,
     return np.stack(tokens, axis=1)
 
 
+def _sown_collections(model: Transformer) -> list:
+    return ["aux_loss", "moe_stats"] if model.n_experts else ["aux_loss"]
+
+
+def _with_sown_terms(loss, updates, aux_loss_coef: float,
+                     router_z_coef: float):
+    """``(total, metrics)``: the LM loss plus the weighted auxiliary terms
+    the layers sowed (``aux_loss``), and their ``moe_stats`` averaged over
+    layers (see ``make_loss_fn``)."""
+    aux = jnp.asarray(0.0)
+    z = jnp.asarray(0.0)
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            updates.get("aux_loss", {}))[0]:
+        if any("router_z" in str(p) for p in path):
+            z = z + leaf
+        else:
+            aux = aux + leaf
+    total = loss + aux_loss_coef * aux + router_z_coef * z
+    metrics = {"lm_loss": loss, "aux_loss": aux, "router_z_loss": z}
+    stats: dict[str, list] = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            updates.get("moe_stats", {}))[0]:
+        name = [p.key for p in path if hasattr(p, "key")][-1]
+        stats.setdefault(f"moe_{name}", []).append(leaf)
+    metrics.update({k: jnp.mean(jnp.stack(v)) for k, v in stats.items()})
+    return total, metrics
+
+
 def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
                  vocab_chunk: int = 0, router_z_coef: float = 1e-3):
     """Next-token LM loss.  Batch: ``{"input_ids": [B, S] int32}`` (targets
@@ -477,7 +555,7 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
     materialized — the HBM-dominant op at large vocab.  Not for
     tensor-parallel vocab-sharded heads (use the dense path there)."""
 
-    sown = ["aux_loss", "moe_stats"] if model.n_experts else ["aux_loss"]
+    sown = _sown_collections(model)
 
     def _reduce(nll, batch, updates):
         mask = batch.get("loss_mask")
@@ -486,23 +564,7 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
             loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         else:
             loss = jnp.mean(nll)
-        aux = jnp.asarray(0.0)
-        z = jnp.asarray(0.0)
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-                updates.get("aux_loss", {}))[0]:
-            if any("router_z" in str(p) for p in path):
-                z = z + leaf
-            else:
-                aux = aux + leaf
-        total = loss + aux_loss_coef * aux + router_z_coef * z
-        metrics = {"lm_loss": loss, "aux_loss": aux, "router_z_loss": z}
-        stats: dict[str, list] = {}
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-                updates.get("moe_stats", {}))[0]:
-            name = [p.key for p in path if hasattr(p, "key")][-1]
-            stats.setdefault(f"moe_{name}", []).append(leaf)
-        metrics.update({k: jnp.mean(jnp.stack(v)) for k, v in stats.items()})
-        return total, metrics
+        return _with_sown_terms(loss, updates, aux_loss_coef, router_z_coef)
 
     if vocab_chunk:
         from tensorflowonspark_tpu.ops.xent import blockwise_cross_entropy
@@ -534,5 +596,83 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
             nll = -jnp.take_along_axis(logp, targets[..., None],
                                        axis=-1)[..., 0]
         return _reduce(nll, batch, updates)
+
+    return loss_fn
+
+
+def corrupt_blocks(ids, noise_seed, block: int, mask_id: int,
+                   t_min: float = 1e-3):
+    """Block diffusion's forward process on rows of token ids ``[rows, L]``
+    (BD3-LM, arXiv:2503.09573, linear schedule): every block of ``block``
+    tokens draws a noise level ``t ~ U[t_min, 1]`` and each of its tokens is
+    replaced by ``mask_id`` with probability ``t``, independently.  The draw
+    is a function of ``noise_seed`` ``[rows]`` (an integer a row, which the
+    feed makes: the step's signature holds no key).  Returns ``(noised ids,
+    masked [rows, L] bool, t [rows, L] float32, each token's level)``."""
+    length = ids.shape[1]
+
+    def row(seed):
+        k_level, k_token = jax.random.split(
+            jax.random.fold_in(jax.random.key(0), seed))
+        t = jnp.repeat(jax.random.uniform(
+            k_level, (length // block,), minval=t_min, maxval=1.0), block)
+        return jax.random.uniform(k_token, (length,)) < t, t
+
+    masked, t = jax.vmap(row)(noise_seed)
+    return jnp.where(masked, mask_id, ids), masked, t
+
+
+def make_block_diffusion_loss_fn(model: Transformer, block: int, mask_id: int,
+                                 aux_loss_coef: float = 0.01,
+                                 vocab_chunk: int = 4096,
+                                 router_z_coef: float = 0.0,
+                                 t_min: float = 1e-3):
+    """The training loss of block diffusion (BD3-LM; SDAR, arXiv:2510.06303).
+    Batch: ``{"input_ids": [rows, L] int32, "noise_seed": [rows] uint32}``.
+
+    A row ``x_0`` is corrupted (``corrupt_blocks``) into ``x_t``; the stack
+    runs ONCE over the 2L positions ``[x_t ‖ x_0]``, both copies at positions
+    ``0 .. L-1``, under the block-diffusion mask (``ops/attention.py``): a
+    noised block sees itself and the clean blocks before it.  The logits of
+    the noised copy at position i predict ``x_0[i]`` itself (no shift), and
+    ``loss = Σ_i masked_i · CE_i / t_i / (rows · L)`` plus the sown auxiliary
+    terms (``make_loss_fn``).  The head and the blockwise cross-entropy
+    (``ops/xent.py``) run over the L noised positions only.  Metrics also
+    carry ``masked_share``: masked tokens over all."""
+    from tensorflowonspark_tpu.ops.xent import blockwise_cross_entropy
+
+    sown = _sown_collections(model)
+    hidden_model = model.clone(return_hidden=True)
+
+    def loss_fn(params, batch):
+        ids = batch["input_ids"]
+        rows, length = ids.shape
+        if length % block:
+            raise ValueError(f"rows of {length} tokens in blocks of {block}")
+        # one scope around the whole loss: a transform names itself around
+        # the OUTERMOST scope it meets ("jvp(block_diffusion)/diffusion/
+        # corrupt/..."), and the readers look for whole components
+        with jax.named_scope("block_diffusion"):
+            with jax.named_scope("diffusion/corrupt"):
+                noised, masked, t = corrupt_blocks(
+                    ids, batch["noise_seed"], block, mask_id, t_min)
+                both = jnp.concatenate([noised, ids], axis=1)
+                positions = jnp.tile(jnp.arange(length), 2)
+                weight = (masked / t).reshape(-1)
+                # materialised here: fused into the embedding's gather and
+                # the loss's sum, the draw would show under their scopes
+                both, weight = jax.lax.optimization_barrier((both, weight))
+            h, updates = hidden_model.apply(
+                {"params": params}, both, positions, (length, block),
+                mutable=sown)
+            h = h[:, :length].reshape(rows * length, h.shape[-1])
+            with jax.named_scope("lm_head_loss"):
+                nll = blockwise_cross_entropy(
+                    h, params["lm_head"]["kernel"].astype(h.dtype),
+                    ids.reshape(-1), chunk=vocab_chunk)
+                loss = jnp.sum(nll * weight) / (rows * length)
+            total, metrics = _with_sown_terms(loss, updates, aux_loss_coef,
+                                              router_z_coef)
+        return total, {**metrics, "masked_share": jnp.mean(masked)}
 
     return loss_fn

@@ -18,6 +18,15 @@ this build, so the hot op gets a real TPU kernel:
 Array convention: ``[batch, seq, heads, head_dim]`` (flax-style).  All
 softmax accumulation is float32 regardless of input dtype (bf16 inputs keep
 the MXU fed; the VPU-side accumulators must not lose mass).
+
+Grouped-query heads: ``k`` and ``v`` may carry fewer heads than ``q`` (a
+divisor); query head ``j`` reads K/V head ``j // group``.  The kernels index
+the K/V block by it and never repeat K or V in memory.
+
+Masks: ``causal``, or ``block_diffusion=(length, block)``, the training mask
+of block diffusion (BD3-LM, arXiv:2503.09573; SDAR, arXiv:2510.06303) over
+the concatenation of a noised and a clean copy of one row
+(``block_diffusion_visible``).
 """
 
 from __future__ import annotations
@@ -50,19 +59,52 @@ def match_vma(x, like):
 # Reference (dense) attention — the spec the kernels are tested against.
 # ---------------------------------------------------------------------------
 
+def block_diffusion_visible(qpos, kpos, length: int, block: int):
+    """The block-diffusion training mask, elementwise on index arrays.
+
+    The sequence is ``[x_t ‖ x_0]``: indices below ``length`` are the NOISED
+    copy of a row of ``length`` tokens, the rest its CLEAN copy; a token's
+    position is its index within its copy and its block ``position //
+    block``.  A noised query sees the noised keys of its own block and the
+    clean keys of earlier blocks; a clean query sees the clean keys of its
+    own and earlier blocks; no query sees a noised key outside its block."""
+    q_noised, k_noised = qpos < length, kpos < length
+    qp = jnp.where(q_noised, qpos, qpos - length)
+    kp = jnp.where(k_noised, kpos, kpos - length)
+    start = qp - qp % block                 # where the query's block starts
+    # no select between masks: Mosaic has none for vectors of booleans
+    own_block = k_noised & q_noised & (kp >= start) & (kp < start + block)
+    clean_past = jnp.logical_not(k_noised) & (
+        kp < jnp.where(q_noised, start, start + block))
+    return own_block | clean_past
+
+
+def _repeat_kv(q, k, v):
+    """K and V at the query's head count (the paths off the kernels)."""
+    group = q.shape[2] // k.shape[2]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+
+
 def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None,
-                  kv_offset: int = 0):
+                  kv_offset: int = 0, block_diffusion=None):
     """Dense O(S²) attention.  ``kv_offset`` is the global position of
     ``k[:, 0]`` relative to ``q[:, 0]`` (ring attention passes non-zero
     offsets so causal masks stay globally consistent across chunks)."""
     *_, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+    k, v = _repeat_kv(q, k, v)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    sq, sk = q.shape[1], k.shape[1]
+    qpos = jnp.arange(sq)[:, None]
+    kpos = kv_offset + jnp.arange(sk)[None, :]
     if causal:
-        sq, sk = q.shape[1], k.shape[1]
-        qpos = jnp.arange(sq)[:, None]
-        kpos = kv_offset + jnp.arange(sk)[None, :]
         logits = jnp.where(kpos <= qpos, logits, NEG_INF)
+    if block_diffusion:
+        logits = jnp.where(block_diffusion_visible(qpos, kpos,
+                                                   *block_diffusion),
+                           logits, NEG_INF)
     weights = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v.astype(jnp.float32)).astype(q.dtype)
 
@@ -119,7 +161,7 @@ def merge_attention(o1, lse1, o2, lse2):
 
 def blockwise_attention(q, k, v, *, causal: bool = True,
                         sm_scale: float | None = None, block_k: int = 512,
-                        kv_offset: int = 0):
+                        kv_offset: int = 0, block_diffusion=None):
     """Flash-style attention as a ``lax.scan`` over KV blocks.
 
     Differentiable, runs on every backend, and with the per-block
@@ -128,6 +170,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    k, v = _repeat_kv(q, k, v)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     block_k = min(block_k, sk)
     nblocks = -(-sk // block_k)
@@ -149,6 +192,9 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
         mask = kpos < kv_offset + sk  # padded tail
         if causal:
             mask = mask & (kpos <= qpos)
+        if block_diffusion:
+            mask = mask & block_diffusion_visible(qpos, kpos,
+                                                  *block_diffusion)
         logits = jnp.where(mask, logits, NEG_INF)
         m_blk = jnp.max(logits, axis=-1)
         m_new = jnp.maximum(m_acc, m_blk)
@@ -178,46 +224,105 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T: contract the last dim of both
 
 
+def _block_diffusion_tile(q_lo, q_hi, k_lo, k_hi, length: int, block: int):
+    """``block_diffusion_visible`` on the tile of queries ``q_lo..q_hi`` and
+    keys ``k_lo..k_hi`` (index ranges, ends included), as scalars: whether
+    SOME pair is visible and whether EVERY pair is.  A tile may straddle
+    the two copies, so each range is taken apart into its noised and its
+    clean positions and the four combinations are judged on their own."""
+
+    def copies(lo, hi):     # (noised?, first position, last position)
+        return ((True, lo, jnp.minimum(hi, length - 1)),
+                (False, jnp.maximum(lo, length) - length, hi - length))
+
+    some, every = False, True
+    for q_noised, qa, qb in copies(q_lo, q_hi):
+        # where the blocks of the first and of the last query start
+        first, last = qa // block * block, qb // block * block
+        for k_noised, ka, kb in copies(k_lo, k_hi):
+            there = (qa <= qb) & (ka <= kb)
+            if k_noised and q_noised:
+                any_ = (ka < last + block) & (kb >= first)
+                all_ = (first == last) & (ka >= first) & (kb < first + block)
+            elif k_noised:
+                any_ = all_ = False
+            else:
+                reach = 0 if q_noised else block
+                any_, all_ = ka < last + reach, kb < first + reach
+            some = some | (there & any_)
+            every = every & (jnp.logical_not(there) | all_)
+    return some, every
+
+
 def _tile_live(q_start, k_start, *, causal: bool, kv_offset: int,
-               block_q: int, sk: int):
+               block_q: int, block_k: int, sk: int, block_diffusion=None):
     """Whether the tile of queries from ``q_start`` and keys from ``k_start``
     (a global position) holds any visible pair: not wholly padding and, under
     ``causal``, not wholly in the future.  All three kernels skip a dead
-    tile; a further mask (segments, a window) adds its condition here."""
+    tile; a further mask adds its condition here, as ``block_diffusion``
+    does."""
     live = k_start < kv_offset + sk
     if causal:
         live = jnp.logical_and(live, k_start <= q_start + block_q - 1)
+    if block_diffusion:
+        live = jnp.logical_and(live, _block_diffusion_tile(
+            q_start, q_start + block_q - 1, k_start,
+            jnp.minimum(k_start + block_k, sk) - 1, *block_diffusion)[0])
     return live
 
 
 def _tile_interior(q_start, k_start, *, causal: bool, kv_offset: int,
-                   block_k: int, sk: int):
+                   block_q: int, block_k: int, sk: int,
+                   block_diffusion=None):
     """Whether EVERY pair of the tile is visible (it holds no padding and,
-    under ``causal``, lies wholly in the past): the backward kernels build
-    no mask there.  A further mask narrows this as it narrows ``_tile_live``."""
+    under ``causal``, lies wholly in the past): the kernels build no mask
+    there.  A further mask narrows this as it narrows ``_tile_live``."""
     interior = k_start + block_k <= kv_offset + sk
     if causal:
         interior = jnp.logical_and(interior, k_start + block_k - 1 <= q_start)
+    if block_diffusion:
+        interior = jnp.logical_and(interior, _block_diffusion_tile(
+            q_start, q_start + block_q - 1, k_start, k_start + block_k - 1,
+            *block_diffusion)[1])
     return interior
 
 
 def _tile_visible(shape, q_dim: int, *, q_start, k_start, causal: bool,
-                  kv_offset: int, sk: int):
+                  kv_offset: int, sk: int, block_diffusion=None):
     """The visible pairs of one live tile, queries along ``q_dim`` of
     ``shape`` and keys along the other: the padded tail of the keys is never
     visible, nor under ``causal`` a key after its query."""
     kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
     mask = kpos < kv_offset + sk
-    if causal:
+    if causal or block_diffusion:
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    if causal:
         mask = jnp.logical_and(mask, kpos <= qpos)
+    if block_diffusion:
+        mask = jnp.logical_and(mask, block_diffusion_visible(
+            qpos, kpos, *block_diffusion))
     return mask
+
+
+def _on_live_tile(attend, q_start, k_start, *, block_q: int, block_k: int,
+                  **mask_args):
+    """Run ``attend(visible)`` if the tile is live: with None where every
+    pair is visible, else with the tile's mask as a function of the score
+    tile's shape and the dimension its queries lie along."""
+    tile = dict(block_q=block_q, block_k=block_k, **mask_args)
+    interior = _tile_interior(q_start, k_start, **tile)
+    live = _tile_live(q_start, k_start, **tile)
+    del tile["block_q"], tile["block_k"]
+    pl.when(interior)(lambda: attend(None))
+    pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
+        lambda: attend(functools.partial(
+            _tile_visible, q_start=q_start, k_start=k_start, **tile)))
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref,
-                      *, sm_scale: float, causal: bool, kv_offset: int,
-                      block_q: int, block_k: int, sk: int):
+                      *, sm_scale: float, kv_offset: int,
+                      block_q: int, block_k: int, **mask_args):
     # m/l scratch is lane-replicated to 128 lanes (column 0 is authoritative)
     # — TPU tiling requires the last dim be 128-aligned.  The lse goes out as
     # a ROW per (batch, head): a residual of the backward, 128 times smaller
@@ -234,17 +339,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     q_start = iq * block_q
     k_start = kv_offset + ik * block_k
-    mask_args = dict(causal=causal, kv_offset=kv_offset, sk=sk)
+    mask_args = dict(kv_offset=kv_offset, **mask_args)
 
-    @pl.when(_tile_live(q_start, k_start, block_q=block_q, **mask_args))
-    def _attend():
+    def attend(visible):
         qb = q_ref[0].astype(jnp.float32)              # [block_q, d]
         kb = k_ref[0].astype(jnp.float32)              # [block_k, d]
         logits = jax.lax.dot_general(
             qb, kb, _NT, preferred_element_type=jnp.float32) * sm_scale
-        mask = _tile_visible(logits.shape, 0, q_start=q_start,
-                             k_start=k_start, **mask_args)
-        logits = jnp.where(mask, logits, NEG_INF)
+        if visible is not None:
+            logits = jnp.where(visible(logits.shape, 0), logits, NEG_INF)
         m_prev = m_ref[:]                               # [block_q, 128]
         m_blk = jnp.max(logits, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_blk)
@@ -257,6 +360,18 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[:] = m_new
+
+    if mask_args["block_diffusion"]:
+        # most live tiles lie whole inside this mask, and it costs more to
+        # build than the causal one: no mask there, as in the backward
+        _on_live_tile(attend, q_start, k_start, block_q=block_q,
+                      block_k=block_k, **mask_args)
+    else:
+        pl.when(_tile_live(q_start, k_start, block_q=block_q,
+                           block_k=block_k, **mask_args))(
+            lambda: attend(functools.partial(
+                _tile_visible, q_start=q_start, k_start=k_start,
+                **mask_args)))
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -295,26 +410,37 @@ def _pad_seq(x, s_p: int):
     return jnp.pad(x, ((0, 0), (0, s_p - x.shape[1]), (0, 0)))
 
 
+def _kv_row(group: int):
+    """Grid row of a query head -> row of the K/V head it reads: head-major
+    rows are ``batch * heads + head``, so ``row // group`` on both sides."""
+    if group == 1:
+        return lambda bh: bh
+    return lambda bh: bh // group
+
+
 def _flash_fwd_pallas(qt, kt, vt, *, causal, sm_scale, kv_offset,
-                      block_q, block_k, interpret):
-    """Run the Pallas forward on head-major ``[B*H, S, D_p]`` operands;
-    returns ``(out [B*H, Sq, D_p], lse [B*H, Sq] float32)``."""
+                      block_q, block_k, interpret, block_diffusion=None):
+    """Run the Pallas forward on head-major ``[B*H, S, D_p]`` operands
+    (``[B*H_kv, S, D_p]`` keys and values); returns ``(out [B*H, Sq, D_p],
+    lse [B*H, Sq] float32)``."""
     bh, sq, d_p = qt.shape
     sk = kt.shape[1]
+    kv = _kv_row(bh // kt.shape[0])
     block_q, block_k, sq_p, sk_p = _blocks(sq, sk, block_q, block_k)
     qt, kt, vt = _pad_seq(qt, sq_p), _pad_seq(kt, sk_p), _pad_seq(vt, sk_p)
 
     grid = (bh, sq_p // block_q, sk_p // block_k)
     kernel = functools.partial(
         _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
-        kv_offset=kv_offset, block_q=block_q, block_k=block_k, sk=sk)
+        kv_offset=kv_offset, block_q=block_q, block_k=block_k, sk=sk,
+        block_diffusion=block_diffusion)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d_p), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_k, d_p), lambda bh, iq, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, block_k, d_p), lambda bh, iq, ik: (bh, ik, 0)),
+            pl.BlockSpec((1, block_k, d_p), lambda bh, iq, ik: (kv(bh), ik, 0)),
+            pl.BlockSpec((1, block_k, d_p), lambda bh, iq, ik: (kv(bh), ik, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d_p), lambda bh, iq, ik: (bh, iq, 0)),
@@ -334,19 +460,6 @@ def _flash_fwd_pallas(qt, kt, vt, *, causal, sm_scale, kv_offset,
     return out[:, :sq], lse[:, 0, :sq]
 
 
-def _on_live_tile(attend, q_start, k_start, *, block_q: int, block_k: int,
-                  **mask_args):
-    """Run ``attend(visible)`` if the tile is live: with None where every
-    pair is visible, else with the tile's mask as a function of the score
-    tile's shape and the dimension its queries lie along."""
-    interior = _tile_interior(q_start, k_start, block_k=block_k, **mask_args)
-    live = _tile_live(q_start, k_start, block_q=block_q, **mask_args)
-    pl.when(interior)(lambda: attend(None))
-    pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
-        lambda: attend(functools.partial(
-            _tile_visible, q_start=q_start, k_start=k_start, **mask_args)))
-
-
 def _recompute_p(logits, visible, q_dim: int, lse):
     """The softmax weights of one tile from the forward's log-sum-exp: no
     running max, no rescale.  ``lse`` (a row or a column against ``logits``)
@@ -360,15 +473,17 @@ def _recompute_p(logits, visible, q_dim: int, lse):
 def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc,
                           *, sm_scale: float, block_q: int, block_k: int,
-                          kv_offset: int, **mask_args):
-    # One KV block against every q block (the sequential axis), on the
-    # TRANSPOSED tile [block_k, block_q]: lse and delta are then rows, lane-
-    # dense as they lie in memory, and both accumulations are plain matmuls.
+                          kv_offset: int, group: int, **mask_args):
+    # One KV block against every q block of every query head of its group
+    # (the sequential axis, head by head), on the TRANSPOSED tile [block_k,
+    # block_q]: lse and delta are then rows, lane-dense as they lie in
+    # memory, and both accumulations are plain matmuls.
     ik = pl.program_id(1)
-    iq = pl.program_id(2)
-    nq = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
+    iq = step if group == 1 else step % (steps // group)
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -390,7 +505,7 @@ def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                   block_q=block_q, block_k=block_k, kv_offset=kv_offset,
                   **mask_args)
 
-    @pl.when(iq == nq - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         dk_ref[0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -432,12 +547,16 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 
 def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, *, causal, sm_scale,
-                      kv_offset, block_q, block_k, interpret):
+                      kv_offset, block_q, block_k, interpret,
+                      block_diffusion=None):
     """Both backward passes on head-major operands; ``lse`` as
     ``_flash_fwd_pallas`` returns it and ``delta = rowsum(dO * O)`` like it,
-    ``[B*H, Sq]`` float32.  Returns ``(dq, dk, dv)`` head-major."""
+    ``[B*H, Sq]`` float32.  Returns ``(dq, dk, dv)`` head-major, dk and dv
+    at the K/V head count: the dk/dv pass sums over a group's query heads."""
     bh, sq, d_p = qt.shape
     sk = kt.shape[1]
+    group = bh // kt.shape[0]
+    kv = _kv_row(group)
     block_q, block_k, sq_p, sk_p = _blocks(sq, sk, block_q, block_k)
     # a row that saw no key has lse = NEG_INF: lifted, so that exp(NEG_INF -
     # lse) is 0 there too.  Padded q rows have do = 0 and so add nothing.
@@ -449,19 +568,29 @@ def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, *, causal, sm_scale,
     kt, vt = _pad_seq(kt, sk_p), _pad_seq(vt, sk_p)
 
     static = dict(sm_scale=sm_scale, causal=causal, kv_offset=kv_offset,
-                  block_q=block_q, block_k=block_k, sk=sk)
+                  block_q=block_q, block_k=block_k, sk=sk,
+                  block_diffusion=block_diffusion)
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     q_block, k_block = (1, block_q, d_p), (1, block_k, d_p)
+    nq = sq_p // block_q
 
+    if group == 1:
+        q_at = lambda bh, ik, iq: (bh, iq, 0)           # noqa: E731
+        row_at = lambda bh, ik, iq: (bh, 0, iq)         # noqa: E731
+    else:   # step = (query head of the group) * nq + q block
+        q_at = lambda bh, ik, step: (                   # noqa: E731
+            bh * group + step // nq, step % nq, 0)
+        row_at = lambda bh, ik, step: (                 # noqa: E731
+            bh * group + step // nq, 0, step % nq)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, **static),
-        grid=(bh, sk_p // block_k, sq_p // block_q),
+        functools.partial(_flash_bwd_dkv_kernel, group=group, **static),
+        grid=(bh // group, sk_p // block_k, group * nq),
         in_specs=[
-            pl.BlockSpec(q_block, lambda bh, ik, iq: (bh, iq, 0)),
-            pl.BlockSpec(q_block, lambda bh, ik, iq: (bh, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, ik, iq: (bh, 0, iq)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, ik, iq: (bh, 0, iq)),
+            pl.BlockSpec(q_block, q_at),
+            pl.BlockSpec(q_block, q_at),
+            pl.BlockSpec((1, 1, block_q), row_at),
+            pl.BlockSpec((1, 1, block_q), row_at),
             pl.BlockSpec(k_block, lambda bh, ik, iq: (bh, ik, 0)),
             pl.BlockSpec(k_block, lambda bh, ik, iq: (bh, ik, 0)),
         ],
@@ -475,14 +604,14 @@ def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, *, causal, sm_scale,
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **static),
-        grid=(bh, sq_p // block_q, sk_p // block_k),
+        grid=(bh, nq, sk_p // block_k),
         in_specs=[
             pl.BlockSpec(q_block, lambda bh, iq, ik: (bh, iq, 0)),
             pl.BlockSpec(q_block, lambda bh, iq, ik: (bh, iq, 0)),
             pl.BlockSpec((1, block_q, 128), lambda bh, iq, ik: (bh, iq, 0)),
             pl.BlockSpec((1, block_q, 128), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec(k_block, lambda bh, iq, ik: (bh, ik, 0)),
-            pl.BlockSpec(k_block, lambda bh, iq, ik: (bh, ik, 0)),
+            pl.BlockSpec(k_block, lambda bh, iq, ik: (kv(bh), ik, 0)),
+            pl.BlockSpec(k_block, lambda bh, iq, ik: (kv(bh), ik, 0)),
         ],
         out_specs=pl.BlockSpec(q_block, lambda bh, iq, ik: (bh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
@@ -498,7 +627,7 @@ def _scale(sm_scale, d: int) -> float:
 
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, kv_offset, block_q, block_k,
-                    interpret):
+                    interpret, block_diffusion):
     # q, k, v stay head-major and lane-padded, as both backward kernels read
     # them (the backward lays out only the cotangent); the output stays as
     # the caller holds it anyway, and the kernel's log-sum-exp is kept.
@@ -508,20 +637,20 @@ def _flash_fwd_rule(q, k, v, causal, sm_scale, kv_offset, block_q, block_k,
         ot, lse = _flash_fwd_pallas(
             qt, kt, vt, causal=causal, sm_scale=_scale(sm_scale, d),
             kv_offset=kv_offset, block_q=block_q, block_k=block_k,
-            interpret=interpret)
+            interpret=interpret, block_diffusion=block_diffusion)
         out = _from_head_major(ot, b, d)
         return out, (qt, kt, vt, out, lse)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_attention_tpu(q, k, v, causal, sm_scale, kv_offset,
-                         block_q, block_k, interpret):
+                         block_q, block_k, interpret, block_diffusion):
     return _flash_fwd_rule(q, k, v, causal, sm_scale, kv_offset,
-                           block_q, block_k, interpret)[0]
+                           block_q, block_k, interpret, block_diffusion)[0]
 
 
 def _flash_bwd_rule(causal, sm_scale, kv_offset, block_q, block_k, interpret,
-                    res, g):
+                    block_diffusion, res, g):
     qt, kt, vt, out, lse = res
     b, sq, h, d = g.shape
     with jax.named_scope("flash_bwd"):
@@ -530,7 +659,8 @@ def _flash_bwd_rule(causal, sm_scale, kv_offset, block_q, block_k, interpret,
         grads = _flash_bwd_pallas(
             qt, kt, vt, _head_major(g, interpret), lse, delta,
             causal=causal, sm_scale=_scale(sm_scale, d), kv_offset=kv_offset,
-            block_q=block_q, block_k=block_k, interpret=interpret)
+            block_q=block_q, block_k=block_k, interpret=interpret,
+            block_diffusion=block_diffusion)
         return tuple(_from_head_major(x, b, d) for x in grads)
 
 
@@ -547,23 +677,44 @@ Impl = Literal["pallas", "pallas_interpret", "xla"]
 def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: float | None = None, kv_offset: int = 0,
                     block_q: int = 512, block_k: int = 512,
-                    impl: Impl | None = None):
-    """Multi-head attention, ``[B, S, H, D]`` in and out.
+                    impl: Impl | None = None,
+                    block_diffusion: tuple[int, int] | None = None):
+    """Attention, ``[B, S, H, D]`` queries in and out; ``k`` and ``v`` carry
+    ``H`` heads or a divisor of it (grouped-query heads).
+
+    ``block_diffusion=(length, block)`` replaces ``causal`` by the training
+    mask of block diffusion over ``2 * length`` positions
+    (``block_diffusion_visible``).
 
     ``impl=None`` auto-selects: Pallas kernel on TPU, blockwise XLA scan
     elsewhere.  ``pallas_interpret`` runs the kernel in interpreter mode (CPU
     tests of the kernel itself).
     """
+    if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"{q.shape[2]} query heads over {k.shape[2]} key and "
+                         f"{v.shape[2]} value heads")
+    if block_diffusion:
+        block_diffusion = tuple(int(x) for x in block_diffusion)
+        length, block = block_diffusion
+        if causal or kv_offset or length % block or not (
+                q.shape[1] == k.shape[1] == 2 * length):
+            raise ValueError(
+                f"block_diffusion={block_diffusion} masks a noised and a "
+                f"clean copy of {length} tokens in whole blocks, queries and "
+                f"keys alike, in place of causal: got {q.shape[1]} queries, "
+                f"{k.shape[1]} keys, causal={causal}, kv_offset={kv_offset}")
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "xla":
         return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                                   block_k=block_k, kv_offset=kv_offset)
+                                   block_k=block_k, kv_offset=kv_offset,
+                                   block_diffusion=block_diffusion)
     if impl in ("pallas", "pallas_interpret"):
         def kernel(q, k, v):
             return _flash_attention_tpu(q, k, v, causal, sm_scale, kv_offset,
                                         block_q, block_k,
-                                        impl == "pallas_interpret")
+                                        impl == "pallas_interpret",
+                                        block_diffusion)
 
         # GSPMD cannot partition a Mosaic kernel: on more than one device
         # jax refuses to lower a bare pallas_call ("Mosaic kernels cannot be

@@ -4,7 +4,10 @@
 every group ``g``, where ``sizes[g]`` consecutive rows belong to it: the
 expert layer of a dropless mixture of experts (``parallel/ep.py``), whose
 (token, expert) pairs are sorted by expert.  Nothing is padded and no shape
-depends on the sizes.
+depends on the sizes.  The sizes may add up to fewer rows than there are (a
+chip that holds some of a layer's experts sorts their pairs to the front):
+the rows past the last group belong to none, no tile of them is visited,
+and their rows of the result are not written.
 
 On TPU all three products are Pallas kernels over tiles of ``tm`` rows, the
 MegaBlocks way (Gale et al., arXiv:2211.15841).  ``_visits`` lists the (row
@@ -239,7 +242,8 @@ Impl = Literal["pallas", "pallas_interpret", "xla"]
 
 def grouped_matmul(rows, w, sizes, *, impl: Impl | None = None):
     """``[m, k]`` rows sorted by group, ``[g, k, n]`` weights, ``[g]`` int32
-    sizes that sum to ``m`` -> ``[m, n]`` in the rows' dtype.
+    sizes that sum to ``m`` or less -> ``[m, n]`` in the rows' dtype (rows
+    past the last group: not initialised by the kernels, zeros off the TPU).
 
     ``impl=None`` auto-selects as ``flash_attention`` does: the kernels on
     TPU, ``jax.lax.ragged_dot`` elsewhere; ``pallas_interpret`` runs the

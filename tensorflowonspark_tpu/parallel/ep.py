@@ -39,10 +39,30 @@ the mean — what shows a router collapsing.  Dropless routing adds
 ``executed_rows``: the rows the grouped matmul's tiles compute over the
 ``n·k`` routed ones (a row tile that holds rows of several experts is
 visited once for each), which is what uneven routing costs the kernel.
+
+**The chip's share of a layer's experts** (``MoEMLP.held``): under expert
+parallelism a chip holds a range of the layer's experts.  The router stays
+as wide as the layer and routes over all of it; this chip computes the part
+of the result that its own experts give, and what the absent ones would add
+is left out (on one chip the layer runs without its exchange).  Every pair
+MAY land here, so a buffer of sorted rows that fits whatever the routing is
+``n·k`` long, while on average ``held / n_experts`` of the pairs come: such
+buffers would cost eight layers' worth of gathers and memory for one
+layer's work.  So one sort over all the pairs puts the held ones first, in
+token order, and they are taken ``piece`` rows at a time (twice the even
+share, a static size): the first piece always, the further ones by a loop
+whose trip count follows the pairs that came (``_held_experts``): none on
+even routing, ``n·k / piece - 1`` if every pair lands here, the same program
+either way.  Within a piece the pairs are sorted by expert for the grouped
+matmul, whose tiles past the piece's held rows are not visited, and taken
+back to token order, where a token's pairs lie next to each other and its
+sum is one more gather.  ``moe_stats/held_pairs`` is the held pairs' share
+of ``n·k``.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -157,6 +177,138 @@ def _combine_bwd(res, dy):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+# ---------------------------------------------------------------------------
+# A chip's share of the experts: the held pairs, a piece at a time.
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rows(transpose, x, idx, aux):
+    """``x[idx]`` whose cotangent is ``transpose(g, *aux)``: where the
+    indices' inverse is known the transpose is a gather too (autodiff would
+    scatter-add rows)."""
+    return x[idx]
+
+
+def _rows_fwd(transpose, x, idx, aux):
+    return x[idx], aux
+
+
+def _rows_bwd(transpose, aux, g):
+    return transpose(g, *aux), None, None
+
+
+_rows.defvjp(_rows_fwd, _rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _sum_runs(z, tok, last, has, run: int):
+    """``[r, d]`` rows in token order (row s is token ``tok[s]``'s: a token's
+    rows lie next to each other, at most ``run`` of them) -> ``[n, d]``,
+    each token's rows summed: ``last[t]`` is the row its run ends at and
+    ``has[t]`` whether it has one."""
+    rows = z.shape[0]
+    # one pass: the run's earlier rows are shifted views of one padded copy
+    z_pad = jnp.pad(z, ((run - 1, 0), (0, 0)))
+    tok_pad = jnp.pad(tok, (run - 1, 0), constant_values=-1)
+    total = z.astype(jnp.float32)
+    for i in range(1, run):
+        at = slice(run - 1 - i, run - 1 - i + rows)
+        total = total + jnp.where((tok_pad[at] == tok)[:, None],
+                                  z_pad[at].astype(jnp.float32), 0.0)
+    return jnp.where(has[:, None], total.astype(z.dtype)[last], 0)
+
+
+def _sum_runs_fwd(z, tok, last, has, run):
+    return _sum_runs(z, tok, last, has, run), tok
+
+
+def _sum_runs_bwd(run, tok, g):
+    return g[tok], None, None, None
+
+
+_sum_runs.defvjp(_sum_runs_fwd, _sum_runs_bwd)
+
+
+def _piece_rows(pairs: int, share: float) -> int:
+    """Rows of one piece of held pairs: twice the even share of the ``pairs``
+    (token, expert) pairs, in whole row tiles, at most all of them."""
+    return min(pairs, -(-int(2 * pairs * share) // 256) * 256)
+
+
+def _held_piece(c, ints, xf, top_p, w_gate, w_up, w_down, *, ffn, first: int,
+                n_held: int, piece: int):
+    """What the held pairs ``c·piece .. (c+1)·piece`` (in token order) add to
+    the layer's output ``[n, d]``."""
+    flat_idx, cum, starts, ends, held_first, held = ints
+    k = flat_idx.shape[0] // xf.shape[0]
+    lo = c * piece
+    with jax.named_scope("moe/dispatch"):
+        valid = lo + jnp.arange(piece, dtype=jnp.int32) < cum[-1]
+        # the pairs that are the piece's held ones, their tokens and experts
+        pair = jax.lax.dynamic_slice(held_first, (lo,), (piece,))
+        tok = pair // k
+        expert = jnp.where(valid, flat_idx[pair] - first, n_held)
+        order = jnp.argsort(expert, stable=True)      # the piece's one sort
+        inverse = (jnp.zeros((piece,), jnp.int32)
+                   .at[order].set(jnp.arange(piece, dtype=jnp.int32)))
+        sizes = jnp.sum(jax.nn.one_hot(expert, n_held, dtype=jnp.int32), 0)
+        # where each token's run ends within the piece, if it has rows here
+        last = jnp.minimum(ends, lo + piece) - 1 - lo
+        has = last >= jnp.maximum(starts, lo) - lo
+        last = jnp.clip(last, 0, piece - 1)
+
+        def sum_tokens(g, inverse, tok, valid, last, has):
+            # rows past the held ones were never written: a select
+            return _sum_runs(jnp.where(valid[:, None], g[inverse], 0),
+                             tok, last, has, k)
+
+        rows = _rows(sum_tokens, xf, tok[order],
+                     (inverse, tok, valid, last, has))
+    with jax.named_scope("moe/experts"):
+        out = ffn(rows, w_gate, w_up, w_down, sizes)
+    with jax.named_scope("moe/combine"):
+        out = _rows(lambda g, order: g[order], out, inverse, (order,))
+        out = jnp.where(valid[:, None], out, 0)
+        # this piece's pairs' routing weights; the others get no cotangent
+        here = held & (cum > lo) & (cum <= lo + piece)
+        weights = _rows(lambda g, here, at: jnp.where(here, g[at], 0),
+                        top_p.reshape(-1), pair,
+                        (here, jnp.clip(cum - 1 - lo, 0, piece - 1)))
+        return _sum_runs(out * weights[:, None].astype(out.dtype),
+                         tok, last, has, k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(piece_fn, piece: int, ints, args):
+    """The sum of ``piece_fn(c, ints, *args)`` over the pieces of ``piece``
+    rows AFTER THE FIRST that hold a held pair (``ints[1][-1]`` of them: the
+    trip count is the routing's, no shape is).  The backward runs each piece
+    again."""
+    n_pieces = -(-ints[1][-1] // piece)
+    return jax.lax.fori_loop(
+        1, n_pieces, lambda c, y: y + piece_fn(c, ints, *args),
+        jnp.zeros_like(args[0]))
+
+
+def _held_experts_fwd(piece_fn, piece, ints, args):
+    return _held_experts(piece_fn, piece, ints, args), (ints, args)
+
+
+def _held_experts_bwd(piece_fn, piece, res, g):
+    ints, args = res
+    n_pieces = -(-ints[1][-1] // piece)
+
+    def body(c, grads):
+        _, vjp = jax.vjp(lambda *a: piece_fn(c, ints, *a), *args)
+        return jax.tree.map(jnp.add, grads, vjp(g))
+
+    return None, jax.lax.fori_loop(
+        1, n_pieces, body, jax.tree.map(jnp.zeros_like, args))
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
 class MoEMLP(nn.Module):
     """Top-k routed SwiGLU MoE FFN, ``[B, S, D] -> [B, S, D]``.
 
@@ -172,6 +324,11 @@ class MoEMLP(nn.Module):
     define it, where the capacity rule counts the first (Switch eq. 4).
     ``norm_topk_prob`` renormalises the k routing weights to sum to 1
     (HF's key of that name; OLMoE publishes ``false``).
+
+    ``held = (first, end)`` is this chip's share of the layer's experts
+    (module docstring): the router and the routing stay ``n_experts`` wide,
+    the expert weights are ``[end - first, ...]`` and the output is the held
+    experts' part of the sum.  Dropless routing only.
     """
 
     d_model: int
@@ -181,6 +338,7 @@ class MoEMLP(nn.Module):
     capacity_factor: Optional[float] = 1.25
     compute_dtype: jnp.dtype = jnp.float32
     norm_topk_prob: bool = True
+    held: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, x):
@@ -188,6 +346,12 @@ class MoEMLP(nn.Module):
         n = b * s
         e = self.n_experts
         dropless = self.capacity_factor is None
+        held = (0, e) if self.held is None else tuple(self.held)
+        if not 0 <= held[0] < held[1] <= e or (self.held and not dropless):
+            raise ValueError(
+                f"held={self.held} of {e} experts, capacity_factor="
+                f"{self.capacity_factor}: a chip's share is a range of the "
+                "layer's experts under dropless routing")
         xf = x.reshape(n, d)
 
         with jax.named_scope("moe/router"):
@@ -216,19 +380,27 @@ class MoEMLP(nn.Module):
             mean_pairs = n * self.top_k / e
             self.sow("moe_stats", "max_load", jnp.max(pairs) / mean_pairs)
             self.sow("moe_stats", "min_load", jnp.min(pairs) / mean_pairs)
+            # the groups of the sorted rows, and how many rows they are
+            sizes, routed = pairs, n * self.top_k
+            if self.held:
+                sizes = pairs[held[0]:held[1]]
+                routed = jnp.sum(sizes)
+                self.sow("moe_stats", "held_pairs",
+                         routed / (n * self.top_k))
             if dropless:
                 self.sow("moe_stats", "executed_rows",
-                         executed_rows(pairs, n * self.top_k)
-                         / (n * self.top_k))
+                         executed_rows(sizes, n * self.top_k)
+                         / jnp.maximum(routed, 1))
             # for a caller that asks (mutable=["intermediates"]): the routing
             self.sow("intermediates", "top_idx", top_idx)
 
+        e_here = held[1] - held[0]
         w_gate = self.param("experts_gate", nn.initializers.lecun_normal(),
-                            (e, d, self.d_ff))
+                            (e_here, d, self.d_ff))
         w_up = self.param("experts_up", nn.initializers.lecun_normal(),
-                          (e, d, self.d_ff))
+                          (e_here, d, self.d_ff))
         w_down = self.param("experts_down", nn.initializers.lecun_normal(),
-                            (e, self.d_ff, d))
+                            (e_here, self.d_ff, d))
         if dropless:
             y = self._dropless(xf, top_idx, top_p, pairs, w_gate, w_up,
                                w_down)
@@ -247,34 +419,72 @@ class MoEMLP(nn.Module):
                 "every expert onto every rank instead.  Use ep=1 (experts "
                 "replicated or tp-sharded) or give a capacity_factor")
         cdt = self.compute_dtype
+        auto = [] if mesh.empty else [a for a in mesh.axis_names
+                                      if a not in mesh.manual_axes]
+        tp = "tp" if "tp" in auto else None
+
+        def ffn(rows, w_gate, w_up, w_down, sizes):
+            if self.held is None:
+                h = (jax.nn.silu(grouped_matmul(rows, w_gate, sizes))
+                     * grouped_matmul(rows, w_up, sizes))
+            else:   # gate and up as ONE product: one cotangent for the rows
+                gate_up = grouped_matmul(
+                    rows, jnp.concatenate([w_gate, w_up], axis=-1), sizes)
+                h = (jax.nn.silu(gate_up[:, :w_gate.shape[-1]])
+                     * gate_up[:, w_gate.shape[-1]:])
+            out = grouped_matmul(h, w_down, sizes)
+            return jax.lax.psum(out, tp) if tp else out
+
+        if auto:
+            # GSPMD cannot partition a Mosaic kernel (see
+            # ``flash_attention``): every rank runs the kernels on all the
+            # rows against its ``tp`` slice of each expert's width, and the
+            # slices' outputs are summed
+            ffn = jax.shard_map(
+                ffn, in_specs=(P(), P(None, None, tp), P(None, None, tp),
+                               P(None, tp, None), P()),
+                out_specs=P(), axis_names=frozenset(auto), check_vma=False)
+        with jax.named_scope("moe/experts"):    # the casts are the experts'
+            weights = tuple(w.astype(cdt) for w in (w_gate, w_up, w_down))
+        if self.held:
+            return self._held(xf.astype(cdt), top_idx, top_p, weights, ffn)
         with jax.named_scope("moe/dispatch"):
             order, row_of_pair = _sorted_layout(top_idx)
             rows = _dispatch(xf.astype(cdt), order, row_of_pair)
         with jax.named_scope("moe/experts"):
-            auto = [] if mesh.empty else [a for a in mesh.axis_names
-                                          if a not in mesh.manual_axes]
-            tp = "tp" if "tp" in auto else None
-
-            def ffn(rows, w_gate, w_up, w_down, sizes):
-                h = (jax.nn.silu(grouped_matmul(rows, w_gate, sizes))
-                     * grouped_matmul(rows, w_up, sizes))
-                out = grouped_matmul(h, w_down, sizes)
-                return jax.lax.psum(out, tp) if tp else out
-
-            if auto:
-                # GSPMD cannot partition a Mosaic kernel (see
-                # ``flash_attention``): every rank runs the kernels on all
-                # the rows against its ``tp`` slice of each expert's width,
-                # and the slices' outputs are summed
-                ffn = jax.shard_map(
-                    ffn, in_specs=(P(), P(None, None, tp), P(None, None, tp),
-                                   P(None, tp, None), P()),
-                    out_specs=P(), axis_names=frozenset(auto),
-                    check_vma=False)
-            out = ffn(rows, *(w.astype(cdt) for w in (w_gate, w_up, w_down)),
-                      pairs)
+            out = ffn(rows, *weights, pairs)
         with jax.named_scope("moe/combine"):
             return _combine(out, top_p, order, row_of_pair)
+
+    def _held(self, xf, top_idx, top_p, weights, ffn):
+        """The held experts' part of the output (module docstring)."""
+        n, k = top_idx.shape
+        first, end = self.held
+        with jax.named_scope("moe/dispatch"):
+            flat_idx = top_idx.reshape(-1)
+            # pairs in token order: how many held ones up to each, and the
+            # run of each token's held pairs among them
+            held = (flat_idx >= first) & (flat_idx < end)
+            cum = jnp.cumsum(held, dtype=jnp.int32)
+            ends = cum.reshape(n, k)[:, -1]
+            starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+            piece = _piece_rows(n * k, (end - first) / self.n_experts)
+            # the one sort over all the pairs: the held ones first, in token
+            # order (padded to whole pieces; what follows them is masked)
+            held_first = jnp.pad(
+                jnp.argsort(jnp.logical_not(held), stable=True)
+                .astype(jnp.int32), (0, -(n * k) % piece))
+        ints = (flat_idx, cum, starts, ends, held_first, held)
+        args = (xf, top_p) + weights
+        piece_fn = functools.partial(_held_piece, ffn=ffn, first=first,
+                                     n_held=end - first, piece=piece)
+        # the first piece by plain autodiff, which keeps its residuals; the
+        # further ones, which only uneven routing fills, are run again
+        # backward (their number is not a shape)
+        y = piece_fn(0, ints, *args)
+        if piece < n * k:
+            y = y + _held_experts(piece_fn, piece, ints, args)
+        return y
 
     def _capacity(self, xf, top_idx, top_p, w_gate, w_up, w_down):
         n, d = xf.shape

@@ -429,7 +429,10 @@ def test_scale_in_non_elastic_drains_promptly(tmp_path, monkeypatch):
     parts = [items[i * 10:(i + 1) * 10] for i in range(8)]
     cluster = tcluster.run(
         mapfuns.record_items,
-        {"batch_size": 10, "out_dir": str(tmp_path), "sleep_per_batch": 0.15},
+        # four batches a node: 1.2 s of consumption, so that the feed is
+        # still blocked on the queues 0.5 s in (at 0.15 it could be through:
+        # one failure in a whole run of the suite, ISSUE 31)
+        {"batch_size": 10, "out_dir": str(tmp_path), "sleep_per_batch": 0.3},
         num_executors=2,
         input_mode=tcluster.InputMode.STREAMING,
         queue_capacity=4,   # backpressure: partitions stay driver-side

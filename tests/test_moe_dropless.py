@@ -342,6 +342,54 @@ def test_kernels_fit_a_v5e_at_olmoes_widths(one_chip, pairs, d, f, dtype):
     assert " while(" not in hlo
 
 
+def test_kernels_fit_a_v5e_at_sdars_widths(one_chip):
+    """ISSUE 31's cell: attention over 8192 positions under the
+    block-diffusion mask with 32 query heads on 4 K/V heads (K and V are
+    never repeated: the kernels' operands keep 4 heads), and the grouped
+    matmul of one piece of held pairs (16,384 rows, 16 experts, gate and up
+    as one product), all Mosaic kernels the chip's compiler accepts."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from tensorflowonspark_tpu.ops.attention import flash_attention
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attention(q, k, v):
+        out = flash_attention(q, k, v, causal=False, impl="pallas",
+                              block_diffusion=(4096, 4))
+        return jnp.sum(out.astype(jnp.float32))
+
+    def experts(rows, w_gate_up, w_down, sizes):
+        gate_up = grouped_matmul(rows, w_gate_up, sizes, impl="pallas")
+        h = jax.nn.silu(gate_up[:, :768]) * gate_up[:, 768:]
+        out = grouped_matmul(h, w_down, sizes, impl="pallas")
+        return jnp.sum(out.astype(jnp.float32))
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        attn = jax.jit(jax.value_and_grad(attention, argnums=(0, 1, 2))).lower(
+            spec(1, 8192, 32, 128), spec(1, 8192, 4, 128),
+            spec(1, 8192, 4, 128)).compile()
+        moe = jax.jit(jax.value_and_grad(experts, argnums=(0, 1, 2))).lower(
+            spec(16384, 2048), spec(16, 2048, 1536), spec(16, 768, 2048),
+            spec(16, dtype=jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    hlo = attn.as_text()
+    kernels = [ln for ln in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 3 and " while(" not in hlo
+    # no [2L, 2L] mask or bias, and no K or V at 32 heads: the program's
+    # temporaries are a few copies of q (64 MB each), not the 128 MB+ of a
+    # mask nor 2 x 64 MB of repeated K and V on top
+    assert "8192,8192" not in hlo
+    assert all("bf16[4,8192,128]" in ln for ln in kernels), kernels[0][:400]
+    assert moe.count('custom_call_target="tpu_custom_call"') == 6
+
+
 def test_dropless_under_tp_matches_and_under_ep_says_what_is_missing():
     model = _system()
     params, ids = _params(skew=True), _ids(1, (4, 16))

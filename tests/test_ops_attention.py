@@ -232,3 +232,135 @@ def test_pallas_kernel_runs_per_shard_under_an_ambient_mesh():
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
     for g, r in zip(grads, ref_grads):
         np.testing.assert_allclose(g, r, atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query heads and the block-diffusion mask (ISSUE 31).
+# ---------------------------------------------------------------------------
+
+def _brute_force_block_diffusion(length: int, block: int) -> np.ndarray:
+    """``M[q, k]`` over ``[x_t ‖ x_0]`` from the three sentences that define
+    it (ISSUE 31), pair by pair."""
+    m = np.zeros((2 * length, 2 * length), bool)
+    for q in range(2 * length):
+        for k in range(2 * length):
+            q_noised, k_noised = q < length, k < length
+            bq, bk = (q % length) // block, (k % length) // block
+            m[q, k] = ((q_noised and k_noised and bq == bk)
+                       or (q_noised and not k_noised and bk < bq)
+                       or (not q_noised and not k_noised and bk <= bq))
+    return m
+
+
+@pytest.mark.parametrize("length,block", [(24, 4), (64, 32), (20, 4)])
+def test_block_diffusion_mask_is_the_brute_force_one(length, block):
+    want = _brute_force_block_diffusion(length, block)
+    idx = jnp.arange(2 * length)
+    got = att.block_diffusion_visible(idx[:, None], idx[None, :], length,
+                                      block)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # clean queries never see a noised key; every query sees some key
+    assert not want[length:, :length].any() and want.any(axis=1).all()
+
+
+@pytest.mark.parametrize("length,block,tile", [(24, 4, 16), (64, 32, 16),
+                                               (20, 4, 16), (32, 4, 8)])
+def test_block_diffusion_tile_bounds_agree_with_the_mask(length, block, tile):
+    """What the kernels decide per tile, for every tile of every tiling the
+    tests below use (tiles that straddle the two copies included): live iff
+    some pair of the tile is visible, interior iff every pair is."""
+    mask = _brute_force_block_diffusion(length, block)
+    s = 2 * length
+    args = dict(causal=False, kv_offset=0, block_q=tile, block_k=tile, sk=s,
+                block_diffusion=(length, block))
+    for q0 in range(0, s, tile):
+        for k0 in range(0, s, tile):
+            sub = mask[q0:q0 + tile, k0:k0 + tile]
+            whole = sub.shape == (tile, tile)
+            live = bool(att._tile_live(q0, k0, **args))
+            interior = bool(att._tile_interior(q0, k0, **args))
+            # rows past the end of the queries are padding: they may keep a
+            # tile alive, never make it interior when a real pair is masked
+            if q0 + tile <= s:
+                assert live == bool(sub.any()), (q0, k0)
+            else:
+                assert live or not sub.any(), (q0, k0)
+            if interior:
+                assert whole and sub.all(), (q0, k0)
+            elif whole:
+                assert not sub.all(), (q0, k0)
+
+
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("impl", ["pallas_interpret", "xla"])
+def test_grouped_query_heads_match_the_reference_on_repeated_heads(group,
+                                                                   impl):
+    """Query head j reads K/V head j // group, forward and all three
+    gradients; dk and dv come back at the K/V head count (the sum over the
+    group's query heads)."""
+    rng = np.random.RandomState(3)
+    b, s, h, d = 2, 40, 8, 8
+    q = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
+    k = jnp.asarray(rng.randn(b, s, h // group, d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, s, h // group, d), jnp.float32)
+    w = jnp.asarray(rng.randn(*q.shape), jnp.float32)
+    fn = lambda q, k, v: att.flash_attention(  # noqa: E731
+        q, k, v, causal=True, block_q=16, block_k=16, impl=impl)
+    ref = lambda q, k, v: att.mha_reference(  # noqa: E731
+        q, jnp.repeat(k, group, 2), jnp.repeat(v, group, 2), causal=True)
+    np.testing.assert_allclose(fn(q, k, v), ref(q, k, v), atol=1e-5,
+                               rtol=1e-5)
+    for a, r in zip(_grads(fn, q, k, v, w), _grads(ref, q, k, v, w)):
+        assert a.shape == r.shape
+        np.testing.assert_allclose(a, r, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("impl", ["pallas_interpret", "xla"])
+@pytest.mark.parametrize("length,block,tile,group", [
+    (24, 4, 16, 1),      # 48 positions in tiles of 16: tiles straddle L
+    (64, 32, 16, 1),
+    (20, 4, 16, 4),      # 40 positions: a padded tail, grouped heads
+    (32, 4, 8, 2),
+])
+def test_block_diffusion_attention_and_both_backward_passes(impl, length,
+                                                            block, tile,
+                                                            group):
+    """Forward, dq and dk/dv under the mask against the dense reference on
+    the brute-force mask's own definition: skipped tiles, interior tiles
+    and masked ones, for L not a multiple of the tile."""
+    rng = np.random.RandomState(5)
+    b, h, d, s = 1, 4, 8, 2 * length
+    q = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
+    k = jnp.asarray(rng.randn(b, s, h // group, d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, s, h // group, d), jnp.float32)
+    w = jnp.asarray(rng.randn(*q.shape), jnp.float32)
+    mask = jnp.asarray(_brute_force_block_diffusion(length, block))
+
+    def ref(q, k, v):
+        k, v = jnp.repeat(k, group, 2), jnp.repeat(v, group, 2)
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+        p = jax.nn.softmax(jnp.where(mask, logits, -jnp.inf), -1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+    fn = lambda q, k, v: att.flash_attention(  # noqa: E731
+        q, k, v, causal=False, block_q=tile, block_k=tile, impl=impl,
+        block_diffusion=(length, block))
+    np.testing.assert_allclose(fn(q, k, v), ref(q, k, v), atol=1e-5,
+                               rtol=1e-5)
+    np.testing.assert_allclose(
+        att.mha_reference(q, k, v, causal=False,
+                          block_diffusion=(length, block)),
+        ref(q, k, v), atol=1e-5, rtol=1e-5)
+    for a, r in zip(_grads(fn, q, k, v, w), _grads(ref, q, k, v, w)):
+        np.testing.assert_allclose(a, r, atol=1e-4, rtol=1e-4)
+
+
+def test_block_diffusion_refuses_what_it_does_not_mask():
+    q, k, v = make_qkv(b=1, s=32, h=2, d=8)
+    for kwargs in (dict(causal=True, block_diffusion=(16, 4)),
+                   dict(causal=False, block_diffusion=(12, 4)),
+                   dict(causal=False, block_diffusion=(16, 5))):
+        with pytest.raises(ValueError, match="block_diffusion"):
+            att.flash_attention(q, k, v, impl="xla", **kwargs)
+    with pytest.raises(ValueError, match="query heads"):
+        att.flash_attention(q, k[:, :, :1], v, impl="xla")
