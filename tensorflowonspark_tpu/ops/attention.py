@@ -42,6 +42,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from tensorflowonspark_tpu import telemetry
+
 NEG_INF = -1e30  # finite stand-in for -inf: keeps exp() exactly 0 without nan
 
 
@@ -226,14 +228,15 @@ _NT = (((1,), (1,)), ((), ()))      # a @ b.T: contract the last dim of both
 
 def _block_diffusion_tile(q_lo, q_hi, k_lo, k_hi, length: int, block: int):
     """``block_diffusion_visible`` on the tile of queries ``q_lo..q_hi`` and
-    keys ``k_lo..k_hi`` (index ranges, ends included), as scalars: whether
-    SOME pair is visible and whether EVERY pair is.  A tile may straddle
-    the two copies, so each range is taken apart into its noised and its
-    clean positions and the four combinations are judged on their own."""
+    keys ``k_lo..k_hi`` (index ranges, ends included; integers or numpy
+    arrays of them): whether SOME pair is visible and whether EVERY pair is.
+    A tile may straddle the two copies, so each range is taken apart into
+    its noised and its clean positions and the four combinations are judged
+    on their own."""
 
     def copies(lo, hi):     # (noised?, first position, last position)
-        return ((True, lo, jnp.minimum(hi, length - 1)),
-                (False, jnp.maximum(lo, length) - length, hi - length))
+        return ((True, lo, np.minimum(hi, length - 1)),
+                (False, np.maximum(lo, length) - length, hi - length))
 
     some, every = False, True
     for q_noised, qa, qb in copies(q_lo, q_hi):
@@ -250,7 +253,7 @@ def _block_diffusion_tile(q_lo, q_hi, k_lo, k_hi, length: int, block: int):
                 reach = 0 if q_noised else block
                 any_, all_ = ka < last + reach, kb < first + reach
             some = some | (there & any_)
-            every = every & (jnp.logical_not(there) | all_)
+            every = every & (np.logical_not(there) | all_)
     return some, every
 
 
@@ -258,16 +261,17 @@ def _tile_live(q_start, k_start, *, causal: bool, kv_offset: int,
                block_q: int, block_k: int, sk: int, block_diffusion=None):
     """Whether the tile of queries from ``q_start`` and keys from ``k_start``
     (a global position) holds any visible pair: not wholly padding and, under
-    ``causal``, not wholly in the future.  All three kernels skip a dead
-    tile; a further mask adds its condition here, as ``block_diffusion``
-    does."""
+    ``causal``, not wholly in the future.  No kernel visits a dead tile
+    (``_walk``); a further mask adds its condition here, as
+    ``block_diffusion`` does.  Judged at trace time, on integers or numpy
+    arrays of them, as ``_tile_interior`` is."""
     live = k_start < kv_offset + sk
     if causal:
-        live = jnp.logical_and(live, k_start <= q_start + block_q - 1)
+        live = live & (k_start <= q_start + block_q - 1)
     if block_diffusion:
-        live = jnp.logical_and(live, _block_diffusion_tile(
+        live = live & _block_diffusion_tile(
             q_start, q_start + block_q - 1, k_start,
-            jnp.minimum(k_start + block_k, sk) - 1, *block_diffusion)[0])
+            np.minimum(k_start + block_k, sk) - 1, *block_diffusion)[0]
     return live
 
 
@@ -279,11 +283,11 @@ def _tile_interior(q_start, k_start, *, causal: bool, kv_offset: int,
     there.  A further mask narrows this as it narrows ``_tile_live``."""
     interior = k_start + block_k <= kv_offset + sk
     if causal:
-        interior = jnp.logical_and(interior, k_start + block_k - 1 <= q_start)
+        interior = interior & (k_start + block_k - 1 <= q_start)
     if block_diffusion:
-        interior = jnp.logical_and(interior, _block_diffusion_tile(
+        interior = interior & _block_diffusion_tile(
             q_start, q_start + block_q - 1, k_start, k_start + block_k - 1,
-            *block_diffusion)[1])
+            *block_diffusion)[1]
     return interior
 
 
@@ -304,42 +308,117 @@ def _tile_visible(shape, q_dim: int, *, q_start, k_start, causal: bool,
     return mask
 
 
-def _on_live_tile(attend, q_start, k_start, *, block_q: int, block_k: int,
-                  **mask_args):
-    """Run ``attend(visible)`` if the tile is live: with None where every
-    pair is visible, else with the tile's mask as a function of the score
-    tile's shape and the dimension its queries lie along."""
-    tile = dict(block_q=block_q, block_k=block_k, **mask_args)
-    interior = _tile_interior(q_start, k_start, **tile)
-    live = _tile_live(q_start, k_start, **tile)
-    del tile["block_q"], tile["block_k"]
-    pl.when(interior)(lambda: attend(None))
-    pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
-        lambda: attend(functools.partial(
-            _tile_visible, q_start=q_start, k_start=k_start, **tile)))
+# What a visit of a kernel's walk is told (bits of its ``flags``): it is the
+# first / the last of its output block (initialise / write the block), and
+# its tile is masked (some pair of it is visible: the mask is built) or
+# interior (every pair is: none is).  A visit with neither attends nothing.
+_FIRST, _LAST, _MASKED, _INTERIOR = 1, 2, 4, 8
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                      acc_ref, m_ref, l_ref,
-                      *, sm_scale: float, kv_offset: int,
-                      block_q: int, block_k: int, **mask_args):
+def _tile_kinds(nq: int, nk: int, *, block_q: int, block_k: int,
+                kv_offset: int, **mask_args):
+    """``[nq, nk]``: ``_MASKED``, ``_INTERIOR`` or 0 (dead) for every tile of
+    the score matrix.  The mask and the shape are static, so this is a
+    constant of the program, worked out in numpy at trace time."""
+    q_start = np.arange(nq)[:, None] * block_q
+    k_start = kv_offset + np.arange(nk)[None, :] * block_k
+    tile = dict(block_q=block_q, block_k=block_k, kv_offset=kv_offset,
+                **mask_args)
+    live = np.broadcast_to(_tile_live(q_start, k_start, **tile), (nq, nk))
+    interior = live & _tile_interior(q_start, k_start, **tile)
+    return np.where(interior, _INTERIOR, live * _MASKED)
+
+
+def _walk(kinds, group: int = 1):
+    """The visits of one kernel's sequential grid axis, in order, as the int32
+    rows ``(block, head, tile, flags)``: for every output block (a row of
+    ``kinds``, in order) its live tiles in ascending order, once for each of
+    the ``group`` query heads that share the block (head by head: the dk/dv
+    pass; ``head`` is 0 throughout in the other two).  A block with no live
+    tile gets ONE visit, which attends nothing, so that its zeros are
+    written.  A dead tile is no visit: no grid step and no fetch.
+
+    The rows are the kernel's scalar-prefetch operands (SMEM, 4 bytes a
+    visit each): at SDAR's 16 x 16 tiles 80 visits, 640 in the dk/dv pass
+    over 8 heads; a causal row of 32k, 2,080."""
+    visits = []
+    for block, row in enumerate(kinds):
+        run = [[block, head, tile, row[tile]] for head in range(group)
+               for tile in np.flatnonzero(row)] or [[block, 0, 0, 0]]
+        run[0][3] |= _FIRST
+        run[-1][3] |= _LAST
+        visits += run
+    return np.asarray(visits, np.int32).T
+
+
+def _walk_call(kernel, table, rows: int, dense: int, *, out_shape,
+               interpret: bool, **specs):
+    """``kernel`` over the grid ``(rows, visits)``: the walk of ``table`` for
+    each (batch, head) row, in place of ``dense`` tiles a row; the index
+    maps and the kernel read the table's rows from SMEM.  The kernel is
+    told which flags EVERY visit carries and which ANY does (``_visit``).
+
+    v5e has one TensorCore: the q (or k) block axis, which could run in
+    parallel, loses nothing by being folded into the sequential walk.
+
+    Counted once per kernel built, for the run report:
+    ``flash.tiles_walked`` over ``flash.tiles`` is the share of the dense
+    grid's steps that the walk keeps."""
+    visits = table.shape[1]
+    telemetry.counter("flash.tiles").inc(dense)
+    telemetry.counter("flash.tiles_walked").inc(visits)
+    call = pl.pallas_call(
+        functools.partial(kernel,
+                          every=int(np.bitwise_and.reduce(table[-1])),
+                          some=int(np.bitwise_or.reduce(table[-1]))),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(table), grid=(rows, visits), **specs),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret)
+    return functools.partial(call, *(jnp.asarray(row) for row in table))
+
+
+def _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, *,
+           block_q: int, block_k: int, kv_offset: int, every: int, some: int,
+           **mask_args):
+    """This grid step's visit of the walk, as its flags say: ``init()`` on
+    the first visit of an output block, ``attend(visible)`` on a live tile
+    (with None on an interior one, else with the tile's mask as a function
+    of the score tile's shape and the dimension its queries lie along),
+    ``finalize()`` on the block's last.  A flag that ``every`` visit of the
+    table carries is no branch, and one that not even ``some`` do is no
+    code: rows of one tile (the walk's shortest) run straight through."""
+    visit = pl.program_id(1)
+    flags = flags_ref[visit]
+
+    def on(flag, run):
+        if every & flag:
+            run()
+        elif some & flag:
+            pl.when((flags & flag) != 0)(run)
+
+    on(_FIRST, init)
+    on(_MASKED, lambda: attend(functools.partial(
+        _tile_visible, q_start=iq_ref[visit] * block_q,
+        k_start=kv_offset + ik_ref[visit] * block_k, kv_offset=kv_offset,
+        **mask_args)))
+    on(_INTERIOR, lambda: attend(None))
+    on(_LAST, finalize)
+
+
+def _flash_fwd_kernel(iq_ref, ik_ref, flags_ref, q_ref, k_ref, v_ref,
+                      o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                      *, sm_scale: float, **walk):
     # m/l scratch is lane-replicated to 128 lanes (column 0 is authoritative)
     # — TPU tiling requires the last dim be 128-aligned.  The lse goes out as
     # a ROW per (batch, head): a residual of the backward, 128 times smaller
     # than the replicated columns and lane-dense as its dk/dv pass reads it.
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ik == 0)
-    def _init():
+    def init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
-
-    q_start = iq * block_q
-    k_start = kv_offset + ik * block_k
-    mask_args = dict(kv_offset=kv_offset, **mask_args)
 
     def attend(visible):
         qb = q_ref[0].astype(jnp.float32)              # [block_q, d]
@@ -361,24 +440,13 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
-    if mask_args["block_diffusion"]:
-        # most live tiles lie whole inside this mask, and it costs more to
-        # build than the causal one: no mask there, as in the backward
-        _on_live_tile(attend, q_start, k_start, block_q=block_q,
-                      block_k=block_k, **mask_args)
-    else:
-        pl.when(_tile_live(q_start, k_start, block_q=block_q,
-                           block_k=block_k, **mask_args))(
-            lambda: attend(functools.partial(
-                _tile_visible, q_start=q_start, k_start=k_start,
-                **mask_args)))
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
+    def finalize():
         l = jnp.maximum(l_ref[:], 1e-30)
         o_ref[0] = (acc_ref[:] / l[:, 0:1]).astype(o_ref.dtype)
         lse = jnp.where(l_ref[:] > 0.0, m_ref[:] + jnp.log(l), NEG_INF)
         lse_ref[0] = lse.T[0:1]                  # a row, as it lies in memory
+
+    _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, **walk)
 
 
 def _head_major(x, interpret: bool):
@@ -410,41 +478,42 @@ def _pad_seq(x, s_p: int):
     return jnp.pad(x, ((0, 0), (0, s_p - x.shape[1]), (0, 0)))
 
 
-def _kv_row(group: int):
-    """Grid row of a query head -> row of the K/V head it reads: head-major
-    rows are ``batch * heads + head``, so ``row // group`` on both sides."""
-    if group == 1:
-        return lambda bh: bh
-    return lambda bh: bh // group
-
-
 def _flash_fwd_pallas(qt, kt, vt, *, causal, sm_scale, kv_offset,
                       block_q, block_k, interpret, block_diffusion=None):
     """Run the Pallas forward on head-major ``[B*H, S, D_p]`` operands
-    (``[B*H_kv, S, D_p]`` keys and values); returns ``(out [B*H, Sq, D_p],
-    lse [B*H, Sq] float32)``."""
+    (``[B*H_kv, S, D_p]`` keys and values: query head ``j`` reads the K/V
+    row ``j // group``, head-major rows being ``batch * heads + head`` on
+    both sides); returns ``(out [B*H, Sq, D_p], lse [B*H, Sq] float32)``."""
     bh, sq, d_p = qt.shape
     sk = kt.shape[1]
-    kv = _kv_row(bh // kt.shape[0])
+    group = bh // kt.shape[0]
     block_q, block_k, sq_p, sk_p = _blocks(sq, sk, block_q, block_k)
     qt, kt, vt = _pad_seq(qt, sq_p), _pad_seq(kt, sk_p), _pad_seq(vt, sk_p)
 
-    grid = (bh, sq_p // block_q, sk_p // block_k)
-    kernel = functools.partial(
-        _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
-        kv_offset=kv_offset, block_q=block_q, block_k=block_k, sk=sk,
-        block_diffusion=block_diffusion)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+    static = dict(causal=causal, kv_offset=kv_offset, block_q=block_q,
+                  block_k=block_k, sk=sk, block_diffusion=block_diffusion)
+    kinds = _tile_kinds(sq_p // block_q, sk_p // block_k, **static)
+    if not block_diffusion:
+        # the causal mask is cheap to build beside this kernel's float32
+        # matmuls: every live tile builds it.  Most live tiles lie whole
+        # inside the block-diffusion mask, and it costs more: no mask there,
+        # as in the backward
+        kinds = np.minimum(kinds, _MASKED)
+    table = _walk(kinds)[[0, 2, 3]]         # (iq, ik, flags): no head row
+    q_at = lambda bh, v, iq, ik, flags: (bh, iq[v], 0)          # noqa: E731
+    kv_at = lambda bh, v, iq, ik, flags: (bh // group, ik[v], 0)  # noqa: E731
+    out, lse = _walk_call(
+        functools.partial(_flash_fwd_kernel, sm_scale=sm_scale, **static),
+        table, bh, kinds.size,
         in_specs=[
-            pl.BlockSpec((1, block_q, d_p), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_k, d_p), lambda bh, iq, ik: (kv(bh), ik, 0)),
-            pl.BlockSpec((1, block_k, d_p), lambda bh, iq, ik: (kv(bh), ik, 0)),
+            pl.BlockSpec((1, block_q, d_p), q_at),
+            pl.BlockSpec((1, block_k, d_p), kv_at),
+            pl.BlockSpec((1, block_k, d_p), kv_at),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d_p), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, iq, ik: (bh, 0, iq)),
+            pl.BlockSpec((1, block_q, d_p), q_at),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda bh, v, iq, ik, flags: (bh, 0, iq[v])),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq_p, d_p), qt.dtype),
@@ -470,21 +539,18 @@ def _recompute_p(logits, visible, q_dim: int, lse):
     return jnp.exp(logits - lse)
 
 
-def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+def _flash_bwd_dkv_kernel(ik_ref, head_ref, iq_ref, flags_ref,
+                          q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc,
-                          *, sm_scale: float, block_q: int, block_k: int,
-                          kv_offset: int, group: int, **mask_args):
-    # One KV block against every q block of every query head of its group
-    # (the sequential axis, head by head), on the TRANSPOSED tile [block_k,
-    # block_q]: lse and delta are then rows, lane-dense as they lie in
-    # memory, and both accumulations are plain matmuls.
-    ik = pl.program_id(1)
-    step = pl.program_id(2)
-    steps = pl.num_programs(2)
-    iq = step if group == 1 else step % (steps // group)
+                          *, sm_scale: float, **walk):
+    # One KV block against the q blocks that see it, of every query head of
+    # its group (head by head: ``head_ref`` is for the index maps), on the
+    # TRANSPOSED tile [block_k, block_q]: lse and delta are then rows,
+    # lane-dense as they lie in memory, and both accumulations are plain
+    # matmuls.
+    del head_ref
 
-    @pl.when(step == 0)
-    def _init():
+    def init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
@@ -501,29 +567,20 @@ def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dk_acc[:] += jnp.dot(ds_t.astype(q.dtype), q,
                              preferred_element_type=jnp.float32)
 
-    _on_live_tile(attend, iq * block_q, kv_offset + ik * block_k,
-                  block_q=block_q, block_k=block_k, kv_offset=kv_offset,
-                  **mask_args)
-
-    @pl.when(step == steps - 1)
-    def _finalize():
+    def finalize():
         dk_ref[0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
+    _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, **walk)
 
-def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                         dq_ref, dq_acc,
-                         *, sm_scale: float, block_q: int, block_k: int,
-                         kv_offset: int, **mask_args):
-    # One q block against every KV block (the sequential axis); lse and
-    # delta are columns here, replicated over 128 lanes like the forward's
-    # m and l (column 0 is read).
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
 
-    @pl.when(ik == 0)
-    def _init():
+def _flash_bwd_dq_kernel(iq_ref, ik_ref, flags_ref,
+                         q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                         dq_ref, dq_acc, *, sm_scale: float, **walk):
+    # One q block against the KV blocks it sees; lse and delta are columns
+    # here, replicated over 128 lanes like the forward's m and l (column 0
+    # is read).
+    def init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     def attend(visible):
@@ -537,13 +594,10 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
                              preferred_element_type=jnp.float32)
 
-    _on_live_tile(attend, iq * block_q, kv_offset + ik * block_k,
-                  block_q=block_q, block_k=block_k, kv_offset=kv_offset,
-                  **mask_args)
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
+    def finalize():
         dq_ref[0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
+
+    _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, **walk)
 
 
 def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, *, causal, sm_scale,
@@ -556,7 +610,6 @@ def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, *, causal, sm_scale,
     bh, sq, d_p = qt.shape
     sk = kt.shape[1]
     group = bh // kt.shape[0]
-    kv = _kv_row(group)
     block_q, block_k, sq_p, sk_p = _blocks(sq, sk, block_q, block_k)
     # a row that saw no key has lse = NEG_INF: lifted, so that exp(NEG_INF -
     # lse) is 0 there too.  Padded q rows have do = 0 and so add nothing.
@@ -567,56 +620,52 @@ def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, *, causal, sm_scale,
     qt, do_t = _pad_seq(qt, sq_p), _pad_seq(do_t, sq_p)
     kt, vt = _pad_seq(kt, sk_p), _pad_seq(vt, sk_p)
 
-    static = dict(sm_scale=sm_scale, causal=causal, kv_offset=kv_offset,
-                  block_q=block_q, block_k=block_k, sk=sk,
-                  block_diffusion=block_diffusion)
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    static = dict(causal=causal, kv_offset=kv_offset, block_q=block_q,
+                  block_k=block_k, sk=sk, block_diffusion=block_diffusion)
+    kinds = _tile_kinds(sq_p // block_q, sk_p // block_k, **static)
+    static["sm_scale"] = sm_scale
     q_block, k_block = (1, block_q, d_p), (1, block_k, d_p)
-    nq = sq_p // block_q
 
-    if group == 1:
-        q_at = lambda bh, ik, iq: (bh, iq, 0)           # noqa: E731
-        row_at = lambda bh, ik, iq: (bh, 0, iq)         # noqa: E731
-    else:   # step = (query head of the group) * nq + q block
-        q_at = lambda bh, ik, step: (                   # noqa: E731
-            bh * group + step // nq, step % nq, 0)
-        row_at = lambda bh, ik, step: (                 # noqa: E731
-            bh * group + step // nq, 0, step % nq)
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, group=group, **static),
-        grid=(bh // group, sk_p // block_k, group * nq),
+    # a grid row is a K/V head; the walk names the query head of its group
+    q_at = lambda bh, v, ik, head, iq, flags: (                 # noqa: E731
+        bh * group + head[v], iq[v], 0)
+    row_at = lambda bh, v, ik, head, iq, flags: (               # noqa: E731
+        bh * group + head[v], 0, iq[v])
+    k_at = lambda bh, v, ik, head, iq, flags: (bh, ik[v], 0)    # noqa: E731
+    dk, dv = _walk_call(
+        functools.partial(_flash_bwd_dkv_kernel, **static),
+        _walk(kinds.T, group), bh // group, group * kinds.size,
         in_specs=[
             pl.BlockSpec(q_block, q_at),
             pl.BlockSpec(q_block, q_at),
             pl.BlockSpec((1, 1, block_q), row_at),
             pl.BlockSpec((1, 1, block_q), row_at),
-            pl.BlockSpec(k_block, lambda bh, ik, iq: (bh, ik, 0)),
-            pl.BlockSpec(k_block, lambda bh, ik, iq: (bh, ik, 0)),
+            pl.BlockSpec(k_block, k_at),
+            pl.BlockSpec(k_block, k_at),
         ],
-        out_specs=[pl.BlockSpec(k_block, lambda bh, ik, iq: (bh, ik, 0))] * 2,
+        out_specs=[pl.BlockSpec(k_block, k_at)] * 2,
         out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
                    jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d_p), jnp.float32)] * 2,
-        compiler_params=params,
         interpret=interpret,
     )(qt, do_t, *rows, kt, vt)
 
-    dq = pl.pallas_call(
+    q_at = lambda bh, v, iq, ik, flags: (bh, iq[v], 0)          # noqa: E731
+    kv_at = lambda bh, v, iq, ik, flags: (bh // group, ik[v], 0)  # noqa: E731
+    dq = _walk_call(
         functools.partial(_flash_bwd_dq_kernel, **static),
-        grid=(bh, nq, sk_p // block_k),
+        _walk(kinds)[[0, 2, 3]], bh, kinds.size,    # (iq, ik, flags)
         in_specs=[
-            pl.BlockSpec(q_block, lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec(q_block, lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec(k_block, lambda bh, iq, ik: (kv(bh), ik, 0)),
-            pl.BlockSpec(k_block, lambda bh, iq, ik: (kv(bh), ik, 0)),
+            pl.BlockSpec(q_block, q_at),
+            pl.BlockSpec(q_block, q_at),
+            pl.BlockSpec((1, block_q, 128), q_at),
+            pl.BlockSpec((1, block_q, 128), q_at),
+            pl.BlockSpec(k_block, kv_at),
+            pl.BlockSpec(k_block, kv_at),
         ],
-        out_specs=pl.BlockSpec(q_block, lambda bh, iq, ik: (bh, iq, 0)),
+        out_specs=pl.BlockSpec(q_block, q_at),
         out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d_p), jnp.float32)],
-        compiler_params=params,
         interpret=interpret,
     )(qt, do_t, *cols, kt, vt)
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]

@@ -152,6 +152,164 @@ def test_pallas_backward_holds_no_scan():
     assert "scan" not in jaxpr and "while" not in jaxpr
 
 
+# ---------------------------------------------------------------------------
+# The walk: the kernels visit the live tiles of their mask and nothing else
+# (ISSUE 32).
+# ---------------------------------------------------------------------------
+
+def _brute_force_tiles(sq, sk, tile, causal, kv_offset, block_diffusion):
+    """Per tile of the padded score matrix, pair by pair from the mask's own
+    elementwise rule: whether SOME pair is visible, whether EVERY pair is.
+    Padded keys are never visible; padded query rows count as rows."""
+    block_q, block_k, sq_p, sk_p = att._blocks(sq, sk, tile, tile)
+    qpos, kidx = np.arange(sq_p)[:, None], np.arange(sk_p)[None, :]
+    kpos = kv_offset + kidx
+    visible = np.broadcast_to(kidx < sk, (sq_p, sk_p)).copy()
+    if causal:
+        visible &= kpos <= qpos
+    if block_diffusion:
+        visible &= np.asarray(att.block_diffusion_visible(
+            qpos, kpos, *block_diffusion))
+    tiles = visible.reshape(sq_p // block_q, block_q, sk_p // block_k,
+                            block_k)
+    static = dict(causal=causal, kv_offset=kv_offset, block_q=block_q,
+                  block_k=block_k, sk=sk, block_diffusion=block_diffusion)
+    return tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3)), static
+
+
+_WALKS = [pytest.param((sq, sk, True, off, None),
+                       id=f"causal-{shape}-kv-offset-{name}")
+          for shape, sq, sk in [("square", 64, 64), ("cross", 32, 80),
+                                ("padded", 40, 52)]
+          for name, off in [("0", 0), ("positive", 24), ("negative", -24),
+                            ("wholly-in-the-future", 96)]]
+_WALKS += [pytest.param((20, 52, False, 0, None), id="not-causal-padded"),
+           pytest.param((12, 12, True, 0, None), id="shorter-than-a-block")]
+_WALKS += [pytest.param((2 * length, 2 * length, False, 0, (length, block)),
+                        id=f"block-diffusion-{length}-{block}")
+           for length, block in [(24, 4), (64, 32), (20, 4)]]
+
+
+def _live(flags):
+    return (flags & (att._MASKED | att._INTERIOR)) != 0
+
+
+def _assert_flags_bracket_each_block(block, flags, blocks):
+    """Every output block is one run of consecutive visits, in order; its
+    first visit and no other carries _FIRST, its last and no other _LAST."""
+    assert list(dict.fromkeys(block.tolist())) == list(range(blocks))
+    first = np.r_[True, block[1:] != block[:-1]]
+    last = np.r_[block[1:] != block[:-1], True]
+    np.testing.assert_array_equal((flags & att._FIRST) != 0, first)
+    np.testing.assert_array_equal((flags & att._LAST) != 0, last)
+
+
+@pytest.mark.parametrize("case", _WALKS)
+def test_walk_visits_the_live_tiles_and_nothing_else(case):
+    """The forward's and the dq pass's table against the brute-force set of
+    tiles in which the mask shows any pair: the same tiles, row-major with k
+    ascending; interior exactly where every pair shows; a q block with no
+    live tile visited once, attending nothing."""
+    sq, sk, causal, kv_offset, block_diffusion = case
+    some, every, static = _brute_force_tiles(sq, sk, 16, causal, kv_offset,
+                                             block_diffusion)
+    kinds = att._tile_kinds(*some.shape, **static)
+    iq, head, ik, flags = att._walk(kinds)
+    live = _live(flags)
+    assert list(zip(iq[live], ik[live])) == list(zip(*np.nonzero(some)))
+    np.testing.assert_array_equal((flags[live] & att._INTERIOR) != 0,
+                                  every[iq[live], ik[live]])
+    both = att._MASKED | att._INTERIOR
+    assert not np.any((flags & both) == both)
+    np.testing.assert_array_equal(iq[~live],
+                                  np.flatnonzero(~some.any(axis=1)))
+    _assert_flags_bracket_each_block(iq, flags, some.shape[0])
+    assert not head.any() and flags.dtype == np.int32
+
+
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("case", _WALKS)
+def test_dkv_walk_visits_each_head_of_each_live_tile_once(case, group):
+    """The dk/dv pass's table: per k block, in order, every (query head of
+    the group, q block) of a live tile exactly once, head by head with q
+    ascending; a k block that no query sees visited once."""
+    sq, sk, causal, kv_offset, block_diffusion = case
+    some, every, static = _brute_force_tiles(sq, sk, 16, causal, kv_offset,
+                                             block_diffusion)
+    kinds = att._tile_kinds(*some.shape, **static)
+    ik, head, iq, flags = att._walk(kinds.T, group)
+    live = _live(flags)
+    for k in range(some.shape[1]):
+        here = live & (ik == k)
+        assert list(zip(head[here], iq[here])) == [
+            (h, q) for h in range(group) for q in np.flatnonzero(some[:, k])]
+    np.testing.assert_array_equal((flags[live] & att._INTERIOR) != 0,
+                                  every[iq[live], ik[live]])
+    np.testing.assert_array_equal(ik[~live],
+                                  np.flatnonzero(~some.any(axis=0)))
+    _assert_flags_bracket_each_block(ik, flags, some.shape[1])
+
+
+def _pallas_grids(jaxpr):
+    from jax._src import core
+
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for sub in core.jaxprs_in_params(eqn.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
+@pytest.mark.parametrize("case", [
+    # mask, heads, K/V heads, tiles of the dense grid a head, live tiles
+    pytest.param((dict(causal=True), 2, 2, 256, 136), id="causal"),
+    pytest.param((dict(causal=False, block_diffusion=(128, 4)), 8, 1, 256,
+                  80), id="block-diffusion-at-sdar-s-tile-count"),
+])
+def test_pallas_grids_have_the_length_of_the_walk(case):
+    """Forward and backward are three ``pallas_call``s whose sequential grid
+    axis is the table's length, not the dense (q tile, k tile) grid: 80 of
+    256 under SDAR's mask (16 x 16 tiles, block 4, scaled down); the dk/dv
+    pass walks them once for each query head of its group.  The two
+    counters read the same share."""
+    from tensorflowonspark_tpu import telemetry
+
+    mask, heads, kv_heads, dense, walked = case
+    group = heads // kv_heads
+    q = jnp.zeros((1, 256, heads, 8))
+    k = jnp.zeros((1, 256, kv_heads, 8))
+    before = telemetry.snapshot()["counters"]
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda q, k, v: jnp.sum(
+        att.flash_attention(q, k, v, block_q=16, block_k=16,
+                            impl="pallas_interpret", **mask)),
+        argnums=(0, 1, 2)))(q, k, k)
+    after = telemetry.snapshot()["counters"]
+    assert _pallas_grids(jaxpr.jaxpr) == [
+        (heads, walked), (kv_heads, group * walked), (heads, walked)]
+    assert "scan" not in str(jaxpr) and "while" not in str(jaxpr)
+    counted = {name: after[name] - before.get(name, 0)
+               for name in ("flash.tiles", "flash.tiles_walked")}
+    assert counted == {"flash.tiles": (2 + group) * dense,
+                       "flash.tiles_walked": (2 + group) * walked}
+
+
+def test_rows_of_one_tile_run_straight_through():
+    """Where the table says that every visit is its block's first and last
+    and builds the mask (one tile a row: 512-id rows at the default tile),
+    no kernel holds a branch; where rows have several tiles, they do."""
+    def jaxpr(s):
+        q = jnp.zeros((1, s, 2, 8))
+        return str(jax.make_jaxpr(jax.value_and_grad(lambda q, k, v: jnp.sum(
+            att.flash_attention(q, k, v, block_q=16, block_k=16,
+                                impl="pallas_interpret")),
+            argnums=(0, 1, 2)))(q, q, q))
+
+    assert "cond" not in jaxpr(16)
+    assert "cond" in jaxpr(32)
+
+
 def test_chunk_merge_equals_full_attention():
     # Split KV into 4 chunks with global offsets, merge — must equal dense.
     q, k, v = make_qkv(b=2, s=64, h=2, d=16)
