@@ -81,6 +81,11 @@ class Attention(nn.Module):
     # scale of that width for all heads (Qwen3's placement) instead of over
     # the whole projection (OLMoE's).
     qk_norm_per_head: bool = False
+    # Learned sparse attention (DeepSeek Sparse Attention, as Keye-VL-2.0
+    # trains it): ``(index heads, index head dim, topk)``.  An indexer scores
+    # the causal pairs and every query attends to its ``topk`` best keys
+    # only (``_sparse_attention``, ``ops/sparse_attention.py``).
+    sparse: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, x, positions=None, block_diffusion=None):
@@ -109,11 +114,18 @@ class Attention(nn.Module):
                             t.reshape(b, s, -1)).reshape(t.shape)
                 q, k = norm(q, "q_norm"), norm(k, "k_norm")
         if self.decode:
-            if h_kv != h or positions is not None or block_diffusion:
+            if (h_kv != h or positions is not None or block_diffusion
+                    or self.sparse):
                 raise NotImplementedError(
                     "the cache path holds one K/V head per query head, at "
                     "the tokens' own places, under the causal mask")
             return self._decode_step(x, q, k, v)
+        if self.sparse:
+            if block_diffusion or self.attn_impl == "ring":
+                raise NotImplementedError(
+                    "learned sparse attention selects among the causal keys "
+                    "of one whole sequence on one chip")
+            return self._sparse_attention(x, q, k, v, positions)
         if self.attn_impl == "ring" and (
                 self.mesh is None or h_kv != h or block_diffusion):
             raise ValueError("ring attention needs mesh=, as many K/V heads "
@@ -189,6 +201,67 @@ class Attention(nn.Module):
         return nn.DenseGeneral(x.shape[-1], axis=(-2, -1), use_bias=False,
                                name="o_proj", dtype=self.compute_dtype)(out)
 
+    def _sparse_attention(self, x, q, k, v, positions):
+        """Attention over the keys the indexer selects.  From the layer's
+        normed hidden state ``x``, with the gradient STOPPED (cross-entropy
+        never reaches the indexer): ``J`` index queries ``a`` and one index
+        key ``b`` a token (LayerNorm, then RoPE over all its dims, as the
+        queries), and the heads' weights ``c``, scaled by ``J^-1/2 ·
+        dim^-1/2``.  ``I[t, s] = Σ_j c[t, j] · ReLU(a[t, j] · b[s])``; every
+        query keeps its ``topk`` best causal keys, a constant of the step,
+        and all heads attend to those.  The indexer's own loss, ``Σ_t KL(p_t
+        ‖ softmax over the kept keys of I[t])`` over this layer's tokens with
+        ``p`` the heads' mean attention probabilities (a constant), is sown
+        as ``aux_loss/index_kl`` (mean over tokens): it reaches ``index_q``,
+        ``index_k``, ``index_k_norm`` and ``index_w`` and nothing else
+        (``make_sparse_loss_fn``).  Operands of the score and attention
+        matmuls are in ``compute_dtype``; accumulation, ReLU, the weighted
+        sum, the threshold and the softmaxes are float32."""
+        from tensorflowonspark_tpu.ops import sparse_attention as dsa
+
+        heads, dim, topk = self.sparse
+        impl = None if self.attn_impl == "auto" else self.attn_impl
+        b, s, _ = x.shape
+        if positions is None:
+            positions = jnp.arange(s)
+        f32 = jnp.float32
+        with jax.named_scope("dsa/index"):
+            u = jax.lax.stop_gradient(x)
+            a = nn.DenseGeneral((heads, dim), use_bias=False, name="index_q",
+                                dtype=self.compute_dtype)(u)
+            key = nn.Dense(dim, use_bias=False, name="index_k",
+                           dtype=self.compute_dtype)(u)
+            key = nn.LayerNorm(epsilon=self.norm_eps, name="index_k_norm",
+                               dtype=f32)(key.astype(f32))
+            c = nn.Dense(heads, use_bias=False, name="index_w", dtype=f32)(
+                u.astype(f32)) * (heads * dim) ** -0.5
+            a = apply_rope(a.astype(f32), positions, self.rope_theta).astype(
+                self.compute_dtype)
+            key = apply_rope(key[:, :, None], positions, self.rope_theta)[
+                :, :, 0].astype(self.compute_dtype)
+        with jax.named_scope("dsa/attend"):
+            q = apply_rope(q, positions, self.rope_theta)
+            k = apply_rope(k, positions, self.rope_theta)
+
+        def row(a, key, c, q, k, v):
+            mask, lse_i = dsa.lightning_select(a, key, c, topk, impl=impl)
+            out, lse = dsa.sparse_attention(q, k, v, mask, impl=impl)
+            kl = dsa.index_kl(a, key, c, q, k, lse, lse_i, mask, impl=impl)
+            return (out, kl, dsa.selection_stats(mask),
+                    mask if shown else None)
+
+        # the selection itself is shown only to a caller that asks for the
+        # intermediates (the benchmark's check): L x L bytes a layer
+        shown = self.is_mutable_collection("intermediates")
+        out, kl, (selected, live), mask = dsa.per_row(row, a, key, c, q, k, v)
+        if shown:
+            self.sow("intermediates", "dsa_mask", mask)
+        self.sow("aux_loss", "index_kl", jnp.sum(kl) / (b * s))
+        self.sow("dsa_stats", "selected_pairs", jnp.sum(selected) / b)
+        self.sow("dsa_stats", "live_tiles", jnp.mean(live))
+        return nn.DenseGeneral(x.shape[-1], axis=(-2, -1), use_bias=False,
+                               name="o_proj", dtype=self.compute_dtype)(out)
+
 
 class SwiGLU(nn.Module):
     d_ff: int
@@ -224,6 +297,7 @@ class Block(nn.Module):
     n_kv_heads: int = 0
     qk_norm_per_head: bool = False
     moe_held: Optional[tuple] = None
+    sparse: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, x, positions=None, block_diffusion=None):
@@ -232,7 +306,7 @@ class Block(nn.Module):
                           self.attn_impl, self.mesh, self.compute_dtype,
                           self.decode, self.max_decode_len, self.qk_norm,
                           self.norm_eps, self.n_kv_heads,
-                          self.qk_norm_per_head, name="attn")(
+                          self.qk_norm_per_head, self.sparse, name="attn")(
                               norm("attn_norm")(x), positions,
                               block_diffusion)
         x = constrain(x, P(BATCH, "sp", None))
@@ -291,11 +365,25 @@ class Transformer(nn.Module):
     n_kv_heads: int = 0
     qk_norm_per_head: bool = False
     moe_held: Optional[tuple] = None
+    # Learned sparse attention in every layer (see ``Attention.sparse``).
+    sparse: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, input_ids, positions=None, block_diffusion=None):
         """``positions`` and ``block_diffusion`` go to every layer's
-        attention as they are (see ``Attention``)."""
+        attention as they are (see ``Attention``).
+
+        Under ``remat`` a block is ``nn.remat(Block, static_argnums=(3,))``:
+        of a block's arguments (the module is 0) ``x`` and ``positions`` are
+        traced arrays and ``block_diffusion`` (3) is STATIC, a tuple of two
+        sizes from which the flash kernels build their visit tables in numpy
+        at trace time; traced, it could build none.  A mask that depends on
+        the data (``sparse``: the indexer's selection) is no argument at
+        all: it is a traced array born inside the block from ``x`` and the
+        indexer's weights.  A recomputation would make it again, and the same
+        (the selection draws nothing and breaks its ties by position); the
+        block's policy saves it instead, with what else the sparse kernels
+        give (``_remat_policy``)."""
         dh = self.d_head or self.d_model // self.n_heads
         dff = self.d_ff or 4 * self.d_model
         emb = nn.Embed(self.vocab_size, self.d_model, name="embed",
@@ -303,8 +391,9 @@ class Transformer(nn.Module):
         x = emb(input_ids)
         x = constrain(x, P(BATCH, "sp", None))
         # the mask is a tuple of sizes: static under remat
-        block_cls = (nn.remat(Block, static_argnums=(3,)) if self.remat
-                     else Block)
+        block_cls = (nn.remat(Block, static_argnums=(3,),
+                              policy=self._remat_policy())
+                     if self.remat else Block)
         for i in range(self.n_layers):
             x = block_cls(self.n_heads, dh, dff, self.n_experts, self.moe_top_k,
                           self.rope_theta, self.attn_impl, self.mesh,
@@ -312,7 +401,7 @@ class Transformer(nn.Module):
                           self.norm_eps, self.qk_norm,
                           self.moe_capacity_factor, self.moe_norm_topk_prob,
                           self.n_kv_heads, self.qk_norm_per_head,
-                          self.moe_held, name=f"block_{i}")(
+                          self.moe_held, self.sparse, name=f"block_{i}")(
                               x, positions, block_diffusion)
         x = RMSNorm(self.norm_eps, name="final_norm")(x)
         if self.return_hidden:
@@ -322,11 +411,25 @@ class Transformer(nn.Module):
                               dtype=self.compute_dtype)(x)
             return constrain(logits.astype(jnp.float32), P(BATCH, "sp", None))
 
+    def _remat_policy(self):
+        """What a rematerialised block keeps besides its input: nothing, or,
+        under ``sparse``, what ``ops/sparse_attention.py`` names (the
+        selection, attention's output and the indexer's loss with its
+        gradient: 0.4 GB a layer at 16k), so that the second forward runs
+        the projections, norms and experts again and none of the sparse
+        kernels."""
+        if not self.sparse:
+            return None
+        from tensorflowonspark_tpu.ops.sparse_attention import SAVED_NAMES
+
+        return jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
+
 
 @register("transformer")
 def build_transformer(config: dict) -> Transformer:
     capacity = config.get("moe_capacity_factor", 1.25)
     held = config.get("moe_held")
+    sparse = config.get("sparse_attention")
     return Transformer(
         vocab_size=int(config.get("vocab_size", 32000)),
         d_model=int(config.get("d_model", 512)),
@@ -348,6 +451,9 @@ def build_transformer(config: dict) -> Transformer:
         n_kv_heads=int(config.get("n_kv_heads", 0)),
         qk_norm_per_head=bool(config.get("qk_norm_per_head", False)),
         moe_held=None if held is None else tuple(int(x) for x in held),
+        sparse=None if sparse is None else tuple(
+            int(sparse[key]) for key in ("index_heads", "index_head_dim",
+                                         "topk")),
     )
 
 
@@ -674,5 +780,63 @@ def make_block_diffusion_loss_fn(model: Transformer, block: int, mask_id: int,
             total, metrics = _with_sown_terms(loss, updates, aux_loss_coef,
                                               router_z_coef)
         return total, {**metrics, "masked_share": jnp.mean(masked)}
+
+    return loss_fn
+
+
+def make_sparse_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
+                        vocab_chunk: int = 4096, router_z_coef: float = 0.0,
+                        index_loss_coef: float = 1.0):
+    """The training loss of a model with learned sparse attention
+    (``Transformer.sparse``): ``make_loss_fn``'s next-token cross-entropy,
+    fused with the head, and sown auxiliary terms, plus ``index_loss_coef``
+    times the indexers' term ``L_I``: the KL terms the layers sowed
+    (``aux_loss/index_kl``, each a mean over tokens), averaged over layers.
+    Batch: ``{"input_ids": [B, S] int32}`` (no ``loss_mask``).
+
+    One ``value_and_grad`` of the sum gives two gradients that never mix:
+    the indexers read the hidden state with the gradient stopped and their
+    selection and their target are constants, so cross-entropy and the
+    routers' terms reach every parameter BUT the indexers', and ``L_I``
+    reaches those and nothing else (DeepSeek-V3.2-Exp's sparse training
+    stage).  Metrics also carry ``index_loss``, ``dsa_selected_pairs`` (kept
+    pairs a row) and ``dsa_live_tiles`` (tiles with a kept pair over causal
+    tiles), means over layers."""
+    from flax import traverse_util
+
+    from tensorflowonspark_tpu.ops.xent import blockwise_cross_entropy
+
+    sown = _sown_collections(model) + ["dsa_stats"]
+    hidden_model = model.clone(return_hidden=True)
+
+    def loss_fn(params, batch):
+        ids = batch["input_ids"]
+        # one scope around the whole loss, as block diffusion's has: the
+        # readers look for whole components of the dsa/* scopes
+        with jax.named_scope("sparse_lm"):
+            h, updates = hidden_model.apply({"params": params}, ids,
+                                            mutable=sown)
+            b, s, d = h.shape
+            with jax.named_scope("lm_head_loss"):
+                nll = blockwise_cross_entropy(
+                    h[:, :-1].reshape(b * (s - 1), d),
+                    params["lm_head"]["kernel"].astype(h.dtype),
+                    ids[:, 1:].reshape(-1), chunk=vocab_chunk)
+                loss = jnp.mean(nll)
+            aux = traverse_util.flatten_dict(dict(updates.get("aux_loss", {})))
+            index = [v for k, v in aux.items() if "index_kl" in k]
+            rest = {k: v for k, v in aux.items() if "index_kl" not in k}
+            total, metrics = _with_sown_terms(
+                loss, {**updates,
+                       "aux_loss": traverse_util.unflatten_dict(rest)},
+                aux_loss_coef, router_z_coef)
+            index_loss = jnp.mean(jnp.stack(jax.tree.leaves(index)))
+            stats = traverse_util.flatten_dict(
+                dict(updates.get("dsa_stats", {})))
+            for name in ("selected_pairs", "live_tiles"):
+                metrics[f"dsa_{name}"] = jnp.mean(jnp.stack(jax.tree.leaves(
+                    [v for k, v in stats.items() if name in k])))
+        return (total + index_loss_coef * index_loss,
+                {**metrics, "index_loss": index_loss})
 
     return loss_fn

@@ -11,8 +11,9 @@ read off the numbers: the run says itself that it is not a chip run.
 The cases between them load every module a cell's processes import:
 DIRECT + ``IngestFeed`` + ``make_bn_train_step`` (ResNet-50), STREAMING +
 ``make_train_step`` + the dropless ``ep.py`` + the attention path (OLMoE),
-and the block-diffusion loss over a chip's share of the experts with
-grouped-query heads (SDAR): a crash on that cell's first step shows here.
+the block-diffusion loss over a chip's share of the experts with
+grouped-query heads (SDAR), and learned sparse attention with its indexer's
+loss under remat (Keye): a crash on that cell's first step shows here.
 """
 
 from __future__ import annotations
@@ -42,7 +43,8 @@ def _missing(path: str) -> list[str]:
 
 @pytest.mark.parametrize("workload", ["resnet50_train_tfrecord",
                                       "olmoe_1b_7b_d1_train_4k",
-                                      "sdar_30b_a3b_d4_ep8_train_bd4k"])
+                                      "sdar_30b_a3b_d4_ep8_train_bd4k",
+                                      "keye_vl2_30b_a3b_d4_ep8_train_16k"])
 def test_cell_rehearses_on_cpu(workload):
     cell = common.resolve_cell(workload)
     # run.py's work directory is not configurable and a DIRECT cell keeps one

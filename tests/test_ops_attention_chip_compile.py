@@ -61,3 +61,51 @@ def test_all_three_flash_kernels_lower_at_sdar_widths(one_chip, mask):
         compilation_cache.reset_cache()
     # forward, dk/dv pass, dq pass
     assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize("step", ["select", "attend", "index_loss"])
+def test_the_sparse_attention_kernels_lower_at_keye_widths(one_chip, step):
+    """``ops/sparse_attention.py`` on one 16,384-token row at Keye-VL-2.0's
+    widths (16 index heads of 64 over one index key, top 2,048; 32 x 128
+    query heads over 4 K/V heads).  What interpret mode cannot show: that
+    Mosaic takes the int8 mask tiles, the visit tables built on the device
+    with a grid whose length is a value of the run, 4 MiB of one block's
+    scores and keys in VMEM under the raised limit, and the loss kernel's
+    resident gradient of the one index key."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from tensorflowonspark_tpu.ops import sparse_attention as dsa
+
+    length = 16384
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+    a, b, c = (shape((length, 16, 64), jnp.bfloat16),
+               shape((length, 64), jnp.bfloat16),
+               shape((length, 16), jnp.float32))
+    q, k = (shape((length, 32, 128), jnp.bfloat16),
+            shape((length, 4, 128), jnp.bfloat16))
+    mask = shape((length, length), jnp.int8)
+    lse, lse_i = shape((32, length), jnp.float32), shape((length,),
+                                                         jnp.float32)
+    if step == "select":        # score tiles and the exact threshold
+        fn, args, calls = (lambda a, b, c: dsa.lightning_select(
+            a, b, c, 2048, impl="pallas")), (a, b, c), 2
+    elif step == "attend":      # forward, dk/dv pass, dq pass
+        fn = jax.value_and_grad(lambda q, k, v, mask: jnp.sum(
+            dsa.sparse_attention(q, k, v, mask, impl="pallas")[0].astype(
+                jnp.float32)), argnums=(0, 1, 2))
+        args, calls = (q, k, k, mask), 3
+    else:                       # the loss walk with the indexer's gradient
+        fn = jax.value_and_grad(lambda a, b, c, *rest: dsa.index_kl(
+            a, b, c, *rest, impl="pallas"), argnums=(0, 1, 2))
+        args, calls = (a, b, c, q, k, lse, lse_i, mask), 1
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        hlo = jax.jit(fn).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == calls
