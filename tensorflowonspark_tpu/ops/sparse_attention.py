@@ -34,6 +34,15 @@ mask decides inside a tile.  A selection that is scattered over the past, as
 seeded weights give, leaves every causal tile live: the walk then skips
 nothing and each tile costs a whole tile (PERF.md §7).
 
+A VISIT of an attention kernel is one live tile for one K/V head and ALL the
+query heads of its group (grid ``(K/V heads, visits)``): K, V and the int8
+mask tile are fetched once, the mask is decoded once into a float32 bias
+that every head of the group adds, and dk / dv sum over the group inside the
+visit.  The group is read from the shapes (32 over 4 heads: 8; one query head
+a K/V head: 1, the same path); ``dsa.visit_heads`` over the attention kernels
+built says which it was.  The loss kernel's visit holds every head of every
+group.
+
 The exact threshold of a row is the ``topk``-th largest score, found by 32
 counting passes over the order-preserving integer image of the float32 scores
 (one bit of the answer a pass), never by a sort; ties at the threshold are
@@ -92,10 +101,14 @@ def _tile(length: int, tile: int = 512) -> int:
     return tile
 
 
-def _count_kernel(name: str) -> None:
-    """Counted once per kernel built (trace time), as ``flash.tiles`` is."""
+def _count_kernel(name: str, visit_heads: int = 0) -> None:
+    """Counted once per kernel built (trace time), as ``flash.tiles`` is;
+    an attention kernel adds the query heads one visit of it serves, so
+    ``dsa.visit_heads`` over the attention kernels built reads the group."""
     telemetry.counter("dsa.kernels").inc(1)
     telemetry.counter(f"dsa.kernels.{name}").inc(1)
+    if visit_heads:
+        telemetry.counter("dsa.visit_heads").inc(visit_heads)
 
 
 # ---------------------------------------------------------------------------
@@ -418,38 +431,35 @@ def selection_stats(mask):
                 jnp.sum(live) / (n * (n + 1) // 2))
 
 
-def _visit_table(live, group: int = 1):
+def _visit_table(live):
     """The walk of one kernel's sequential axis over the live tiles of a
     causal ``[n, n]`` tile map (or its transpose), built on the device:
-    ``(block, head, tile, flags, count)`` — for every output block (a row of
-    ``live``) its live tiles in ascending order, once for each of ``group``
-    heads (head by head: the dk/dv pass).  The diagonal tile is always
+    ``(block, tile, flags, count)`` — for every output block (a row of
+    ``live``) its live tiles in ascending order.  The diagonal tile is always
     visited, so every block has a first and a last visit; the table has room
     for every causal tile and ``count`` says how many visits it holds."""
     n = live.shape[0]
-    visit = (live | jnp.eye(n, dtype=bool))[:, None, :]
-    visit = jnp.broadcast_to(visit, (n, group, n))
-    room = n * (n + 1) // 2 * group
+    visit = live | jnp.eye(n, dtype=bool)
+    room = n * (n + 1) // 2
     flat = jnp.nonzero(visit.reshape(-1), size=room, fill_value=0)[0]
     count = jnp.sum(visit).astype(jnp.int32)
     flat = flat.astype(jnp.int32)
-    block, rest = flat // (group * n), flat % (group * n)
-    head, tile = rest // n, rest % n
+    block, tile = flat // n, flat % n
     index = jnp.arange(room)
     real = index < count
     prev = jnp.where(index > 0, jnp.roll(block, 1), -1)
     nxt = jnp.where(index + 1 < count, jnp.roll(block, -1), -1)
     flags = ((block != prev) * _FIRST + (block != nxt) * _LAST) * real
-    return block, head, tile, flags.astype(jnp.int32), count
+    return block, tile, flags.astype(jnp.int32), count
 
 
 def _walk_call(kernel, name: str, grid: tuple, tables, *, out_shape,
                interpret: bool, scratch_shapes, in_specs, out_specs,
-               vmem_limit: int | None = None):
+               visit_heads: int = 0):
     """``kernel`` over ``grid``, whose LAST axis is the walk (its length the
     table's ``count``, a value of the run); the axes before it run in
     parallel.  The tables are the scalar-prefetch operands."""
-    _count_kernel(name)
+    _count_kernel(name, visit_heads)
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -459,7 +469,7 @@ def _walk_call(kernel, name: str, grid: tuple, tables, *, out_shape,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * (len(grid) - 1)
-            + ("arbitrary",), vmem_limit_bytes=vmem_limit),
+            + ("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret)
     return functools.partial(call, *tables)
 
@@ -474,101 +484,135 @@ def _kept(mask_ref):
 
 # ---------------------------------------------------------------------------
 # Kernels 3-5: attention over the kept pairs, forward and both backward
-# passes, as ``ops/attention.py``'s three walk their trace-time tables.
+# passes.  A VISIT is one live tile for one K/V head and ALL the query heads
+# of its group: the grid is ``(K/V heads, visits)``, the K/V tiles and the
+# int8 mask tile are fetched once a visit, the mask is decoded once (into an
+# additive float32 bias, 0 on a kept pair and ``NEG_INF`` elsewhere:
+# ``logits + bias`` is ``where(kept, logits, NEG_INF)`` to the bit for finite
+# logits) and the group's heads are a loop inside the visit over the
+# ``[group, tile, d]`` blocks of the query side.  ``group`` is read from the
+# shapes; with one query head a K/V head the loop has one turn.
 # ---------------------------------------------------------------------------
 
+def _decode(mask_ref, bias_ref):
+    bias_ref[...] = jnp.where(_kept(mask_ref), 0.0, NEG_INF)
+
+
+def _lanes(x, width: int):
+    """A lane-replicated statistic ``[rows, 128]`` as ``[rows, width]``:
+    whole copies of its 128 lanes side by side (the first lanes of one for a
+    narrower tile).  Not ``x[:, 0:1]`` broadcast: that one-lane column cost
+    the forward kernel as much as its matmuls (PERF.md §6, PR 34)."""
+    return jnp.tile(x, (1, pl.cdiv(width, 128)))[:, :width]
+
+
 def _fwd_kernel(iq_ref, ik_ref, flags_ref, q_ref, k_ref, v_ref, mask_ref,
-                o_ref, lse_ref, acc_ref, m_ref, l_ref, *, sm_scale: float):
+                o_ref, lse_ref, acc_ref, m_ref, l_ref, bias_ref, *,
+                sm_scale: float):
     del iq_ref, ik_ref
     flags = flags_ref[pl.program_id(1)]
+    group, tile, d = q_ref.shape
 
     def init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
     def finalize():
-        l = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = (acc_ref[:] / l[:, 0:1]).astype(o_ref.dtype)
-        lse = jnp.where(l_ref[:] > 0.0, m_ref[:] + jnp.log(l), NEG_INF)
-        lse_ref[0] = lse.T[0:1]
+        for h in range(group):
+            l = jnp.maximum(l_ref[h], 1e-30)
+            o_ref[h] = (acc_ref[h] / _lanes(l, d)).astype(o_ref.dtype)
+            lse = jnp.where(l_ref[h] > 0.0, m_ref[h] + jnp.log(l), NEG_INF)
+            lse_ref[h] = lse.T[0:1]
 
     _on(flags, _FIRST, init)
-    v = v_ref[0]
-    logits = lax.dot_general(q_ref[0], k_ref[0], _NT,
-                             preferred_element_type=jnp.float32) * sm_scale
-    logits = jnp.where(_kept(mask_ref), logits, NEG_INF)
-    m_prev = m_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-    m_safe = jnp.maximum(m_new, NEG_INF / 2)
-    p = jnp.exp(logits - m_safe[:, 0:1])
-    p = jnp.where(logits <= NEG_INF / 2, 0.0, p)
-    alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
-    l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha[:, 0:1] + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_ref[:] = m_new
+    _decode(mask_ref, bias_ref)
+    k, v = k_ref[0], v_ref[0]
+    for h in range(group):
+        logits = lax.dot_general(
+            q_ref[h], k, _NT,
+            preferred_element_type=jnp.float32) * sm_scale + bias_ref[...]
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        # a row that has kept nothing yet: exp(NEG_INF - NEG_INF / 2) = 0
+        m_safe = jnp.maximum(m_new, NEG_INF / 2)
+        p = jnp.exp(logits - _lanes(m_safe, tile))
+        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
+                          jnp.exp(m_prev - m_safe))
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * _lanes(alpha, d) + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
     _on(flags, _LAST, finalize)
 
 
-def _dkv_kernel(ik_ref, head_ref, iq_ref, flags_ref, q_ref, do_ref, lse_ref,
-                delta_ref, k_ref, v_ref, mask_t_ref, dk_ref, dv_ref,
-                dk_acc, dv_acc, *, sm_scale: float):
-    # the transposed tile [keys, queries], as ops/attention.py's dk/dv pass
-    del ik_ref, head_ref, iq_ref
+def _dkv_kernel(ik_ref, iq_ref, flags_ref, q_ref, do_ref, lse_ref, delta_ref,
+                k_ref, v_ref, mask_t_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+                bias_ref, *, sm_scale: float):
+    # the transposed tile [keys, queries], as ops/attention.py's dk/dv pass;
+    # dk and dv sum over the group's heads inside the visit
+    del ik_ref, iq_ref
     flags = flags_ref[pl.program_id(1)]
 
     def init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def finalize():
-        dk_ref[0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
     _on(flags, _FIRST, init)
-    q, do = q_ref[0], do_ref[0]
-    logits_t = lax.dot_general(k_ref[0], q, _NT,
-                               preferred_element_type=jnp.float32) * sm_scale
-    p_t = jnp.exp(jnp.where(_kept(mask_t_ref), logits_t, NEG_INF)
-                  - lse_ref[0])
-    dv_acc[:] += jnp.dot(p_t.astype(do.dtype), do,
-                         preferred_element_type=jnp.float32)
-    dp_t = lax.dot_general(v_ref[0], do, _NT,
-                           preferred_element_type=jnp.float32)
-    ds_t = p_t * (dp_t - delta_ref[0])
-    dk_acc[:] += jnp.dot(ds_t.astype(q.dtype), q,
-                         preferred_element_type=jnp.float32)
+    _decode(mask_t_ref, bias_ref)
+    k, v = k_ref[0], v_ref[0]
+    for h in range(q_ref.shape[0]):
+        q, do = q_ref[h], do_ref[h]
+        logits_t = lax.dot_general(
+            k, q, _NT,
+            preferred_element_type=jnp.float32) * sm_scale + bias_ref[...]
+        p_t = jnp.exp(logits_t - lse_ref[h])
+        dv_acc[...] += jnp.dot(p_t.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dp_t = lax.dot_general(v, do, _NT,
+                               preferred_element_type=jnp.float32)
+        ds_t = p_t * (dp_t - delta_ref[h])
+        dk_acc[...] += jnp.dot(ds_t.astype(q.dtype), q,
+                               preferred_element_type=jnp.float32)
     _on(flags, _LAST, finalize)
 
 
 def _dq_kernel(iq_ref, ik_ref, flags_ref, q_ref, do_ref, lse_ref, delta_ref,
-               k_ref, v_ref, mask_ref, dq_ref, dq_acc, *, sm_scale: float):
+               k_ref, v_ref, mask_ref, dq_ref, dq_acc, bias_ref, *,
+               sm_scale: float):
     del iq_ref, ik_ref
     flags = flags_ref[pl.program_id(1)]
 
     def init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def finalize():
-        dq_ref[0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
+        dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
     _on(flags, _FIRST, init)
-    k = k_ref[0]
-    logits = lax.dot_general(q_ref[0], k, _NT,
-                             preferred_element_type=jnp.float32) * sm_scale
-    p = jnp.exp(jnp.where(_kept(mask_ref), logits, NEG_INF)
-                - lse_ref[0][:, 0:1])
-    dp = lax.dot_general(do_ref[0], v_ref[0], _NT,
-                         preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0][:, 0:1])
-    dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
-                         preferred_element_type=jnp.float32)
+    _decode(mask_ref, bias_ref)
+    k, v = k_ref[0], v_ref[0]
+    group, tile, _ = q_ref.shape
+    for h in range(group):
+        logits = lax.dot_general(
+            q_ref[h], k, _NT,
+            preferred_element_type=jnp.float32) * sm_scale + bias_ref[...]
+        p = jnp.exp(logits - _lanes(lse_ref[h], tile))
+        dp = lax.dot_general(do_ref[h], v, _NT,
+                             preferred_element_type=jnp.float32)
+        ds = p * (dp - _lanes(delta_ref[h], tile))
+        dq_acc[h] += jnp.dot(ds.astype(k.dtype), k,
+                             preferred_element_type=jnp.float32)
     _on(flags, _LAST, finalize)
 
 
 def _head_major(x):
-    """``[L, H, D]`` -> ``[H, L, D]``, one head per grid row."""
+    """``[L, H, D]`` -> ``[H, L, D]``: a K/V group's query heads are
+    neighbours, so one block holds them."""
     return x.transpose(1, 0, 2)
 
 
@@ -577,29 +621,31 @@ def _fwd_pallas(qt, kt, vt, mask, table, *, sm_scale, tile, interpret):
     values under ``mask``: ``(out [H, L, D], lse [H, L] float32)``."""
     heads, length, d = qt.shape
     group = heads // kt.shape[0]
-    block, _head, col, flags, count = table
-    q_at = lambda h, v, iq, ik, f: (h, iq[v], 0)                # noqa: E731
-    kv_at = lambda h, v, iq, ik, f: (h // group, ik[v], 0)      # noqa: E731
+    block, col, flags, count = table
+    q_at = lambda g, v, iq, ik, f: (g, iq[v], 0)                # noqa: E731
+    kv_at = lambda g, v, iq, ik, f: (g, ik[v], 0)               # noqa: E731
     out, lse = _walk_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale), "attend_fwd",
-        (heads, count), (block, col, flags),
+        (heads // group, count), (block, col, flags),
         in_specs=[
-            pl.BlockSpec((1, tile, d), q_at),
+            pl.BlockSpec((group, tile, d), q_at),
             pl.BlockSpec((1, tile, d), kv_at),
             pl.BlockSpec((1, tile, d), kv_at),
             pl.BlockSpec((tile, tile),
-                         lambda h, v, iq, ik, f: (iq[v], ik[v])),
+                         lambda g, v, iq, ik, f: (iq[v], ik[v])),
         ],
         out_specs=[
-            pl.BlockSpec((1, tile, d), q_at),
-            pl.BlockSpec((1, 1, tile), lambda h, v, iq, ik, f: (h, 0, iq[v])),
+            pl.BlockSpec((group, tile, d), q_at),
+            pl.BlockSpec((group, 1, tile),
+                         lambda g, v, iq, ik, f: (g, 0, iq[v])),
         ],
         out_shape=[jax.ShapeDtypeStruct((heads, length, d), qt.dtype),
                    jax.ShapeDtypeStruct((heads, 1, length), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32),
-                        pltpu.VMEM((tile, 128), jnp.float32),
-                        pltpu.VMEM((tile, 128), jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((group, tile, d), jnp.float32),
+                        pltpu.VMEM((group, tile, 128), jnp.float32),
+                        pltpu.VMEM((group, tile, 128), jnp.float32),
+                        pltpu.VMEM((tile, tile), jnp.float32)],
+        interpret=interpret, visit_heads=group,
     )(qt, kt, vt, mask)
     return out, lse[:, 0]
 
@@ -611,59 +657,60 @@ def _bwd_pallas(qt, kt, vt, do_t, lse, delta, mask, table, table_t, *,
     rows = [x[:, None, :] for x in (lse, delta)]                # [H, 1, L]
     cols = [jnp.broadcast_to(x[:, :, None], (heads, length, 128))
             for x in (lse, delta)]
-    q_block, k_block = (1, tile, d), (1, tile, d)
+    q_block, k_block = (group, tile, d), (1, tile, d)
+    bias = pltpu.VMEM((tile, tile), jnp.float32)
 
-    block, head, col, flags, count = table_t     # blocks: K/V tiles
-    q_at = lambda g, v, ik, hd, iq, f: (g * group + hd[v], iq[v], 0)  # noqa: E731
-    row_at = lambda g, v, ik, hd, iq, f: (g * group + hd[v], 0, iq[v])  # noqa: E731
-    k_at = lambda g, v, ik, hd, iq, f: (g, ik[v], 0)            # noqa: E731
+    block, col, flags, count = table_t           # blocks: K/V tiles
+    q_at = lambda g, v, ik, iq, f: (g, iq[v], 0)                # noqa: E731
+    row_at = lambda g, v, ik, iq, f: (g, 0, iq[v])              # noqa: E731
+    k_at = lambda g, v, ik, iq, f: (g, ik[v], 0)                # noqa: E731
     dk, dv = _walk_call(
         functools.partial(_dkv_kernel, sm_scale=sm_scale), "attend_dkv",
-        (heads // group, count), (block, head, col, flags),
+        (heads // group, count), (block, col, flags),
         in_specs=[
             pl.BlockSpec(q_block, q_at),
             pl.BlockSpec(q_block, q_at),
-            pl.BlockSpec((1, 1, tile), row_at),
-            pl.BlockSpec((1, 1, tile), row_at),
+            pl.BlockSpec((group, 1, tile), row_at),
+            pl.BlockSpec((group, 1, tile), row_at),
             pl.BlockSpec(k_block, k_at),
             pl.BlockSpec(k_block, k_at),
             pl.BlockSpec((tile, tile),
-                         lambda g, v, ik, hd, iq, f: (ik[v], iq[v])),
+                         lambda g, v, ik, iq, f: (ik[v], iq[v])),
         ],
         out_specs=[pl.BlockSpec(k_block, k_at)] * 2,
         out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
                    jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
-        scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)] * 2,
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)] * 2 + [bias],
+        interpret=interpret, visit_heads=group,
     )(qt, do_t, *rows, kt, vt, mask.T)
 
-    block, _head, col, flags, count = table
-    q_at = lambda h, v, iq, ik, f: (h, iq[v], 0)                # noqa: E731
-    kv_at = lambda h, v, iq, ik, f: (h // group, ik[v], 0)      # noqa: E731
+    block, col, flags, count = table
+    q_at = lambda g, v, iq, ik, f: (g, iq[v], 0)                # noqa: E731
+    kv_at = lambda g, v, iq, ik, f: (g, ik[v], 0)               # noqa: E731
     dq = _walk_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale), "attend_dq",
-        (heads, count), (block, col, flags),
+        (heads // group, count), (block, col, flags),
         in_specs=[
             pl.BlockSpec(q_block, q_at),
             pl.BlockSpec(q_block, q_at),
-            pl.BlockSpec((1, tile, 128), q_at),
-            pl.BlockSpec((1, tile, 128), q_at),
+            pl.BlockSpec((group, tile, 128), q_at),
+            pl.BlockSpec((group, tile, 128), q_at),
             pl.BlockSpec(k_block, kv_at),
             pl.BlockSpec(k_block, kv_at),
             pl.BlockSpec((tile, tile),
-                         lambda h, v, iq, ik, f: (iq[v], ik[v])),
+                         lambda g, v, iq, ik, f: (iq[v], ik[v])),
         ],
         out_specs=pl.BlockSpec(q_block, q_at),
         out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
-        scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((group, tile, d), jnp.float32), bias],
+        interpret=interpret, visit_heads=group,
     )(qt, do_t, *cols, kt, vt, mask)
     return dq, dk, dv
 
 
-def _tables(mask, tile: int, group: int):
+def _tables(mask, tile: int):
     live = live_tiles(mask, tile)
-    return _visit_table(live), _visit_table(live.T, group)
+    return _visit_table(live), _visit_table(live.T)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -674,7 +721,7 @@ def _attend_tpu(q, k, v, mask, sm_scale, interpret):
 def _attend_fwd(q, k, v, mask, sm_scale, interpret):
     tile = _tile(q.shape[0])
     qt, kt, vt = (_head_major(x) for x in (q, k, v))
-    tables = _tables(mask, tile, q.shape[1] // k.shape[1])
+    tables = _tables(mask, tile)
     ot, lse = _fwd_pallas(qt, kt, vt, mask, tables[0], sm_scale=sm_scale,
                           tile=tile, interpret=interpret)
     out = checkpoint_name(ot.transpose(1, 0, 2), "dsa_out")
@@ -801,7 +848,7 @@ def _kl_pallas(a, b, c, qt, kt, lse, lse_i, mask, table, *, sm_scale,
     length, index_heads, dim = a.shape
     heads, _, d = qt.shape
     tile = _tile(length)
-    block, _head, col, flags, count = table
+    block, col, flags, count = table
     q_rows = lambda v, iq, ik, f: (iq[v], 0)                    # noqa: E731
     kl, da, dc, db = _walk_call(
         functools.partial(_kl_kernel, sm_scale=sm_scale, tile=tile,
@@ -835,7 +882,7 @@ def _kl_pallas(a, b, c, qt, kt, lse, lse_i, mask, table, *, sm_scale,
         scratch_shapes=[pltpu.VMEM((tile, 1), jnp.float32),
                         pltpu.VMEM((index_heads, tile, dim), jnp.float32),
                         pltpu.VMEM((tile, index_heads), jnp.float32)],
-        interpret=interpret, vmem_limit=_VMEM_LIMIT,
+        interpret=interpret,
     )(qt, kt, lse.T, a.transpose(1, 0, 2), b, c,
       jnp.broadcast_to(lse_i[:, None], (length, 128)), mask)
     return jnp.sum(kl), da.transpose(1, 0, 2), db, dc

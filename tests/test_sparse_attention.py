@@ -1,24 +1,28 @@
 """Learned sparse attention (``ops/sparse_attention.py``): the dense path and
 the kernels in interpret mode against a dense masked softmax written here, at
-grouped-query heads 4 over 2."""
+grouped-query heads 4 over 2 (attention itself: 4 over 4, 2 and 1 too, a
+visit of its kernels serving 1, 2 or 4 query heads)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tensorflowonspark_tpu import telemetry
 from tensorflowonspark_tpu.ops import attention as att
 from tensorflowonspark_tpu.ops import sparse_attention as dsa
+from tests.test_ops_attention import _pallas_grids
 
 IMPLS = ["xla", "pallas_interpret"]
 L, H, HKV, D, J, DI = 64, 4, 2, 16, 3, 8
+GROUPS = [1, 2, 4]          # query heads a K/V head: H over 4, 2 and 1
 
 
-def make_row(seed=0, length=L, dtype=jnp.float32):
+def make_row(seed=0, length=L, dtype=jnp.float32, kv_heads=HKV):
     rng = np.random.RandomState(seed)
     draw = lambda *shape: jnp.asarray(rng.randn(*shape), dtype)  # noqa: E731
-    return {"q": draw(length, H, D), "k": draw(length, HKV, D),
-            "v": draw(length, HKV, D), "a": draw(length, J, DI),
+    return {"q": draw(length, H, D), "k": draw(length, kv_heads, D),
+            "v": draw(length, kv_heads, D), "a": draw(length, J, DI),
             "b": draw(length, DI), "c": draw(length, J)}
 
 
@@ -94,19 +98,21 @@ def test_the_selection_goes_chunk_by_chunk_of_queries(impl):
         sorted_selection(dense_scores(row["a"], row["b"], row["c"]), 9))
 
 
+@pytest.fixture
+def tiles_of_16(monkeypatch):
+    monkeypatch.setattr(dsa, "_tile",
+                        lambda length, tile=16: min(tile, length))
+
+
 def _select(row, topk, impl):
     return dsa.lightning_select(row["a"], row["b"], row["c"], topk,
                                 impl=impl)
 
 
-@pytest.mark.parametrize("impl", IMPLS)
-def test_attention_over_the_kept_pairs_and_its_three_gradients(impl):
-    row = make_row(seed=1)
-    mask, _ = _select(row, 12, "xla")
-    # queries with fewer causal keys than topk keep them all
-    np.testing.assert_array_equal(np.asarray(mask)[:12],
-                                  np.tril(np.ones((L, L), np.int8))[:12])
-    w = jnp.asarray(np.random.RandomState(2).randn(L, H, D), jnp.float32)
+def assert_attention_and_its_three_gradients(row, mask, impl, seed):
+    """``sparse_attention`` under ``mask`` against the dense softmax: the
+    output, the log-sum-exp and the gradients in q, k and v."""
+    w = jnp.asarray(np.random.RandomState(seed).randn(L, H, D), jnp.float32)
 
     def loss(fn):
         return lambda q, k, v: jnp.sum(fn(q, k, v)[0] * w)
@@ -121,7 +127,63 @@ def test_attention_over_the_kept_pairs_and_its_three_gradients(impl):
     want = jax.grad(loss(lambda q, k, v: dense_attention(q, k, v, mask)),
                     argnums=(0, 1, 2))(*qkv)
     for g, g_want in zip(got, want):
+        assert g.shape == g_want.shape
         np.testing.assert_allclose(g, g_want, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("group", GROUPS)
+def test_attention_over_the_kept_pairs_and_its_three_gradients(group, impl):
+    row = make_row(seed=1, kv_heads=H // group)
+    mask, _ = _select(row, 12, "xla")
+    # queries with fewer causal keys than topk keep them all
+    np.testing.assert_array_equal(np.asarray(mask)[:12],
+                                  np.tril(np.ones((L, L), np.int8))[:12])
+    assert_attention_and_its_three_gradients(row, mask, impl, seed=2)
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("group", GROUPS)
+def test_rows_that_keep_nothing_inside_a_visited_tile(group, impl,
+                                                      tiles_of_16):
+    """Tiles of 16.  Every query keeps itself, the even ones key 0 and every
+    fourth one key 20 as well: in the tiles ``(i, 0)`` and ``(i, 1)`` below
+    the diagonal most rows keep nothing.  An odd row comes to its diagonal
+    tile with no maximum yet (``m_prev = NEG_INF``: its visits so far must
+    have added exactly nothing), a row ``2 mod 4`` meets a tile with nothing
+    for it AFTER it has a maximum."""
+    t = np.arange(L)
+    mask = np.eye(L, dtype=np.int8)
+    mask[t % 2 == 0, 0] = 1
+    mask[(t % 4 == 0) & (t >= 32), 20] = 1
+    live = np.asarray(dsa.live_tiles(jnp.asarray(mask), 16))
+    assert live[2].tolist() == [True, True, True, False]
+    assert not mask[33, :32].any() and not mask[34, 16:32].any()
+    assert_attention_and_its_three_gradients(
+        make_row(seed=12, kv_heads=H // group), jnp.asarray(mask), impl,
+        seed=13)
+
+
+@pytest.mark.parametrize("group", GROUPS)
+def test_a_visit_serves_the_whole_group_of_query_heads(group):
+    """The three attention kernels have the grid ``(K/V heads, visits)`` and
+    count the query heads a visit serves: ``dsa.visit_heads`` over the
+    attention kernels built reads the group."""
+    row = make_row(seed=14, kv_heads=H // group)
+    mask, _ = _select(row, 12, "xla")
+    before = telemetry.snapshot()["counters"]
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda q, k, v: jnp.sum(dsa.sparse_attention(
+            q, k, v, mask, impl="pallas_interpret")[0]),
+        argnums=(0, 1, 2)))(row["q"], row["k"], row["v"])
+    after = telemetry.snapshot()["counters"]
+    counted = {name.removeprefix("dsa."): after[name] - before.get(name, 0)
+               for name in after if name.startswith("dsa.")}
+    built = sum(counted[f"kernels.attend_{name}"]
+                for name in ("fwd", "dkv", "dq"))
+    assert built == counted["kernels"] == 3
+    assert counted["visit_heads"] == group * built
+    assert [grid[0] for grid in _pallas_grids(jaxpr.jaxpr)] == [H // group] * 3
 
 
 @pytest.mark.parametrize("impl", IMPLS)
@@ -178,17 +240,17 @@ def test_the_indexers_loss_and_its_gradient(impl):
 
 @pytest.mark.parametrize("impl", IMPLS)
 def test_a_selection_that_leaves_tiles_empty_walks_the_live_ones(impl,
-                                                               monkeypatch):
+                                                               tiles_of_16):
     """Tiles of 16: index scores that favour the first keys leave most of
     the causal tiles without a kept pair; the visit table lists the live
     ones (and each block's diagonal), and the results are the dense ones."""
-    monkeypatch.setattr(dsa, "_tile", lambda length, tile=16: min(tile, length))
     row = make_row(seed=8)
     scores = jnp.broadcast_to(-jnp.arange(L, dtype=jnp.float32), (L, L))
     mask, lse_i = dsa.select_topk(scores, 8, impl=impl)
     live = dsa.live_tiles(mask, 16)
     assert int(live.sum()) == 4         # the first column of tiles
-    block, _head, tile, flags, count = dsa._visit_table(live)
+    # no head column: a visit serves every query head of its group
+    block, tile, flags, count = dsa._visit_table(live)
     assert int(count) == 4 + 3          # and the three other diagonal tiles
     visits = list(zip(*(np.asarray(x)[:int(count)]
                         for x in (block, tile, flags))))
