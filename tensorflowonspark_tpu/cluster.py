@@ -434,6 +434,7 @@ class TPUCluster:
         heartbeat_interval: float = 2.0,
         elastic: bool | RestartPolicy = False,
         log_dir: str = "",
+        started_at: float | None = None,
     ):
         self.coordinator = coordinator
         self.launcher = launcher
@@ -442,7 +443,10 @@ class TPUCluster:
         self.input_mode = input_mode
         self.queues = queues
         self.log_dir = log_dir
-        self._started_at = time.monotonic()
+        # the run report's wall_secs counts from here: ``run`` passes its own
+        # entry, so that the launch and the nodes' start are inside it
+        self._started_at = (time.monotonic() if started_at is None
+                            else started_at)
         self.input_qnames = [q for q in queues if q not in ("output", "error")]
         self.feed_timeout = feed_timeout
         self.heartbeat_interval = heartbeat_interval
@@ -2150,41 +2154,42 @@ class TPUCluster:
             # order — match processes through the launch_index each node
             # reported at registration (pids can't do this: over ssh
             # transports the local handle's pid is the ssh client).
-            procs = self.launcher.processes
-            id_to_proc = {
-                m["executor_id"]: procs[m["launch_index"]]
-                for m in self.cluster_info
-                if 0 <= m.get("launch_index", -1) < len(procs)
-            }
-            # Ingest workers FIRST: their EOF ends the shard feed, each
-            # service forwards its pipeline tail and exits — and the brief
-            # join below lets that tail land BEFORE any trainer's
-            # EndOfFeed is queued (FIFO: a chunk delivered before the
-            # trainer's EOF is consumed, one after it is teardown-dropped).
-            def _eof_node(executor_id: int) -> None:
-                proc = id_to_proc.get(executor_id)
-                if proc is not None and not proc.is_alive():
-                    # node already finished and tore down its data plane;
-                    # an EOF would only block on a dead peer
-                    logger.debug("node %d already exited; skipping EOF",
-                                 executor_id)
-                    return
-                for qname in self.input_qnames:
-                    self._send_eof_best_effort(executor_id, qname, proc=proc)
+            with ttrace.lifecycle("shutdown.eof"):
+                procs = self.launcher.processes
+                id_to_proc = {
+                    m["executor_id"]: procs[m["launch_index"]]
+                    for m in self.cluster_info
+                    if 0 <= m.get("launch_index", -1) < len(procs)
+                }
+                # Ingest workers FIRST: their EOF ends the shard feed, each
+                # service forwards its pipeline tail and exits — and the brief
+                # join below lets that tail land BEFORE any trainer's
+                # EndOfFeed is queued (FIFO: a chunk delivered before the
+                # trainer's EOF is consumed, one after it is teardown-dropped).
+                def _eof_node(executor_id: int) -> None:
+                    proc = id_to_proc.get(executor_id)
+                    if proc is not None and not proc.is_alive():
+                        # node already finished and tore down its data plane;
+                        # an EOF would only block on a dead peer
+                        logger.debug("node %d already exited; skipping EOF",
+                                     executor_id)
+                        return
+                    for qname in self.input_qnames:
+                        self._send_eof_best_effort(executor_id, qname, proc=proc)
 
-            for executor_id in self._ingest_ids:
-                _eof_node(executor_id)
-            if self._ingest_ids:
-                tail_deadline = time.monotonic() + min(15.0, timeout / 4.0)
-                while time.monotonic() < tail_deadline and any(
-                        p is not None and p.is_alive()
-                        for p in (id_to_proc.get(e)
-                                  for e in self._ingest_ids)):
-                    time.sleep(0.1)
-            for executor_id in self._feed_ids:
-                _eof_node(executor_id)
-            if grace_secs:
-                time.sleep(grace_secs)
+                for executor_id in self._ingest_ids:
+                    _eof_node(executor_id)
+                if self._ingest_ids:
+                    tail_deadline = time.monotonic() + min(15.0, timeout / 4.0)
+                    while time.monotonic() < tail_deadline and any(
+                            p is not None and p.is_alive()
+                            for p in (id_to_proc.get(e)
+                                      for e in self._ingest_ids)):
+                        time.sleep(0.1)
+                for executor_id in self._feed_ids:
+                    _eof_node(executor_id)
+                if grace_secs:
+                    time.sleep(grace_secs)
             # Politely wait for map_funs to finish; only then escalate.  The
             # stop flag breaks in-flight barriers/reduces, so raising it early
             # would abort healthy nodes mid-collective.  The wait is
@@ -2195,49 +2200,56 @@ class TPUCluster:
             forced = False
             death_detected = False
             deadline = time.monotonic() + timeout
-            while True:
-                slice_ = min(2.0, max(0.05, deadline - time.monotonic()))
-                if self.launcher.join(slice_):
-                    break
-                dead = self._record_deaths()
-                if dead:
-                    death_detected = True
-                    logger.warning("nodes %s died during shutdown; escalating now", dead)
-                if death_detected or time.monotonic() >= deadline:
-                    alive = self.launcher.alive()
-                    logger.warning("nodes %s still running; signalling stop", alive)
-                    self.coordinator.signal_stop()  # heartbeats tell stragglers to stop
-                    # with a confirmed death, survivors wedged in collectives
-                    # never drain — keep the post-stop grace short
-                    if not self.launcher.join(5.0 if death_detected else 15.0):
-                        forced = True
-                        logger.warning("nodes %s ignored stop; terminating", self.launcher.alive())
-                        self.launcher.terminate()
-                    break
+            with ttrace.lifecycle("shutdown.join"):
+                while True:
+                    slice_ = min(2.0, max(0.05, deadline - time.monotonic()))
+                    if self.launcher.join(slice_):
+                        break
+                    dead = self._record_deaths()
+                    if dead:
+                        death_detected = True
+                        logger.warning("nodes %s died during shutdown; escalating now", dead)
+                    if death_detected or time.monotonic() >= deadline:
+                        alive = self.launcher.alive()
+                        logger.warning("nodes %s still running; signalling stop", alive)
+                        self.coordinator.signal_stop()  # heartbeats tell stragglers to stop
+                        # with a confirmed death, survivors wedged in collectives
+                        # never drain — keep the post-stop grace short
+                        if not self.launcher.join(5.0 if death_detected else 15.0):
+                            forced = True
+                            logger.warning("nodes %s ignored stop; terminating", self.launcher.alive())
+                            self.launcher.terminate()
+                        break
             for c in self._clients.values():
                 c.close()
             # Run report BEFORE error propagation: a failed run is exactly
             # when the recorded restarts/faults/spans matter most.  Every
             # node has deregistered (or died) by now, so the coordinator's
-            # per-node store holds the final snapshots.
-            self._stop_metrics_export()
-            # stream assembly copies every bounded span store and parses
-            # every flight dump: gather once, feed both writers
+            # per-node store holds the final snapshots.  Stream assembly
+            # copies every bounded span store and parses every flight dump:
+            # gather once, feed both writers.
             trace_streams: dict[str, dict] | None = None
+            with ttrace.lifecycle("shutdown.gather"):
+                self._stop_metrics_export()
+                try:
+                    trace_streams = self._trace_streams_with_dumps()
+                except Exception:  # noqa: BLE001 - tracing must not mask errors
+                    logger.warning("could not gather trace streams",
+                                   exc_info=True)
+                try:
+                    trace_path = self.write_trace_artifacts(trace_streams)
+                    if trace_path:
+                        logger.info("merged trace written to %s (load it at "
+                                    "https://ui.perfetto.dev)", trace_path)
+                except Exception:  # noqa: BLE001 - tracing must not mask errors
+                    logger.warning("could not write trace artifacts",
+                                   exc_info=True)
             try:
-                trace_streams = self._trace_streams_with_dumps()
-            except Exception:  # noqa: BLE001 - tracing must not mask errors
-                logger.warning("could not gather trace streams",
-                               exc_info=True)
-            try:
-                trace_path = self.write_trace_artifacts(trace_streams)
-                if trace_path:
-                    logger.info("merged trace written to %s (load it at "
-                                "https://ui.perfetto.dev)", trace_path)
-            except Exception:  # noqa: BLE001 - tracing must not mask errors
-                logger.warning("could not write trace artifacts",
-                               exc_info=True)
-            try:
+                # the driver's stream once more: what it recorded since the
+                # gather began (``shutdown.gather`` itself) is the report's
+                driver = self.coordinator.trace_streams().get("driver")
+                if trace_streams is not None and driver is not None:
+                    trace_streams["driver"] = driver
                 if telemetry.enabled() and _env_bool("TOS_RUN_REPORT", True):
                     report_path = self.write_run_report(
                         streams=trace_streams)
@@ -2360,9 +2372,13 @@ class TPUCluster:
             os.path.join(self.log_dir, "trace.json"), streams)
 
     def debug_dump(self) -> str:
-        """Human-readable text report of ``metrics()`` (paste into a bug
-        report; the run report is the JSON twin)."""
-        return telemetry.debug_dump(self.metrics())
+        """Human-readable text report of ``metrics()`` and of the run
+        report's ``lifecycle`` block so far (paste into a bug report; the run
+        report is the JSON twin)."""
+        aggregated = self.metrics()
+        return telemetry.debug_dump(aggregated, telemetry.build_lifecycle(
+            ttrace.merge_events(self._trace_streams_with_dumps()),
+            aggregated.get("nodes") or {}))
 
     def write_run_report(self, path: str | None = None,
                          streams: dict[str, dict] | None = None) -> str | None:
@@ -2569,6 +2585,7 @@ def run(
     connection with the per-cluster ``authkey`` (HMAC challenge-response,
     same handshake as the data plane).
     """
+    started_at = time.monotonic()
     # TPUPodLauncher forces jax_distributed=True on every NodeConfig it
     # launches, so checking the parameter alone would let a pod job slip
     # past the guard.
@@ -2633,44 +2650,50 @@ def run(
     authkey = secrets.token_bytes(16)
     if log_dir:
         os.makedirs(log_dir, exist_ok=True)
-    # Control-plane write-ahead journal (ISSUE 13): with a log_dir every
-    # coordinator mutation is journaled to <log_dir>/coordinator.journal and
-    # a coordinator crash becomes a supervised, epoch-bumping restart
-    # (TPUCluster wires the CoordinatorSupervisor); journal-less
-    # coordinators keep the old behaviour — a crash is fatal.
-    coordinator = CoordinatorServer(
-        total_procs, roles, authkey=authkey,
-        journal_path=(os.path.join(log_dir, "coordinator.journal")
-                      if log_dir else None))
-    addr = coordinator.start(coordinator_host)
+    # the driver's own part of tos.run before any node exists
+    with ttrace.lifecycle("cluster.launch"):
+        # Control-plane write-ahead journal (ISSUE 13): with a log_dir every
+        # coordinator mutation is journaled to <log_dir>/coordinator.journal
+        # and a coordinator crash becomes a supervised, epoch-bumping restart
+        # (TPUCluster wires the CoordinatorSupervisor); journal-less
+        # coordinators keep the old behaviour — a crash is fatal.
+        coordinator = CoordinatorServer(
+            total_procs, roles, authkey=authkey,
+            journal_path=(os.path.join(log_dir, "coordinator.journal")
+                          if log_dir else None))
+        addr = coordinator.start(coordinator_host)
 
-    configs = [
-        NodeConfig(
-            coordinator_addr=addr,
-            authkey=authkey,
-            map_fun=map_fun,
-            tf_args=tf_args,
-            queues=tuple(queues),
-            input_qnames=tuple(q for q in queues if q not in ("output", "error")),
-            input_mode=("direct" if input_mode == InputMode.DIRECT
-                        else "streaming"),
-            queue_capacity=queue_capacity,
-            feed_timeout=feed_timeout,
-            reservation_timeout=reservation_timeout,
-            heartbeat_interval=heartbeat_interval,
-            default_fs=default_fs,
-            log_dir=log_dir,
-            tensorboard=tensorboard,
-            jax_distributed=jax_distributed,
-            env=node_envs[i],
-            launch_index=i,
-            ingest_opts=dict(ingest_opts) if ingest_opts else None,
-        )
-        for i in range(total_procs)
-    ]
-    launcher.launch(configs, log_dir or None)
+        configs = [
+            NodeConfig(
+                coordinator_addr=addr,
+                authkey=authkey,
+                map_fun=map_fun,
+                tf_args=tf_args,
+                queues=tuple(queues),
+                input_qnames=tuple(q for q in queues
+                                   if q not in ("output", "error")),
+                input_mode=("direct" if input_mode == InputMode.DIRECT
+                            else "streaming"),
+                queue_capacity=queue_capacity,
+                feed_timeout=feed_timeout,
+                reservation_timeout=reservation_timeout,
+                heartbeat_interval=heartbeat_interval,
+                default_fs=default_fs,
+                log_dir=log_dir,
+                tensorboard=tensorboard,
+                jax_distributed=jax_distributed,
+                env=node_envs[i],
+                launch_index=i,
+                ingest_opts=dict(ingest_opts) if ingest_opts else None,
+            )
+            for i in range(total_procs)
+        ]
+        launcher.launch(configs, log_dir or None)
     try:
-        cluster_info = coordinator.await_registrations(reservation_timeout)
+        # what the driver waits while the nodes start, import and register
+        with ttrace.lifecycle("cluster.await_registrations"):
+            cluster_info = coordinator.await_registrations(
+                reservation_timeout)
     except TimeoutError:
         launcher.terminate()
         coordinator.stop()
@@ -2678,4 +2701,4 @@ def run(
     logger.info("cluster up: %s", [(m["executor_id"], m["job_name"]) for m in cluster_info])
     return TPUCluster(coordinator, launcher, cluster_info, authkey, input_mode,
                       queues, feed_timeout, heartbeat_interval, elastic=elastic,
-                      log_dir=log_dir)
+                      log_dir=log_dir, started_at=started_at)

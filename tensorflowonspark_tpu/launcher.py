@@ -30,6 +30,7 @@ from __future__ import annotations
 import multiprocessing as mp
 import os
 import shlex
+import socket
 import subprocess
 import sys
 import time
@@ -50,6 +51,13 @@ def _child_entry(payload: bytes, log_path: str | None) -> None:
     from tensorflowonspark_tpu.node import node_main
 
     sys.exit(node_main(config))
+
+
+def _stamped_payload(config: NodeConfig) -> bytes:
+    """The pickled config, stamped with this spawn's epoch time and host:
+    the start of the node's lifecycle stage ``node.spawn``."""
+    config.spawned = (time.time(), socket.gethostname())
+    return cloudpickle.dumps(config)
 
 
 class _RespawnMixin:
@@ -146,7 +154,7 @@ class LocalLauncher(_RespawnMixin):
     def _spawn_one(self, i: int, config: NodeConfig) -> mp.Process:
         ctx = mp.get_context("spawn")
         log_path = os.path.join(self._log_dir, f"node_{i}.log") if self._log_dir else None
-        payload = cloudpickle.dumps(config)
+        payload = _stamped_payload(config)
         p = ctx.Process(target=_child_entry, args=(payload, log_path), name=f"tpu-node-{i}")
         p.daemon = False
         p.start()
@@ -266,7 +274,7 @@ class SubprocessLauncher(_RespawnMixin):
             log_f = open(os.path.join(self._log_dir, f"node_{i}.log"), "ab", buffering=0)
         else:
             log_f = None
-        payload = cloudpickle.dumps(config)
+        payload = _stamped_payload(config)
         proc = subprocess.Popen(
             _node_command(),
             stdin=subprocess.PIPE,
@@ -420,7 +428,7 @@ class TPUPodLauncher(_RespawnMixin):
         log_f = None
         if self._log_dir:
             log_f = open(os.path.join(self._log_dir, f"node_{i}.log"), "ab", buffering=0)
-        payload = cloudpickle.dumps(config)
+        payload = _stamped_payload(config)
         try:
             return self._spawn(self.hosts[i], config.env, payload, log_f)
         finally:

@@ -27,6 +27,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import os
+import socket
 import subprocess
 import sys
 import threading
@@ -34,6 +35,7 @@ import time
 import traceback
 from typing import Any, Callable, Sequence
 
+from tensorflowonspark_tpu import telemetry
 from tensorflowonspark_tpu.coordinator import CoordinatorClient
 from tensorflowonspark_tpu.dataserver import DataServer
 from tensorflowonspark_tpu.feeding import DataFeed, FeedQueues
@@ -83,6 +85,10 @@ class NodeConfig:
     # carried on EVERY config because role assignment is registration-order,
     # so any launched process may become an ingest worker.
     ingest_opts: dict | None = None
+    # (epoch seconds, hostname) of the launcher's spawn of THIS process,
+    # stamped just before it starts (a respawn stamps anew): node_main makes
+    # the lifecycle stage ``node.spawn`` of it.  None: not stamped.
+    spawned: tuple[float, str] | None = None
 
 
 class NodeContext:
@@ -228,7 +234,12 @@ class NodeContext:
         jit-compiled SPMD programs shard over (XLA collectives over ICI).
         """
         from tensorflowonspark_tpu.parallel.mesh import make_mesh
+        from tensorflowonspark_tpu.telemetry import xla_events
 
+        # a node whose environment pinned its device summary never imported
+        # jax itself: this import may be the runtime's first (tpu_info.
+        # device_summary is the other place that listens)
+        xla_events.install()
         return make_mesh(**axis_sizes)
 
     # -- global consensus (sync SPMD end-of-data, SURVEY.md §7.3-1) ----------
@@ -369,8 +380,6 @@ class NodeContext:
             ctx.metrics.counter("train.samples").inc(n)
             with ctx.metrics.timed("train.step_secs"): ...
         """
-        from tensorflowonspark_tpu import telemetry
-
         return telemetry.get_registry()
 
 
@@ -433,9 +442,17 @@ def _start_tensorboard(log_dir: str) -> tuple[subprocess.Popen | None, str | Non
 
 def node_main(config: NodeConfig) -> int:
     """Entry point of one node process; returns a process exit code."""
+    entered = time.time()
     for k, v in config.env.items():
         os.environ[k] = v
     _apply_jax_env_config()
+    # From the launcher's spawn to here: the interpreter's start, the
+    # unpickling of the config (which imports the map_fun's modules) and the
+    # package import.  The stamp is the launcher's CLOCK_REALTIME: shared on
+    # one host; from another host (ssh) the stage is left out, not guessed.
+    if config.spawned and config.spawned[1] == socket.gethostname():
+        telemetry.record_lifecycle("node.spawn", config.spawned[0],
+                                   max(0.0, entered - config.spawned[0]))
     logging.basicConfig(
         level=logging.INFO,
         format="%(asctime)s %(levelname)s [node %(process)d] %(name)s: %(message)s",
@@ -466,30 +483,32 @@ def node_main(config: NodeConfig) -> int:
     device_pending = device_meta is None
     if device_pending:
         device_meta = dict(tpu_info.CLAIM_PENDING)
-    ident = client.register({"host": local_ip(), "data_port": data_port,
-                             "pid": os.getpid(), "device": device_meta,
-                             "launch_index": config.launch_index},
-                            replace=(config.replace_executor_id
-                                     if config.replace_executor_id >= 0 else None))
-    executor_id = ident["executor_id"]
-    incarnation = int(ident.get("incarnation", 0))
-    # Every control-plane message from here carries this identity, so a
-    # zombie predecessor of this slot (or this process, once IT is declared
-    # dead) is fenced by the coordinator instead of racing its replacement.
-    client.set_identity(executor_id, incarnation)
-    # chaos identity includes the assigned ROLE: `role=ingest` filters let
-    # a cluster-wide TOS_FAULTINJECT spec target exactly the data-service
-    # tier even though role assignment is registration-order
-    faultinject.set_identity(executor_id, incarnation,
-                             role=ident["job_name"])
-    if config.log_dir:
-        # chaos-kill postmortem: a `kill` fault dumps this process's flight
-        # recorder (recent spans + events) next to the job logs before the
-        # SIGKILL — the one record of the node's last seconds that survives
-        faultinject.set_flight_dump(
-            os.path.join(config.log_dir, f"flight_node{executor_id}.json"),
-            node=f"node{executor_id}")
-    cluster_info = client.await_cluster(timeout=config.reservation_timeout)
+    # the control plane's round trips and the wait for the peers
+    with telemetry.lifecycle("node.register"):
+        ident = client.register({"host": local_ip(), "data_port": data_port,
+                                 "pid": os.getpid(), "device": device_meta,
+                                 "launch_index": config.launch_index},
+                                replace=(config.replace_executor_id
+                                         if config.replace_executor_id >= 0 else None))
+        executor_id = ident["executor_id"]
+        incarnation = int(ident.get("incarnation", 0))
+        # Every control-plane message from here carries this identity, so a
+        # zombie predecessor of this slot (or this process, once IT is declared
+        # dead) is fenced by the coordinator instead of racing its replacement.
+        client.set_identity(executor_id, incarnation)
+        # chaos identity includes the assigned ROLE: `role=ingest` filters let
+        # a cluster-wide TOS_FAULTINJECT spec target exactly the data-service
+        # tier even though role assignment is registration-order
+        faultinject.set_identity(executor_id, incarnation,
+                                 role=ident["job_name"])
+        if config.log_dir:
+            # chaos-kill postmortem: a `kill` fault dumps this process's flight
+            # recorder (recent spans + events) next to the job logs before the
+            # SIGKILL — the one record of the node's last seconds that survives
+            faultinject.set_flight_dump(
+                os.path.join(config.log_dir, f"flight_node{executor_id}.json"),
+                node=f"node{executor_id}")
+        cluster_info = client.await_cluster(timeout=config.reservation_timeout)
 
     # Heartbeats must start IMMEDIATELY after registration — before
     # jax.distributed.initialize and before map_fun's first XLA compiles
@@ -502,7 +521,6 @@ def node_main(config: NodeConfig) -> int:
 
     def _heartbeat_loop() -> None:
         nonlocal incarnation
-        from tensorflowonspark_tpu import telemetry
         from tensorflowonspark_tpu.telemetry import trace as ttrace
         from tensorflowonspark_tpu.utils.envtune import env_float
 
@@ -778,9 +796,8 @@ def node_main(config: NodeConfig) -> int:
                 if ident["job_name"] in ("evaluator", "ingest")
                 else tpu_info.device_summary())})
         logger.info("node %d (%s:%d) invoking map_fun", executor_id, ident["job_name"], ident["task_index"])
-        from tensorflowonspark_tpu import telemetry
-
-        with telemetry.timed("node.map_fun_secs"):
+        with telemetry.lifecycle("node.map_fun"), \
+                telemetry.timed("node.map_fun_secs"):
             effective_map_fun(config.tf_args, ctx)
     except Exception:
         tb = traceback.format_exc()
@@ -794,20 +811,13 @@ def node_main(config: NodeConfig) -> int:
                          "coordinator", exc_info=True)
         exit_code = 1
     finally:
-        ctx.stop_requested.set()
-        server.stop()
-        if tb_proc is not None:
-            tb_proc.terminate()
-        try:
-            # Deliberate exit (normal completion, or error already reported
-            # above): tell the driver to stop liveness-tracking this node so
-            # its monitor never mistakes the exit for a death.  The final
-            # telemetry snapshot rides along — metrics recorded after the
-            # last heartbeat (tail batches, the map_fun span itself) must
-            # still reach the driver's cluster view.
-            from tensorflowonspark_tpu import telemetry
-            from tensorflowonspark_tpu.telemetry import trace as ttrace
-
+        # What a finished map_fun waits for before it may deregister; ends
+        # BEFORE the final snapshot, so that it rides in it.
+        with telemetry.lifecycle("node.drain"):
+            ctx.stop_requested.set()
+            server.stop()
+            if tb_proc is not None:
+                tb_proc.terminate()
             # The tracer drain is single-consumer: wait for the heartbeat
             # thread (the in-run consumer) to see the stop flag before the
             # final drain, else a failed in-flight ping could restore_delta
@@ -816,6 +826,15 @@ def node_main(config: NodeConfig) -> int:
             # rather than racing for it — metrics stay safe either way
             # (absolute values, idempotent).
             hb.join(config.heartbeat_interval + 10.0)
+        try:
+            # Deliberate exit (normal completion, or error already reported
+            # above): tell the driver to stop liveness-tracking this node so
+            # its monitor never mistakes the exit for a death.  The final
+            # telemetry snapshot rides along — metrics recorded after the
+            # last heartbeat (tail batches, the map_fun span itself) must
+            # still reach the driver's cluster view.
+            from tensorflowonspark_tpu.telemetry import trace as ttrace
+
             final_metrics = (telemetry.collect_changed(None)[0]
                              if telemetry.enabled() else None)
             client.deregister(executor_id, metrics=final_metrics or None,

@@ -30,6 +30,14 @@ The framework's observability substrate (stdlib-only):
   under ``TOS_TRACE=1``.  The feed path is split this way (README
   "Observability" lists the stages).
 
+- **Lifecycle stages and XLA's work** — ``lifecycle(name)`` is a stage that
+  happens once a process (launch, spawn, registration, the jax import, the
+  chip claim, the map_fun, the drain, the driver's shutdown) and also leaves
+  a flight event, so the run report's ``"lifecycle"`` block lists each
+  process's stages in order with the gaps between them; ``xla_events.py``
+  listens to ``jax.monitoring`` in the nodes and keeps what XLA's tracing,
+  lowering and compile-or-load cost, by program (README "Observability").
+
 Master switch: ``TOS_METRICS`` (default on).  Disabled, every accessor
 returns a shared no-op object, so instrumentation costs one dict miss.
 
@@ -57,12 +65,17 @@ from tensorflowonspark_tpu.telemetry.registry import (  # noqa: F401
 )
 from tensorflowonspark_tpu.telemetry.report import (  # noqa: F401
     aggregate_snapshots,
+    build_lifecycle,
     build_run_report,
     debug_dump,
     write_run_report,
 )
 from tensorflowonspark_tpu.telemetry import trace  # noqa: F401
-from tensorflowonspark_tpu.telemetry.trace import stage  # noqa: F401
+from tensorflowonspark_tpu.telemetry.trace import (  # noqa: F401
+    lifecycle,
+    record_lifecycle,
+    stage,
+)
 
 _lock = threading.Lock()
 _registry: MetricsRegistry | None = None

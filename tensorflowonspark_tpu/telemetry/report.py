@@ -11,7 +11,11 @@ heartbeat deltas arrive); this module turns ``{node_key: snapshot}`` into
 - an **end-of-run JSON run report** (``build_run_report``), written next to
   the job's checkpoints/logs at shutdown — throughput, restarts, span
   percentiles, per-node detail (the tf.data-paper "built-in per-stage
-  counters" idea applied run-level).
+  counters" idea applied run-level);
+- the report's **lifecycle block** (``build_lifecycle``): per process the
+  once-a-process stages in order with the gaps between them, and the
+  programs XLA traced, lowered and compiled or loaded — where the time a
+  chip is held and not stepping went.
 """
 
 from __future__ import annotations
@@ -21,6 +25,7 @@ import time
 from typing import Any
 
 from tensorflowonspark_tpu.telemetry.registry import percentile_of
+from tensorflowonspark_tpu.telemetry.trace import event_origin
 
 #: Percentiles rendered for every merged histogram.
 PERCENTILES = (50.0, 90.0, 99.0)
@@ -75,8 +80,118 @@ def _strip_samples(nodes: dict[str, dict]) -> dict[str, dict]:
     return out
 
 
-def debug_dump(aggregated: dict) -> str:
-    """Render an ``aggregate_snapshots`` result as a text report."""
+#: Fields of a flight event that are the recorder's own, not the stage's tags.
+_EVENT_BOOKKEEPING = frozenset(
+    ("kind", "t0", "wall", "t", "node", "stage", "start", "secs"))
+#: ``xla.*`` counters (``xla_events.py``) -> their names in the block.
+_XLA_SECONDS = {"xla.trace.us": "trace_secs", "xla.lower.us": "lower_secs",
+                "xla.backend.us": "backend_secs",
+                "xla.cache_load.us": "cache_load_secs"}
+_XLA_COUNTS = {"xla.programs": "programs", "xla.cache.hits": "cache_hits",
+               "xla.cache.misses": "cache_misses"}
+
+
+def build_lifecycle(events: list[dict], nodes: dict[str, dict]) -> dict:
+    """Where each process's time went when no step ran, from the flight
+    events ``lifecycle`` and ``xla_program`` (``trace.lifecycle``,
+    ``xla_events.py``) and the ``xla.*`` counters.
+
+    ``events`` is ``trace.merge_events``' list (each event with ``node`` and
+    ``t``, driver-monotonic seconds: a lifecycle event is recorded as its
+    stage ENDS, so ``t - secs`` is its beginning on one clock for all
+    processes); ``nodes`` the per-node snapshots of the aggregate.  Per
+    process (``driver``, ``node0``, ...):
+
+    - ``stages``: in order of their beginning, each with ``stage``, ``start``
+      (epoch seconds), ``secs``, its tags and ``gap_secs``, the time since
+      the end of the stage before it that no stage names (None for the
+      first; negative: it began inside that stage);
+    - ``programs``: the programs over ``xla_events.PROGRAM_FLOOR_SECS``, in
+      order, with ``trace_secs`` / ``lower_secs`` / ``backend_secs`` /
+      ``cache_load_secs`` and ``cache`` (``hit`` or ``miss``);
+    - ``xla``: the process's totals over ALL its programs, from the counters;
+    - ``exit_secs`` (a node): from the end of its ``node.drain`` to the end
+      of the driver's ``shutdown.join`` — the final snapshot, the deregister
+      and the process's own exit (the PJRT client's teardown).  Exact for a
+      job of one node process that was still alive when the driver began
+      to join (``exit_secs_exact``); otherwise an upper bound.
+    """
+    out: dict[str, dict] = {}
+    spans: dict[str, list] = {}     # process -> [(begin, end, its entry)]
+    for ev in events:
+        kind = ev.get("kind")
+        if kind not in ("lifecycle", "xla_program"):
+            continue
+        key = event_origin(str(ev.get("node", "")))
+        proc = out.setdefault(key, {"stages": [], "programs": []})
+        secs = float(ev.get("secs") or 0.0)
+        entry = {"start": ev.get("start"), "secs": round(secs, 6),
+                 **{k: v for k, v in ev.items()
+                    if k not in _EVENT_BOOKKEEPING}}
+        if kind == "xla_program":
+            proc["programs"].append(entry)
+        else:
+            end = float(ev.get("t", 0.0))
+            spans.setdefault(key, []).append(
+                (end - secs, end, {"stage": ev.get("stage"), **entry}))
+    last: dict[tuple, tuple] = {}   # (process, stage) -> its last (end, secs)
+    for key, proc in out.items():
+        reached = None
+        for begin, end, entry in sorted(spans.get(key, ()),
+                                        key=lambda span: span[:2]):
+            entry["gap_secs"] = (None if reached is None
+                                 else round(begin - reached, 6))
+            reached = end if reached is None else max(reached, end)
+            proc["stages"].append(entry)
+            last[key, entry["stage"]] = (end, entry["secs"])
+        counters = (nodes.get(key[len("node"):] if key.startswith("node")
+                              else key) or {}).get("counters") or {}
+        if counters.get("xla.programs"):
+            proc["xla"] = {
+                **{name: counters.get(c, 0) for c, name in _XLA_COUNTS.items()},
+                **{name: counters.get(c, 0) / 1e6
+                   for c, name in _XLA_SECONDS.items()}}
+    joined, join_secs = last.get(("driver", "shutdown.join"), (None, 0.0))
+    drained = {key: end for (key, stage), (end, _secs) in last.items()
+               if stage == "node.drain"}
+    if joined is not None:
+        for key, end in drained.items():
+            out[key]["exit_secs"] = round(max(0.0, joined - end), 6)
+            out[key]["exit_secs_exact"] = (len(drained) == 1
+                                           and join_secs > 0.05)
+    return out
+
+
+def _lifecycle_lines(lifecycle: dict) -> list[str]:
+    lines = ["-- lifecycle (once-a-process stages, XLA by program) --"]
+    for key in sorted(lifecycle):
+        proc = lifecycle[key]
+        lines.append(f"  {key}")
+        for st in proc.get("stages") or ():
+            gap = st.get("gap_secs")
+            lines.append(f"    {st['stage']:<28} {st['secs']:>10.3f}s"
+                         + ("" if gap is None else f"  gap {gap:+.3f}s"))
+        if "exit_secs" in proc:
+            exact = "" if proc.get("exit_secs_exact") else " (at most)"
+            lines.append(f"    {'(exit)':<28} {proc['exit_secs']:>10.3f}s"
+                         f"{exact}")
+        xla = proc.get("xla")
+        if xla:
+            lines.append("    xla: " + " ".join(
+                f"{k}={v:.3f}" if isinstance(v, float) else f"{k}={v}"
+                for k, v in xla.items()))
+        for prog in proc.get("programs") or ():
+            lines.append(
+                f"    program {prog.get('fun_name')}: {prog['secs']:.3f}s "
+                f"(trace {prog.get('trace_secs', 0.0):.3f} lower "
+                f"{prog.get('lower_secs', 0.0):.3f} backend "
+                f"{prog.get('backend_secs', 0.0):.3f}) {prog.get('cache')}")
+    return lines
+
+
+def debug_dump(aggregated: dict, lifecycle: dict | None = None) -> str:
+    """Render an ``aggregate_snapshots`` result as a text report, with the
+    run report's ``lifecycle`` block (``build_lifecycle``) when given."""
     lines: list[str] = ["== cluster metrics =="]
     counters = aggregated.get("counters") or {}
     if counters:
@@ -108,6 +223,8 @@ def debug_dump(aggregated: dict) -> str:
         for name in sorted(snap.get("histograms") or {}):
             d = snap["histograms"][name]
             lines.append(f"  {name} count={d.get('count')} sum={d.get('sum')}")
+    if lifecycle:
+        lines.extend(_lifecycle_lines(lifecycle))
     return "\n".join(lines)
 
 
@@ -234,6 +351,11 @@ def build_run_report(aggregated: dict, *, wall_secs: float | None = None,
     }
     if extras:
         report.update(extras)
+    flight = (report.get("flight") or {}).get("events")
+    if flight:
+        lifecycle = build_lifecycle(flight, report["nodes"])
+        if lifecycle:
+            report["lifecycle"] = lifecycle
     return report
 
 

@@ -34,6 +34,12 @@ before that node died":
   ``TraceAnnotation`` on the thread that did the work, when jax is loaded
   in this process) and, with ``TOS_TRACE=1``, this module's span ring
   (unsampled, nested under the enclosing stage of the thread).
+- **Lifecycle** — :func:`lifecycle` is a stage that happens once a
+  process (spawn, registration, the jax import, the chip claim, the
+  map_fun, the drain, the driver's launch and shutdown): the stage's three
+  readers plus one flight event ``lifecycle`` with its epoch start and its
+  duration, so that order and gaps survive into the run report with
+  tracing off (``report.build_lifecycle``).
 - **Transport** — ``collect_delta()`` drains new spans/events for the
   heartbeat piggyback (``node.py``), stamped with this process's clock
   anchor and its current clock-offset estimate so the export can merge
@@ -183,14 +189,16 @@ class _Stage:
     """``with telemetry.stage(name):`` — see :func:`stage`."""
 
     __slots__ = ("_name", "_us", "_calls", "_tracer", "_annotation", "_t0",
-                 "_mark", "_sid", "_parent")
+                 "_mark", "_sid", "_parent", "_tags")
 
-    def __init__(self, name: str, us, calls, tracer: "Tracer", annotation):
+    def __init__(self, name: str, us, calls, tracer: "Tracer", annotation,
+                 tags: dict | None = None):
         self._name = name
         self._us = us
         self._calls = calls
         self._tracer = tracer
         self._annotation = annotation
+        self._tags = tags
 
     def __enter__(self) -> "_Stage":
         if self._annotation is not None:
@@ -223,9 +231,25 @@ class _Stage:
             tracer._local.stage = self._parent
             tracer.record_span(self._name,
                                TraceContext(tracer._loop_trace, self._sid),
-                               self._parent, self._t0, self._mark - self._t0)
+                               self._parent, self._t0, self._mark - self._t0,
+                               self._tags)
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
+
+
+class _Lifecycle(_Stage):
+    """``with telemetry.lifecycle(name):`` — see :func:`lifecycle`."""
+
+    __slots__ = ("_wall",)
+
+    def __enter__(self) -> "_Lifecycle":
+        self._wall = time.time()
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> None:
+        super().__exit__(*exc)
+        self._tracer.event("lifecycle", stage=self._name, start=self._wall,
+                           secs=self._mark - self._t0, **(self._tags or {}))
 
 
 class Tracer:
@@ -348,15 +372,28 @@ class Tracer:
             return _LiveSpan(self, name, ctx, None, tags)
         return _LiveSpan(self, name, self.derive(parent), parent[1], tags)
 
-    def stage(self, name: str, registry):
+    def stage(self, name: str, registry, tags: dict | None = None,
+              cls=_Stage):
         """See the module-level :func:`stage`; ``registry`` is the metrics
         registry whose ``<name>.us`` / ``<name>.calls`` counters it feeds."""
         if not registry.enabled:
             return NULL_SPAN
         annotate = _trace_annotation()
-        return _Stage(name, registry.counter(name + ".us"),
-                      registry.counter(name + ".calls"), self,
-                      annotate(name) if annotate is not None else None)
+        return cls(name, registry.counter(name + ".us"),
+                   registry.counter(name + ".calls"), self,
+                   annotate(name) if annotate is not None else None, tags)
+
+    def record_at(self, name: str, start: float, secs: float,
+                  tags: dict | None = None) -> None:
+        """A span of this process's loop trace whose start came as
+        CLOCK_REALTIME seconds (the launcher's spawn stamp, a
+        ``jax.monitoring`` time span): the anchor places it in the ring."""
+        if self.enabled:
+            self.record_span(name,
+                             TraceContext(self._loop_trace, self._new_id()),
+                             None,
+                             self.anchor[0] + (start - self.anchor[1] / 1e9),
+                             secs, tags)
 
     # -- flight recorder ------------------------------------------------------
 
@@ -592,6 +629,37 @@ def stage(name: str):
     from tensorflowonspark_tpu import telemetry
 
     return get_tracer().stage(name, telemetry.get_registry())
+
+
+def lifecycle(name: str, **tags):
+    """A :func:`stage` that happens ONCE A PROCESS, where a job's time goes
+    when no step runs: launch, spawn, registration, the jax import, the chip
+    claim, the map_fun, the drain, the driver's shutdown.  Besides the
+    stage's three readers it leaves one flight event ``lifecycle`` with
+    ``stage``, ``start`` (epoch seconds) and ``secs`` (and the ``tags``), so
+    the order of the stages and the gaps between them reach the run report's
+    ``lifecycle`` block with tracing off.  Never on a per-step path: the
+    flight ring holds 256 events."""
+    from tensorflowonspark_tpu import telemetry
+
+    return get_tracer().stage(name, telemetry.get_registry(), tags or None,
+                              cls=_Lifecycle)
+
+
+def record_lifecycle(name: str, start: float, secs: float, **tags) -> None:
+    """:func:`lifecycle` for a stage that began before this process could
+    time it (``node.spawn`` starts in the launcher): ``start`` is epoch
+    seconds on a clock this process shares with whoever stamped it."""
+    from tensorflowonspark_tpu import telemetry
+
+    registry = telemetry.get_registry()
+    if not registry.enabled:
+        return
+    registry.counter(name + ".us").inc(int(secs * 1e6 + 0.5))
+    registry.counter(name + ".calls").inc()
+    tracer = get_tracer()
+    tracer.record_at(name, start, secs, tags or None)
+    tracer.event("lifecycle", stage=name, start=start, secs=secs, **tags)
 
 
 def record_span(name: str, ctx: TraceContext | None, parent: int | None,

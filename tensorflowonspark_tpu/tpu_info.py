@@ -103,14 +103,27 @@ def device_summary() -> dict:
     summary = env_device_summary()
     if summary is not None:
         return summary
-    import jax
+    import sys
 
+    from tensorflowonspark_tpu import telemetry
+    from tensorflowonspark_tpu.telemetry import xla_events
+
+    # Two lifecycle stages (README "Observability"): the import alone, then
+    # the backend's initialisation = the chip claim.  ``preloaded``: the
+    # map_fun's module (or jax.distributed.initialize) already imported jax,
+    # so the import reads 0 here and its time is in ``node.spawn``.
+    with telemetry.lifecycle("node.import_jax",
+                             preloaded="jax" in sys.modules):
+        import jax
+    xla_events.install()
     # local_devices/process_index, NOT jax.devices(): after
     # jax.distributed.initialize the latter is pod-global, and every node
     # would report the whole pod's chips instead of its own.
-    devices = jax.local_devices()
+    with telemetry.lifecycle("node.claim"):
+        devices = jax.local_devices()
+        platform = jax.default_backend()
     return {
-        "platform": jax.default_backend(),
+        "platform": platform,
         "device_kind": devices[0].device_kind if devices else "none",
         "num_devices": len(devices),
         "coords": [list(getattr(d, "coords", ()) or ()) for d in devices],

@@ -14,6 +14,15 @@ DIRECT + ``IngestFeed`` + ``make_bn_train_step`` (ResNet-50), STREAMING +
 the block-diffusion loss over a chip's share of the experts with
 grouped-query heads (SDAR), and learned sparse attention with its indexer's
 loss under remat (Keye): a crash on that cell's first step shows here.
+
+The ResNet-50 case also reads the rehearsal's own ``logs/run_report.json``
+before the clean-up (ISSUE 35): the ``lifecycle`` block's stages in order
+and the eight readers that split ``setup_s``, through the files a chip run
+reads them through.  On the CPU the environment pins the node's device
+summary (``tpu_info.env_device_summary``), so the node neither imports jax
+nor claims a backend at its start: those two stages do not run, their two
+readers find nothing, and ``tests/test_telemetry_lifecycle.py`` holds them on
+the path that does.  No further run of ``run.py``.
 """
 
 from __future__ import annotations
@@ -30,6 +39,22 @@ import pytest
 from benchmark import common
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+SPLIT_READERS = ("start_spawn_s", "start_register_s", "start_import_jax_s",
+                 "start_chip_claim_s", "xla_trace_lower_s", "xla_backend_s",
+                 "xla_cache_load_s", "xla_cache_misses")
+
+
+def _read_setup_split(cell: dict) -> dict:
+    """The rehearsal's own run report, and what the eight readers make of it
+    (a run that began at the epoch's start: any report is this run's)."""
+    run = {"cell": cell, "facts": {"window_epoch_start": 0.0}}
+    with open(os.path.join(common.WORK_DIR, "runs", cell["workload"], "logs",
+                           "run_report.json")) as f:
+        report = json.load(f)
+    return {"lifecycle": report["lifecycle"], "wall_secs": report["wall_secs"],
+            "values": {name: common.load_module("layer_metrics", name).read(run)
+                       for name in SPLIT_READERS}}
 
 
 def _missing(path: str) -> list[str]:
@@ -68,6 +93,9 @@ def test_cell_rehearses_on_cpu(workload):
             os.killpg(proc.pid, signal.SIGKILL)
             out, err = proc.communicate()
             pytest.fail(f"no result within 180 s\n{out[-3000:]}\n{err[-3000:]}")
+        split = (_read_setup_split(cell)
+                 if workload == "resnet50_train_tfrecord"
+                 and proc.returncode == 0 else None)
     finally:
         for chain in made:
             if chain:
@@ -85,3 +113,31 @@ def test_cell_rehearses_on_cpu(workload):
     assert result["correct"] is True, tail
     assert result["attempted"] > 0 and result["failed"] == 0, tail
     assert result["device"]["platform"] == "cpu", tail
+    if split is None:
+        return
+    stages = {key: [st["stage"] for st in proc_block["stages"]]
+              for key, proc_block in split["lifecycle"].items()}
+    assert stages == {
+        "driver": ["cluster.launch", "cluster.await_registrations",
+                   "shutdown.eof", "shutdown.join", "shutdown.gather"],
+        "node0": ["node.spawn", "node.register", "node.map_fun",
+                  "node.drain"]}, stages
+    node = split["lifecycle"]["node0"]
+    assert sum(st["secs"] for st in node["stages"]) <= split["wall_secs"]
+    assert [p["fun_name"] for p in node["programs"]
+            if p["fun_name"] == "jit(step)"], node["programs"]
+    values = split["values"]
+    # pinned by the CPU environment: no import and no claim at the start
+    assert values.pop("start_import_jax_s") is None
+    assert values.pop("start_chip_claim_s") is None
+    assert all(isinstance(v, (int, float)) for v in values.values()), values
+    assert values["start_spawn_s"] > 0 and values["start_register_s"] > 0
+    assert values["xla_backend_s"] > 0 and values["xla_trace_lower_s"] > 0
+    assert 0 <= values["xla_cache_load_s"] <= values["xla_backend_s"]
+    assert values["xla_cache_misses"] >= 0
+    # what the listener saw is inside what the node timed around it
+    node_seconds = json.loads(next(
+        ln for ln in lines if ln.startswith("bench: facts: "))[
+            len("bench: facts: "):])["node_seconds"]
+    assert (values["xla_trace_lower_s"] + values["xla_backend_s"]
+            <= sum(node_seconds.values())), (values, node_seconds)

@@ -245,10 +245,11 @@ def test_fenced_zombie_metrics_are_dropped():
 
 def _poll_metrics(cluster, want_nodes, want_rows=None, timeout=30.0):
     """Wait until every wanted node key reported data-plane rows — and,
-    when ``want_rows`` is given, until the aggregate row count reaches it:
-    a node's counters ride the NEXT heartbeat after they move, so a
-    snapshot taken the moment a node first shows up can still be a stale
-    mid-train value (nonzero but not final)."""
+    when ``want_rows`` is given, until the aggregate row count reaches it,
+    ARRIVED and CONSUMED: a node's counters ride the NEXT heartbeat after
+    they move, so a snapshot taken the moment a node first shows up can
+    still be a stale mid-train value (nonzero but not final), and one taken
+    when the last rows arrived can precede a node's first batch."""
     import time
 
     deadline = time.monotonic() + timeout
@@ -258,8 +259,9 @@ def _poll_metrics(cluster, want_nodes, want_rows=None, timeout=30.0):
         nodes = snap.get("nodes", {})
         if all(nodes.get(k, {}).get("counters", {}).get("dataplane.rows_in")
                for k in want_nodes):
-            if (want_rows is None
-                    or snap["counters"].get("dataplane.rows_in") == want_rows):
+            if want_rows is None or all(
+                    snap["counters"].get(name) == want_rows
+                    for name in ("dataplane.rows_in", "feed.rows_consumed")):
                 return snap
         time.sleep(0.25)
     return snap
