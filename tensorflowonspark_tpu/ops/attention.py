@@ -8,20 +8,28 @@ this build, so the hot op gets a real TPU kernel:
   directions: an online-softmax forward that keeps its log-sum-exp, and a
   backward of two passes over the same tiles (dk/dv with the q blocks
   sequential, dq with the KV blocks sequential) that recompute each tile's
-  softmax weights from it.  Elsewhere it lowers to ``blockwise_attention``
+  softmax weights from it.  No kernel has a dense (q tile, k tile) grid:
+  each walks a trace-time table of the LIVE tiles of its mask, and a visit
+  of the walk is one live tile for one K/V head and the query heads of its
+  group (``_visit_heads``).  Elsewhere it lowers to ``blockwise_attention``
   (a ``lax.scan`` over KV blocks with per-block rematerialisation, so memory
   stays O(S·block) instead of O(S²)).
 - ``chunk_attention`` / ``merge_attention`` — the (output, logsumexp)
   chunk-compute and online-softmax merge primitives that
   ``parallel/sp.py``'s ring attention composes over ICI neighbours.
 
-Array convention: ``[batch, seq, heads, head_dim]`` (flax-style).  All
-softmax accumulation is float32 regardless of input dtype (bf16 inputs keep
-the MXU fed; the VPU-side accumulators must not lose mass).
+Array convention: ``[batch, seq, heads, head_dim]`` (flax-style).  Every
+matmul of the kernels takes its operands as the model holds them (bf16
+inputs keep the MXU fed; the softmax weights round to that dtype for their
+product with V or dO) and accumulates in float32; the running max, the sum,
+the rescale and the log-sum-exp are float32 regardless of input dtype (the
+VPU-side accumulators must not lose mass).
 
 Grouped-query heads: ``k`` and ``v`` may carry fewer heads than ``q`` (a
-divisor); query head ``j`` reads K/V head ``j // group``.  The kernels index
-the K/V block by it and never repeat K or V in memory.
+divisor); query head ``j`` reads K/V head ``j // group``.  A group's query
+heads are neighbouring head-major rows, so one block of a visit holds them:
+K and V are fetched once for the group and never repeated in memory, and
+dk and dv are summed over it inside the visit.
 
 Masks: ``causal``, or ``block_diffusion=(length, block)``, the training mask
 of block diffusion (BD3-LM, arXiv:2503.09573; SDAR, arXiv:2510.06303) over
@@ -33,7 +41,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Literal
+from typing import Literal, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -219,8 +227,8 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU kernels — forward (online softmax over a sequential k-block
-# grid) and backward (a dk/dv pass and a dq pass on the forward's lse).
+# Pallas TPU kernels — forward (online softmax over the live k blocks of a q
+# block) and backward (a dk/dv pass and a dq pass on the forward's lse).
 # ---------------------------------------------------------------------------
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T: contract the last dim of both
@@ -329,53 +337,115 @@ def _tile_kinds(nq: int, nk: int, *, block_q: int, block_k: int,
     return np.where(interior, _INTERIOR, live * _MASKED)
 
 
-def _walk(kinds, group: int = 1):
+def _walk(kinds):
     """The visits of one kernel's sequential grid axis, in order, as the int32
-    rows ``(block, head, tile, flags)``: for every output block (a row of
-    ``kinds``, in order) its live tiles in ascending order, once for each of
-    the ``group`` query heads that share the block (head by head: the dk/dv
-    pass; ``head`` is 0 throughout in the other two).  A block with no live
-    tile gets ONE visit, which attends nothing, so that its zeros are
-    written.  A dead tile is no visit: no grid step and no fetch.
+    rows ``(block, tile, flags)``: for every output block (a row of
+    ``kinds``, in order) its live tiles in ascending order, each ONCE: a visit
+    serves the query heads of a K/V group together (``_visit_heads``).  A
+    block with no live tile gets ONE visit, which attends nothing, so that
+    its zeros are written.  A dead tile is no visit: no grid step and no
+    fetch.
 
     The rows are the kernel's scalar-prefetch operands (SMEM, 4 bytes a
-    visit each): at SDAR's 16 x 16 tiles 80 visits, 640 in the dk/dv pass
-    over 8 heads; a causal row of 32k, 2,080."""
+    visit each): at SDAR's 16 x 16 tiles 80 visits in all three kernels; a
+    causal row of 32k, 2,080."""
     visits = []
     for block, row in enumerate(kinds):
-        run = [[block, head, tile, row[tile]] for head in range(group)
-               for tile in np.flatnonzero(row)] or [[block, 0, 0, 0]]
-        run[0][3] |= _FIRST
-        run[-1][3] |= _LAST
+        run = [[block, tile, row[tile]]
+               for tile in np.flatnonzero(row)] or [[block, 0, 0]]
+        run[0][2] |= _FIRST
+        run[-1][2] |= _LAST
         visits += run
     return np.asarray(visits, np.int32).T
 
 
-def _walk_call(kernel, table, rows: int, dense: int, *, out_shape,
+# A visit of several heads outgrows Mosaic's default VMEM scope of 16 MiB: its
+# kernels get _VMEM_LIMIT (of a v5e's 128 MiB), of which _VMEM_BLOCKS are for
+# the visit's blocks and scratch and the rest for the tile body's score tiles.
+# A visit of one head keeps the default scope: under the raised one the same
+# three kernels ran 4-14% slower (PERF.md §6, PR 36).
+_VMEM_LIMIT = 96 << 20
+_VMEM_BLOCKS = 24 << 20
+
+
+def _visit_heads(group: int, block_q: int, d_p: int, itemsize: int) -> int:
+    """How many query heads of a K/V group one visit serves: the largest
+    divisor of ``group`` whose query-side blocks and scratch fit
+    ``_VMEM_BLOCKS`` in the kernel that holds most a head, the dq pass (q, dO
+    and dq double-buffered, the two float32 column blocks of 128 lanes
+    likewise, the float32 accumulator).  8 heads of 512 x 128 in bf16 hold
+    16 MiB; a group of 32 is served in several visits a tile."""
+    tile = block_q * d_p
+    a_head = 6 * tile * itemsize + 4 * block_q * 128 * 4 + tile * 4
+    return max(heads for heads in range(1, group + 1)
+               if group % heads == 0
+               and (heads == 1 or heads * a_head <= _VMEM_BLOCKS))
+
+
+class _Plan(NamedTuple):
+    """What is static of one call of the kernels, worked out from the shapes
+    and the mask at trace time (``_plan``).  Hashable: it keys the trace
+    caches of ``_flash_fwd_pallas`` and ``_flash_bwd_pallas``."""
+    tile: tuple         # the mask and the tile sides, items of _tile_kinds' kwargs
+    sq_p: int           # the tiled lengths
+    sk_p: int
+    heads: int          # query heads a visit serves
+    dense: int          # tiles of the dense grid
+    walk: tuple         # rows (iq, ik, flags): the forward's and the dq pass's
+    walk_t: tuple       # rows (ik, iq, flags): the dk/dv pass's
+
+
+def _plan(qt, kt, *, causal, kv_offset, block_q, block_k,
+          block_diffusion) -> _Plan:
+    """The plan of the kernels over head-major ``qt`` and ``kt``: numpy and
+    integers only, worked out in each rule of the VJP (which counts by it)."""
+    bh, sq, d_p = qt.shape
+    sk = kt.shape[1]
+    block_q, block_k, sq_p, sk_p = _blocks(sq, sk, block_q, block_k)
+    tile = dict(causal=causal, kv_offset=kv_offset, block_q=block_q,
+                block_k=block_k, sk=sk, block_diffusion=block_diffusion)
+    kinds = _tile_kinds(sq_p // block_q, sk_p // block_k, **tile)
+    rows = lambda table: tuple(map(tuple, table.tolist()))      # noqa: E731
+    return _Plan(tuple(tile.items()), sq_p, sk_p,
+                 _visit_heads(bh // kt.shape[0], block_q, d_p,
+                              qt.dtype.itemsize),
+                 kinds.size, rows(_walk(kinds)), rows(_walk(kinds.T)))
+
+
+def _count(plan: _Plan, *tables) -> None:
+    """For the run report, once for each kernel a traced program holds (the
+    check's included): ``flash.tiles_walked`` over ``flash.tiles`` is the
+    share of the dense grid's steps that the walks keep,
+    ``flash.visit_heads`` over ``flash.kernels`` the query heads a visit
+    serves."""
+    for table in tables:
+        telemetry.counter("flash.kernels").inc()
+        telemetry.counter("flash.visit_heads").inc(plan.heads)
+        telemetry.counter("flash.tiles").inc(plan.dense)
+        telemetry.counter("flash.tiles_walked").inc(len(table[0]))
+
+
+def _walk_call(kernel, table, rows: int, heads: int, *, out_shape,
                interpret: bool, **specs):
     """``kernel`` over the grid ``(rows, visits)``: the walk of ``table`` for
-    each (batch, head) row, in place of ``dense`` tiles a row; the index
-    maps and the kernel read the table's rows from SMEM.  The kernel is
-    told which flags EVERY visit carries and which ANY does (``_visit``).
+    each grid row (``heads`` query heads that share a K/V head); the index
+    maps and the kernel read the table's rows from SMEM.  The kernel is told
+    which flags EVERY visit carries and which ANY does (``_visit``).
 
     v5e has one TensorCore: the q (or k) block axis, which could run in
-    parallel, loses nothing by being folded into the sequential walk.
-
-    Counted once per kernel built, for the run report:
-    ``flash.tiles_walked`` over ``flash.tiles`` is the share of the dense
-    grid's steps that the walk keeps."""
-    visits = table.shape[1]
-    telemetry.counter("flash.tiles").inc(dense)
-    telemetry.counter("flash.tiles_walked").inc(visits)
+    parallel, loses nothing by being folded into the sequential walk."""
+    table = np.asarray(table, np.int32)
     call = pl.pallas_call(
         functools.partial(kernel,
                           every=int(np.bitwise_and.reduce(table[-1])),
                           some=int(np.bitwise_or.reduce(table[-1]))),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(table), grid=(rows, visits), **specs),
+            num_scalar_prefetch=len(table), grid=(rows, table.shape[1]),
+            **specs),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT if heads > 1 else None),
         interpret=interpret)
     return functools.partial(call, *(jnp.asarray(row) for row in table))
 
@@ -408,51 +478,90 @@ def _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, *,
     on(_LAST, finalize)
 
 
+def _lanes(x, width: int):
+    """A lane-replicated statistic ``[rows, 128]`` as ``[rows, width]``:
+    whole copies of its 128 lanes side by side (the first lanes of one for a
+    narrower tile).  Not ``x[:, 0:1]`` broadcast: that one-lane column cost
+    a forward kernel as much as its matmuls (PERF.md §6, PR 34).  As
+    ``ops/sparse_attention.py``'s, which stays as it is (ROADMAP D18)."""
+    return jnp.tile(x, (1, pl.cdiv(width, 128)))[:, :width]
+
+
+def _bias(visible, shape, q_dim: int):
+    """A masked tile's mask as what every head of the visit ADDS to its
+    scores: 0 on a visible pair, ``NEG_INF`` elsewhere, built once a visit
+    (``logits + bias`` is ``where(visible, logits, NEG_INF)`` to the bit for
+    finite logits).  None on an interior tile."""
+    if visible is None:
+        return None
+    return jnp.where(visible(shape, q_dim), 0.0, NEG_INF)
+
+
+def _scores(a, b, sm_scale: float, bias):
+    """``a @ b.T`` scaled, float32 from the operands as the model holds them
+    (the products of bf16 values are exact in float32), plus the bias."""
+    logits = jax.lax.dot_general(
+        a, b, _NT, preferred_element_type=jnp.float32) * sm_scale
+    return logits if bias is None else logits + bias
+
+
+# A VISIT of the three kernels is one live tile for one K/V head and the
+# query heads of its group that share a grid row (``_visit_heads``: all of
+# them where they fit): the query-side blocks are ``[heads, block, d]``, K and
+# V are fetched once a visit, a masked tile's bias is built once, and the
+# heads are a static loop inside the visit.  With one query head a K/V head
+# the loop has one turn.
+
 def _flash_fwd_kernel(iq_ref, ik_ref, flags_ref, q_ref, k_ref, v_ref,
                       o_ref, lse_ref, acc_ref, m_ref, l_ref,
                       *, sm_scale: float, **walk):
-    # m/l scratch is lane-replicated to 128 lanes (column 0 is authoritative)
-    # — TPU tiling requires the last dim be 128-aligned.  The lse goes out as
-    # a ROW per (batch, head): a residual of the backward, 128 times smaller
-    # than the replicated columns and lane-dense as its dk/dv pass reads it.
+    # m/l scratch is lane-replicated to 128 lanes — TPU tiling requires the
+    # last dim be 128-aligned — and read as whole lanes (``_lanes``).  The lse
+    # goes out as a ROW per (batch, head): a residual of the backward, 128
+    # times smaller than the replicated columns and lane-dense as its dk/dv
+    # pass reads it.
+    heads, block_q, d = q_ref.shape
+    block_k = k_ref.shape[1]
+
     def init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
     def attend(visible):
-        qb = q_ref[0].astype(jnp.float32)              # [block_q, d]
-        kb = k_ref[0].astype(jnp.float32)              # [block_k, d]
-        logits = jax.lax.dot_general(
-            qb, kb, _NT, preferred_element_type=jnp.float32) * sm_scale
-        if visible is not None:
-            logits = jnp.where(visible(logits.shape, 0), logits, NEG_INF)
-        m_prev = m_ref[:]                               # [block_q, 128]
-        m_blk = jnp.max(logits, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        m_safe = jnp.maximum(m_new, NEG_INF / 2)
-        p = jnp.exp(logits - m_safe[:, 0:1])
-        p = jnp.where(logits <= NEG_INF / 2, 0.0, p)
-        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha[:, 0:1] + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+        k, v = k_ref[0], v_ref[0]                      # [block_k, d]
+        bias = _bias(visible, (block_q, block_k), 0)
+        for h in range(heads):
+            logits = _scores(q_ref[h], k, sm_scale, bias)
+            m_prev = m_ref[h]                           # [block_q, 128]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(logits, axis=-1, keepdims=True))
+            # a row that has seen nothing yet: exp(NEG_INF - NEG_INF / 2) = 0
+            m_safe = jnp.maximum(m_new, NEG_INF / 2)
+            p = jnp.exp(logits - _lanes(m_safe, block_k))
+            alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
+                              jnp.exp(m_prev - m_safe))
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            # the weights round to V's dtype, as the backward's do
+            acc_ref[h] = acc_ref[h] * _lanes(alpha, d) + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     def finalize():
-        l = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = (acc_ref[:] / l[:, 0:1]).astype(o_ref.dtype)
-        lse = jnp.where(l_ref[:] > 0.0, m_ref[:] + jnp.log(l), NEG_INF)
-        lse_ref[0] = lse.T[0:1]                  # a row, as it lies in memory
+        for h in range(heads):
+            l = jnp.maximum(l_ref[h], 1e-30)
+            o_ref[h] = (acc_ref[h] / _lanes(l, d)).astype(o_ref.dtype)
+            lse = jnp.where(l_ref[h] > 0.0, m_ref[h] + jnp.log(l), NEG_INF)
+            lse_ref[h] = lse.T[0:1]              # a row, as it lies in memory
 
     _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, **walk)
 
 
 def _head_major(x, interpret: bool):
     """``[B, S, H, D]`` -> ``[B*H, S, D_p]``, the layout all three kernels
-    read: one (batch, head) per grid row, D zero-padded to the 128-lane
-    width (not under ``interpret``, which has no tiling)."""
+    read: a (batch, head) a row, the query heads of a K/V group neighbours
+    (one block holds them), D zero-padded to the 128-lane width (not under
+    ``interpret``, which has no tiling)."""
     b, s, h, d = x.shape
     d_p = d if interpret else -(-d // 128) * 128
     x = x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -478,98 +587,91 @@ def _pad_seq(x, s_p: int):
     return jnp.pad(x, ((0, 0), (0, s_p - x.shape[1]), (0, 0)))
 
 
-def _flash_fwd_pallas(qt, kt, vt, *, causal, sm_scale, kv_offset,
-                      block_q, block_k, interpret, block_diffusion=None):
+# The two wrappers of the kernels are jitted on what is static of a call: a
+# program's layers, and a process's programs, share ONE trace and one
+# lowering a program of each kernel.  A visit's static head loop is 8 tile
+# bodies to trace and lower: built anew for every layer of every program it
+# added 13 s to SDAR's set-up (PERF.md §6, PR 36).
+_STATIC = ("plan", "sm_scale", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _flash_fwd_pallas(qt, kt, vt, *, plan: _Plan, sm_scale, interpret):
     """Run the Pallas forward on head-major ``[B*H, S, D_p]`` operands
     (``[B*H_kv, S, D_p]`` keys and values: query head ``j`` reads the K/V
     row ``j // group``, head-major rows being ``batch * heads + head`` on
     both sides); returns ``(out [B*H, Sq, D_p], lse [B*H, Sq] float32)``."""
     bh, sq, d_p = qt.shape
-    sk = kt.shape[1]
-    group = bh // kt.shape[0]
-    block_q, block_k, sq_p, sk_p = _blocks(sq, sk, block_q, block_k)
-    qt, kt, vt = _pad_seq(qt, sq_p), _pad_seq(kt, sk_p), _pad_seq(vt, sk_p)
+    static, heads = dict(plan.tile), plan.heads
+    block_q, block_k, sq_p = static["block_q"], static["block_k"], plan.sq_p
+    qt = _pad_seq(qt, sq_p)
+    kt, vt = _pad_seq(kt, plan.sk_p), _pad_seq(vt, plan.sk_p)
+    parts = bh // kt.shape[0] // heads  # grid rows that share a K/V head
 
-    static = dict(causal=causal, kv_offset=kv_offset, block_q=block_q,
-                  block_k=block_k, sk=sk, block_diffusion=block_diffusion)
-    kinds = _tile_kinds(sq_p // block_q, sk_p // block_k, **static)
-    if not block_diffusion:
-        # the causal mask is cheap to build beside this kernel's float32
-        # matmuls: every live tile builds it.  Most live tiles lie whole
-        # inside the block-diffusion mask, and it costs more: no mask there,
-        # as in the backward
-        kinds = np.minimum(kinds, _MASKED)
-    table = _walk(kinds)[[0, 2, 3]]         # (iq, ik, flags): no head row
-    q_at = lambda bh, v, iq, ik, flags: (bh, iq[v], 0)          # noqa: E731
-    kv_at = lambda bh, v, iq, ik, flags: (bh // group, ik[v], 0)  # noqa: E731
+    q_at = lambda r, v, iq, ik, flags: (r, iq[v], 0)            # noqa: E731
+    kv_at = lambda r, v, iq, ik, flags: (r // parts, ik[v], 0)  # noqa: E731
     out, lse = _walk_call(
         functools.partial(_flash_fwd_kernel, sm_scale=sm_scale, **static),
-        table, bh, kinds.size,
+        plan.walk, bh // heads, heads,
         in_specs=[
-            pl.BlockSpec((1, block_q, d_p), q_at),
+            pl.BlockSpec((heads, block_q, d_p), q_at),
             pl.BlockSpec((1, block_k, d_p), kv_at),
             pl.BlockSpec((1, block_k, d_p), kv_at),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d_p), q_at),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bh, v, iq, ik, flags: (bh, 0, iq[v])),
+            pl.BlockSpec((heads, block_q, d_p), q_at),
+            pl.BlockSpec((heads, 1, block_q),
+                         lambda r, v, iq, ik, flags: (r, 0, iq[v])),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq_p, d_p), qt.dtype),
             jax.ShapeDtypeStruct((bh, 1, sq_p), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d_p), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((heads, block_q, d_p), jnp.float32),
+            pltpu.VMEM((heads, block_q, 128), jnp.float32),
+            pltpu.VMEM((heads, block_q, 128), jnp.float32),
         ],
         interpret=interpret,
     )(qt, kt, vt)
     return out[:, :sq], lse[:, 0, :sq]
 
 
-def _recompute_p(logits, visible, q_dim: int, lse):
-    """The softmax weights of one tile from the forward's log-sum-exp: no
-    running max, no rescale.  ``lse`` (a row or a column against ``logits``)
-    is at least ``NEG_INF / 2``, so a pair that is not visible gets exactly
-    0, also in a row that sees no key at all."""
-    if visible is not None:
-        logits = jnp.where(visible(logits.shape, q_dim), logits, NEG_INF)
-    return jnp.exp(logits - lse)
-
-
-def _flash_bwd_dkv_kernel(ik_ref, head_ref, iq_ref, flags_ref,
+def _flash_bwd_dkv_kernel(ik_ref, iq_ref, flags_ref,
                           q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc,
                           *, sm_scale: float, **walk):
-    # One KV block against the q blocks that see it, of every query head of
-    # its group (head by head: ``head_ref`` is for the index maps), on the
-    # TRANSPOSED tile [block_k, block_q]: lse and delta are then rows,
-    # lane-dense as they lie in memory, and both accumulations are plain
-    # matmuls.
-    del head_ref
+    # One KV block against the q blocks that see it, summed over the visit's
+    # query heads, on the TRANSPOSED tile [block_k, block_q]: lse and delta
+    # are then rows, lane-dense as they lie in memory, and both
+    # accumulations are plain matmuls.  The softmax weights of a tile come
+    # from the forward's log-sum-exp: no running max, no rescale.  ``lse``
+    # is at least ``NEG_INF / 2``, so a pair that is not visible gets exactly
+    # 0, also in a row that sees no key at all.
+    heads, block_q, _ = q_ref.shape
+    block_k = k_ref.shape[1]
 
     def init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def attend(visible):
-        q, do = q_ref[0], do_ref[0]                    # [block_q, d]
-        logits_t = jax.lax.dot_general(
-            k_ref[0], q, _NT, preferred_element_type=jnp.float32) * sm_scale
-        p_t = _recompute_p(logits_t, visible, 1, lse_ref[0])
-        dv_acc[:] += jnp.dot(p_t.astype(do.dtype), do,
-                             preferred_element_type=jnp.float32)
-        dp_t = jax.lax.dot_general(
-            v_ref[0], do, _NT, preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - delta_ref[0])
-        dk_acc[:] += jnp.dot(ds_t.astype(q.dtype), q,
-                             preferred_element_type=jnp.float32)
+        k, v = k_ref[0], v_ref[0]
+        bias = _bias(visible, (block_k, block_q), 1)
+        for h in range(heads):
+            q, do = q_ref[h], do_ref[h]                # [block_q, d]
+            p_t = jnp.exp(_scores(k, q, sm_scale, bias) - lse_ref[h])
+            dv_acc[...] += jnp.dot(p_t.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
+            dp_t = jax.lax.dot_general(
+                v, do, _NT, preferred_element_type=jnp.float32)
+            ds_t = p_t * (dp_t - delta_ref[h])
+            dk_acc[...] += jnp.dot(ds_t.astype(q.dtype), q,
+                                   preferred_element_type=jnp.float32)
 
     def finalize():
-        dk_ref[0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
     _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, **walk)
 
@@ -577,40 +679,47 @@ def _flash_bwd_dkv_kernel(ik_ref, head_ref, iq_ref, flags_ref,
 def _flash_bwd_dq_kernel(iq_ref, ik_ref, flags_ref,
                          q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                          dq_ref, dq_acc, *, sm_scale: float, **walk):
-    # One q block against the KV blocks it sees; lse and delta are columns
-    # here, replicated over 128 lanes like the forward's m and l (column 0
-    # is read).
+    # One q block of the visit's heads against the KV blocks it sees; lse and
+    # delta are columns here, replicated over 128 lanes like the forward's m
+    # and l, and read as whole lanes.
+    heads, block_q, _ = q_ref.shape
+    block_k = k_ref.shape[1]
+
     def init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def attend(visible):
-        k = k_ref[0]                                   # [block_k, d]
-        logits = jax.lax.dot_general(
-            q_ref[0], k, _NT, preferred_element_type=jnp.float32) * sm_scale
-        p = _recompute_p(logits, visible, 0, lse_ref[0][:, 0:1])
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], _NT, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, 0:1])
-        dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
-                             preferred_element_type=jnp.float32)
+        k, v = k_ref[0], v_ref[0]                      # [block_k, d]
+        bias = _bias(visible, (block_q, block_k), 0)
+        for h in range(heads):
+            p = jnp.exp(_scores(q_ref[h], k, sm_scale, bias)
+                        - _lanes(lse_ref[h], block_k))
+            dp = jax.lax.dot_general(
+                do_ref[h], v, _NT, preferred_element_type=jnp.float32)
+            ds = p * (dp - _lanes(delta_ref[h], block_k))
+            dq_acc[h] += jnp.dot(ds.astype(k.dtype), k,
+                                 preferred_element_type=jnp.float32)
 
     def finalize():
-        dq_ref[0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
+        dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
     _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, **walk)
 
 
-def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, *, causal, sm_scale,
-                      kv_offset, block_q, block_k, interpret,
-                      block_diffusion=None):
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, *, plan: _Plan, sm_scale,
+                      interpret):
     """Both backward passes on head-major operands; ``lse`` as
     ``_flash_fwd_pallas`` returns it and ``delta = rowsum(dO * O)`` like it,
     ``[B*H, Sq]`` float32.  Returns ``(dq, dk, dv)`` head-major, dk and dv
-    at the K/V head count: the dk/dv pass sums over a group's query heads."""
+    at the K/V head count: the dk/dv pass sums over the query heads of a
+    visit inside it (where a group takes several grid rows, each writes its
+    float32 share and they are added here)."""
     bh, sq, d_p = qt.shape
     sk = kt.shape[1]
-    group = bh // kt.shape[0]
-    block_q, block_k, sq_p, sk_p = _blocks(sq, sk, block_q, block_k)
+    static, heads = dict(plan.tile, sm_scale=sm_scale), plan.heads
+    block_q, block_k = static["block_q"], static["block_k"]
+    sq_p, sk_p = plan.sq_p, plan.sk_p
     # a row that saw no key has lse = NEG_INF: lifted, so that exp(NEG_INF -
     # lse) is 0 there too.  Padded q rows have do = 0 and so add nothing.
     lse = jnp.maximum(lse, NEG_INF / 2)
@@ -619,53 +728,51 @@ def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, *, causal, sm_scale,
     cols = [jnp.broadcast_to(x[:, :, None], (bh, sq_p, 128)) for x in stats]
     qt, do_t = _pad_seq(qt, sq_p), _pad_seq(do_t, sq_p)
     kt, vt = _pad_seq(kt, sk_p), _pad_seq(vt, sk_p)
+    parts = bh // kt.shape[0] // heads
 
-    static = dict(causal=causal, kv_offset=kv_offset, block_q=block_q,
-                  block_k=block_k, sk=sk, block_diffusion=block_diffusion)
-    kinds = _tile_kinds(sq_p // block_q, sk_p // block_k, **static)
-    static["sm_scale"] = sm_scale
-    q_block, k_block = (1, block_q, d_p), (1, block_k, d_p)
+    q_block, k_block = (heads, block_q, d_p), (1, block_k, d_p)
+    share = jax.ShapeDtypeStruct(
+        (bh // heads, sk_p, d_p), kt.dtype if parts == 1 else jnp.float32)
 
-    # a grid row is a K/V head; the walk names the query head of its group
-    q_at = lambda bh, v, ik, head, iq, flags: (                 # noqa: E731
-        bh * group + head[v], iq[v], 0)
-    row_at = lambda bh, v, ik, head, iq, flags: (               # noqa: E731
-        bh * group + head[v], 0, iq[v])
-    k_at = lambda bh, v, ik, head, iq, flags: (bh, ik[v], 0)    # noqa: E731
+    q_at = lambda r, v, ik, iq, flags: (r, iq[v], 0)            # noqa: E731
+    row_at = lambda r, v, ik, iq, flags: (r, 0, iq[v])          # noqa: E731
+    k_at = lambda r, v, ik, iq, flags: (r // parts, ik[v], 0)   # noqa: E731
+    dk_at = lambda r, v, ik, iq, flags: (r, ik[v], 0)           # noqa: E731
     dk, dv = _walk_call(
         functools.partial(_flash_bwd_dkv_kernel, **static),
-        _walk(kinds.T, group), bh // group, group * kinds.size,
+        plan.walk_t, bh // heads, heads,
         in_specs=[
             pl.BlockSpec(q_block, q_at),
             pl.BlockSpec(q_block, q_at),
-            pl.BlockSpec((1, 1, block_q), row_at),
-            pl.BlockSpec((1, 1, block_q), row_at),
+            pl.BlockSpec((heads, 1, block_q), row_at),
+            pl.BlockSpec((heads, 1, block_q), row_at),
             pl.BlockSpec(k_block, k_at),
             pl.BlockSpec(k_block, k_at),
         ],
-        out_specs=[pl.BlockSpec(k_block, k_at)] * 2,
-        out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
-                   jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
+        out_specs=[pl.BlockSpec(k_block, dk_at)] * 2,
+        out_shape=[share, share],
         scratch_shapes=[pltpu.VMEM((block_k, d_p), jnp.float32)] * 2,
         interpret=interpret,
     )(qt, do_t, *rows, kt, vt)
+    dk, dv = (x.reshape(-1, parts, sk_p, d_p).sum(1).astype(kt.dtype)
+              for x in (dk, dv))
 
-    q_at = lambda bh, v, iq, ik, flags: (bh, iq[v], 0)          # noqa: E731
-    kv_at = lambda bh, v, iq, ik, flags: (bh // group, ik[v], 0)  # noqa: E731
+    q_at = lambda r, v, iq, ik, flags: (r, iq[v], 0)            # noqa: E731
+    kv_at = lambda r, v, iq, ik, flags: (r // parts, ik[v], 0)  # noqa: E731
     dq = _walk_call(
         functools.partial(_flash_bwd_dq_kernel, **static),
-        _walk(kinds)[[0, 2, 3]], bh, kinds.size,    # (iq, ik, flags)
+        plan.walk, bh // heads, heads,
         in_specs=[
             pl.BlockSpec(q_block, q_at),
             pl.BlockSpec(q_block, q_at),
-            pl.BlockSpec((1, block_q, 128), q_at),
-            pl.BlockSpec((1, block_q, 128), q_at),
+            pl.BlockSpec((heads, block_q, 128), q_at),
+            pl.BlockSpec((heads, block_q, 128), q_at),
             pl.BlockSpec(k_block, kv_at),
             pl.BlockSpec(k_block, kv_at),
         ],
         out_specs=pl.BlockSpec(q_block, q_at),
         out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d_p), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM(q_block, jnp.float32)],
         interpret=interpret,
     )(qt, do_t, *cols, kt, vt)
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]
@@ -683,10 +790,13 @@ def _flash_fwd_rule(q, k, v, causal, sm_scale, kv_offset, block_q, block_k,
     b, _, _, d = q.shape
     with jax.named_scope("flash_fwd"):
         qt, kt, vt = (_head_major(x, interpret) for x in (q, k, v))
-        ot, lse = _flash_fwd_pallas(
-            qt, kt, vt, causal=causal, sm_scale=_scale(sm_scale, d),
-            kv_offset=kv_offset, block_q=block_q, block_k=block_k,
-            interpret=interpret, block_diffusion=block_diffusion)
+        plan = _plan(qt, kt, causal=causal, kv_offset=kv_offset,
+                     block_q=block_q, block_k=block_k,
+                     block_diffusion=block_diffusion)
+        _count(plan, plan.walk)
+        ot, lse = _flash_fwd_pallas(qt, kt, vt, plan=plan,
+                                    sm_scale=_scale(sm_scale, d),
+                                    interpret=interpret)
         out = _from_head_major(ot, b, d)
         return out, (qt, kt, vt, out, lse)
 
@@ -705,11 +815,13 @@ def _flash_bwd_rule(causal, sm_scale, kv_offset, block_q, block_k, interpret,
     with jax.named_scope("flash_bwd"):
         delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1).transpose(0, 2, 1).reshape(b * h, sq)
+        plan = _plan(qt, kt, causal=causal, kv_offset=kv_offset,
+                     block_q=block_q, block_k=block_k,
+                     block_diffusion=block_diffusion)
+        _count(plan, plan.walk_t, plan.walk)
         grads = _flash_bwd_pallas(
-            qt, kt, vt, _head_major(g, interpret), lse, delta,
-            causal=causal, sm_scale=_scale(sm_scale, d), kv_offset=kv_offset,
-            block_q=block_q, block_k=block_k, interpret=interpret,
-            block_diffusion=block_diffusion)
+            qt, kt, vt, _head_major(g, interpret), lse, delta, plan=plan,
+            sm_scale=_scale(sm_scale, d), interpret=interpret)
         return tuple(_from_head_major(x, b, d) for x in grads)
 
 

@@ -214,7 +214,7 @@ def test_walk_visits_the_live_tiles_and_nothing_else(case):
     some, every, static = _brute_force_tiles(sq, sk, 16, causal, kv_offset,
                                              block_diffusion)
     kinds = att._tile_kinds(*some.shape, **static)
-    iq, head, ik, flags = att._walk(kinds)
+    iq, ik, flags = att._walk(kinds)
     live = _live(flags)
     assert list(zip(iq[live], ik[live])) == list(zip(*np.nonzero(some)))
     np.testing.assert_array_equal((flags[live] & att._INTERIOR) != 0,
@@ -224,42 +224,74 @@ def test_walk_visits_the_live_tiles_and_nothing_else(case):
     np.testing.assert_array_equal(iq[~live],
                                   np.flatnonzero(~some.any(axis=1)))
     _assert_flags_bracket_each_block(iq, flags, some.shape[0])
-    assert not head.any() and flags.dtype == np.int32
+    assert flags.dtype == np.int32
+
+
+def _pallas_calls(jaxpr):
+    """``(grid, block shapes of the operands and results, VMEM limit)`` of
+    every ``pallas_call`` of a jaxpr, in order."""
+    from jax._src import core
+
+    calls = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            mapping = eqn.params["grid_mapping"]
+            calls.append((tuple(mapping.grid), [
+                tuple(getattr(dim, "block_size", dim)
+                      for dim in block.block_shape)
+                for block in mapping.block_mappings],
+                eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes))
+        for sub in core.jaxprs_in_params(eqn.params):
+            calls += _pallas_calls(sub)
+    return calls
+
+
+def _pallas_grids(jaxpr):
+    return [grid for grid, _, _ in _pallas_calls(jaxpr)]
+
+
+def _flash_calls(q, k, **kwargs):
+    """The forward, dk/dv and dq ``pallas_call``s of one gradient program."""
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda q, k, v: jnp.sum(
+        att.flash_attention(q, k, v, impl="pallas_interpret", **kwargs)),
+        argnums=(0, 1, 2)))(q, k, k)
+    assert "scan" not in str(jaxpr) and "while" not in str(jaxpr)
+    return _pallas_calls(jaxpr.jaxpr)
 
 
 @pytest.mark.parametrize("group", [1, 8])
 @pytest.mark.parametrize("case", _WALKS)
 def test_dkv_walk_visits_each_head_of_each_live_tile_once(case, group):
-    """The dk/dv pass's table: per k block, in order, every (query head of
-    the group, q block) of a live tile exactly once, head by head with q
-    ascending; a k block that no query sees visited once."""
+    """The dk/dv pass's table: per k block, in order, every live tile ONCE
+    with q ascending, whatever the group; a k block that no query sees
+    visited once.  The group's query heads are served INSIDE the visit: the
+    pass's grid is (K/V heads, visits) and its query-side blocks hold the
+    ``group`` heads, so every (head, live tile) is met exactly once."""
     sq, sk, causal, kv_offset, block_diffusion = case
     some, every, static = _brute_force_tiles(sq, sk, 16, causal, kv_offset,
                                              block_diffusion)
     kinds = att._tile_kinds(*some.shape, **static)
-    ik, head, iq, flags = att._walk(kinds.T, group)
+    ik, iq, flags = att._walk(kinds.T)
     live = _live(flags)
     for k in range(some.shape[1]):
         here = live & (ik == k)
-        assert list(zip(head[here], iq[here])) == [
-            (h, q) for h in range(group) for q in np.flatnonzero(some[:, k])]
+        assert list(iq[here]) == list(np.flatnonzero(some[:, k]))
     np.testing.assert_array_equal((flags[live] & att._INTERIOR) != 0,
                                   every[iq[live], ik[live]])
     np.testing.assert_array_equal(ik[~live],
                                   np.flatnonzero(~some.any(axis=0)))
     _assert_flags_bracket_each_block(ik, flags, some.shape[1])
 
-
-def _pallas_grids(jaxpr):
-    from jax._src import core
-
-    grids = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            grids.append(tuple(eqn.params["grid_mapping"].grid))
-        for sub in core.jaxprs_in_params(eqn.params):
-            grids += _pallas_grids(sub)
-    return grids
+    kv_heads, d = 2, 8
+    _, (grid, blocks, _), _ = _flash_calls(
+        jnp.zeros((1, sq, kv_heads * group, d)),
+        jnp.zeros((1, sk, kv_heads, d)), causal=causal, kv_offset=kv_offset,
+        block_q=16, block_k=16, block_diffusion=block_diffusion)
+    assert grid == (kv_heads, ik.size)
+    block_q, block_k = static["block_q"], static["block_k"]
+    assert blocks == (
+        [(group, block_q, d)] * 2 + [(group, 1, block_q)] * 2   # q dO lse delta
+        + [(1, block_k, d)] * 4)                                # k v dk dv
 
 
 @pytest.mark.parametrize("case", [
@@ -269,11 +301,12 @@ def _pallas_grids(jaxpr):
                   80), id="block-diffusion-at-sdar-s-tile-count"),
 ])
 def test_pallas_grids_have_the_length_of_the_walk(case):
-    """Forward and backward are three ``pallas_call``s whose sequential grid
-    axis is the table's length, not the dense (q tile, k tile) grid: 80 of
-    256 under SDAR's mask (16 x 16 tiles, block 4, scaled down); the dk/dv
-    pass walks them once for each query head of its group.  The two
-    counters read the same share."""
+    """Forward and backward are three ``pallas_call``s whose grid is (K/V
+    heads, the table's length), not the dense (q tile, k tile) grid of every
+    query head: 80 of 256 under SDAR's mask (16 x 16 tiles, block 4, scaled
+    down), in the dk/dv pass too, which serves a group's query heads inside
+    the visit.  The four counters read the share of the dense grid that is
+    walked and the heads a visit serves."""
     from tensorflowonspark_tpu import telemetry
 
     mask, heads, kv_heads, dense, walked = case
@@ -281,18 +314,51 @@ def test_pallas_grids_have_the_length_of_the_walk(case):
     q = jnp.zeros((1, 256, heads, 8))
     k = jnp.zeros((1, 256, kv_heads, 8))
     before = telemetry.snapshot()["counters"]
-    jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda q, k, v: jnp.sum(
-        att.flash_attention(q, k, v, block_q=16, block_k=16,
-                            impl="pallas_interpret", **mask)),
-        argnums=(0, 1, 2)))(q, k, k)
+    calls = _flash_calls(q, k, block_q=16, block_k=16, **mask)
     after = telemetry.snapshot()["counters"]
-    assert _pallas_grids(jaxpr.jaxpr) == [
-        (heads, walked), (kv_heads, group * walked), (heads, walked)]
-    assert "scan" not in str(jaxpr) and "while" not in str(jaxpr)
+    assert [grid for grid, _, _ in calls] == [(kv_heads, walked)] * 3
+    # a visit of one head keeps Mosaic's default VMEM scope
+    assert [limit for _, _, limit in calls] == [
+        att._VMEM_LIMIT if group > 1 else None] * 3
     counted = {name: after[name] - before.get(name, 0)
-               for name in ("flash.tiles", "flash.tiles_walked")}
-    assert counted == {"flash.tiles": (2 + group) * dense,
-                       "flash.tiles_walked": (2 + group) * walked}
+               for name in ("flash.kernels", "flash.visit_heads",
+                            "flash.tiles", "flash.tiles_walked")}
+    assert counted == {"flash.kernels": 3, "flash.visit_heads": 3 * group,
+                       "flash.tiles": 3 * dense,
+                       "flash.tiles_walked": 3 * walked}
+
+
+def test_layers_and_programs_share_one_trace_of_each_kernel(monkeypatch):
+    """The kernels' wrappers are jitted on what is static of a call: the
+    second layer of a program, and the next program of the process, trace no
+    kernel anew (a visit's head loop is 8 tile bodies: SDAR's set-up paid
+    13 s for tracing them per layer and program).  The counters still count
+    every kernel a program holds."""
+    from tensorflowonspark_tpu import telemetry
+
+    visits = []
+    real = att._visit
+    monkeypatch.setattr(att, "_visit", lambda *args, **kwargs: (
+        visits.append(1), real(*args, **kwargs))[1])
+    q, k = jnp.zeros((1, 32, 8, 8)), jnp.zeros((1, 32, 2, 8))
+    attend = functools.partial(     # a scale of its own: a signature no
+        att.flash_attention, sm_scale=0.1357, block_q=16, block_k=16,
+        impl="pallas_interpret")    # other test has traced
+
+    def two_layers(q, k, v):
+        return jnp.sum(attend(attend(q, k, v), k, v))
+
+    before = telemetry.snapshot()["counters"]
+    first = _pallas_calls(jax.make_jaxpr(jax.grad(two_layers))(q, k, k).jaxpr)
+    assert len(first) == 6 and len(visits) == 3     # forward, dk/dv, dq
+    again = _pallas_calls(jax.make_jaxpr(jax.value_and_grad(
+        lambda q, k, v: 2 * two_layers(q, k, v)))(q, k, k).jaxpr)
+    assert again == first and len(visits) == 3
+    after = telemetry.snapshot()["counters"]
+    # two programs of two layers of three kernels, 4 heads a visit
+    assert after["flash.kernels"] - before.get("flash.kernels", 0) == 12
+    assert after["flash.visit_heads"] - before.get(
+        "flash.visit_heads", 0) == 12 * 4
 
 
 def test_rows_of_one_tile_run_straight_through():
@@ -511,6 +577,95 @@ def test_block_diffusion_attention_and_both_backward_passes(impl, length,
         ref(q, k, v), atol=1e-5, rtol=1e-5)
     for a, r in zip(_grads(fn, q, k, v, w), _grads(ref, q, k, v, w)):
         np.testing.assert_allclose(a, r, atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# A visit serves the query heads of a K/V group together (ISSUE 36).
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shapes,heads", [
+    # SDAR's and Keye's: 8 heads of 512 x 128 in bf16 fill a visit
+    pytest.param((8, 512, 128, 2), 8, id="sdar-8-over-1-group"),
+    pytest.param((1, 512, 128, 2), 1, id="group-1"),
+    pytest.param((1, 512, 128, 4), 1, id="group-1-float32"),
+    # 32 over 1 does not fit: a proper divisor, several visits a tile
+    pytest.param((32, 512, 128, 2), 8, id="32-over-1-in-four-visits"),
+    pytest.param((8, 512, 128, 4), 8, id="8-heads-float32"),
+    pytest.param((12, 512, 128, 4), 6, id="12-heads-the-largest-divisor"),
+    pytest.param((7, 512, 128, 4), 7, id="a-prime-group-that-fits"),
+    pytest.param((11, 512, 128, 4), 1, id="a-prime-group-that-does-not"),
+    pytest.param((32, 16, 8, 4), 32, id="interpret-mode-tiles-all-fit"),
+])
+def test_heads_a_visit_is_the_largest_divisor_that_fits(shapes, heads):
+    """Read from the shapes at trace time: the whole group where its blocks
+    and scratch fit the budget, else its largest divisor that does; one head
+    always (a single head's blocks are what the kernels held before)."""
+    group = shapes[0]
+    got = att._visit_heads(*shapes)
+    assert got == heads and group % got == 0
+    assert att._VMEM_BLOCKS < att._VMEM_LIMIT <= 100 << 20
+
+
+@pytest.mark.parametrize("case", [
+    # batch, sq, sk, heads, K/V heads, d, mask, dtype, heads a visit or None
+    pytest.param((2, 40, 40, 8, 1, 8, dict(causal=True), jnp.float32, None),
+                 id="8-over-1-batch-2-length-not-whole-blocks"),
+    pytest.param((2, 40, 72, 8, 2, 8, dict(causal=True, kv_offset=-24),
+                  jnp.float32, None), id="8-over-2-batch-2-causal-kv-offset"),
+    pytest.param((1, 48, 48, 2, 2, 96, dict(causal=True), jnp.float32, None),
+                 id="group-1-head-dim-96"),
+    pytest.param((2, 40, 40, 8, 2, 8,
+                  dict(causal=False, block_diffusion=(20, 4)), jnp.float32,
+                  None), id="8-over-2-batch-2-block-diffusion-padded"),
+    pytest.param((2, 48, 48, 8, 2, 8, dict(causal=False), jnp.float32, None),
+                 id="8-over-2-batch-2-not-causal"),
+    pytest.param((2, 48, 48, 8, 2, 8, dict(causal=True), jnp.bfloat16, None),
+                 id="bf16-8-over-2-batch-2"),
+    pytest.param((1, 48, 48, 8, 1, 8,
+                  dict(causal=False, block_diffusion=(24, 4)), jnp.bfloat16,
+                  None), id="bf16-8-over-1-block-diffusion"),
+    pytest.param((1, 40, 72, 2, 2, 96, dict(causal=True, kv_offset=-24),
+                  jnp.bfloat16, None), id="bf16-group-1-head-dim-96-offset"),
+    pytest.param((2, 40, 40, 8, 1, 8, dict(causal=True), jnp.float32, 2),
+                 id="8-over-1-batch-2-in-four-visits-a-tile"),
+    pytest.param((2, 40, 40, 8, 2, 8,
+                  dict(causal=False, block_diffusion=(20, 4)), jnp.bfloat16,
+                  2), id="bf16-8-over-2-block-diffusion-in-two-visits"),
+])
+def test_a_visit_serves_the_query_heads_of_its_group(case, monkeypatch):
+    """Values and all three gradients of the kernels (interpret mode) against
+    the dense float32 reference where a grid row holds a K/V head's whole
+    group (a group's rows are neighbours, across the batch too), and where
+    the budget splits a group over several grid rows (their dk/dv shares
+    are added outside the kernel).  Errors relative to the reference's
+    largest entry: float32 inputs round nothing, bf16 ones at the tolerance
+    of ``test_pallas_backward_kernels_match_reference``."""
+    b, sq, sk, h, kv_heads, d, mask, dtype, visit_heads = case
+    group = h // kv_heads
+    if visit_heads:
+        a_head = 6 * 16 * d * jnp.dtype(dtype).itemsize + 4 * 16 * 128 * 4 \
+            + 16 * d * 4
+        monkeypatch.setattr(att, "_VMEM_BLOCKS", visit_heads * a_head)
+    assert att._visit_heads(group, 16, d, jnp.dtype(dtype).itemsize) == (
+        visit_heads or group)
+    rng = np.random.RandomState(7)
+    q = jnp.asarray(rng.randn(b, sq, h, d), dtype)
+    k = jnp.asarray(rng.randn(b, sk, kv_heads, d), dtype)
+    v = jnp.asarray(rng.randn(b, sk, kv_heads, d), dtype)
+    w = jnp.asarray(rng.randn(*q.shape), jnp.float32)
+    fn = lambda q, k, v: att.flash_attention(  # noqa: E731
+        q, k, v, block_q=16, block_k=16, impl="pallas_interpret", **mask)
+    ref = lambda q, k, v: att.mha_reference(q, k, v, **mask)  # noqa: E731
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    grid, _, _ = _flash_calls(q, k, block_q=16, block_k=16, **mask)[1]
+    assert grid[0] == b * h // (visit_heads or group)
+    got = [fn(q, k, v), *_grads(fn, q, k, v, w)]
+    want = [ref(*f32), *_grads(ref, *f32, w)]
+    for name, a, r in zip(["out", "dq", "dk", "dv"], got, want):
+        assert a.dtype == dtype and a.shape == r.shape, name
+        err = jnp.max(jnp.abs(a.astype(jnp.float32) - r)) / jnp.max(jnp.abs(r))
+        assert float(err) < tol, (name, float(err))
 
 
 def test_block_diffusion_refuses_what_it_does_not_mask():

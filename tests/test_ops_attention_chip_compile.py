@@ -2,10 +2,10 @@
 (nothing runs, no chip needed): 32 x 128 query heads over 4 K/V heads, 8,192
 positions under the block-diffusion mask of block 4.  What interpret mode
 cannot show: that Mosaic takes the walk's scalar-prefetch tables (80 visits
-a head; 640 in the dk/dv pass, which walks a group's 8 query heads) and
-index maps that read them.  The topology is described inside a fixture,
-never at import (only one process may load the TPU library; see the
-on-chip-measurement guide)."""
+a K/V head in all three kernels), index maps that read them, and a visit's
+blocks of a group's 8 query heads (16 MiB in the dq pass, under the raised
+limit).  The topology is described inside a fixture, never at import (only
+one process may load the TPU library; see the on-chip-measurement guide)."""
 
 from __future__ import annotations
 
@@ -28,21 +28,29 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("mask", [
-    pytest.param(dict(causal=False, block_diffusion=(4096, 4)),
+@pytest.mark.parametrize("case", [
+    # positions, query heads, K/V heads, head dim, mask
+    pytest.param((8192, 32, 4, 128,
+                  dict(causal=False, block_diffusion=(4096, 4))),
                  id="block-diffusion"),
-    pytest.param(dict(causal=True), id="causal"),
+    pytest.param((8192, 32, 4, 128, dict(causal=True)), id="causal"),
+    # the dense LM's: one query head a K/V head, 96 wide padded to 128 lanes
+    pytest.param((2048, 32, 32, 96, dict(causal=True)),
+                 id="group-1-head-dim-96"),
+    # a group too large for one visit: four visits of 8 heads a tile
+    pytest.param((2048, 32, 1, 128, dict(causal=True)), id="32-over-1"),
 ])
-def test_all_three_flash_kernels_lower_at_sdar_widths(one_chip, mask):
+def test_all_three_flash_kernels_lower_at_sdar_widths(one_chip, case):
     import jax
     import jax.numpy as jnp
     from jax.experimental.compilation_cache import compilation_cache
 
     from tensorflowonspark_tpu.ops.attention import flash_attention
 
-    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
+    length, heads, kv_heads, d, mask = case
+    q = jax.ShapeDtypeStruct((1, length, heads, d), jnp.bfloat16,
                              sharding=one_chip)
-    k = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16,
+    k = jax.ShapeDtypeStruct((1, length, kv_heads, d), jnp.bfloat16,
                              sharding=one_chip)
 
     def loss(q, k, v):
