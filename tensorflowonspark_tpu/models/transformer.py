@@ -86,6 +86,13 @@ class Attention(nn.Module):
     # the causal pairs and every query attends to its ``topk`` best keys
     # only (``_sparse_attention``, ``ops/sparse_attention.py``).
     sparse: Optional[tuple] = None
+    # Latent attention (MLA, DeepSeek-V2, arXiv:2405.04434, without a query
+    # latent): ``(kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
+    # v_head_dim)``.  Keys and values are up-projections of one normed latent
+    # a token, RoPE turns ``qk_rope_head_dim`` columns of a query and ONE key
+    # head of that width that all query heads share (``_latent_attention``);
+    # ``d_head``, ``n_kv_heads`` and QK-norm say nothing here.
+    latent: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, x, positions=None, block_diffusion=None):
@@ -94,6 +101,8 @@ class Attention(nn.Module):
         that mask in place of the causal one (``ops/attention.py``).  The
         caller hands in both: what a sequence holds is the loss's business
         (``make_block_diffusion_loss_fn``)."""
+        if self.latent:
+            return self._latent_attention(x, positions, block_diffusion)
         b, s, _ = x.shape
         h, dh = self.n_heads, self.d_head
         h_kv = self.n_kv_heads or h
@@ -154,6 +163,57 @@ class Attention(nn.Module):
         out = nn.DenseGeneral(x.shape[-1], axis=(-2, -1), use_bias=False,
                               name="o_proj", dtype=self.compute_dtype)(out)
         return out
+
+    def _latent_attention(self, x, positions, block_diffusion):
+        """Latent attention on the training path.  From the layer's normed
+        hidden state ``u``: ``q = u W_q`` = per head ``[q_nope | q_rope]``;
+        ``[c | k_r] = u W_kva``, ``c`` RMS-normed (``kv_a_norm``), ``k_r`` ONE
+        rotary key head for all query heads; ``[k_nope | v] = c W_kvb`` per
+        head.  RoPE turns ``q_rope`` and ``k_r`` only (this file's half-split
+        pairing: against the published interleaved one a fixed permutation
+        of the rotary columns of ``W_q`` and ``W_kva``, which no score
+        sees).  ``score = (q_nope · k_nope + q_rope · k_r) / sqrt(nope +
+        rope)``: the kernels take ``k_r`` as their shared key, so no copy of
+        it a head exists, forward or backward.  Autodiff keeps ``k_nope``
+        and ``v`` whole for the backward (the kernels' residuals)."""
+        if self.decode:
+            raise NotImplementedError(
+                "latent attention (Attention.latent) has no cache path: "
+                "decode=True would have to cache the latent and the rotary "
+                "key, which Attention._decode_step does not")
+        if self.attn_impl == "ring" or self.sparse or self.qk_norm:
+            raise NotImplementedError(
+                "latent attention runs one whole sequence through "
+                "flash_attention, without QK-norm and without an indexer")
+        rank, nope, rope, dv = self.latent
+        h, s = self.n_heads, x.shape[1]
+        cdt = self.compute_dtype
+        with jax.named_scope("mla/project"):
+            q = nn.DenseGeneral((h, nope + rope), use_bias=False,
+                                name="q_proj", dtype=cdt)(x)
+            kv_a = nn.Dense(rank + rope, use_bias=False, name="kv_a_proj",
+                            dtype=cdt)(x)
+            c = RMSNorm(self.norm_eps, name="kv_a_norm")(kv_a[..., :rank])
+            kv = nn.DenseGeneral((h, nope + dv), use_bias=False,
+                                 name="kv_b_proj", dtype=cdt)(c)
+            if positions is None:
+                positions = jnp.arange(s)
+            q = jnp.concatenate(
+                [q[..., :nope],
+                 apply_rope(q[..., nope:], positions, self.rope_theta)], -1)
+            k_r = apply_rope(kv_a[:, :, None, rank:], positions,
+                             self.rope_theta)[:, :, 0]
+            q = constrain(q, P(BATCH, "sp", "tp", None))
+            k = constrain(kv[..., :nope], P(BATCH, "sp", "tp", None))
+            v = constrain(kv[..., nope:], P(BATCH, "sp", "tp", None))
+        with jax.named_scope("attention"):
+            out = flash_attention(
+                q, k, v, k_shared=k_r, causal=not block_diffusion,
+                impl=None if self.attn_impl == "auto" else self.attn_impl,
+                block_diffusion=block_diffusion)
+        with jax.named_scope("mla/project"):
+            return nn.DenseGeneral(x.shape[-1], axis=(-2, -1), use_bias=False,
+                                   name="o_proj", dtype=cdt)(out)
 
     def _decode_step(self, x, q, k, v):
         """``s`` tokens through a static-size KV cache (``cache`` collection).
@@ -298,6 +358,9 @@ class Block(nn.Module):
     qk_norm_per_head: bool = False
     moe_held: Optional[tuple] = None
     sparse: Optional[tuple] = None
+    latent: Optional[tuple] = None
+    moe_router: Optional[tuple] = None      # see Transformer
+    moe_shared_d_ff: int = 0
 
     @nn.compact
     def __call__(self, x, positions=None, block_diffusion=None):
@@ -306,21 +369,33 @@ class Block(nn.Module):
                           self.attn_impl, self.mesh, self.compute_dtype,
                           self.decode, self.max_decode_len, self.qk_norm,
                           self.norm_eps, self.n_kv_heads,
-                          self.qk_norm_per_head, self.sparse, name="attn")(
+                          self.qk_norm_per_head, self.sparse, self.latent,
+                          name="attn")(
                               norm("attn_norm")(x), positions,
                               block_diffusion)
         x = constrain(x, P(BATCH, "sp", None))
         if self.n_experts:
             from tensorflowonspark_tpu.parallel.ep import MoEMLP
 
+            scoring, bias, scale = self.moe_router or ("softmax", False, 1.0)
             ffn = MoEMLP(x.shape[-1], self.d_ff, self.n_experts,
                          self.moe_top_k, self.moe_capacity_factor,
                          compute_dtype=self.compute_dtype,
                          norm_topk_prob=self.moe_norm_topk_prob,
-                         held=self.moe_held, name="moe")
+                         held=self.moe_held, scoring=scoring,
+                         selection_bias=bias, routed_scale=scale, name="moe")
         else:
             ffn = SwiGLU(self.d_ff, self.compute_dtype, name="mlp")
-        x = x + ffn(norm("mlp_norm")(x))
+        y = norm("mlp_norm")(x)
+        x = x + ffn(y)
+        if self.n_experts and self.moe_shared_d_ff:
+            # the shared experts, as ONE SwiGLU of their summed width that
+            # every token passes beside its routed experts: every chip of an
+            # expert-parallel stage computes it whole, so under ``moe_held``
+            # it is in the layer's output once, as it is without
+            with jax.named_scope("moe/shared"):
+                x = x + SwiGLU(self.moe_shared_d_ff, self.compute_dtype,
+                               name="shared")(y)
         return constrain(x, P(BATCH, "sp", None))
 
 
@@ -367,6 +442,19 @@ class Transformer(nn.Module):
     moe_held: Optional[tuple] = None
     # Learned sparse attention in every layer (see ``Attention.sparse``).
     sparse: Optional[tuple] = None
+    # Latent attention in every layer (see ``Attention.latent``).
+    latent: Optional[tuple] = None
+    # The router of the expert layers beyond softmax -> top-k: ``(scoring,
+    # selection_bias, routed_scale)`` and the width of the shared SwiGLU that
+    # every token also passes (0: none); see ``parallel/ep.MoEMLP``.
+    moe_router: Optional[tuple] = None
+    moe_shared_d_ff: int = 0
+    # Layers that differ inside one model: one entry a layer, the width of a
+    # DENSE SwiGLU that the layer has in place of the model's own FFN, or 0
+    # for that (the experts where ``n_experts``, else ``d_ff``).  None: every
+    # layer the model's own.  DeepSeek-V3's ``first_k_dense_replace`` 1 over
+    # 5 layers is ``(6144, 0, 0, 0, 0)``.
+    layer_ffn: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, input_ids, positions=None, block_diffusion=None):
@@ -394,15 +482,21 @@ class Transformer(nn.Module):
         block_cls = (nn.remat(Block, static_argnums=(3,),
                               policy=self._remat_policy())
                      if self.remat else Block)
-        for i in range(self.n_layers):
-            x = block_cls(self.n_heads, dh, dff, self.n_experts, self.moe_top_k,
+        layer_ffn = self.layer_ffn or (0,) * self.n_layers
+        if len(layer_ffn) != self.n_layers:
+            raise ValueError(f"layer_ffn={self.layer_ffn} names "
+                             f"{len(layer_ffn)} of {self.n_layers} layers")
+        for i, dense in enumerate(layer_ffn):
+            x = block_cls(self.n_heads, dh, dense or dff,
+                          0 if dense else self.n_experts, self.moe_top_k,
                           self.rope_theta, self.attn_impl, self.mesh,
                           self.compute_dtype, self.decode, self.max_decode_len,
                           self.norm_eps, self.qk_norm,
                           self.moe_capacity_factor, self.moe_norm_topk_prob,
                           self.n_kv_heads, self.qk_norm_per_head,
-                          self.moe_held, self.sparse, name=f"block_{i}")(
-                              x, positions, block_diffusion)
+                          self.moe_held, self.sparse, self.latent,
+                          self.moe_router, self.moe_shared_d_ff,
+                          name=f"block_{i}")(x, positions, block_diffusion)
         x = RMSNorm(self.norm_eps, name="final_norm")(x)
         if self.return_hidden:
             return x
@@ -430,6 +524,13 @@ def build_transformer(config: dict) -> Transformer:
     capacity = config.get("moe_capacity_factor", 1.25)
     held = config.get("moe_held")
     sparse = config.get("sparse_attention")
+    latent = config.get("latent_attention")
+    router = config.get("moe_router")
+    layer_ffn = config.get("layer_ffn")
+    if router is not None and int(router.get("n_group", 1)) > 1:
+        raise NotImplementedError(
+            f"group-limited routing (n_group {router['n_group']}): "
+            "parallel/ep.py chooses among all of a layer's experts")
     return Transformer(
         vocab_size=int(config.get("vocab_size", 32000)),
         d_model=int(config.get("d_model", 512)),
@@ -454,6 +555,17 @@ def build_transformer(config: dict) -> Transformer:
         sparse=None if sparse is None else tuple(
             int(sparse[key]) for key in ("index_heads", "index_head_dim",
                                          "topk")),
+        latent=None if latent is None else tuple(
+            int(latent[key]) for key in (
+                "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+                "v_head_dim")),
+        moe_router=None if router is None else (
+            str(router.get("scoring", "softmax")),
+            bool(router.get("selection_bias", False)),
+            float(router.get("routed_scale", 1.0))),
+        moe_shared_d_ff=int(config.get("moe_shared_d_ff", 0)),
+        layer_ffn=None if layer_ffn is None else tuple(
+            int(width) for width in layer_ffn),
     )
 
 
@@ -659,9 +771,19 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
     ``vocab_chunk > 0`` fuses the lm_head matmul into a blockwise
     cross-entropy (``ops/xent.py``): the ``[B, S, V]`` logits are never
     materialized — the HBM-dominant op at large vocab.  Not for
-    tensor-parallel vocab-sharded heads (use the dense path there)."""
+    tensor-parallel vocab-sharded heads (use the dense path there).
+
+    A model that keeps BUFFERS (the collection ``buffers``: a router's
+    selection bias, ``parallel/ep.MoEMLP``) is handed them as a third
+    argument, ``loss_fn(params, batch, buffers)``: what
+    ``parallel/dp.make_train_step`` calls where the train state carries
+    them.  The loss is not differentiated by them."""
 
     sown = _sown_collections(model)
+
+    def _variables(params, buffers):
+        return ({"params": params} if buffers is None
+                else {"params": params, "buffers": buffers})
 
     def _reduce(nll, batch, updates):
         mask = batch.get("loss_mask")
@@ -677,9 +799,9 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
 
         hidden_model = model.clone(return_hidden=True)
 
-        def fused_loss_fn(params, batch):
+        def fused_loss_fn(params, batch, buffers=None):
             ids = batch["input_ids"]
-            h, updates = hidden_model.apply({"params": params}, ids,
+            h, updates = hidden_model.apply(_variables(params, buffers), ids,
                                             mutable=sown)
             b, s, d = h.shape
             h = h[:, :-1].reshape(b * (s - 1), d)
@@ -692,9 +814,9 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
 
         return fused_loss_fn
 
-    def loss_fn(params, batch):
+    def loss_fn(params, batch, buffers=None):
         ids = batch["input_ids"]
-        logits, updates = model.apply({"params": params}, ids,
+        logits, updates = model.apply(_variables(params, buffers), ids,
                                       mutable=sown)
         with jax.named_scope("lm_head_loss"):
             logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))

@@ -171,33 +171,42 @@ def merge_attention(o1, lse1, o2, lse2):
 
 def blockwise_attention(q, k, v, *, causal: bool = True,
                         sm_scale: float | None = None, block_k: int = 512,
-                        kv_offset: int = 0, block_diffusion=None):
+                        kv_offset: int = 0, block_diffusion=None,
+                        k_shared=None):
     """Flash-style attention as a ``lax.scan`` over KV blocks.
 
     Differentiable, runs on every backend, and with the per-block
     ``jax.checkpoint`` memory is O(Sq·block_k) — ``impl="xla"``: the path off
-    the TPU.
+    the TPU.  ``k_shared`` as ``flash_attention`` takes it.
     """
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, d_k = k.shape[1], k.shape[-1]
     k, v = _repeat_kv(q, k, v)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     block_k = min(block_k, sk)
     nblocks = -(-sk // block_k)
     pad = nblocks * block_k - sk
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    kb = k.reshape(b, nblocks, block_k, h, d).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(b, nblocks, block_k, h, d).transpose(1, 0, 2, 3, 4)
+
+    def blocks(x):          # [B, Sk, ...] -> [nblocks, B, block_k, ...]
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(
+            x.reshape(b, nblocks, block_k, *x.shape[2:]), 1, 0)
+
+    kb, vb = blocks(k), blocks(v)
+    shared = () if k_shared is None else (blocks(k_shared),)
+    q, q_shared = q[..., :d_k], q[..., d_k:]
 
     qpos = jnp.arange(sq)[:, None]
 
     @jax.checkpoint
     def block(carry, inputs):
         o_acc, m_acc, l_acc = carry
-        kc, vc, start = inputs
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, kc).astype(jnp.float32) * scale
+        kc, vc, start, *kc_shared = inputs
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, kc).astype(jnp.float32)
+        if kc_shared:       # the one key head every query head also meets
+            logits = logits + jnp.einsum(
+                "bqhd,bkd->bhqk", q_shared, kc_shared[0]).astype(jnp.float32)
+        logits = logits * scale
         kpos = kv_offset + start + jnp.arange(block_k)[None, :]
         mask = kpos < kv_offset + sk  # padded tail
         if causal:
@@ -217,11 +226,12 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
                  + jnp.einsum("bhqk,bkhd->bqhd", p, vc.astype(jnp.float32)))
         return (o_new, m_new, l_new), None
 
-    o0 = match_vma(jnp.zeros((b, sq, h, d), jnp.float32), q)
+    o0 = match_vma(jnp.zeros((b, sq, h, v.shape[-1]), jnp.float32), q)
     m0 = match_vma(jnp.full((b, h, sq), NEG_INF, jnp.float32), q)
     l0 = match_vma(jnp.zeros((b, h, sq), jnp.float32), q)
     starts = jnp.arange(nblocks) * block_k
-    (o, m, l), _ = jax.lax.scan(block, (o0, m0, l0), (kb, vb, starts))
+    (o, m, l), _ = jax.lax.scan(block, (o0, m0, l0),
+                                (kb, vb, starts, *shared))
     o = o / jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
     return o.astype(q.dtype)
 
@@ -368,15 +378,17 @@ _VMEM_LIMIT = 96 << 20
 _VMEM_BLOCKS = 24 << 20
 
 
-def _visit_heads(group: int, block_q: int, d_p: int, itemsize: int) -> int:
+def _visit_heads(group: int, block_q: int, d_p: int, itemsize: int,
+                 more: int = 0) -> int:
     """How many query heads of a K/V group one visit serves: the largest
     divisor of ``group`` whose query-side blocks and scratch fit
     ``_VMEM_BLOCKS`` in the kernel that holds most a head, the dq pass (q, dO
     and dq double-buffered, the two float32 column blocks of 128 lanes
-    likewise, the float32 accumulator).  8 heads of 512 x 128 in bf16 hold
-    16 MiB; a group of 32 is served in several visits a tile."""
+    likewise, the float32 accumulator, and ``more`` bytes a head of what
+    else it holds).  8 heads of 512 x 128 in bf16 hold 16 MiB; a group of 32
+    is served in several visits a tile."""
     tile = block_q * d_p
-    a_head = 6 * tile * itemsize + 4 * block_q * 128 * 4 + tile * 4
+    a_head = 6 * tile * itemsize + 4 * block_q * 128 * 4 + tile * 4 + more
     return max(heads for heads in range(1, group + 1)
                if group % heads == 0
                and (heads == 1 or heads * a_head <= _VMEM_BLOCKS))
@@ -390,39 +402,58 @@ class _Plan(NamedTuple):
     sq_p: int           # the tiled lengths
     sk_p: int
     heads: int          # query heads a visit serves
+    kv_heads: int       # K/V heads a visit holds: 1, or one a query head
     dense: int          # tiles of the dense grid
     walk: tuple         # rows (iq, ik, flags): the forward's and the dq pass's
     walk_t: tuple       # rows (ik, iq, flags): the dk/dv pass's
 
 
-def _plan(qt, kt, *, causal, kv_offset, block_q, block_k,
+def _plan(qt, kt, kr=None, *, causal, kv_offset, block_q, block_k,
           block_diffusion) -> _Plan:
     """The plan of the kernels over head-major ``qt`` and ``kt``: numpy and
-    integers only, worked out in each rule of the VJP (which counts by it)."""
+    integers only, worked out in each rule of the VJP (which counts by it).
+
+    Where every query head has K and V of its own AND all meet one shared
+    key ``kr`` (latent attention), a visit serves several (query, K/V) heads
+    of one row beside one fetch of the shared key's block, and sums the
+    shared key's gradient over them in the visit."""
     bh, sq, d_p = qt.shape
     sk = kt.shape[1]
+    group = bh // kt.shape[0]
     block_q, block_k, sq_p, sk_p = _blocks(sq, sk, block_q, block_k)
     tile = dict(causal=causal, kv_offset=kv_offset, block_q=block_q,
                 block_k=block_k, sk=sk, block_diffusion=block_diffusion)
     kinds = _tile_kinds(sq_p // block_q, sk_p // block_k, **tile)
     rows = lambda table: tuple(map(tuple, table.tolist()))      # noqa: E731
-    return _Plan(tuple(tile.items()), sq_p, sk_p,
-                 _visit_heads(bh // kt.shape[0], block_q, d_p,
-                              qt.dtype.itemsize),
+    size = qt.dtype.itemsize
+    if kr is not None and group == 1:
+        # beside the query side: the shared product's q, dq (double-buffered)
+        # and accumulator, and the head's own K and V blocks
+        shared = block_q * kr.shape[-1]
+        heads = kv_heads = _visit_heads(
+            bh // kr.shape[0], block_q, d_p, size,
+            4 * shared * size + shared * 4 + 4 * block_k * d_p * size)
+    else:
+        heads, kv_heads = _visit_heads(group, block_q, d_p, size), 1
+    return _Plan(tuple(tile.items()), sq_p, sk_p, heads, kv_heads,
                  kinds.size, rows(_walk(kinds)), rows(_walk(kinds.T)))
 
 
-def _count(plan: _Plan, *tables) -> None:
+def _count(plan: _Plan, latent: bool, *tables) -> None:
     """For the run report, once for each kernel a traced program holds (the
     check's included): ``flash.tiles_walked`` over ``flash.tiles`` is the
     share of the dense grid's steps that the walks keep,
     ``flash.visit_heads`` over ``flash.kernels`` the query heads a visit
-    serves."""
+    serves.  Of these, the kernels that take a shared key are counted again
+    as ``flash.latent_kernels`` and ``flash.latent_visit_heads``."""
     for table in tables:
         telemetry.counter("flash.kernels").inc()
         telemetry.counter("flash.visit_heads").inc(plan.heads)
         telemetry.counter("flash.tiles").inc(plan.dense)
         telemetry.counter("flash.tiles_walked").inc(len(table[0]))
+        if latent:
+            telemetry.counter("flash.latent_kernels").inc()
+            telemetry.counter("flash.latent_visit_heads").inc(plan.heads)
 
 
 def _walk_call(kernel, table, rows: int, heads: int, *, out_shape,
@@ -497,12 +528,27 @@ def _bias(visible, shape, q_dim: int):
     return jnp.where(visible(shape, q_dim), 0.0, NEG_INF)
 
 
-def _scores(a, b, sm_scale: float, bias):
+def _scores(a, b, sm_scale: float, bias, shared=None):
     """``a @ b.T`` scaled, float32 from the operands as the model holds them
-    (the products of bf16 values are exact in float32), plus the bias."""
+    (the products of bf16 values are exact in float32), plus the bias.
+    ``shared`` is a second pair of operands whose product joins the first
+    before the scale: the columns of a query that meet the shared key."""
     logits = jax.lax.dot_general(
-        a, b, _NT, preferred_element_type=jnp.float32) * sm_scale
+        a, b, _NT, preferred_element_type=jnp.float32)
+    if shared is not None:
+        logits = logits + jax.lax.dot_general(
+            *shared, _NT, preferred_element_type=jnp.float32)
+    logits = logits * sm_scale
     return logits if bias is None else logits + bias
+
+
+def _kv_of(k_ref, v_ref):
+    """``h -> (k, v)`` of a visit's head ``h``: the one K/V head the visit's
+    query heads share, read once, or the head's own."""
+    if k_ref.shape[0] == 1:
+        kv = k_ref[0], v_ref[0]
+        return lambda h: kv
+    return lambda h: (k_ref[h], v_ref[h])
 
 
 # A VISIT of the three kernels is one live tile for one K/V head and the
@@ -510,18 +556,24 @@ def _scores(a, b, sm_scale: float, bias):
 # them where they fit): the query-side blocks are ``[heads, block, d]``, K and
 # V are fetched once a visit, a masked tile's bias is built once, and the
 # heads are a static loop inside the visit.  With one query head a K/V head
-# the loop has one turn.
+# the loop has one turn.  Under a shared key (``latent``) the refs ``qr`` (the
+# queries' columns that meet it) and ``kr`` (its block, one fetch a visit)
+# follow q, k and v, every head of the visit has a K/V block of its own, and
+# the backward passes write dqr and the visit's sum of dkr.
 
-def _flash_fwd_kernel(iq_ref, ik_ref, flags_ref, q_ref, k_ref, v_ref,
-                      o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                      *, sm_scale: float, **walk):
+def _flash_fwd_kernel(iq_ref, ik_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
+                      sm_scale: float, latent: bool, **walk):
     # m/l scratch is lane-replicated to 128 lanes — TPU tiling requires the
     # last dim be 128-aligned — and read as whole lanes (``_lanes``).  The lse
     # goes out as a ROW per (batch, head): a residual of the backward, 128
     # times smaller than the replicated columns and lane-dense as its dk/dv
     # pass reads it.
-    heads, block_q, d = q_ref.shape
-    block_k = k_ref.shape[1]
+    if latent:
+        qr_ref, kr_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    heads, block_q, _ = q_ref.shape
+    block_k, d = k_ref.shape[1], v_ref.shape[2]
 
     def init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -529,10 +581,12 @@ def _flash_fwd_kernel(iq_ref, ik_ref, flags_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     def attend(visible):
-        k, v = k_ref[0], v_ref[0]                      # [block_k, d]
+        kv = _kv_of(k_ref, v_ref)                      # [block_k, d] each
         bias = _bias(visible, (block_q, block_k), 0)
         for h in range(heads):
-            logits = _scores(q_ref[h], k, sm_scale, bias)
+            k, v = kv(h)
+            logits = _scores(q_ref[h], k, sm_scale, bias,
+                             (qr_ref[h], kr_ref[0]) if latent else None)
             m_prev = m_ref[h]                           # [block_q, 128]
             m_new = jnp.maximum(m_prev,
                                 jnp.max(logits, axis=-1, keepdims=True))
@@ -595,129 +649,186 @@ def _pad_seq(x, s_p: int):
 _STATIC = ("plan", "sm_scale", "interpret")
 
 
+def _visit_rows(plan: _Plan, qt, kt, kr):
+    """How a kernel's grid rows (``plan.heads`` query heads each) meet the
+    rows of the other operands: ``parts`` grid rows share one block of
+    ``plan.kv_heads`` K/V heads, and ``batch_rows`` of them are one batch
+    row's and meet its shared key (without one: all of them)."""
+    rows = qt.shape[0] // plan.heads
+    return (rows * plan.kv_heads // kt.shape[0],
+            rows // (1 if kr is None else kr.shape[0]))
+
+
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _flash_fwd_pallas(qt, kt, vt, *, plan: _Plan, sm_scale, interpret):
+def _flash_fwd_pallas(qt, kt, vt, qr=None, kr=None, *, plan: _Plan, sm_scale,
+                      interpret):
     """Run the Pallas forward on head-major ``[B*H, S, D_p]`` operands
     (``[B*H_kv, S, D_p]`` keys and values: query head ``j`` reads the K/V
     row ``j // group``, head-major rows being ``batch * heads + head`` on
-    both sides); returns ``(out [B*H, Sq, D_p], lse [B*H, Sq] float32)``."""
+    both sides; ``vt`` may be of another width than ``qt`` and ``kt``);
+    returns ``(out [B*H, Sq, Dv_p], lse [B*H, Sq] float32)``.  ``qr`` ``[B*H,
+    S, Dr_p]`` and ``kr`` ``[B, S, Dr_p]``: the queries' columns for a key
+    that all heads of a batch row share, and that key."""
     bh, sq, d_p = qt.shape
+    dv_p, latent = vt.shape[2], kr is not None
     static, heads = dict(plan.tile), plan.heads
     block_q, block_k, sq_p = static["block_q"], static["block_k"], plan.sq_p
     qt = _pad_seq(qt, sq_p)
     kt, vt = _pad_seq(kt, plan.sk_p), _pad_seq(vt, plan.sk_p)
-    parts = bh // kt.shape[0] // heads  # grid rows that share a K/V head
+    parts, batch_rows = _visit_rows(plan, qt, kt, kr)
+    shared, shared_specs = (), []
+    if latent:
+        shared = _pad_seq(qr, sq_p), _pad_seq(kr, plan.sk_p)
+        shared_specs = [
+            pl.BlockSpec((heads, block_q, qr.shape[2]),
+                         lambda r, v, iq, ik, flags: (r, iq[v], 0)),
+            pl.BlockSpec((1, block_k, kr.shape[2]),
+                         lambda r, v, iq, ik, flags: (
+                             r // batch_rows, ik[v], 0))]
 
     q_at = lambda r, v, iq, ik, flags: (r, iq[v], 0)            # noqa: E731
     kv_at = lambda r, v, iq, ik, flags: (r // parts, ik[v], 0)  # noqa: E731
     out, lse = _walk_call(
-        functools.partial(_flash_fwd_kernel, sm_scale=sm_scale, **static),
+        functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
+                          latent=latent, **static),
         plan.walk, bh // heads, heads,
         in_specs=[
             pl.BlockSpec((heads, block_q, d_p), q_at),
-            pl.BlockSpec((1, block_k, d_p), kv_at),
-            pl.BlockSpec((1, block_k, d_p), kv_at),
+            pl.BlockSpec((plan.kv_heads, block_k, d_p), kv_at),
+            pl.BlockSpec((plan.kv_heads, block_k, dv_p), kv_at),
+            *shared_specs,
         ],
         out_specs=[
-            pl.BlockSpec((heads, block_q, d_p), q_at),
+            pl.BlockSpec((heads, block_q, dv_p), q_at),
             pl.BlockSpec((heads, 1, block_q),
                          lambda r, v, iq, ik, flags: (r, 0, iq[v])),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq_p, d_p), qt.dtype),
+            jax.ShapeDtypeStruct((bh, sq_p, dv_p), qt.dtype),
             jax.ShapeDtypeStruct((bh, 1, sq_p), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((heads, block_q, d_p), jnp.float32),
+            pltpu.VMEM((heads, block_q, dv_p), jnp.float32),
             pltpu.VMEM((heads, block_q, 128), jnp.float32),
             pltpu.VMEM((heads, block_q, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(qt, kt, vt)
+    )(qt, kt, vt, *shared)
     return out[:, :sq], lse[:, 0, :sq]
 
 
 def _flash_bwd_dkv_kernel(ik_ref, iq_ref, flags_ref,
                           q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc,
-                          *, sm_scale: float, **walk):
+                          *refs, sm_scale: float, latent: bool, **walk):
     # One KV block against the q blocks that see it, summed over the visit's
     # query heads, on the TRANSPOSED tile [block_k, block_q]: lse and delta
     # are then rows, lane-dense as they lie in memory, and both
     # accumulations are plain matmuls.  The softmax weights of a tile come
     # from the forward's log-sum-exp: no running max, no rescale.  ``lse``
     # is at least ``NEG_INF / 2``, so a pair that is not visible gets exactly
-    # 0, also in a row that sees no key at all.
+    # 0, also in a row that sees no key at all.  The shared key's gradient is
+    # the sum over the visit's heads, as dk and dv are over a group's.
+    if latent:
+        qr_ref, kr_ref, dk_ref, dv_ref, dkr_ref, dk_acc, dv_acc, dkr_acc = refs
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = refs
     heads, block_q, _ = q_ref.shape
-    block_k = k_ref.shape[1]
+    kv_heads, block_k, _ = k_ref.shape
 
     def init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+        if latent:
+            dkr_acc[...] = jnp.zeros_like(dkr_acc)
 
     def attend(visible):
-        k, v = k_ref[0], v_ref[0]
+        kv = _kv_of(k_ref, v_ref)
         bias = _bias(visible, (block_k, block_q), 1)
         for h in range(heads):
             q, do = q_ref[h], do_ref[h]                # [block_q, d]
-            p_t = jnp.exp(_scores(k, q, sm_scale, bias) - lse_ref[h])
-            dv_acc[...] += jnp.dot(p_t.astype(do.dtype), do,
-                                   preferred_element_type=jnp.float32)
+            (k, v), at = kv(h), h % kv_heads
+            p_t = jnp.exp(_scores(
+                k, q, sm_scale, bias,
+                (kr_ref[0], qr_ref[h]) if latent else None) - lse_ref[h])
+            dv_acc[at] += jnp.dot(p_t.astype(do.dtype), do,
+                                  preferred_element_type=jnp.float32)
             dp_t = jax.lax.dot_general(
                 v, do, _NT, preferred_element_type=jnp.float32)
-            ds_t = p_t * (dp_t - delta_ref[h])
-            dk_acc[...] += jnp.dot(ds_t.astype(q.dtype), q,
-                                   preferred_element_type=jnp.float32)
+            ds_t = (p_t * (dp_t - delta_ref[h])).astype(q.dtype)
+            dk_acc[at] += jnp.dot(ds_t, q,
+                                  preferred_element_type=jnp.float32)
+            if latent:
+                dkr_acc[0] += jnp.dot(ds_t, qr_ref[h],
+                                      preferred_element_type=jnp.float32)
 
     def finalize():
-        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        if latent:
+            dkr_ref[...] = (dkr_acc[...] * sm_scale).astype(dkr_ref.dtype)
 
     _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, **walk)
 
 
 def _flash_bwd_dq_kernel(iq_ref, ik_ref, flags_ref,
                          q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                         dq_ref, dq_acc, *, sm_scale: float, **walk):
+                         *refs, sm_scale: float, latent: bool, **walk):
     # One q block of the visit's heads against the KV blocks it sees; lse and
     # delta are columns here, replicated over 128 lanes like the forward's m
     # and l, and read as whole lanes.
+    if latent:
+        qr_ref, kr_ref, dq_ref, dqr_ref, dq_acc, dqr_acc = refs
+    else:
+        dq_ref, dq_acc = refs
     heads, block_q, _ = q_ref.shape
     block_k = k_ref.shape[1]
 
     def init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        if latent:
+            dqr_acc[...] = jnp.zeros_like(dqr_acc)
 
     def attend(visible):
-        k, v = k_ref[0], v_ref[0]                      # [block_k, d]
+        kv = _kv_of(k_ref, v_ref)                      # [block_k, d] each
         bias = _bias(visible, (block_q, block_k), 0)
         for h in range(heads):
-            p = jnp.exp(_scores(q_ref[h], k, sm_scale, bias)
-                        - _lanes(lse_ref[h], block_k))
+            k, v = kv(h)
+            p = jnp.exp(_scores(
+                q_ref[h], k, sm_scale, bias,
+                (qr_ref[h], kr_ref[0]) if latent else None)
+                - _lanes(lse_ref[h], block_k))
             dp = jax.lax.dot_general(
                 do_ref[h], v, _NT, preferred_element_type=jnp.float32)
-            ds = p * (dp - _lanes(delta_ref[h], block_k))
-            dq_acc[h] += jnp.dot(ds.astype(k.dtype), k,
-                                 preferred_element_type=jnp.float32)
+            ds = (p * (dp - _lanes(delta_ref[h], block_k))).astype(k.dtype)
+            dq_acc[h] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+            if latent:
+                dqr_acc[h] += jnp.dot(ds, kr_ref[0],
+                                      preferred_element_type=jnp.float32)
 
     def finalize():
         dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+        if latent:
+            dqr_ref[...] = (dqr_acc[...] * sm_scale).astype(dqr_ref.dtype)
 
     _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, **walk)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, *, plan: _Plan, sm_scale,
-                      interpret):
+def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, qr=None, kr=None, *,
+                      plan: _Plan, sm_scale, interpret):
     """Both backward passes on head-major operands; ``lse`` as
     ``_flash_fwd_pallas`` returns it and ``delta = rowsum(dO * O)`` like it,
     ``[B*H, Sq]`` float32.  Returns ``(dq, dk, dv)`` head-major, dk and dv
     at the K/V head count: the dk/dv pass sums over the query heads of a
     visit inside it (where a group takes several grid rows, each writes its
-    float32 share and they are added here)."""
+    float32 share and they are added here).  With ``qr`` and ``kr`` (see
+    ``_flash_fwd_pallas``) also ``(dqr, dkr)``: the shared key's gradient is
+    summed over a visit's heads in the dk/dv pass, and over a batch row's
+    visits here."""
     bh, sq, d_p = qt.shape
-    sk = kt.shape[1]
-    static, heads = dict(plan.tile, sm_scale=sm_scale), plan.heads
+    sk, dv_p, latent = kt.shape[1], vt.shape[2], kr is not None
+    static = dict(plan.tile, sm_scale=sm_scale, latent=latent)
+    heads, kv_heads = plan.heads, plan.kv_heads
     block_q, block_k = static["block_q"], static["block_k"]
     sq_p, sk_p = plan.sq_p, plan.sk_p
     # a row that saw no key has lse = NEG_INF: lifted, so that exp(NEG_INF -
@@ -728,101 +839,149 @@ def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, *, plan: _Plan, sm_scale,
     cols = [jnp.broadcast_to(x[:, :, None], (bh, sq_p, 128)) for x in stats]
     qt, do_t = _pad_seq(qt, sq_p), _pad_seq(do_t, sq_p)
     kt, vt = _pad_seq(kt, sk_p), _pad_seq(vt, sk_p)
-    parts = bh // kt.shape[0] // heads
+    parts, batch_rows = _visit_rows(plan, qt, kt, kr)
 
-    q_block, k_block = (heads, block_q, d_p), (1, block_k, d_p)
-    share = jax.ShapeDtypeStruct(
-        (bh // heads, sk_p, d_p), kt.dtype if parts == 1 else jnp.float32)
+    q_block, do_block = (heads, block_q, d_p), (heads, block_q, dv_p)
+    k_block, v_block = (kv_heads, block_k, d_p), (kv_heads, block_k, dv_p)
+    share = lambda width, n, wide: jax.ShapeDtypeStruct(        # noqa: E731
+        (bh // heads * n, sk_p, width), kt.dtype if wide == 1 else jnp.float32)
+    shared = ()
+    if latent:
+        shared = _pad_seq(qr, sq_p), _pad_seq(kr, sk_p)
+        qr_block = (heads, block_q, qr.shape[2])
+        kr_block = (1, block_k, kr.shape[2])
+
+    def shared_specs(q_at, kr_at):      # the shared product's two operands
+        return [pl.BlockSpec(qr_block, q_at),
+                pl.BlockSpec(kr_block, kr_at)] if latent else []
 
     q_at = lambda r, v, ik, iq, flags: (r, iq[v], 0)            # noqa: E731
     row_at = lambda r, v, ik, iq, flags: (r, 0, iq[v])          # noqa: E731
     k_at = lambda r, v, ik, iq, flags: (r // parts, ik[v], 0)   # noqa: E731
+    kr_at = lambda r, v, ik, iq, flags: (                       # noqa: E731
+        r // batch_rows, ik[v], 0)
     dk_at = lambda r, v, ik, iq, flags: (r, ik[v], 0)           # noqa: E731
-    dk, dv = _walk_call(
+    outs = [(k_block, share(d_p, kv_heads, parts)),
+            (v_block, share(dv_p, kv_heads, parts))]
+    if latent:
+        outs.append((kr_block, share(kr.shape[2], 1, batch_rows)))
+    dk, dv, *dkr = _walk_call(
         functools.partial(_flash_bwd_dkv_kernel, **static),
         plan.walk_t, bh // heads, heads,
         in_specs=[
             pl.BlockSpec(q_block, q_at),
-            pl.BlockSpec(q_block, q_at),
+            pl.BlockSpec(do_block, q_at),
             pl.BlockSpec((heads, 1, block_q), row_at),
             pl.BlockSpec((heads, 1, block_q), row_at),
             pl.BlockSpec(k_block, k_at),
-            pl.BlockSpec(k_block, k_at),
+            pl.BlockSpec(v_block, k_at),
+            *shared_specs(q_at, kr_at),
         ],
-        out_specs=[pl.BlockSpec(k_block, dk_at)] * 2,
-        out_shape=[share, share],
-        scratch_shapes=[pltpu.VMEM((block_k, d_p), jnp.float32)] * 2,
+        out_specs=[pl.BlockSpec(block, dk_at) for block, _ in outs],
+        out_shape=[shape for _, shape in outs],
+        scratch_shapes=[pltpu.VMEM(block, jnp.float32) for block, _ in outs],
         interpret=interpret,
-    )(qt, do_t, *rows, kt, vt)
-    dk, dv = (x.reshape(-1, parts, sk_p, d_p).sum(1).astype(kt.dtype)
+    )(qt, do_t, *rows, kt, vt, *shared)
+    dk, dv = (x.reshape(-1, parts, sk_p, x.shape[2]).sum(1).astype(kt.dtype)
               for x in (dk, dv))
+    dkr = [x.reshape(-1, batch_rows, sk_p, x.shape[2]).sum(1).astype(kt.dtype)
+           for x in dkr]
 
     q_at = lambda r, v, iq, ik, flags: (r, iq[v], 0)            # noqa: E731
     kv_at = lambda r, v, iq, ik, flags: (r // parts, ik[v], 0)  # noqa: E731
-    dq = _walk_call(
+    kr_at = lambda r, v, iq, ik, flags: (                       # noqa: E731
+        r // batch_rows, ik[v], 0)
+    outs = [(q_block, qt)] + ([(qr_block, shared[0])] if latent else [])
+    dq, *dqr = _walk_call(
         functools.partial(_flash_bwd_dq_kernel, **static),
         plan.walk, bh // heads, heads,
         in_specs=[
             pl.BlockSpec(q_block, q_at),
-            pl.BlockSpec(q_block, q_at),
+            pl.BlockSpec(do_block, q_at),
             pl.BlockSpec((heads, block_q, 128), q_at),
             pl.BlockSpec((heads, block_q, 128), q_at),
             pl.BlockSpec(k_block, kv_at),
-            pl.BlockSpec(k_block, kv_at),
+            pl.BlockSpec(v_block, kv_at),
+            *shared_specs(q_at, kr_at),
         ],
-        out_specs=pl.BlockSpec(q_block, q_at),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
-        scratch_shapes=[pltpu.VMEM(q_block, jnp.float32)],
+        out_specs=[pl.BlockSpec(block, q_at) for block, _ in outs],
+        out_shape=[jax.ShapeDtypeStruct(like.shape, qt.dtype)
+                   for _, like in outs],
+        scratch_shapes=[pltpu.VMEM(block, jnp.float32) for block, _ in outs],
         interpret=interpret,
-    )(qt, do_t, *cols, kt, vt)
-    return dq[:, :sq], dk[:, :sk], dv[:, :sk]
+    )(qt, do_t, *cols, kt, vt, *shared)
+    return (dq[:, :sq], dk[:, :sk], dv[:, :sk],
+            *(x[:, :sq] for x in dqr), *(x[:, :sk] for x in dkr))
 
 
 def _scale(sm_scale, d: int) -> float:
     return sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
 
 
-def _flash_fwd_rule(q, k, v, causal, sm_scale, kv_offset, block_q, block_k,
-                    interpret, block_diffusion):
+def _split_shared(q, k, k_shared, interpret: bool):
+    """The head-major operands of the shared product, or two Nones: the
+    queries' columns past the keys' own width, and the shared key ``[B, S,
+    D_r]`` as the one row a batch row has."""
+    if k_shared is None:
+        return None, None
+    return (_head_major(q[..., k.shape[-1]:], interpret),
+            _head_major(k_shared[:, :, None], interpret))
+
+
+def _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, kv_offset, block_q,
+                    block_k, interpret, block_diffusion):
     # q, k, v stay head-major and lane-padded, as both backward kernels read
     # them (the backward lays out only the cotangent); the output stays as
     # the caller holds it anyway, and the kernel's log-sum-exp is kept.
-    b, _, _, d = q.shape
+    b = q.shape[0]
     with jax.named_scope("flash_fwd"):
-        qt, kt, vt = (_head_major(x, interpret) for x in (q, k, v))
-        plan = _plan(qt, kt, causal=causal, kv_offset=kv_offset,
+        qt, kt, vt = (_head_major(x, interpret)
+                      for x in (q[..., :k.shape[-1]], k, v))
+        qr, kr = _split_shared(q, k, k_shared, interpret)
+        plan = _plan(qt, kt, kr, causal=causal, kv_offset=kv_offset,
                      block_q=block_q, block_k=block_k,
                      block_diffusion=block_diffusion)
-        _count(plan, plan.walk)
-        ot, lse = _flash_fwd_pallas(qt, kt, vt, plan=plan,
-                                    sm_scale=_scale(sm_scale, d),
+        _count(plan, kr is not None, plan.walk)
+        ot, lse = _flash_fwd_pallas(qt, kt, vt, qr, kr, plan=plan,
+                                    sm_scale=_scale(sm_scale, q.shape[-1]),
                                     interpret=interpret)
-        out = _from_head_major(ot, b, d)
-        return out, (qt, kt, vt, out, lse)
+        out = _from_head_major(ot, b, v.shape[-1])
+        # the widths of the keys and of the shared key, as empty arrays: the
+        # backward reads them off shapes (the operands are lane-padded)
+        widths = (jnp.zeros((0, k.shape[-1])),
+                  jnp.zeros((0, q.shape[-1] - k.shape[-1])))
+        return out, (qt, kt, vt, qr, kr, out, lse, widths)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash_attention_tpu(q, k, v, causal, sm_scale, kv_offset,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash_attention_tpu(q, k, v, k_shared, causal, sm_scale, kv_offset,
                          block_q, block_k, interpret, block_diffusion):
-    return _flash_fwd_rule(q, k, v, causal, sm_scale, kv_offset,
+    return _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, kv_offset,
                            block_q, block_k, interpret, block_diffusion)[0]
 
 
 def _flash_bwd_rule(causal, sm_scale, kv_offset, block_q, block_k, interpret,
                     block_diffusion, res, g):
-    qt, kt, vt, out, lse = res
-    b, sq, h, d = g.shape
+    qt, kt, vt, qr, kr, out, lse, widths = res
+    b, sq, h, d_v = g.shape
+    d_k, d_r = (x.shape[1] for x in widths)
     with jax.named_scope("flash_bwd"):
         delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1).transpose(0, 2, 1).reshape(b * h, sq)
-        plan = _plan(qt, kt, causal=causal, kv_offset=kv_offset,
+        plan = _plan(qt, kt, kr, causal=causal, kv_offset=kv_offset,
                      block_q=block_q, block_k=block_k,
                      block_diffusion=block_diffusion)
-        _count(plan, plan.walk_t, plan.walk)
-        grads = _flash_bwd_pallas(
-            qt, kt, vt, _head_major(g, interpret), lse, delta, plan=plan,
-            sm_scale=_scale(sm_scale, d), interpret=interpret)
-        return tuple(_from_head_major(x, b, d) for x in grads)
+        _count(plan, kr is not None, plan.walk_t, plan.walk)
+        dq, dk, dv, *shared = _flash_bwd_pallas(
+            qt, kt, vt, _head_major(g, interpret), lse, delta, qr, kr,
+            plan=plan, sm_scale=_scale(sm_scale, d_k + d_r),
+            interpret=interpret)
+        dq, dk, dv = (_from_head_major(x, b, d)
+                      for x, d in ((dq, d_k), (dk, d_k), (dv, d_v)))
+        if kr is None:
+            return dq, dk, dv, None
+        dqr, dkr = (_from_head_major(x, b, d_r) for x in shared)
+        return jnp.concatenate([dq, dqr], axis=-1), dk, dv, dkr[:, :, 0]
 
 
 _flash_attention_tpu.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -839,9 +998,19 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: float | None = None, kv_offset: int = 0,
                     block_q: int = 512, block_k: int = 512,
                     impl: Impl | None = None,
-                    block_diffusion: tuple[int, int] | None = None):
-    """Attention, ``[B, S, H, D]`` queries in and out; ``k`` and ``v`` carry
-    ``H`` heads or a divisor of it (grouped-query heads).
+                    block_diffusion: tuple[int, int] | None = None,
+                    k_shared=None):
+    """Attention, ``[B, S, H, D]`` queries in, ``[B, S, H, D_v]`` out; ``k``
+    and ``v`` carry ``H`` heads or a divisor of it (grouped-query heads), and
+    ``v`` may be of another width than ``q`` and ``k``.
+
+    ``k_shared`` ``[B, S, D_r]`` is ONE further key head that every query
+    head meets beside its own (latent attention's rotary key, DeepSeek-V2,
+    arXiv:2405.04434): ``q`` is then ``D + D_r`` wide, ``k`` ``D``, and a
+    score is ``q[:D] · k + q[D:] · k_shared``, scaled by ``(D + D_r)^-1/2``
+    unless ``sm_scale`` says otherwise.  The kernels fetch it once a visit
+    and sum its gradient over the heads inside the visit; no copy of it a
+    head ever exists.
 
     ``block_diffusion=(length, block)`` replaces ``causal`` by the training
     mask of block diffusion over ``2 * length`` positions
@@ -854,6 +1023,12 @@ def flash_attention(q, k, v, *, causal: bool = True,
     if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
         raise ValueError(f"{q.shape[2]} query heads over {k.shape[2]} key and "
                          f"{v.shape[2]} value heads")
+    d_shared = 0 if k_shared is None else k_shared.shape[-1]
+    if q.shape[-1] != k.shape[-1] + d_shared or (
+            d_shared and k_shared.shape != k.shape[:2] + (d_shared,)):
+        raise ValueError(
+            f"queries {q.shape[-1]} wide over keys {k.shape[-1]} wide and a "
+            f"shared key of shape {getattr(k_shared, 'shape', None)}")
     if block_diffusion:
         block_diffusion = tuple(int(x) for x in block_diffusion)
         length, block = block_diffusion
@@ -869,13 +1044,16 @@ def flash_attention(q, k, v, *, causal: bool = True,
     if impl == "xla":
         return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                                    block_k=block_k, kv_offset=kv_offset,
-                                   block_diffusion=block_diffusion)
+                                   block_diffusion=block_diffusion,
+                                   k_shared=k_shared)
     if impl in ("pallas", "pallas_interpret"):
-        def kernel(q, k, v):
-            return _flash_attention_tpu(q, k, v, causal, sm_scale, kv_offset,
-                                        block_q, block_k,
+        def kernel(q, k, v, k_shared=None):
+            return _flash_attention_tpu(q, k, v, k_shared, causal, sm_scale,
+                                        kv_offset, block_q, block_k,
                                         impl == "pallas_interpret",
                                         block_diffusion)
+
+        shared = () if k_shared is None else (k_shared,)
 
         # GSPMD cannot partition a Mosaic kernel: on more than one device
         # jax refuses to lower a bare pallas_call ("Mosaic kernels cannot be
@@ -895,7 +1073,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
             # accepts nothing less); the ones the spec does not name see
             # q/k/v replicated and compute the same shard redundantly
             kernel = jax.shard_map(
-                kernel, in_specs=(spec, spec, spec), out_specs=spec,
+                kernel, in_specs=(spec, spec, spec) + (P(*spec[:2], None),)
+                * len(shared), out_specs=spec,
                 axis_names=frozenset(auto), check_vma=False)
-        return kernel(q, k, v)
+        return kernel(q, k, v, *shared)
     raise ValueError(f"unknown attention impl {impl!r}")

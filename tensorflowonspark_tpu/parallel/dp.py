@@ -16,7 +16,8 @@ Usage::
     for batch in feed:
         state, metrics = step(state, shard_batch(mesh, batch))
 
-``loss_fn(params, batch) -> (loss, aux_metrics)`` is the user contract.
+``loss_fn(params, batch) -> (loss, aux_metrics)`` is the user contract
+(``loss_fn(params, batch, buffers)`` where ``TrainState.buffers`` is set).
 """
 
 from __future__ import annotations
@@ -32,15 +33,24 @@ from tensorflowonspark_tpu.parallel.mesh import batch_sharding, replicated
 
 
 class TrainState(NamedTuple):
-    """Minimal functional train state (params + optimizer state + step)."""
+    """Minimal functional train state (params + optimizer state + step).
+
+    ``buffers`` is what the model reads beside its parameters and nobody
+    trains (a flax collection of that name: a router's selection bias,
+    ``parallel/ep.MoEMLP``): the step hands it to the loss, takes no
+    gradient by it, shows it to no optimizer and passes it on as it is.
+    ``None``: the model has none."""
 
     params: Any
     opt_state: Any
     step: jax.Array
+    buffers: Any = None
 
     @classmethod
-    def create(cls, params: Any, optimizer: optax.GradientTransformation) -> "TrainState":
-        return cls(params=params, opt_state=optimizer.init(params), step=jnp.zeros((), jnp.int32))
+    def create(cls, params: Any, optimizer: optax.GradientTransformation,
+               buffers: Any = None) -> "TrainState":
+        return cls(params=params, opt_state=optimizer.init(params),
+                   step=jnp.zeros((), jnp.int32), buffers=buffers)
 
 
 def replicate(tree: Any, mesh) -> Any:
@@ -104,11 +114,14 @@ def make_train_step(
     single-program step byte-for-byte as before.
     """
 
-    def grads_and_metrics(params: Any, batch: Any) -> tuple[Any, dict]:
+    def grads_and_metrics(params: Any, batch: Any,
+                          buffers: Any = None) -> tuple[Any, dict]:
+        fn = loss_fn if buffers is None else (
+            lambda params, batch: loss_fn(params, batch, buffers))
         if accum_steps == 1:
             with jax.named_scope("loss_and_grad"):
                 (loss, aux), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params, batch)
+                    fn, has_aux=True)(params, batch)
             return grads, {"loss": loss, **aux}
         micro = jax.tree.map(
             lambda x: x.reshape(accum_steps, x.shape[0] // accum_steps,
@@ -117,7 +130,7 @@ def make_train_step(
         def body(carry, mb):
             grads_acc, metrics_acc = carry
             with jax.named_scope("loss_and_grad"):
-                (l, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                (l, aux), g = jax.value_and_grad(fn, has_aux=True)(
                     params, mb)
             m = {"loss": l, **aux}
             return (jax.tree.map(jnp.add, grads_acc, g),
@@ -126,7 +139,7 @@ def make_train_step(
         # Carry structure from an abstract eval — loss_fn is traced once
         # (inside the scan body), not twice.
         loss_sd, aux_sd = jax.eval_shape(
-            loss_fn, params, jax.tree.map(lambda x: x[0], micro))
+            fn, params, jax.tree.map(lambda x: x[0], micro))
         zeros = lambda sd: jnp.zeros(sd.shape, sd.dtype)  # noqa: E731
         init = (jax.tree.map(jnp.zeros_like, params),
                 jax.tree.map(zeros, {"loss": loss_sd, **aux_sd}))
@@ -140,11 +153,12 @@ def make_train_step(
             updates, opt_state = optimizer.update(grads, state.opt_state,
                                                   state.params)
             params = optax.apply_updates(state.params, updates)
-        return TrainState(params, opt_state, state.step + 1)
+        return TrainState(params, opt_state, state.step + 1, state.buffers)
 
     if cross_host_grad_fn is None:
         def step(state: TrainState, batch: Any) -> tuple[TrainState, dict]:
-            grads, metrics = grads_and_metrics(state.params, batch)
+            grads, metrics = grads_and_metrics(state.params, batch,
+                                               state.buffers)
             return apply_update(state, grads), metrics
 
         # Shardings are inferred from operand placement (replicated params +
@@ -155,7 +169,7 @@ def make_train_step(
     apply_step = jax.jit(apply_update, donate_argnums=(0,) if donate else ())
 
     def hooked_step(state: TrainState, batch: Any) -> tuple[TrainState, dict]:
-        grads, metrics = grad_step(state.params, batch)
+        grads, metrics = grad_step(state.params, batch, state.buffers)
         grads = cross_host_grad_fn(grads)
         return apply_step(state, grads), metrics
 

@@ -329,6 +329,20 @@ class MoEMLP(nn.Module):
     (module docstring): the router and the routing stay ``n_experts`` wide,
     the expert weights are ``[end - first, ...]`` and the output is the held
     experts' part of the sum.  Dropless routing only.
+
+    The router of DeepSeek-V3 (arXiv:2412.19437; transformers'
+    ``deepseek_v3``): ``scoring="sigmoid"`` scores an expert by the sigmoid
+    of its logit, on its own; ``selection_bias`` adds a bias a expert
+    (``e_score_correction_bias``) to the scores FOR THE CHOICE of the top k
+    only, the weights being the UNBIASED scores of the chosen, renormalised
+    (``norm_topk_prob``: over their sum + 1e-20) and multiplied by
+    ``routed_scale``.  The bias is a BUFFER, a variable of the collection
+    ``buffers`` beside ``params``: the loss is not differentiated by it and
+    no optimizer is handed it (``parallel/dp.TrainState.buffers`` carries
+    the collection through a step as it is); balance is its business, so
+    neither the load-balance nor the z term is sown under it, and
+    ``moe_stats/bias_moved`` is the share of the (token, expert) choices
+    that the unbiased scores would not have made.
     """
 
     d_model: int
@@ -339,6 +353,9 @@ class MoEMLP(nn.Module):
     compute_dtype: jnp.dtype = jnp.float32
     norm_topk_prob: bool = True
     held: Optional[tuple] = None
+    scoring: str = "softmax"
+    selection_bias: bool = False
+    routed_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -352,31 +369,52 @@ class MoEMLP(nn.Module):
                 f"held={self.held} of {e} experts, capacity_factor="
                 f"{self.capacity_factor}: a chip's share is a range of the "
                 "layer's experts under dropless routing")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring={self.scoring!r}")
         xf = x.reshape(n, d)
 
         with jax.named_scope("moe/router"):
             router = nn.Dense(e, use_bias=False, name="router",
                               dtype=jnp.float32)  # routing always f32
             router_logits = router(xf.astype(jnp.float32))
-            probs = jax.nn.softmax(router_logits, axis=-1)
-            top_p, top_idx = jax.lax.top_k(probs, self.top_k)     # [n, k]
+            probs = (jax.nn.sigmoid(router_logits)
+                     if self.scoring == "sigmoid"
+                     else jax.nn.softmax(router_logits, axis=-1))
+            if self.selection_bias:
+                bias = self.variable(
+                    "buffers", "e_score_correction_bias",
+                    lambda: jnp.zeros((e,), jnp.float32)).value
+                _, top_idx = jax.lax.top_k(probs + bias, self.top_k)
+                top_p = jnp.take_along_axis(probs, top_idx, axis=-1)
+                # a choice the unbiased scores would not have made: k or
+                # more experts score higher than the chosen one
+                higher = jnp.sum(probs[:, None, :] > top_p[:, :, None], -1)
+                self.sow("moe_stats", "bias_moved",
+                         jnp.mean(higher >= self.top_k))
+            else:
+                top_p, top_idx = jax.lax.top_k(probs, self.top_k)  # [n, k]
             if self.norm_topk_prob:
-                top_p = top_p / jnp.maximum(
-                    jnp.sum(top_p, -1, keepdims=True), 1e-9)
+                top_p = top_p / (
+                    jnp.sum(top_p, -1, keepdims=True) + 1e-20
+                    if self.scoring == "sigmoid" else
+                    jnp.maximum(jnp.sum(top_p, -1, keepdims=True), 1e-9))
+            if self.routed_scale != 1.0:
+                top_p = top_p * self.routed_scale
 
-            # Load-balancing aux loss, e · Σ_e f_e · P_e: f_e the share of
-            # tokens whose FIRST choice is e (Switch eq. 4) or, dropless,
-            # the pairs routed to e per token (all k choices).
             pairs = jnp.sum(jax.nn.one_hot(top_idx, e, dtype=jnp.int32),
                             axis=(0, 1))                           # [e]
-            counted = pairs if dropless else jnp.sum(
-                jax.nn.one_hot(top_idx[:, 0], e, dtype=jnp.float32), axis=0)
-            frac_probs = jnp.mean(probs, axis=0)
-            self.sow("aux_loss", "load_balance",
-                     e * jnp.sum(counted / n * frac_probs))
-            # Router z-loss (ST-MoE): keeps router logits bounded.
-            z = jax.scipy.special.logsumexp(router_logits, axis=-1)
-            self.sow("aux_loss", "router_z", jnp.mean(z * z))
+            if not self.selection_bias:
+                # Load-balancing aux loss, e · Σ_e f_e · P_e: f_e the share
+                # of tokens whose FIRST choice is e (Switch eq. 4) or,
+                # dropless, the pairs routed to e per token (all k choices).
+                counted = pairs if dropless else jnp.sum(jax.nn.one_hot(
+                    top_idx[:, 0], e, dtype=jnp.float32), axis=0)
+                frac_probs = jnp.mean(probs, axis=0)
+                self.sow("aux_loss", "load_balance",
+                         e * jnp.sum(counted / n * frac_probs))
+                # Router z-loss (ST-MoE): keeps router logits bounded.
+                z = jax.scipy.special.logsumexp(router_logits, axis=-1)
+                self.sow("aux_loss", "router_z", jnp.mean(z * z))
             mean_pairs = n * self.top_k / e
             self.sow("moe_stats", "max_load", jnp.max(pairs) / mean_pairs)
             self.sow("moe_stats", "min_load", jnp.min(pairs) / mean_pairs)

@@ -27,11 +27,11 @@ def _lm_step_text(**model_overrides) -> str:
                             "d_model": 32, "n_layers": 1, "n_heads": 2,
                             "d_ff": 64, **model_overrides})
     ids = jnp.zeros((2, 16), jnp.int32)
-    params = jax.eval_shape(
-        lambda: model.init(jax.random.key(0), ids)["params"])
+    variables = jax.eval_shape(lambda: model.init(jax.random.key(0), ids))
     optimizer = optax.adamw(1e-3)
-    state = jax.eval_shape(lambda p: dp.TrainState.create(p, optimizer),
-                           params)
+    state = jax.eval_shape(
+        lambda p, b: dp.TrainState.create(p, optimizer, b),
+        variables["params"], variables.get("buffers"))
     step = dp.make_train_step(transformer.make_loss_fn(model), optimizer)
     return _hlo_op_names(step.lower(state, {"input_ids": ids}))
 
@@ -61,9 +61,37 @@ def test_moe_step_names_router_dispatch_experts_combine_and_qk_norm(capacity):
         assert "/moe/experts/while" not in text
 
 
+def test_latent_step_names_its_projections_the_shared_expert_and_router():
+    """ISSUE 39: latent attention's projections (both halves of the layer:
+    what feeds the kernels and the output projection), the flash kernels
+    under it, the shared expert and the router, forward and backward (the
+    readers in ``benchmark/layer_metrics/mla_*.py``, ``moe_shared_ms`` and
+    ``moe_router_ms`` sum device time by these)."""
+    text = _lm_step_text(
+        n_layers=2, n_heads=4, n_experts=4, moe_top_k=2,
+        moe_capacity_factor=None, attn_impl="pallas_interpret",
+        latent_attention={"kv_lora_rank": 16, "qk_nope_head_dim": 8,
+                          "qk_rope_head_dim": 4, "v_head_dim": 8},
+        moe_router={"scoring": "sigmoid", "selection_bias": True,
+                    "routed_scale": 2.448},
+        moe_shared_d_ff=32, layer_ffn=[48, 0])
+    for scope in ("mla/project", "flash_fwd", "flash_bwd", "moe/shared",
+                  "moe/router", "moe/dispatch", "moe/experts"):
+        assert f"/{scope}/" in text, scope
+        backward = [line for line in text.split("jit(step)")
+                    if f"/{scope}/" in line and "transpose(" in line]
+        assert backward or scope in ("flash_fwd", "flash_bwd"), scope
+    for name in ("q_proj", "kv_a_proj", "kv_b_proj", "o_proj"):
+        assert f"/mla/project/{name}/" in text, name
+    # the leading layer is dense: its FFN is no expert's
+    assert "/block_0/mlp/" in text and "/block_0/moe/" not in text
+    assert "/block_1/moe/" in text
+
+
 def test_a_dense_step_has_no_moe_scope():
     text = _lm_step_text(attn_impl="xla")
     assert "/moe/" not in text and "/qk_norm/" not in text
+    assert "/mla/" not in text
 
 
 def test_fused_head_loss_is_named_too():

@@ -71,6 +71,40 @@ def test_all_three_flash_kernels_lower_at_sdar_widths(one_chip, case):
     assert hlo.count('custom_call_target="tpu_custom_call"') == 3
 
 
+def test_the_latent_kernels_lower_at_kanana_widths(one_chip):
+    """Kanana-2's latent attention on one 8,192-token row (ISSUE 39): 32
+    query heads of 128 + 64 over per-head keys and values of 128 and ONE
+    rotary key of 64.  What interpret mode cannot show: that Mosaic takes a
+    visit of several (query, K/V) heads beside one block of the shared key
+    (64 columns padded to 128 lanes), its second product, and the dk/dv
+    pass's third accumulator."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from tensorflowonspark_tpu.ops.attention import flash_attention
+
+    shape = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.bfloat16, sharding=one_chip)
+    q, k, k_shared = (shape(1, 8192, 32, 192), shape(1, 8192, 32, 128),
+                      shape(1, 8192, 64))
+
+    def loss(q, k, v, k_shared):
+        out = flash_attention(q, k, v, k_shared=k_shared, impl="pallas")
+        return jnp.sum(out.astype(jnp.float32))
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+            q, k, k, k_shared).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    # forward, dk/dv pass, dq pass
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+
+
 @pytest.mark.parametrize("step", ["select", "attend", "index_loss"])
 def test_the_sparse_attention_kernels_lower_at_keye_widths(one_chip, step):
     """``ops/sparse_attention.py`` on one 16,384-token row at Keye-VL-2.0's
