@@ -6,9 +6,12 @@ this build, so the hot op gets a real TPU kernel:
 
 - ``flash_attention`` — public entry.  On TPU it runs Pallas kernels in both
   directions: an online-softmax forward that keeps its log-sum-exp, and a
-  backward of two passes over the same tiles (dk/dv with the q blocks
-  sequential, dq with the KV blocks sequential) that recompute each tile's
-  softmax weights from it.  No kernel has a dense (q tile, k tile) grid:
+  backward of ONE pass over the same tiles that recomputes each tile's
+  softmax weights from it once and takes all three gradients from them, dk
+  and dv resident in VMEM over the whole key length (where they do not fit:
+  two passes, dk/dv with the q blocks sequential, dq with the KV blocks
+  sequential; ``_plan`` decides from the shapes).  No kernel has a dense (q
+  tile, k tile) grid:
   each walks a trace-time table of the LIVE tiles of its mask, and a visit
   of the walk is one live tile for one K/V head and the query heads of its
   group (``_visit_heads``).  Elsewhere it lowers to ``blockwise_attention``
@@ -238,10 +241,12 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
 # ---------------------------------------------------------------------------
 # Pallas TPU kernels — forward (online softmax over the live k blocks of a q
-# block) and backward (a dk/dv pass and a dq pass on the forward's lse).
+# block) and backward (one pass on the forward's lse; a dk/dv pass and a dq
+# pass where one pass's accumulators do not fit).
 # ---------------------------------------------------------------------------
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T: contract the last dim of both
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b: contract the first dim of both
 
 
 def _block_diffusion_tile(q_lo, q_hi, k_lo, k_hi, length: int, block: int):
@@ -330,7 +335,9 @@ def _tile_visible(shape, q_dim: int, *, q_start, k_start, causal: bool,
 # first / the last of its output block (initialise / write the block), and
 # its tile is masked (some pair of it is visible: the mask is built) or
 # interior (every pair is: none is).  A visit with neither attends nothing.
-_FIRST, _LAST, _MASKED, _INTERIOR = 1, 2, 4, 8
+# The first and the last visit of the whole table open and close a grid row:
+# what is resident over the row is zeroed there and written there.
+_FIRST, _LAST, _MASKED, _INTERIOR, _OPEN, _CLOSE = 1, 2, 4, 8, 16, 32
 
 
 def _tile_kinds(nq: int, nk: int, *, block_q: int, block_k: int,
@@ -354,7 +361,8 @@ def _walk(kinds):
     serves the query heads of a K/V group together (``_visit_heads``).  A
     block with no live tile gets ONE visit, which attends nothing, so that
     its zeros are written.  A dead tile is no visit: no grid step and no
-    fetch.
+    fetch.  The table's first visit also carries ``_OPEN`` and its last
+    ``_CLOSE``.
 
     The rows are the kernel's scalar-prefetch operands (SMEM, 4 bytes a
     visit each): at SDAR's 16 x 16 tiles 80 visits in all three kernels; a
@@ -366,29 +374,51 @@ def _walk(kinds):
         run[0][2] |= _FIRST
         run[-1][2] |= _LAST
         visits += run
+    visits[0][2] |= _OPEN
+    visits[-1][2] |= _CLOSE
     return np.asarray(visits, np.int32).T
 
 
-# A visit of several heads outgrows Mosaic's default VMEM scope of 16 MiB: its
-# kernels get _VMEM_LIMIT (of a v5e's 128 MiB), of which _VMEM_BLOCKS are for
-# the visit's blocks and scratch and the rest for the tile body's score tiles.
-# A visit of one head keeps the default scope: under the raised one the same
-# three kernels ran 4-14% slower (PERF.md §6, PR 36).
+# A visit of several heads outgrows Mosaic's default VMEM scope of 16 MiB
+# (_VMEM_DEFAULT): its kernels get _VMEM_LIMIT (of a v5e's 128 MiB), of which
+# _VMEM_BLOCKS are for the visit's blocks and scratch and the rest for the
+# tile body's score tiles.  A visit of one head keeps the default scope: under
+# the raised one the forward and the two passes ran 4-14% slower (PERF.md §6,
+# PR 36).
+#
+# The one-pass backward also keeps dk and dv (and the shared key's gradient)
+# of its grid row resident over the WHOLE padded key length: float32
+# accumulators beside the output blocks they are written to once a row, which
+# the pipeline holds twice.  Blocks and resident set together may take the
+# limit less _VMEM_BODY (SDAR's 8,192 positions of 128 + 128 under 8 heads of
+# blocks: 16 + 16 MiB; Mosaic's own count came out 2.5 MiB over this sum's
+# parts there and under the sum for a shared key); what does not fit takes the
+# two passes.  One head a visit stays in the default scope while the sum
+# leaves the body's room there too (the dense LM's 2,048 positions: 4 + 2 MiB;
+# the one-pass kernel ran alike under either scope, PERF.md §6, PR 40).
+_VMEM_DEFAULT = 16 << 20
 _VMEM_LIMIT = 96 << 20
 _VMEM_BLOCKS = 24 << 20
+_VMEM_BODY = 6 << 20
+
+
+def _head_bytes(block_q: int, d_p: int, itemsize: int, more: int = 0) -> int:
+    """What one query head of a visit holds in the kernel that holds most,
+    the dq pass: q, dO and dq double-buffered, the two float32 column blocks
+    of 128 lanes likewise, the float32 accumulator, and ``more`` bytes of
+    what else it holds (the one-pass backward holds the same less the column
+    blocks)."""
+    tile = block_q * d_p
+    return 6 * tile * itemsize + 4 * block_q * 128 * 4 + tile * 4 + more
 
 
 def _visit_heads(group: int, block_q: int, d_p: int, itemsize: int,
                  more: int = 0) -> int:
     """How many query heads of a K/V group one visit serves: the largest
-    divisor of ``group`` whose query-side blocks and scratch fit
-    ``_VMEM_BLOCKS`` in the kernel that holds most a head, the dq pass (q, dO
-    and dq double-buffered, the two float32 column blocks of 128 lanes
-    likewise, the float32 accumulator, and ``more`` bytes a head of what
-    else it holds).  8 heads of 512 x 128 in bf16 hold 16 MiB; a group of 32
-    is served in several visits a tile."""
-    tile = block_q * d_p
-    a_head = 6 * tile * itemsize + 4 * block_q * 128 * 4 + tile * 4 + more
+    divisor of ``group`` whose query-side blocks and scratch
+    (``_head_bytes``) fit ``_VMEM_BLOCKS``.  8 heads of 512 x 128 in bf16
+    hold 16 MiB; a group of 32 is served in several visits a tile."""
+    a_head = _head_bytes(block_q, d_p, itemsize, more)
     return max(heads for heads in range(1, group + 1)
                if group % heads == 0
                and (heads == 1 or heads * a_head <= _VMEM_BLOCKS))
@@ -404,19 +434,33 @@ class _Plan(NamedTuple):
     heads: int          # query heads a visit serves
     kv_heads: int       # K/V heads a visit holds: 1, or one a query head
     dense: int          # tiles of the dense grid
-    walk: tuple         # rows (iq, ik, flags): the forward's and the dq pass's
+    walk: tuple         # rows (iq, ik, flags): the forward's, the one-pass
+    #                     backward's and the dq pass's
     walk_t: tuple       # rows (ik, iq, flags): the dk/dv pass's
+    fused: int          # query heads a visit of the one-pass backward serves;
+    #                     0: its resident set does not fit, the two passes run
+    fused_limit: int | None     # that kernel's VMEM limit (None: the default)
 
 
-def _plan(qt, kt, kr=None, *, causal, kv_offset, block_q, block_k,
+def _plan(qt, kt, vt, kr=None, *, causal, kv_offset, block_q, block_k,
           block_diffusion) -> _Plan:
-    """The plan of the kernels over head-major ``qt`` and ``kt``: numpy and
-    integers only, worked out in each rule of the VJP (which counts by it).
+    """The plan of the kernels over head-major ``qt``, ``kt`` and ``vt``:
+    numpy and integers only, worked out in each rule of the VJP (which counts
+    by it).
 
     Where every query head has K and V of its own AND all meet one shared
     key ``kr`` (latent attention), a visit serves several (query, K/V) heads
     of one row beside one fetch of the shared key's block, and sums the
-    shared key's gradient over them in the visit."""
+    shared key's gradient over them in the visit.
+
+    The backward is ONE pass where a visit's blocks and the gradients of a
+    grid row's K/V heads (and of the shared key) over the whole padded key
+    length leave the tile body its room in the VMEM limit: float32
+    accumulators, and the output blocks twice (the pipeline's two buffers;
+    float32 too where several grid rows share a K/V head and their shares
+    are added outside).  With K and V of its own a head, fewer heads a visit
+    hold less: the largest divisor of the forward's that fits.  Nothing fits
+    a 128k row: ``fused`` is 0 and the two passes run."""
     bh, sq, d_p = qt.shape
     sk = kt.shape[1]
     group = bh // kt.shape[0]
@@ -426,42 +470,67 @@ def _plan(qt, kt, kr=None, *, causal, kv_offset, block_q, block_k,
     kinds = _tile_kinds(sq_p // block_q, sk_p // block_k, **tile)
     rows = lambda table: tuple(map(tuple, table.tolist()))      # noqa: E731
     size = qt.dtype.itemsize
-    if kr is not None and group == 1:
+    own = kr is not None and group == 1     # K and V of its own a query head
+    more = 0
+    if own:
         # beside the query side: the shared product's q, dq (double-buffered)
         # and accumulator, and the head's own K and V blocks
         shared = block_q * kr.shape[-1]
-        heads = kv_heads = _visit_heads(
-            bh // kr.shape[0], block_q, d_p, size,
-            4 * shared * size + shared * 4 + 4 * block_k * d_p * size)
-    else:
-        heads, kv_heads = _visit_heads(group, block_q, d_p, size), 1
+        group = bh // kr.shape[0]
+        more = 4 * shared * size + shared * 4 + 4 * block_k * d_p * size
+    heads = _visit_heads(group, block_q, d_p, size, more)
+    kv_heads = heads if own else 1
+
+    def one_pass(visit: int) -> int:
+        """Bytes of the one-pass backward at ``visit`` heads a visit: their
+        blocks, and accumulator and two output buffers of every gradient
+        that is resident over the grid row."""
+        out = lambda rows: 4 + 2 * (size if rows == 1 else 4)   # noqa: E731
+        own_width = sk_p * (d_p + vt.shape[2])
+        if own:
+            resident = (visit * own_width * out(1)
+                        + sk_p * kr.shape[-1] * out(group // visit))
+        else:
+            resident = own_width * out(group // visit)
+            if kr is not None:
+                resident += sk_p * kr.shape[-1] * out(
+                    bh // kr.shape[0] // visit)
+        return visit * _head_bytes(block_q, d_p, size, more) + resident
+
+    fused = max((visit for visit in range(1, heads + 1)
+                 if heads % visit == 0 and (own or visit == heads)
+                 and one_pass(visit) <= _VMEM_LIMIT - _VMEM_BODY), default=0)
+    default = fused == 1 and one_pass(1) <= _VMEM_DEFAULT - _VMEM_BODY
     return _Plan(tuple(tile.items()), sq_p, sk_p, heads, kv_heads,
-                 kinds.size, rows(_walk(kinds)), rows(_walk(kinds.T)))
+                 kinds.size, rows(_walk(kinds)), rows(_walk(kinds.T)),
+                 fused, None if default or not fused else _VMEM_LIMIT)
 
 
-def _count(plan: _Plan, latent: bool, *tables) -> None:
+def _count(plan: _Plan, latent: bool, heads: int, *tables) -> None:
     """For the run report, once for each kernel a traced program holds (the
-    check's included): ``flash.tiles_walked`` over ``flash.tiles`` is the
-    share of the dense grid's steps that the walks keep,
-    ``flash.visit_heads`` over ``flash.kernels`` the query heads a visit
-    serves.  Of these, the kernels that take a shared key are counted again
-    as ``flash.latent_kernels`` and ``flash.latent_visit_heads``."""
+    check's included; ``heads`` query heads a visit, one table a kernel):
+    ``flash.tiles_walked`` over ``flash.tiles`` is the share of the dense
+    grid's steps that the walks keep (the one-pass backward is ONE kernel
+    over one table), ``flash.visit_heads`` over ``flash.kernels`` the query
+    heads a visit serves.  Of these, the kernels that take a shared key are
+    counted again as ``flash.latent_kernels`` and
+    ``flash.latent_visit_heads``."""
     for table in tables:
         telemetry.counter("flash.kernels").inc()
-        telemetry.counter("flash.visit_heads").inc(plan.heads)
+        telemetry.counter("flash.visit_heads").inc(heads)
         telemetry.counter("flash.tiles").inc(plan.dense)
         telemetry.counter("flash.tiles_walked").inc(len(table[0]))
         if latent:
             telemetry.counter("flash.latent_kernels").inc()
-            telemetry.counter("flash.latent_visit_heads").inc(plan.heads)
+            telemetry.counter("flash.latent_visit_heads").inc(heads)
 
 
-def _walk_call(kernel, table, rows: int, heads: int, *, out_shape,
-               interpret: bool, **specs):
+def _walk_call(kernel, table, rows: int, vmem_limit: int | None, *,
+               out_shape, interpret: bool, **specs):
     """``kernel`` over the grid ``(rows, visits)``: the walk of ``table`` for
-    each grid row (``heads`` query heads that share a K/V head); the index
-    maps and the kernel read the table's rows from SMEM.  The kernel is told
-    which flags EVERY visit carries and which ANY does (``_visit``).
+    each grid row (a visit's query heads); the index maps and the kernel
+    read the table's rows from SMEM.  The kernel is told which flags EVERY
+    visit carries and which ANY does (``_visit``).
 
     v5e has one TensorCore: the q (or k) block axis, which could run in
     parallel, loses nothing by being folded into the sequential walk."""
@@ -476,19 +545,21 @@ def _walk_call(kernel, table, rows: int, heads: int, *, out_shape,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT if heads > 1 else None),
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret)
     return functools.partial(call, *(jnp.asarray(row) for row in table))
 
 
 def _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, *,
            block_q: int, block_k: int, kv_offset: int, every: int, some: int,
-           **mask_args):
+           open_row=None, close_row=None, **mask_args):
     """This grid step's visit of the walk, as its flags say: ``init()`` on
     the first visit of an output block, ``attend(visible)`` on a live tile
     (with None on an interior one, else with the tile's mask as a function
     of the score tile's shape and the dimension its queries lie along),
-    ``finalize()`` on the block's last.  A flag that ``every`` visit of the
+    ``finalize()`` on the block's last; around them ``open_row()`` on the
+    table's first visit and ``close_row()`` on its last, where a kernel keeps
+    something over the whole grid row.  A flag that ``every`` visit of the
     table carries is no branch, and one that not even ``some`` do is no
     code: rows of one tile (the walk's shortest) run straight through."""
     visit = pl.program_id(1)
@@ -500,6 +571,8 @@ def _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, *,
         elif some & flag:
             pl.when((flags & flag) != 0)(run)
 
+    if open_row is not None:
+        on(_OPEN, open_row)
     on(_FIRST, init)
     on(_MASKED, lambda: attend(functools.partial(
         _tile_visible, q_start=iq_ref[visit] * block_q,
@@ -507,6 +580,8 @@ def _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, *,
         **mask_args)))
     on(_INTERIOR, lambda: attend(None))
     on(_LAST, finalize)
+    if close_row is not None:
+        on(_CLOSE, close_row)
 
 
 def _lanes(x, width: int):
@@ -551,15 +626,17 @@ def _kv_of(k_ref, v_ref):
     return lambda h: (k_ref[h], v_ref[h])
 
 
-# A VISIT of the three kernels is one live tile for one K/V head and the
-# query heads of its group that share a grid row (``_visit_heads``: all of
-# them where they fit): the query-side blocks are ``[heads, block, d]``, K and
-# V are fetched once a visit, a masked tile's bias is built once, and the
-# heads are a static loop inside the visit.  With one query head a K/V head
-# the loop has one turn.  Under a shared key (``latent``) the refs ``qr`` (the
-# queries' columns that meet it) and ``kr`` (its block, one fetch a visit)
-# follow q, k and v, every head of the visit has a K/V block of its own, and
-# the backward passes write dqr and the visit's sum of dkr.
+# A VISIT of the kernels (the forward, the one-pass backward, and the two
+# passes that run where its accumulators do not fit) is one live tile for one
+# K/V head and the query heads of its group that share a grid row
+# (``_visit_heads``: all of them where they fit): the query-side blocks are
+# ``[heads, block, d]``, K and V are fetched once a visit, a masked tile's
+# bias is built once, and the heads are a static loop inside the visit.  With
+# one query head a K/V head the loop has one turn.  Under a shared key
+# (``latent``) the refs ``qr`` (the queries' columns that meet it) and ``kr``
+# (its block, one fetch a visit) follow q, k and v, every head of the visit
+# has a K/V block of its own, and the backward writes dqr and the visit's sum
+# of dkr.
 
 def _flash_fwd_kernel(iq_ref, ik_ref, flags_ref, q_ref, k_ref, v_ref, *refs,
                       sm_scale: float, latent: bool, **walk):
@@ -649,13 +726,13 @@ def _pad_seq(x, s_p: int):
 _STATIC = ("plan", "sm_scale", "interpret")
 
 
-def _visit_rows(plan: _Plan, qt, kt, kr):
-    """How a kernel's grid rows (``plan.heads`` query heads each) meet the
-    rows of the other operands: ``parts`` grid rows share one block of
-    ``plan.kv_heads`` K/V heads, and ``batch_rows`` of them are one batch
-    row's and meet its shared key (without one: all of them)."""
-    rows = qt.shape[0] // plan.heads
-    return (rows * plan.kv_heads // kt.shape[0],
+def _visit_rows(heads: int, kv_heads: int, qt, kt, kr):
+    """How a kernel's grid rows (``heads`` query heads each) meet the rows
+    of the other operands: ``parts`` grid rows share one block of
+    ``kv_heads`` K/V heads, and ``batch_rows`` of them are one batch row's
+    and meet its shared key (without one: all of them)."""
+    rows = qt.shape[0] // heads
+    return (rows * kv_heads // kt.shape[0],
             rows // (1 if kr is None else kr.shape[0]))
 
 
@@ -675,7 +752,7 @@ def _flash_fwd_pallas(qt, kt, vt, qr=None, kr=None, *, plan: _Plan, sm_scale,
     block_q, block_k, sq_p = static["block_q"], static["block_k"], plan.sq_p
     qt = _pad_seq(qt, sq_p)
     kt, vt = _pad_seq(kt, plan.sk_p), _pad_seq(vt, plan.sk_p)
-    parts, batch_rows = _visit_rows(plan, qt, kt, kr)
+    parts, batch_rows = _visit_rows(heads, plan.kv_heads, qt, kt, kr)
     shared, shared_specs = (), []
     if latent:
         shared = _pad_seq(qr, sq_p), _pad_seq(kr, plan.sk_p)
@@ -691,7 +768,7 @@ def _flash_fwd_pallas(qt, kt, vt, qr=None, kr=None, *, plan: _Plan, sm_scale,
     out, lse = _walk_call(
         functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
                           latent=latent, **static),
-        plan.walk, bh // heads, heads,
+        plan.walk, bh // heads, _VMEM_LIMIT if heads > 1 else None,
         in_specs=[
             pl.BlockSpec((heads, block_q, d_p), q_at),
             pl.BlockSpec((plan.kv_heads, block_k, d_p), kv_at),
@@ -813,103 +890,201 @@ def _flash_bwd_dq_kernel(iq_ref, ik_ref, flags_ref,
     _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize, **walk)
 
 
+def _flash_bwd_kernel(iq_ref, ik_ref, flags_ref,
+                      q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *refs,
+                      sm_scale: float, latent: bool, **walk):
+    # ONE pass: a live tile of the q-major walk and, for every head of the
+    # visit, its scores and dP ONCE, on the TRANSPOSED tile [block_k, block_q]
+    # as the dk/dv pass has it: lse and delta are rows, lane-dense as they lie
+    # in memory, dv += p_t dO and dk += ds_t q are plain matmuls, and dq +=
+    # ds_t^T k is the one product that contracts over the tile's other side.
+    # dq (and dqr) accumulate over a q block's visits, as in the dq pass; dk
+    # and dv (and dkr, summed over the visit's heads) accumulate over the
+    # WHOLE key length of the grid row's K/V heads, at the tile's rows: zeroed
+    # where the row opens, scaled, cast and written where it closes.  The
+    # weights come from the forward's log-sum-exp (see the dk/dv pass).
+    if latent:
+        (qr_ref, kr_ref, dq_ref, dk_ref, dv_ref, dqr_ref, dkr_ref,
+         dq_acc, dk_acc, dv_acc, dqr_acc, dkr_acc) = refs
+    else:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+    heads, block_q, _ = q_ref.shape
+    kv_heads, block_k, _ = k_ref.shape
+    # the tile's rows of the resident accumulators
+    keys = pl.ds(pl.multiple_of(ik_ref[pl.program_id(1)] * block_k, block_k),
+                 block_k)
+
+    def open_row():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+        if latent:
+            dkr_acc[...] = jnp.zeros_like(dkr_acc)
+
+    def init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        if latent:
+            dqr_acc[...] = jnp.zeros_like(dqr_acc)
+
+    def attend(visible):
+        kv = _kv_of(k_ref, v_ref)
+        bias = _bias(visible, (block_k, block_q), 1)
+        for h in range(heads):
+            q, do = q_ref[h], do_ref[h]                # [block_q, d]
+            (k, v), at = kv(h), h % kv_heads
+            p_t = jnp.exp(_scores(
+                k, q, sm_scale, bias,
+                (kr_ref[0], qr_ref[h]) if latent else None) - lse_ref[h])
+            dv_acc[at, keys] += jnp.dot(p_t.astype(do.dtype), do,
+                                        preferred_element_type=jnp.float32)
+            dp_t = jax.lax.dot_general(
+                v, do, _NT, preferred_element_type=jnp.float32)
+            ds_t = (p_t * (dp_t - delta_ref[h])).astype(q.dtype)
+            dk_acc[at, keys] += jnp.dot(ds_t, q,
+                                        preferred_element_type=jnp.float32)
+            dq_acc[h] += jax.lax.dot_general(
+                ds_t, k, _TN, preferred_element_type=jnp.float32)
+            if latent:
+                dkr_acc[0, keys] += jnp.dot(
+                    ds_t, qr_ref[h], preferred_element_type=jnp.float32)
+                dqr_acc[h] += jax.lax.dot_general(
+                    ds_t, kr_ref[0], _TN, preferred_element_type=jnp.float32)
+
+    def finalize():
+        dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+        if latent:
+            dqr_ref[...] = (dqr_acc[...] * sm_scale).astype(dqr_ref.dtype)
+
+    def close_row():
+        dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        if latent:
+            dkr_ref[...] = (dkr_acc[...] * sm_scale).astype(dkr_ref.dtype)
+
+    _visit(iq_ref, ik_ref, flags_ref, init, attend, finalize,
+           open_row=open_row, close_row=close_row, **walk)
+
+
+def _sum_shares(x, shares: int, dtype):
+    """The float32 shares that ``shares`` neighbouring grid rows wrote of one
+    gradient, added (one share: the gradient itself, in its own dtype)."""
+    return x.reshape(-1, shares, *x.shape[1:]).sum(1).astype(dtype)
+
+
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_bwd_pallas(qt, kt, vt, do_t, lse, delta, qr=None, kr=None, *,
                       plan: _Plan, sm_scale, interpret):
-    """Both backward passes on head-major operands; ``lse`` as
-    ``_flash_fwd_pallas`` returns it and ``delta = rowsum(dO * O)`` like it,
-    ``[B*H, Sq]`` float32.  Returns ``(dq, dk, dv)`` head-major, dk and dv
-    at the K/V head count: the dk/dv pass sums over the query heads of a
-    visit inside it (where a group takes several grid rows, each writes its
-    float32 share and they are added here).  With ``qr`` and ``kr`` (see
-    ``_flash_fwd_pallas``) also ``(dqr, dkr)``: the shared key's gradient is
-    summed over a visit's heads in the dk/dv pass, and over a batch row's
-    visits here."""
+    """The backward on head-major operands; ``lse`` as ``_flash_fwd_pallas``
+    returns it and ``delta = rowsum(dO * O)`` like it, ``[B*H, Sq]`` float32.
+    Returns ``(dq, dk, dv)`` head-major, dk and dv at the K/V head count:
+    summed over the query heads of a visit inside the kernel (where a group
+    takes several grid rows, each writes its float32 share and they are added
+    here).  With ``qr`` and ``kr`` (see ``_flash_fwd_pallas``) also ``(dqr,
+    dkr)``: the shared key's gradient is summed over a visit's heads in the
+    kernel, and over a batch row's grid rows here.
+
+    ONE kernel where the plan says that dk and dv fit VMEM over the whole key
+    length (``plan.fused`` query heads a visit, the q-major walk); else the
+    dk/dv pass over the k-major walk and the dq pass over the q-major one,
+    ``plan.heads`` a visit."""
     bh, sq, d_p = qt.shape
     sk, dv_p, latent = kt.shape[1], vt.shape[2], kr is not None
     static = dict(plan.tile, sm_scale=sm_scale, latent=latent)
-    heads, kv_heads = plan.heads, plan.kv_heads
     block_q, block_k = static["block_q"], static["block_k"]
     sq_p, sk_p = plan.sq_p, plan.sk_p
+    heads = plan.fused or plan.heads
+    kv_heads = heads if plan.kv_heads > 1 else 1
     # a row that saw no key has lse = NEG_INF: lifted, so that exp(NEG_INF -
     # lse) is 0 there too.  Padded q rows have do = 0 and so add nothing.
     lse = jnp.maximum(lse, NEG_INF / 2)
     stats = [jnp.pad(x, ((0, 0), (0, sq_p - sq))) for x in (lse, delta)]
     rows = [x[:, None, :] for x in stats]                       # [bh, 1, sq_p]
-    cols = [jnp.broadcast_to(x[:, :, None], (bh, sq_p, 128)) for x in stats]
     qt, do_t = _pad_seq(qt, sq_p), _pad_seq(do_t, sq_p)
     kt, vt = _pad_seq(kt, sk_p), _pad_seq(vt, sk_p)
-    parts, batch_rows = _visit_rows(plan, qt, kt, kr)
+    shared = (_pad_seq(qr, sq_p), _pad_seq(kr, sk_p)) if latent else ()
+    parts, batch_rows = _visit_rows(heads, kv_heads, qt, kt, kr)
+    grid_rows = bh // heads
 
-    q_block, do_block = (heads, block_q, d_p), (heads, block_q, dv_p)
-    k_block, v_block = (kv_heads, block_k, d_p), (kv_heads, block_k, dv_p)
-    share = lambda width, n, wide: jax.ShapeDtypeStruct(        # noqa: E731
-        (bh // heads * n, sk_p, width), kt.dtype if wide == 1 else jnp.float32)
-    shared = ()
+    # index maps over a walk's table, whose row ``i`` holds the block index:
+    # a query-side block of this grid row (or its own block of a K/V-side
+    # gradient), a block of its statistics' rows, a block of the K/V head(s)
+    # or of the shared key that ``rows`` grid rows meet
+    q_of = lambda i: lambda r, v, *table: (r, table[i][v], 0)   # noqa: E731
+    stat_of = lambda i: lambda r, v, *table: (r, 0, table[i][v])    # noqa: E731
+    k_of = lambda i, rows: lambda r, v, *table: (               # noqa: E731
+        r // rows, table[i][v], 0)
+    q_block = (heads, block_q, d_p)
     if latent:
-        shared = _pad_seq(qr, sq_p), _pad_seq(kr, sk_p)
         qr_block = (heads, block_q, qr.shape[2])
-        kr_block = (1, block_k, kr.shape[2])
 
-    def shared_specs(q_at, kr_at):      # the shared product's two operands
-        return [pl.BlockSpec(qr_block, q_at),
-                pl.BlockSpec(kr_block, kr_at)] if latent else []
+    def operands(iq, ik, stat_block, stat_at):
+        """The in_specs of q, dO, lse, delta, K, V (and qr, kr) over a table
+        whose rows ``iq`` and ``ik`` are the q and the k block."""
+        specs = [pl.BlockSpec(q_block, q_of(iq)),
+                 pl.BlockSpec((heads, block_q, dv_p), q_of(iq)),
+                 pl.BlockSpec(stat_block, stat_at),
+                 pl.BlockSpec(stat_block, stat_at),
+                 pl.BlockSpec((kv_heads, block_k, d_p), k_of(ik, parts)),
+                 pl.BlockSpec((kv_heads, block_k, dv_p), k_of(ik, parts))]
+        if latent:
+            specs += [pl.BlockSpec(qr_block, q_of(iq)),
+                      pl.BlockSpec((1, block_k, kr.shape[2]),
+                                   k_of(ik, batch_rows))]
+        return specs
 
-    q_at = lambda r, v, ik, iq, flags: (r, iq[v], 0)            # noqa: E731
-    row_at = lambda r, v, ik, iq, flags: (r, 0, iq[v])          # noqa: E731
-    k_at = lambda r, v, ik, iq, flags: (r // parts, ik[v], 0)   # noqa: E731
-    kr_at = lambda r, v, ik, iq, flags: (                       # noqa: E731
-        r // batch_rows, ik[v], 0)
-    dk_at = lambda r, v, ik, iq, flags: (r, ik[v], 0)           # noqa: E731
-    outs = [(k_block, share(d_p, kv_heads, parts)),
-            (v_block, share(dv_p, kv_heads, parts))]
+    def k_side(length, at):
+        """(block over ``length`` keys, index map, shape and dtype) of dk, dv
+        (and dkr) as a grid row writes them: the gradient, or its float32
+        share where several grid rows hold one."""
+        sides = [(kv_heads, d_p, parts), (kv_heads, dv_p, parts)]
+        if latent:
+            sides.append((1, kr.shape[2], batch_rows))
+        return [((n, length, width), at, jax.ShapeDtypeStruct(
+            (grid_rows * n, sk_p, width),
+            kt.dtype if shares == 1 else jnp.float32))
+            for n, width, shares in sides]
+
+    def call(kernel, table, limit, specs, outs):
+        """``kernel`` over ``table``; ``outs`` are (block, index map, shape
+        and dtype) of its results, each with a float32 accumulator."""
+        return _walk_call(
+            functools.partial(kernel, **static), table, grid_rows, limit,
+            in_specs=specs,
+            out_specs=[pl.BlockSpec(block, at) for block, at, _ in outs],
+            out_shape=[shape for _, _, shape in outs],
+            scratch_shapes=[pltpu.VMEM(block, jnp.float32)
+                            for block, _, _ in outs],
+            interpret=interpret)
+
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, qt.dtype)    # noqa: E731
+    q_side = [(q_block, q_of(0), like(qt))]
     if latent:
-        outs.append((kr_block, share(kr.shape[2], 1, batch_rows)))
-    dk, dv, *dkr = _walk_call(
-        functools.partial(_flash_bwd_dkv_kernel, **static),
-        plan.walk_t, bh // heads, heads,
-        in_specs=[
-            pl.BlockSpec(q_block, q_at),
-            pl.BlockSpec(do_block, q_at),
-            pl.BlockSpec((heads, 1, block_q), row_at),
-            pl.BlockSpec((heads, 1, block_q), row_at),
-            pl.BlockSpec(k_block, k_at),
-            pl.BlockSpec(v_block, k_at),
-            *shared_specs(q_at, kr_at),
-        ],
-        out_specs=[pl.BlockSpec(block, dk_at) for block, _ in outs],
-        out_shape=[shape for _, shape in outs],
-        scratch_shapes=[pltpu.VMEM(block, jnp.float32) for block, _ in outs],
-        interpret=interpret,
-    )(qt, do_t, *rows, kt, vt, *shared)
-    dk, dv = (x.reshape(-1, parts, sk_p, x.shape[2]).sum(1).astype(kt.dtype)
-              for x in (dk, dv))
-    dkr = [x.reshape(-1, batch_rows, sk_p, x.shape[2]).sum(1).astype(kt.dtype)
-           for x in dkr]
-
-    q_at = lambda r, v, iq, ik, flags: (r, iq[v], 0)            # noqa: E731
-    kv_at = lambda r, v, iq, ik, flags: (r // parts, ik[v], 0)  # noqa: E731
-    kr_at = lambda r, v, iq, ik, flags: (                       # noqa: E731
-        r // batch_rows, ik[v], 0)
-    outs = [(q_block, qt)] + ([(qr_block, shared[0])] if latent else [])
-    dq, *dqr = _walk_call(
-        functools.partial(_flash_bwd_dq_kernel, **static),
-        plan.walk, bh // heads, heads,
-        in_specs=[
-            pl.BlockSpec(q_block, q_at),
-            pl.BlockSpec(do_block, q_at),
-            pl.BlockSpec((heads, block_q, 128), q_at),
-            pl.BlockSpec((heads, block_q, 128), q_at),
-            pl.BlockSpec(k_block, kv_at),
-            pl.BlockSpec(v_block, kv_at),
-            *shared_specs(q_at, kr_at),
-        ],
-        out_specs=[pl.BlockSpec(block, q_at) for block, _ in outs],
-        out_shape=[jax.ShapeDtypeStruct(like.shape, qt.dtype)
-                   for _, like in outs],
-        scratch_shapes=[pltpu.VMEM(block, jnp.float32) for block, _ in outs],
-        interpret=interpret,
-    )(qt, do_t, *cols, kt, vt, *shared)
+        q_side.append((qr_block, q_of(0), like(shared[0])))
+    row_block = (heads, 1, block_q)
+    if plan.fused:
+        # the q-major walk, rows (iq, ik, flags); the kernel's order: dq, dk,
+        # dv (, dqr, dkr), the K/V side ONE block over all keys a grid row
+        whole = k_side(sk_p, lambda r, v, *table: (r, 0, 0))
+        dq, dk, dv, *more = call(
+            _flash_bwd_kernel, plan.walk, plan.fused_limit,
+            operands(0, 1, row_block, stat_of(0)),
+            [q_side[0], *whole[:2], *q_side[1:], *whole[2:]])(
+            qt, do_t, *rows, kt, vt, *shared)
+        dqr, dkr = more[:1], more[1:]
+    else:
+        limit = _VMEM_LIMIT if heads > 1 else None
+        # the k-major walk, rows (ik, iq, flags), then the q-major one
+        dk, dv, *dkr = call(
+            _flash_bwd_dkv_kernel, plan.walk_t, limit,
+            operands(1, 0, row_block, stat_of(1)), k_side(block_k, q_of(0)))(
+            qt, do_t, *rows, kt, vt, *shared)
+        cols = [jnp.broadcast_to(x[:, :, None], (bh, sq_p, 128))
+                for x in stats]
+        dq, *dqr = call(
+            _flash_bwd_dq_kernel, plan.walk, limit,
+            operands(0, 1, (heads, block_q, 128), q_of(0)), q_side)(
+            qt, do_t, *cols, kt, vt, *shared)
+    dk, dv = (_sum_shares(x, parts, kt.dtype) for x in (dk, dv))
+    dkr = [_sum_shares(x, batch_rows, kt.dtype) for x in dkr]
     return (dq[:, :sq], dk[:, :sk], dv[:, :sk],
             *(x[:, :sq] for x in dqr), *(x[:, :sk] for x in dkr))
 
@@ -938,10 +1113,10 @@ def _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, kv_offset, block_q,
         qt, kt, vt = (_head_major(x, interpret)
                       for x in (q[..., :k.shape[-1]], k, v))
         qr, kr = _split_shared(q, k, k_shared, interpret)
-        plan = _plan(qt, kt, kr, causal=causal, kv_offset=kv_offset,
+        plan = _plan(qt, kt, vt, kr, causal=causal, kv_offset=kv_offset,
                      block_q=block_q, block_k=block_k,
                      block_diffusion=block_diffusion)
-        _count(plan, kr is not None, plan.walk)
+        _count(plan, kr is not None, plan.heads, plan.walk)
         ot, lse = _flash_fwd_pallas(qt, kt, vt, qr, kr, plan=plan,
                                     sm_scale=_scale(sm_scale, q.shape[-1]),
                                     interpret=interpret)
@@ -968,10 +1143,16 @@ def _flash_bwd_rule(causal, sm_scale, kv_offset, block_q, block_k, interpret,
     with jax.named_scope("flash_bwd"):
         delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1).transpose(0, 2, 1).reshape(b * h, sq)
-        plan = _plan(qt, kt, kr, causal=causal, kv_offset=kv_offset,
+        plan = _plan(qt, kt, vt, kr, causal=causal, kv_offset=kv_offset,
                      block_q=block_q, block_k=block_k,
                      block_diffusion=block_diffusion)
-        _count(plan, kr is not None, plan.walk_t, plan.walk)
+        # the share of backward calls traced that took the one-pass kernel
+        telemetry.counter("flash.bwd_calls").inc()
+        if plan.fused:
+            telemetry.counter("flash.bwd_fused").inc()
+            _count(plan, kr is not None, plan.fused, plan.walk)
+        else:
+            _count(plan, kr is not None, plan.heads, plan.walk_t, plan.walk)
         dq, dk, dv, *shared = _flash_bwd_pallas(
             qt, kt, vt, _head_major(g, interpret), lse, delta, qr, kr,
             plan=plan, sm_scale=_scale(sm_scale, d_k + d_r),

@@ -381,7 +381,8 @@ def test_kernels_fit_a_v5e_at_sdars_widths(one_chip):
     hlo = attn.as_text()
     kernels = [ln for ln in hlo.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
-    assert len(kernels) == 3 and " while(" not in hlo
+    # the forward and the one-pass backward
+    assert len(kernels) == 2 and " while(" not in hlo
     # no [2L, 2L] mask or bias, and no K or V at 32 heads: the program's
     # temporaries are a few copies of q (64 MB each), not the 128 MB+ of a
     # mask nor 2 x 64 MB of repeated K and V on top

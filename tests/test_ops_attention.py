@@ -67,7 +67,29 @@ def _grads(fn, q, k, v, w):
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
 
-def test_pallas_backward_matches_reference_under_a_squared_loss():
+# The backward is one kernel where the plan finds room for dk and dv over the
+# whole key length (every shape of this file), else the dk/dv pass and the dq
+# pass: with no room beside the tile body's, the plan takes the two.
+_PASSES = [pytest.param(1, id="one-pass"), pytest.param(2, id="two-passes")]
+
+
+def _backward_kernels(passes, monkeypatch):
+    if passes == 2:
+        monkeypatch.setattr(att, "_VMEM_BODY", att._VMEM_LIMIT)
+
+
+def _fused_calls():
+    from tensorflowonspark_tpu import telemetry
+
+    counters = telemetry.snapshot()["counters"]
+    return (counters.get("flash.bwd_fused", 0),
+            counters.get("flash.bwd_calls", 0))
+
+
+@pytest.mark.parametrize("passes", _PASSES)
+def test_pallas_backward_matches_reference_under_a_squared_loss(passes,
+                                                                monkeypatch):
+    _backward_kernels(passes, monkeypatch)
     q, k, v = make_qkv(b=1, s=32, h=2, d=8)
 
     def loss(fn):
@@ -103,17 +125,24 @@ def test_pallas_backward_matches_reference_under_a_squared_loss():
     pytest.param((40, 72, -24, True, 16, 16, jnp.bfloat16, 2e-2),
                  id="bf16-sq-ne-sk-kv-offset"),
 ])
-def test_pallas_backward_kernels_match_reference(case):
-    """The dk/dv and dq kernels (interpret mode) against ``jax.grad`` of the
+@pytest.mark.parametrize("passes", _PASSES)
+def test_pallas_backward_kernels_match_reference(case, passes, monkeypatch):
+    """The one-pass kernel, and the dk/dv and dq kernels that run where its
+    accumulators do not fit (interpret mode), against ``jax.grad`` of the
     dense reference in float32, under a random cotangent: skipped, interior
-    and masked tiles, padded tails on both sides, an offset KV chunk.  Errors
-    are relative to the largest entry of the reference gradient."""
+    and masked tiles, one tile a row and several, padded tails on both sides,
+    an offset KV chunk.  Errors are relative to the largest entry of the
+    reference gradient."""
     sq, sk, kv_offset, causal, block_q, block_k, dtype, tol = case
+    _backward_kernels(passes, monkeypatch)
     q, k, v = make_qkv(b=2, s=sq, h=2, d=8, sk=sk, dtype=dtype)
     w = jnp.asarray(np.random.RandomState(1).randn(*q.shape), jnp.float32)
+    fused, calls = _fused_calls()
     got = _grads(lambda q, k, v: att.flash_attention(
         q, k, v, causal=causal, kv_offset=kv_offset, block_q=block_q,
         block_k=block_k, impl="pallas_interpret"), q, k, v, w)
+    # the path is the plan's choice, counted where the backward is traced
+    assert _fused_calls() == (fused + (passes == 1), calls + 1)
     want = _grads(lambda q, k, v: att.mha_reference(
         q, k, v, causal=causal, kv_offset=kv_offset),
         *(x.astype(jnp.float32) for x in (q, k, v)), w)
@@ -123,10 +152,13 @@ def test_pallas_backward_kernels_match_reference(case):
         assert float(err) < tol, (name, float(err))
 
 
-def test_pallas_backward_rows_that_see_no_key_get_and_give_no_gradient():
+@pytest.mark.parametrize("passes", _PASSES)
+def test_pallas_backward_rows_that_see_no_key_get_and_give_no_gradient(
+        passes, monkeypatch):
     # a KV chunk from the future of the first rows (ring attention's
     # offsets): those rows' output is exactly 0, their lse NEG_INF, and the
     # backward must recompute p = 0 there, not exp(NEG_INF - NEG_INF) = 1
+    _backward_kernels(passes, monkeypatch)
     q, k, v = make_qkv(b=1, s=32, h=2, d=8, sk=16)
     w = jnp.asarray(np.random.RandomState(1).randn(*q.shape), jnp.float32)
     off = 8
@@ -140,15 +172,18 @@ def test_pallas_backward_rows_that_see_no_key_get_and_give_no_gradient():
         np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
 
 
-def test_pallas_backward_holds_no_scan():
+@pytest.mark.parametrize("passes", _PASSES)
+def test_pallas_backward_holds_no_scan(passes, monkeypatch):
     """Neither rule of the kernel's VJP goes through ``blockwise_attention``:
-    the gradient program is three ``pallas_call``s and layout, no loop."""
+    the gradient program is the forward's ``pallas_call``, the backward's one
+    or two, and layout, no loop."""
+    _backward_kernels(passes, monkeypatch)
     q, k, v = make_qkv(b=1, s=32, h=2, d=8)
     jaxpr = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
         att.flash_attention(q, k, v, block_q=16, block_k=16,
                             impl="pallas_interpret")), argnums=(0, 1, 2)))(
                                 q, k, v))
-    assert jaxpr.count("pallas_call") == 3
+    assert jaxpr.count("pallas_call") == 1 + passes
     assert "scan" not in jaxpr and "while" not in jaxpr
 
 
@@ -251,7 +286,8 @@ def _pallas_grids(jaxpr):
 
 
 def _flash_calls(q, k, **kwargs):
-    """The forward, dk/dv and dq ``pallas_call``s of one gradient program."""
+    """The ``pallas_call``s of one gradient program: the forward and the
+    one-pass backward, or the forward, the dk/dv pass and the dq pass."""
     jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda q, k, v: jnp.sum(
         att.flash_attention(q, k, v, impl="pallas_interpret", **kwargs)),
         argnums=(0, 1, 2)))(q, k, k)
@@ -261,13 +297,15 @@ def _flash_calls(q, k, **kwargs):
 
 @pytest.mark.parametrize("group", [1, 8])
 @pytest.mark.parametrize("case", _WALKS)
-def test_dkv_walk_visits_each_head_of_each_live_tile_once(case, group):
+def test_dkv_walk_visits_each_head_of_each_live_tile_once(case, group,
+                                                          monkeypatch):
     """The dk/dv pass's table: per k block, in order, every live tile ONCE
     with q ascending, whatever the group; a k block that no query sees
     visited once.  The group's query heads are served INSIDE the visit: the
     pass's grid is (K/V heads, visits) and its query-side blocks hold the
     ``group`` heads, so every (head, live tile) is met exactly once."""
     sq, sk, causal, kv_offset, block_diffusion = case
+    _backward_kernels(2, monkeypatch)
     some, every, static = _brute_force_tiles(sq, sk, 16, causal, kv_offset,
                                              block_diffusion)
     kinds = att._tile_kinds(*some.shape, **static)
@@ -294,38 +332,117 @@ def test_dkv_walk_visits_each_head_of_each_live_tile_once(case, group):
         + [(1, block_k, d)] * 4)                                # k v dk dv
 
 
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("case", _WALKS)
+def test_one_pass_backward_walks_the_forward_s_table(case, group):
+    """The one-pass backward: grid (K/V heads, the q-major table's length),
+    the query-side blocks of the ``group`` heads and dq a q block, K and V a
+    k block, and dk and dv ONE block over the whole padded key length a grid
+    row, whatever the visit: resident from the row's first visit to its
+    last, which carry the table's ``_OPEN`` and ``_CLOSE`` and no other."""
+    sq, sk, causal, kv_offset, block_diffusion = case
+    some, _, static = _brute_force_tiles(sq, sk, 16, causal, kv_offset,
+                                         block_diffusion)
+    iq, _, flags = att._walk(att._tile_kinds(*some.shape, **static))
+    np.testing.assert_array_equal((flags & att._OPEN) != 0,
+                                  np.arange(iq.size) == 0)
+    np.testing.assert_array_equal((flags & att._CLOSE) != 0,
+                                  np.arange(iq.size) == iq.size - 1)
+    kv_heads, d = 2, 8
+    calls = _flash_calls(
+        jnp.zeros((1, sq, kv_heads * group, d)),
+        jnp.zeros((1, sk, kv_heads, d)), causal=causal, kv_offset=kv_offset,
+        block_q=16, block_k=16, block_diffusion=block_diffusion)
+    assert len(calls) == 2
+    grid, blocks, _ = calls[1]
+    assert grid == (kv_heads, iq.size)
+    block_q, block_k = static["block_q"], static["block_k"]
+    sk_p = some.shape[1] * block_k
+    assert blocks == (
+        [(group, block_q, d)] * 2 + [(group, 1, block_q)] * 2   # q dO lse delta
+        + [(1, block_k, d)] * 2                                 # k v
+        + [(group, block_q, d)] + [(1, sk_p, d)] * 2)           # dq dk dv
+
+
+@pytest.mark.parametrize("case", [
+    # query rows (batch x heads), K/V rows, positions, shared key?, dtype
+    # size -> heads a visit forward, in the one pass, its VMEM limit raised?
+    pytest.param((32, 4, 8192, False, 2, 8, 8, True), id="sdar-8k-32-over-4"),
+    pytest.param((128, 128, 2048, False, 2, 1, 1, False),
+                 id="dense-lm-2k-default-scope"),
+    pytest.param((32, 32, 4096, False, 2, 1, 1, False),
+                 id="olmoe-4k-default-scope"),
+    pytest.param((32, 32, 8192, False, 2, 1, 1, True), id="one-head-8k"),
+    # every head K and V of its own: 76 MiB of them at the forward's 4 heads
+    pytest.param((32, 32, 8192, True, 2, 4, 4, True), id="kanana-8k-latent"),
+    pytest.param((32, 32, 16384, True, 2, 4, 1, True), id="latent-16k"),
+    # float32 shares of dk and dv where a group takes four grid rows
+    pytest.param((32, 1, 2048, False, 2, 8, 8, True), id="32-over-1"),
+    pytest.param((32, 4, 32768, False, 2, 8, 8, True), id="32k-32-over-4"),
+    pytest.param((32, 4, 65536, False, 2, 8, 0, False), id="64k-32-over-4"),
+    pytest.param((8, 1, 131072, False, 2, 8, 0, False), id="128k-row"),
+    pytest.param((8, 8, 16384, False, 4, 1, 1, True), id="float32-16k"),
+])
+def test_plan_takes_one_pass_where_dk_and_dv_fit(case):
+    """From shapes and bytes alone, at the default tile: one pass where a
+    visit's blocks and the float32 accumulators and two output buffers of a
+    grid row's dk and dv leave the tile body its room in the VMEM limit, at
+    the forward's heads a visit (fewer where every head has K and V of its
+    own); the two passes for a row too long.  A one-head visit keeps
+    Mosaic's default scope while the same sum leaves that room there."""
+    rows, kv_rows, length, latent, size, heads, fused, raised = case
+    dtype = {2: jnp.bfloat16, 4: jnp.float32}[size]
+    shape = lambda n, width=128: jax.ShapeDtypeStruct(  # noqa: E731
+        (n, length, width), dtype)
+    plan = att._plan(shape(rows), shape(kv_rows), shape(kv_rows),
+                     shape(1) if latent else None, causal=True, kv_offset=0,
+                     block_q=512, block_k=512, block_diffusion=None)
+    assert (plan.heads, plan.fused) == (heads, fused)
+    assert plan.fused_limit == (att._VMEM_LIMIT if raised else None)
+    assert att._VMEM_DEFAULT < att._VMEM_BLOCKS + att._VMEM_BODY \
+        < att._VMEM_LIMIT
+
+
+@pytest.mark.parametrize("passes", _PASSES)
 @pytest.mark.parametrize("case", [
     # mask, heads, K/V heads, tiles of the dense grid a head, live tiles
     pytest.param((dict(causal=True), 2, 2, 256, 136), id="causal"),
     pytest.param((dict(causal=False, block_diffusion=(128, 4)), 8, 1, 256,
                   80), id="block-diffusion-at-sdar-s-tile-count"),
 ])
-def test_pallas_grids_have_the_length_of_the_walk(case):
-    """Forward and backward are three ``pallas_call``s whose grid is (K/V
-    heads, the table's length), not the dense (q tile, k tile) grid of every
-    query head: 80 of 256 under SDAR's mask (16 x 16 tiles, block 4, scaled
-    down), in the dk/dv pass too, which serves a group's query heads inside
-    the visit.  The four counters read the share of the dense grid that is
-    walked and the heads a visit serves."""
+def test_pallas_grids_have_the_length_of_the_walk(case, passes, monkeypatch):
+    """Forward and backward are two ``pallas_call``s (three where the
+    backward takes its two passes) whose grid is (K/V heads, the table's
+    length), not the dense (q tile, k tile) grid of every query head: 80 of
+    256 under SDAR's mask (16 x 16 tiles, block 4, scaled down), in the
+    backward too, which serves a group's query heads inside the visit.  The
+    counters read the share of the dense grid that is walked, the heads a
+    visit serves and the share of backward calls that took one pass: a
+    one-pass backward is ONE kernel over one table."""
     from tensorflowonspark_tpu import telemetry
 
     mask, heads, kv_heads, dense, walked = case
     group = heads // kv_heads
+    kernels = 1 + passes
+    _backward_kernels(passes, monkeypatch)
     q = jnp.zeros((1, 256, heads, 8))
     k = jnp.zeros((1, 256, kv_heads, 8))
     before = telemetry.snapshot()["counters"]
     calls = _flash_calls(q, k, block_q=16, block_k=16, **mask)
     after = telemetry.snapshot()["counters"]
-    assert [grid for grid, _, _ in calls] == [(kv_heads, walked)] * 3
+    assert [grid for grid, _, _ in calls] == [(kv_heads, walked)] * kernels
     # a visit of one head keeps Mosaic's default VMEM scope
     assert [limit for _, _, limit in calls] == [
-        att._VMEM_LIMIT if group > 1 else None] * 3
-    counted = {name: after[name] - before.get(name, 0)
+        att._VMEM_LIMIT if group > 1 else None] * kernels
+    counted = {name: after.get(name, 0) - before.get(name, 0)
                for name in ("flash.kernels", "flash.visit_heads",
-                            "flash.tiles", "flash.tiles_walked")}
-    assert counted == {"flash.kernels": 3, "flash.visit_heads": 3 * group,
-                       "flash.tiles": 3 * dense,
-                       "flash.tiles_walked": 3 * walked}
+                            "flash.tiles", "flash.tiles_walked",
+                            "flash.bwd_calls", "flash.bwd_fused")}
+    assert counted == {"flash.kernels": kernels,
+                       "flash.visit_heads": kernels * group,
+                       "flash.tiles": kernels * dense,
+                       "flash.tiles_walked": kernels * walked,
+                       "flash.bwd_calls": 1, "flash.bwd_fused": 2 - passes}
 
 
 def test_layers_and_programs_share_one_trace_of_each_kernel(monkeypatch):
@@ -350,21 +467,25 @@ def test_layers_and_programs_share_one_trace_of_each_kernel(monkeypatch):
 
     before = telemetry.snapshot()["counters"]
     first = _pallas_calls(jax.make_jaxpr(jax.grad(two_layers))(q, k, k).jaxpr)
-    assert len(first) == 6 and len(visits) == 3     # forward, dk/dv, dq
+    assert len(first) == 4 and len(visits) == 2     # forward, backward
     again = _pallas_calls(jax.make_jaxpr(jax.value_and_grad(
         lambda q, k, v: 2 * two_layers(q, k, v)))(q, k, k).jaxpr)
-    assert again == first and len(visits) == 3
+    assert again == first and len(visits) == 2
     after = telemetry.snapshot()["counters"]
-    # two programs of two layers of three kernels, 4 heads a visit
-    assert after["flash.kernels"] - before.get("flash.kernels", 0) == 12
+    # two programs of two layers of two kernels, 4 heads a visit
+    assert after["flash.kernels"] - before.get("flash.kernels", 0) == 8
     assert after["flash.visit_heads"] - before.get(
-        "flash.visit_heads", 0) == 12 * 4
+        "flash.visit_heads", 0) == 8 * 4
 
 
-def test_rows_of_one_tile_run_straight_through():
+@pytest.mark.parametrize("passes", _PASSES)
+def test_rows_of_one_tile_run_straight_through(passes, monkeypatch):
     """Where the table says that every visit is its block's first and last
     and builds the mask (one tile a row: 512-id rows at the default tile),
-    no kernel holds a branch; where rows have several tiles, they do."""
+    no kernel holds a branch (the one visit also opens and closes the row of
+    the one-pass backward); where rows have several tiles, they do."""
+    _backward_kernels(passes, monkeypatch)
+
     def jaxpr(s):
         q = jnp.zeros((1, s, 2, 8))
         return str(jax.make_jaxpr(jax.value_and_grad(lambda q, k, v: jnp.sum(
@@ -632,16 +753,20 @@ def test_heads_a_visit_is_the_largest_divisor_that_fits(shapes, heads):
                   dict(causal=False, block_diffusion=(20, 4)), jnp.bfloat16,
                   2), id="bf16-8-over-2-block-diffusion-in-two-visits"),
 ])
-def test_a_visit_serves_the_query_heads_of_its_group(case, monkeypatch):
+@pytest.mark.parametrize("passes", _PASSES)
+def test_a_visit_serves_the_query_heads_of_its_group(case, passes,
+                                                     monkeypatch):
     """Values and all three gradients of the kernels (interpret mode) against
     the dense float32 reference where a grid row holds a K/V head's whole
     group (a group's rows are neighbours, across the batch too), and where
     the budget splits a group over several grid rows (their dk/dv shares
-    are added outside the kernel).  Errors relative to the reference's
+    are added outside the kernel; in the one-pass backward each share is
+    resident over the whole key length).  Errors relative to the reference's
     largest entry: float32 inputs round nothing, bf16 ones at the tolerance
     of ``test_pallas_backward_kernels_match_reference``."""
     b, sq, sk, h, kv_heads, d, mask, dtype, visit_heads = case
     group = h // kv_heads
+    _backward_kernels(passes, monkeypatch)
     if visit_heads:
         a_head = 6 * 16 * d * jnp.dtype(dtype).itemsize + 4 * 16 * 128 * 4 \
             + 16 * d * 4

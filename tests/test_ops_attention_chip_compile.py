@@ -1,11 +1,14 @@
-"""The three flash kernels at SDAR's widths, compiled for a DESCRIBED v5e
-(nothing runs, no chip needed): 32 x 128 query heads over 4 K/V heads, 8,192
+"""The flash kernels at SDAR's widths, compiled for a DESCRIBED v5e (nothing
+runs, no chip needed): 32 x 128 query heads over 4 K/V heads, 8,192
 positions under the block-diffusion mask of block 4.  What interpret mode
 cannot show: that Mosaic takes the walk's scalar-prefetch tables (80 visits
-a K/V head in all three kernels), index maps that read them, and a visit's
-blocks of a group's 8 query heads (16 MiB in the dq pass, under the raised
-limit).  The topology is described inside a fixture, never at import (only
-one process may load the TPU library; see the on-chip-measurement guide)."""
+a K/V head in every kernel), index maps that read them, a visit's blocks of
+a group's 8 query heads (16 MiB in the dq pass, under the raised limit), and
+the one-pass backward's resident dk and dv over the whole key length (16 MiB
+of accumulators and output buffers at these widths) with its product over
+the tile's other side.  The topology is described inside a fixture, never at
+import (only one process may load the TPU library; see the
+on-chip-measurement guide)."""
 
 from __future__ import annotations
 
@@ -28,26 +31,54 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _two_passes(monkeypatch):
+    """No room beside the tile body's: the plan takes the dk/dv pass and the
+    dq pass, which stay the path of rows too long for one pass."""
+    from tensorflowonspark_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_VMEM_BODY", attention._VMEM_LIMIT)
+
+
 @pytest.mark.parametrize("case", [
-    # positions, query heads, K/V heads, head dim, mask
+    # positions, query heads, K/V heads, head dim, mask, backward kernels
     pytest.param((8192, 32, 4, 128,
-                  dict(causal=False, block_diffusion=(4096, 4))),
+                  dict(causal=False, block_diffusion=(4096, 4)), 1),
                  id="block-diffusion"),
-    pytest.param((8192, 32, 4, 128, dict(causal=True)), id="causal"),
-    # the dense LM's: one query head a K/V head, 96 wide padded to 128 lanes
-    pytest.param((2048, 32, 32, 96, dict(causal=True)),
+    pytest.param((8192, 32, 4, 128,
+                  dict(causal=False, block_diffusion=(4096, 4)), 2),
+                 id="block-diffusion-two-passes"),
+    pytest.param((8192, 32, 4, 128, dict(causal=True), 1), id="causal"),
+    # the dense LM's: one query head a K/V head, 96 wide padded to 128 lanes;
+    # the one-pass backward under Mosaic's default VMEM scope
+    pytest.param((2048, 32, 32, 96, dict(causal=True), 1),
                  id="group-1-head-dim-96"),
-    # a group too large for one visit: four visits of 8 heads a tile
-    pytest.param((2048, 32, 1, 128, dict(causal=True)), id="32-over-1"),
+    pytest.param((2048, 32, 32, 96, dict(causal=True), 2),
+                 id="group-1-head-dim-96-two-passes"),
+    # its 512-id rows: one tile a row, no branch in the kernel
+    pytest.param((512, 32, 32, 96, dict(causal=True), 1),
+                 id="group-1-one-tile-a-row"),
+    # OLMoE's: one head a visit whose 8 MiB resident set still fits the
+    # default scope; at 8,192 positions the limit is raised
+    pytest.param((4096, 16, 16, 128, dict(causal=True), 1),
+                 id="group-1-4k-row"),
+    pytest.param((8192, 16, 16, 128, dict(causal=True), 1),
+                 id="group-1-8k-row"),
+    # the longest row of SDAR's widths that takes one pass: 64 MiB resident
+    pytest.param((32768, 32, 4, 128, dict(causal=True), 1), id="32k-row"),
+    # a group too large for one visit: four visits of 8 heads a tile, whose
+    # float32 shares of dk and dv are resident and added outside
+    pytest.param((2048, 32, 1, 128, dict(causal=True), 1), id="32-over-1"),
 ])
-def test_all_three_flash_kernels_lower_at_sdar_widths(one_chip, case):
+def test_the_flash_kernels_lower_at_sdar_widths(one_chip, case, monkeypatch):
     import jax
     import jax.numpy as jnp
     from jax.experimental.compilation_cache import compilation_cache
 
     from tensorflowonspark_tpu.ops.attention import flash_attention
 
-    length, heads, kv_heads, d, mask = case
+    length, heads, kv_heads, d, mask, backward = case
+    if backward == 2:
+        _two_passes(monkeypatch)
     q = jax.ShapeDtypeStruct((1, length, heads, d), jnp.bfloat16,
                              sharding=one_chip)
     k = jax.ShapeDtypeStruct((1, length, kv_heads, d), jnp.bfloat16,
@@ -67,17 +98,29 @@ def test_all_three_flash_kernels_lower_at_sdar_widths(one_chip, case):
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
-    # forward, dk/dv pass, dq pass
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    # forward and the one-pass backward, or forward, dk/dv pass, dq pass,
+    # under the scopes the benchmark's readers sum by
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 + backward
+    assert sum("flash_bwd" in line for line in kernels) == backward
+    assert sum("flash_fwd" in line for line in kernels) == 1
 
 
-def test_the_latent_kernels_lower_at_kanana_widths(one_chip):
+@pytest.mark.parametrize("backward", [
+    pytest.param(1, id="one-pass"), pytest.param(2, id="two-passes")])
+def test_the_latent_kernels_lower_at_kanana_widths(one_chip, backward,
+                                                   monkeypatch):
     """Kanana-2's latent attention on one 8,192-token row (ISSUE 39): 32
     query heads of 128 + 64 over per-head keys and values of 128 and ONE
     rotary key of 64.  What interpret mode cannot show: that Mosaic takes a
     visit of several (query, K/V) heads beside one block of the shared key
-    (64 columns padded to 128 lanes), its second product, and the dk/dv
-    pass's third accumulator."""
+    (64 columns padded to 128 lanes), its second product, the dk/dv pass's
+    third accumulator, and in the one-pass backward the resident dk and dv
+    of a visit's 4 heads (every head has K and V of its own: 64 MiB) beside
+    the shared key's float32 share (12 MiB)."""
+    if backward == 2:
+        _two_passes(monkeypatch)
     import jax
     import jax.numpy as jnp
     from jax.experimental.compilation_cache import compilation_cache
@@ -101,8 +144,7 @@ def test_the_latent_kernels_lower_at_kanana_widths(one_chip):
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
-    # forward, dk/dv pass, dq pass
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1 + backward
 
 
 @pytest.mark.parametrize("step", ["select", "attend", "index_loss"])
