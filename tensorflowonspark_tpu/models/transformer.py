@@ -93,6 +93,9 @@ class Attention(nn.Module):
     # head of that width that all query heads share (``_latent_attention``);
     # ``d_head``, ``n_kv_heads`` and QK-norm say nothing here.
     latent: Optional[tuple] = None
+    # False: no rotation and no position input at all (``nemotron_h``'s
+    # attention layers: the state-space layers around them carry the order).
+    rope: bool = True
 
     @nn.compact
     def __call__(self, x, positions=None, block_diffusion=None):
@@ -101,6 +104,10 @@ class Attention(nn.Module):
         that mask in place of the causal one (``ops/attention.py``).  The
         caller hands in both: what a sequence holds is the loss's business
         (``make_block_diffusion_loss_fn``)."""
+        if not self.rope and (self.latent or self.sparse or self.decode):
+            raise NotImplementedError(
+                "rope=False is the plain training path's: latent attention, "
+                "the indexer and the cache path all turn their keys")
         if self.latent:
             return self._latent_attention(x, positions, block_diffusion)
         b, s, _ = x.shape
@@ -142,10 +149,11 @@ class Attention(nn.Module):
         # named scope: rope, layout and the kernel (both halves of its
         # VJP) carry "attention" in their op names, whatever XLA fuses
         with jax.named_scope("attention"):
-            if positions is None:
-                positions = jnp.arange(s)
-            q = apply_rope(q, positions, self.rope_theta)
-            k = apply_rope(k, positions, self.rope_theta)
+            if self.rope:
+                if positions is None:
+                    positions = jnp.arange(s)
+                q = apply_rope(q, positions, self.rope_theta)
+                k = apply_rope(k, positions, self.rope_theta)
             q = constrain(q, P(BATCH, "sp", "tp", None))
             k = constrain(k, P(BATCH, "sp", "tp", None))
             v = constrain(v, P(BATCH, "sp", "tp", None))
@@ -338,6 +346,182 @@ class SwiGLU(nn.Module):
             return dense(x.shape[-1], "down_proj")(h)
 
 
+class Relu2MLP(nn.Module):
+    """``relu(x·U)²·V``: two matrices and no gate (``mlp_hidden_act``
+    ``relu2``)."""
+
+    d_ff: int
+    compute_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, name=name, dtype=self.compute_dtype)
+        with jax.named_scope("mlp"):
+            h = jnp.square(jax.nn.relu(dense(self.d_ff, "up_proj")(x)))
+            h = constrain(h, P(BATCH, "sp", "tp"))
+            return dense(x.shape[-1], "down_proj")(h)
+
+
+class Mamba2(nn.Module):
+    """Mamba-2's mixer (Dao & Gu, arXiv:2405.21060; transformers'
+    ``nemotron_h``), ``[B, L, D] -> [B, L, D]``, ``L`` a multiple of
+    ``chunk``.  With ``H`` heads of ``P`` channels, ``G`` groups and a state
+    of ``N`` columns: ``[z | xBC | dt] = u·W_in`` (``H·P`` | ``H·P + 2·G·N``
+    | ``H``); ``xBC <- silu(conv1d(xBC) + b)``, depthwise, causal, ``conv``
+    taps; ``x`` in heads of ``P``, ``B`` and ``C`` in groups of ``N`` (head
+    ``j`` reads group ``j // (H / G)``); ``Δ = softplus(dt + dt_bias)``, ``a =
+    -exp(A_log)``; the recurrence of ``ops/ssd.py``; the gated norm ``y <-
+    RMSNorm_group(y · silu(z))`` over groups of ``H·P / G`` channels with one
+    weight a channel; ``y·W_out``.  No bias but the conv's.
+
+    ``Δ``, the decay, its running sums, the carried state and the gated norm
+    are float32; the projections, the conv's operands and the scan's
+    products ``compute_dtype``.  Seeded as Mamba-2 is: ``A`` uniform in [1,
+    16], ``D`` = 1, ``dt_bias`` the inverse softplus of a log-uniform draw
+    from ``dt_range`` = (min, max, floor)."""
+
+    n_heads: int
+    head_dim: int
+    n_groups: int
+    state_size: int
+    conv: int = 4
+    chunk: int = 128
+    dt_range: tuple = (0.001, 0.1, 1e-4)
+    norm_eps: float = 1e-5
+    compute_dtype: Any = jnp.bfloat16
+    state_dtype: Any = jnp.float32      # ``ops/ssd.py``: a check's control
+
+    @nn.compact
+    def __call__(self, u):
+        from tensorflowonspark_tpu.ops.ssd import causal_conv1d, ssd_scan
+
+        b, length, d = u.shape
+        h, p, g, n = (self.n_heads, self.head_dim, self.n_groups,
+                      self.state_size)
+        inner, f32 = h * p, jnp.float32
+
+        def dt_bias_init(key, shape):
+            lo, hi, floor = self.dt_range
+            dt = jnp.exp(jax.random.uniform(key, shape)
+                         * (math.log(hi) - math.log(lo)) + math.log(lo))
+            dt = jnp.maximum(dt, floor)
+            return dt + jnp.log(-jnp.expm1(-dt))        # softplus^-1
+
+        with jax.named_scope("ssm"):
+            with jax.named_scope("ssm/in_proj"):
+                zxbcdt = nn.Dense(2 * inner + 2 * g * n + h, use_bias=False,
+                                  name="in_proj", dtype=self.compute_dtype)(u)
+                z, xbc, dt = jnp.split(
+                    zxbcdt, [inner, 2 * inner + 2 * g * n], axis=-1)
+            with jax.named_scope("ssm/conv"):
+                kernel = self.param(
+                    "conv_kernel", lambda key, shape: jax.random.uniform(
+                        key, shape, minval=-1.0, maxval=1.0)
+                    / math.sqrt(self.conv), (self.conv, inner + 2 * g * n))
+                bias = self.param("conv_bias", nn.initializers.zeros,
+                                  (inner + 2 * g * n,))
+                xbc = jax.nn.silu(causal_conv1d(xbc, kernel, bias))
+                x, bm, cm = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+            with jax.named_scope("ssm/scan"):
+                a_log = self.param(
+                    "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                        key, shape, minval=1.0, maxval=16.0)), (h,))
+                skip = self.param("D", nn.initializers.ones, (h,))
+                dt_bias = self.param("dt_bias", dt_bias_init, (h,))
+                y = ssd_scan(
+                    x.reshape(b, length, h, p),
+                    jax.nn.softplus(dt.astype(f32) + dt_bias),
+                    -jnp.exp(a_log.astype(f32)),
+                    bm.reshape(b, length, g, n), cm.reshape(b, length, g, n),
+                    skip, chunk=self.chunk, state_dtype=self.state_dtype)
+            with jax.named_scope("ssm/gate_norm"):
+                scale = self.param("norm_scale", nn.initializers.ones,
+                                   (inner,))
+                y = (y.reshape(b, length, inner).astype(f32)
+                     * jax.nn.silu(z.astype(f32))).reshape(b, length, g, -1)
+                y = y * jax.lax.rsqrt(
+                    jnp.mean(y * y, axis=-1, keepdims=True) + self.norm_eps)
+                y = (y.reshape(b, length, inner) * scale).astype(
+                    self.compute_dtype)
+            with jax.named_scope("ssm/out_proj"):
+                return nn.Dense(d, use_bias=False, name="out_proj",
+                                dtype=self.compute_dtype)(y)
+
+
+class MixerBlock(nn.Module):
+    """A layer of ONE pre-norm, ONE mixer and ONE residual add, ``h <- h +
+    mixer(norm(h))``, the mixer by ``kind`` (``nemotron_h``'s
+    ``hybrid_override_pattern``): ``"M"`` a ``Mamba2`` (``ssm``), ``"*"``
+    grouped-query ``Attention`` (``attn``), ``"E"`` the experts of
+    ``parallel/ep.MoEMLP`` (``moe``) beside the shared expert that every
+    token passes (``shared``, in the experts' own form, under ``moe/shared``:
+    every chip of an expert-parallel stage computes it whole, so under
+    ``moe_held`` it is in the layer's output once).  The fields are
+    ``Transformer``'s."""
+
+    kind: str
+    n_heads: int
+    d_head: int
+    d_ff: int
+    n_kv_heads: int = 0
+    rope: bool = True
+    rope_theta: float = 10000.0
+    attn_impl: str = "auto"
+    mesh: Optional[Any] = None
+    compute_dtype: Any = jnp.bfloat16
+    norm_eps: float = 1e-6
+    ssm: Optional[tuple] = None
+    ssm_state_dtype: Any = jnp.float32
+    n_experts: int = 0
+    moe_top_k: int = 2
+    moe_norm_topk_prob: bool = True
+    moe_held: Optional[tuple] = None
+    moe_router: Optional[tuple] = None
+    moe_shared_d_ff: int = 0
+    moe_expert_act: str = "swiglu"
+    moe_latent: int = 0
+
+    @nn.compact
+    def __call__(self, x, positions=None, block_diffusion=None):
+        u = RMSNorm(self.norm_eps, name="norm")(x)
+        if self.kind == "*":
+            y = Attention(self.n_heads, self.d_head, self.rope_theta,
+                          self.attn_impl, self.mesh, self.compute_dtype,
+                          norm_eps=self.norm_eps, n_kv_heads=self.n_kv_heads,
+                          rope=self.rope, name="attn")(
+                              u, positions, block_diffusion)
+        elif block_diffusion:
+            raise NotImplementedError(
+                "a block-diffusion mask is attention's: a state-space or an "
+                "expert layer of a per-layer-mixer model takes none")
+        elif self.kind == "M":
+            heads, head_dim, groups, state, conv, chunk, *dt_range = self.ssm
+            y = Mamba2(heads, head_dim, groups, state, conv, chunk,
+                       tuple(dt_range), self.norm_eps, self.compute_dtype,
+                       self.ssm_state_dtype, name="ssm")(u)
+        elif self.kind == "E":
+            from tensorflowonspark_tpu.parallel.ep import MoEMLP
+
+            scoring, bias, scale = self.moe_router or ("softmax", False, 1.0)
+            y = MoEMLP(x.shape[-1], self.d_ff, self.n_experts, self.moe_top_k,
+                       None, compute_dtype=self.compute_dtype,
+                       norm_topk_prob=self.moe_norm_topk_prob,
+                       held=self.moe_held, scoring=scoring,
+                       selection_bias=bias, routed_scale=scale,
+                       expert_act=self.moe_expert_act, latent=self.moe_latent,
+                       name="moe")(u)
+            if self.moe_shared_d_ff:
+                shared = (SwiGLU if self.moe_expert_act == "swiglu"
+                          else Relu2MLP)
+                with jax.named_scope("moe/shared"):
+                    y = y + shared(self.moe_shared_d_ff, self.compute_dtype,
+                                   name="shared")(u)
+        else:
+            raise ValueError(f"layer kind {self.kind!r}: M, * or E")
+        return constrain(x + y, P(BATCH, "sp", None))
+
+
 class Block(nn.Module):
     n_heads: int
     d_head: int
@@ -455,6 +639,22 @@ class Transformer(nn.Module):
     # layer the model's own.  DeepSeek-V3's ``first_k_dense_replace`` 1 over
     # 5 layers is ``(6144, 0, 0, 0, 0)``.
     layer_ffn: Optional[tuple] = None
+    # A MIXER a layer (``nemotron_h``'s ``hybrid_override_pattern``): one
+    # entry a layer, ``"M"`` a Mamba-2 mixer (``ssm`` = (heads, head dim,
+    # groups, state size, conv taps, chunk, dt min, max, floor)), ``"*"``
+    # attention, ``"E"`` the experts; such a layer is a ``MixerBlock`` (one
+    # norm, one mixer, one add), not a ``Block`` (attention THEN an FFN).
+    # None: every layer a ``Block``, and nothing below says anything.
+    # ``rope`` False: attention turns nothing; ``moe_expert_act`` / ``moe_
+    # latent``: the experts' form and the latent they live in
+    # (``parallel/ep.MoEMLP``), the shared expert in the same form.  Dropless
+    # routing, training path (no cache for the recurrent and conv state).
+    layer_mixer: Optional[tuple] = None
+    ssm: Optional[tuple] = None
+    ssm_state_dtype: Any = jnp.float32      # ``ops/ssd.py``: a check's control
+    rope: bool = True
+    moe_expert_act: str = "swiglu"
+    moe_latent: int = 0
 
     @nn.compact
     def __call__(self, input_ids, positions=None, block_diffusion=None):
@@ -478,25 +678,55 @@ class Transformer(nn.Module):
                        dtype=self.compute_dtype)
         x = emb(input_ids)
         x = constrain(x, P(BATCH, "sp", None))
-        # the mask is a tuple of sizes: static under remat
-        block_cls = (nn.remat(Block, static_argnums=(3,),
-                              policy=self._remat_policy())
-                     if self.remat else Block)
-        layer_ffn = self.layer_ffn or (0,) * self.n_layers
-        if len(layer_ffn) != self.n_layers:
-            raise ValueError(f"layer_ffn={self.layer_ffn} names "
-                             f"{len(layer_ffn)} of {self.n_layers} layers")
-        for i, dense in enumerate(layer_ffn):
-            x = block_cls(self.n_heads, dh, dense or dff,
-                          0 if dense else self.n_experts, self.moe_top_k,
-                          self.rope_theta, self.attn_impl, self.mesh,
-                          self.compute_dtype, self.decode, self.max_decode_len,
-                          self.norm_eps, self.qk_norm,
-                          self.moe_capacity_factor, self.moe_norm_topk_prob,
-                          self.n_kv_heads, self.qk_norm_per_head,
-                          self.moe_held, self.sparse, self.latent,
-                          self.moe_router, self.moe_shared_d_ff,
-                          name=f"block_{i}")(x, positions, block_diffusion)
+        if self.layer_mixer:
+            if len(self.layer_mixer) != self.n_layers or (
+                    self.decode or self.sparse or self.latent
+                    or self.layer_ffn or self.qk_norm
+                    or self.moe_capacity_factor is not None):
+                raise NotImplementedError(
+                    f"layer_mixer={self.layer_mixer} over {self.n_layers} "
+                    "layers: a mixer a layer is plain grouped-query "
+                    "attention, Mamba-2 and dropless experts on the training "
+                    "path (no cache for recurrent state, no latent or sparse "
+                    "attention, no QK-norm, no layer_ffn)")
+            block_cls = (nn.remat(MixerBlock, static_argnums=(3,),
+                                  policy=self._remat_policy())
+                         if self.remat else MixerBlock)
+            for i, kind in enumerate(self.layer_mixer):
+                x = block_cls(
+                    kind, self.n_heads, dh, dff, n_kv_heads=self.n_kv_heads,
+                    rope=self.rope, rope_theta=self.rope_theta,
+                    attn_impl=self.attn_impl, mesh=self.mesh,
+                    compute_dtype=self.compute_dtype, norm_eps=self.norm_eps,
+                    ssm=self.ssm, ssm_state_dtype=self.ssm_state_dtype,
+                    n_experts=self.n_experts,
+                    moe_top_k=self.moe_top_k,
+                    moe_norm_topk_prob=self.moe_norm_topk_prob,
+                    moe_held=self.moe_held, moe_router=self.moe_router,
+                    moe_shared_d_ff=self.moe_shared_d_ff,
+                    moe_expert_act=self.moe_expert_act,
+                    moe_latent=self.moe_latent, name=f"block_{i}")(
+                        x, positions, block_diffusion)
+        else:
+            layer_ffn = self.layer_ffn or (0,) * self.n_layers
+            if len(layer_ffn) != self.n_layers:
+                raise ValueError(f"layer_ffn={self.layer_ffn} names "
+                                 f"{len(layer_ffn)} of {self.n_layers} layers")
+            # the mask is a tuple of sizes: static under remat
+            block_cls = (nn.remat(Block, static_argnums=(3,),
+                                  policy=self._remat_policy())
+                         if self.remat else Block)
+            for i, dense in enumerate(layer_ffn):
+                x = block_cls(
+                    self.n_heads, dh, dense or dff,
+                    0 if dense else self.n_experts, self.moe_top_k,
+                    self.rope_theta, self.attn_impl, self.mesh,
+                    self.compute_dtype, self.decode, self.max_decode_len,
+                    self.norm_eps, self.qk_norm, self.moe_capacity_factor,
+                    self.moe_norm_topk_prob, self.n_kv_heads,
+                    self.qk_norm_per_head, self.moe_held, self.sparse,
+                    self.latent, self.moe_router, self.moe_shared_d_ff,
+                    name=f"block_{i}")(x, positions, block_diffusion)
         x = RMSNorm(self.norm_eps, name="final_norm")(x)
         if self.return_hidden:
             return x
@@ -527,6 +757,8 @@ def build_transformer(config: dict) -> Transformer:
     latent = config.get("latent_attention")
     router = config.get("moe_router")
     layer_ffn = config.get("layer_ffn")
+    layer_mixer = config.get("layer_mixer")
+    ssm = config.get("ssm")
     if router is not None and int(router.get("n_group", 1)) > 1:
         raise NotImplementedError(
             f"group-limited routing (n_group {router['n_group']}): "
@@ -566,6 +798,16 @@ def build_transformer(config: dict) -> Transformer:
         moe_shared_d_ff=int(config.get("moe_shared_d_ff", 0)),
         layer_ffn=None if layer_ffn is None else tuple(
             int(width) for width in layer_ffn),
+        layer_mixer=None if layer_mixer is None else tuple(layer_mixer),
+        ssm=None if ssm is None else (
+            *(int(ssm[key]) for key in (
+                "n_heads", "head_dim", "n_groups", "state_size",
+                "conv_kernel", "chunk_size")),
+            *(float(ssm[key]) for key in ("dt_min", "dt_max", "dt_floor"))),
+        ssm_state_dtype=jnp.dtype(config.get("ssm_state_dtype", "float32")),
+        rope=bool(config.get("rope", True)),
+        moe_expert_act=str(config.get("moe_expert_act", "swiglu")),
+        moe_latent=int(config.get("moe_latent", 0)),
     )
 
 

@@ -57,7 +57,8 @@ either way.  Within a piece the pairs are sorted by expert for the grouped
 matmul, whose tiles past the piece's held rows are not visited, and taken
 back to token order, where a token's pairs lie next to each other and its
 sum is one more gather.  ``moe_stats/held_pairs`` is the held pairs' share
-of ``n·k``.
+of ``n·k``, ``moe_stats/held_max_load`` the pairs at the busiest held expert
+over the held experts' mean.
 """
 
 from __future__ import annotations
@@ -235,12 +236,15 @@ def _piece_rows(pairs: int, share: float) -> int:
     return min(pairs, -(-int(2 * pairs * share) // 256) * 256)
 
 
-def _held_piece(c, ints, xf, top_p, w_gate, w_up, w_down, *, ffn, first: int,
-                n_held: int, piece: int):
+def _held_piece(c, ints, xf, top_p, *weights, ffn, first: int, n_held: int,
+                piece: int):
     """What the held pairs ``c·piece .. (c+1)·piece`` (in token order) add to
-    the layer's output ``[n, d]``."""
+    the layer's output ``[n, d]``; ``weights``: the experts' matrices, as
+    ``ffn`` takes them."""
     flat_idx, cum, starts, ends, held_first, held = ints
     k = flat_idx.shape[0] // xf.shape[0]
+    # a token's held rows: at most one an expert held (8 of a top 22)
+    run = min(k, n_held)
     lo = c * piece
     with jax.named_scope("moe/dispatch"):
         valid = lo + jnp.arange(piece, dtype=jnp.int32) < cum[-1]
@@ -260,22 +264,22 @@ def _held_piece(c, ints, xf, top_p, w_gate, w_up, w_down, *, ffn, first: int,
         def sum_tokens(g, inverse, tok, valid, last, has):
             # rows past the held ones were never written: a select
             return _sum_runs(jnp.where(valid[:, None], g[inverse], 0),
-                             tok, last, has, k)
+                             tok, last, has, run)
 
         rows = _rows(sum_tokens, xf, tok[order],
                      (inverse, tok, valid, last, has))
     with jax.named_scope("moe/experts"):
-        out = ffn(rows, w_gate, w_up, w_down, sizes)
+        out = ffn(rows, *weights, sizes)
     with jax.named_scope("moe/combine"):
         out = _rows(lambda g, order: g[order], out, inverse, (order,))
         out = jnp.where(valid[:, None], out, 0)
         # this piece's pairs' routing weights; the others get no cotangent
         here = held & (cum > lo) & (cum <= lo + piece)
-        weights = _rows(lambda g, here, at: jnp.where(here, g[at], 0),
-                        top_p.reshape(-1), pair,
-                        (here, jnp.clip(cum - 1 - lo, 0, piece - 1)))
-        return _sum_runs(out * weights[:, None].astype(out.dtype),
-                         tok, last, has, k)
+        gates = _rows(lambda g, here, at: jnp.where(here, g[at], 0),
+                      top_p.reshape(-1), pair,
+                      (here, jnp.clip(cum - 1 - lo, 0, piece - 1)))
+        return _sum_runs(out * gates[:, None].astype(out.dtype),
+                         tok, last, has, run)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -343,6 +347,15 @@ class MoEMLP(nn.Module):
     neither the load-balance nor the z term is sown under it, and
     ``moe_stats/bias_moved`` is the share of the (token, expert) choices
     that the unbiased scores would not have made.
+
+    LatentMoE (Nemotron-3's ``E`` layers): ``latent`` > 0 puts the routed
+    experts in a latent of that width between two linear maps that are the
+    layer's own, ``ℓ = x·latent_down`` (``d_model`` -> ``latent``) before the
+    dispatch and ``·latent_up`` after the combine, both under the scope
+    ``moe/latent``; the ROUTER still reads ``x``.  ``expert_act="relu2"``
+    gives an expert two matrices and no gate, ``relu(ℓ·U_e)²·V_e``
+    (``experts_up``, ``experts_down``; ``"swiglu"``: the three of a SwiGLU).
+    Both under dropless routing only.
     """
 
     d_model: int
@@ -356,6 +369,8 @@ class MoEMLP(nn.Module):
     scoring: str = "softmax"
     selection_bias: bool = False
     routed_scale: float = 1.0
+    expert_act: str = "swiglu"
+    latent: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -371,6 +386,13 @@ class MoEMLP(nn.Module):
                 "layer's experts under dropless routing")
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring={self.scoring!r}")
+        gated = self.expert_act == "swiglu"
+        if self.expert_act not in ("swiglu", "relu2") or (
+                not dropless and (self.latent or not gated)):
+            raise ValueError(
+                f"expert_act={self.expert_act!r}, latent={self.latent}, "
+                f"capacity_factor={self.capacity_factor}: relu2 experts and "
+                "a latent are the dropless path's")
         xf = x.reshape(n, d)
 
         with jax.named_scope("moe/router"):
@@ -425,6 +447,8 @@ class MoEMLP(nn.Module):
                 routed = jnp.sum(sizes)
                 self.sow("moe_stats", "held_pairs",
                          routed / (n * self.top_k))
+                self.sow("moe_stats", "held_max_load",
+                         jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9))
             if dropless:
                 self.sow("moe_stats", "executed_rows",
                          executed_rows(sizes, n * self.top_k)
@@ -433,20 +457,28 @@ class MoEMLP(nn.Module):
             self.sow("intermediates", "top_idx", top_idx)
 
         e_here = held[1] - held[0]
-        w_gate = self.param("experts_gate", nn.initializers.lecun_normal(),
-                            (e_here, d, self.d_ff))
-        w_up = self.param("experts_up", nn.initializers.lecun_normal(),
-                          (e_here, d, self.d_ff))
-        w_down = self.param("experts_down", nn.initializers.lecun_normal(),
-                            (e_here, self.d_ff, d))
+        width = self.latent or d            # what an expert maps from and to
+        shapes = {"experts_gate": (e_here, width, self.d_ff),
+                  "experts_up": (e_here, width, self.d_ff),
+                  "experts_down": (e_here, self.d_ff, width)}
+        weights = tuple(
+            self.param(name, nn.initializers.lecun_normal(), shapes[name])
+            for name in shapes if gated or name != "experts_gate")
+        if self.latent:
+            with jax.named_scope("moe/latent"):
+                xf = nn.Dense(self.latent, use_bias=False, name="latent_down",
+                              dtype=self.compute_dtype)(xf)
         if dropless:
-            y = self._dropless(xf, top_idx, top_p, pairs, w_gate, w_up,
-                               w_down)
+            y = self._dropless(xf, top_idx, top_p, pairs, weights)
         else:
-            y = self._capacity(xf, top_idx, top_p, w_gate, w_up, w_down)
+            y = self._capacity(xf, top_idx, top_p, *weights)
+        if self.latent:
+            with jax.named_scope("moe/latent"):
+                y = nn.Dense(d, use_bias=False, name="latent_up",
+                             dtype=self.compute_dtype)(y)
         return y.reshape(b, s, d).astype(x.dtype)
 
-    def _dropless(self, xf, top_idx, top_p, pairs, w_gate, w_up, w_down):
+    def _dropless(self, xf, top_idx, top_p, pairs, weights):
         mesh = jax.sharding.get_abstract_mesh()
         if not mesh.empty and dict(mesh.shape).get("ep", 1) > 1:
             raise NotImplementedError(
@@ -461,7 +493,7 @@ class MoEMLP(nn.Module):
                                       if a not in mesh.manual_axes]
         tp = "tp" if "tp" in auto else None
 
-        def ffn(rows, w_gate, w_up, w_down, sizes):
+        def swiglu(rows, w_gate, w_up, w_down, sizes):
             if self.held is None:
                 h = (jax.nn.silu(grouped_matmul(rows, w_gate, sizes))
                      * grouped_matmul(rows, w_up, sizes))
@@ -473,17 +505,23 @@ class MoEMLP(nn.Module):
             out = grouped_matmul(h, w_down, sizes)
             return jax.lax.psum(out, tp) if tp else out
 
+        def relu2(rows, w_up, w_down, sizes):
+            h = jnp.square(jax.nn.relu(grouped_matmul(rows, w_up, sizes)))
+            out = grouped_matmul(h, w_down, sizes)
+            return jax.lax.psum(out, tp) if tp else out
+
+        ffn = swiglu if self.expert_act == "swiglu" else relu2
         if auto:
             # GSPMD cannot partition a Mosaic kernel (see
             # ``flash_attention``): every rank runs the kernels on all the
             # rows against its ``tp`` slice of each expert's width, and the
             # slices' outputs are summed
+            wide = (P(None, None, tp),) * (len(weights) - 1)
             ffn = jax.shard_map(
-                ffn, in_specs=(P(), P(None, None, tp), P(None, None, tp),
-                               P(None, tp, None), P()),
+                ffn, in_specs=(P(), *wide, P(None, tp, None), P()),
                 out_specs=P(), axis_names=frozenset(auto), check_vma=False)
         with jax.named_scope("moe/experts"):    # the casts are the experts'
-            weights = tuple(w.astype(cdt) for w in (w_gate, w_up, w_down))
+            weights = tuple(w.astype(cdt) for w in weights)
         if self.held:
             return self._held(xf.astype(cdt), top_idx, top_p, weights, ffn)
         with jax.named_scope("moe/dispatch"):

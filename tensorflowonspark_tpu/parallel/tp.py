@@ -118,4 +118,11 @@ TRANSFORMER_TP_RULES: Rules = (
     (r"router/kernel$", P()),
     # QK-norm scales span all heads (the norm is over n_heads·d_head)
     (r"(q_norm|k_norm)/scale$", P()),
+    # a per-layer-mixer model (``Transformer.layer_mixer``): the state-space
+    # mixer and the experts' latent maps are replicated over tp (sharding a
+    # Mamba-2 layer's heads and B/C groups over tp is not built); the shared
+    # relu2 expert's ``up_proj`` / ``down_proj`` take the rules above
+    (r"ssm/(in_proj|out_proj)/kernel$", P()),
+    (r"ssm/(conv_kernel|conv_bias|A_log|D|dt_bias|norm_scale)$", P()),
+    (r"moe/latent_(down|up)/kernel$", P()),
 )

@@ -13,9 +13,11 @@ DIRECT + ``IngestFeed`` + ``make_bn_train_step`` (ResNet-50), STREAMING +
 ``make_train_step`` + the dropless ``ep.py`` + the attention path (OLMoE),
 the block-diffusion loss over a chip's share of the experts with
 grouped-query heads (SDAR), learned sparse attention with its indexer's
-loss under remat (Keye), and latent attention over a leading dense layer and
+loss under remat (Keye), latent attention over a leading dense layer and
 bias-corrected sigmoid routing with a buffer the optimizer leaves alone
-(Kanana-2): a crash on that cell's first step shows here.
+(Kanana-2), and a mixer a layer: the Mamba-2 scan, attention without
+rotation and relu² experts in a latent (Nemotron-3): a crash on that cell's
+first step shows here.
 
 The ResNet-50 case also reads the rehearsal's own ``logs/run_report.json``
 before the clean-up (ISSUE 35): the ``lifecycle`` block's stages in order
@@ -72,7 +74,8 @@ def _missing(path: str) -> list[str]:
                                       "olmoe_1b_7b_d1_train_4k",
                                       "sdar_30b_a3b_d4_ep8_train_bd4k",
                                       "keye_vl2_30b_a3b_d4_ep8_train_16k",
-                                      "kanana2_30b_a3b_d5_ep8_train_8k"])
+                                      "kanana2_30b_a3b_d5_ep8_train_8k",
+                                      "nemotron3_super_d11_tp8_ep64_train_8k"])
 def test_cell_rehearses_on_cpu(workload):
     cell = common.resolve_cell(workload)
     # run.py's work directory is not configurable and a DIRECT cell keeps one

@@ -88,10 +88,48 @@ def test_latent_step_names_its_projections_the_shared_expert_and_router():
     assert "/block_1/moe/" in text
 
 
+def test_mixer_step_names_the_state_space_mixer_and_the_latent_maps():
+    """ISSUE 41: a model of one mixer a layer names the Mamba-2 mixer
+    (``ssm``) and its five parts, LatentMoE's two maps (``moe/latent``), the
+    shared expert, the router and the experts, and attention's kernels,
+    forward and backward (the readers ``ssm_mixer_ms``, ``ssm_scan_ms``,
+    ``ssm_scan_roofline`` and ``moe_latent_ms`` sum device time by these)."""
+    text = _lm_step_text(
+        n_layers=3, n_heads=4, n_kv_heads=1, d_head=8, layer_mixer=list("ME*"),
+        ssm={"n_heads": 4, "head_dim": 8, "n_groups": 1, "state_size": 16,
+             "conv_kernel": 4, "chunk_size": 8, "dt_min": 0.001,
+             "dt_max": 0.1, "dt_floor": 1e-4},
+        rope=False, d_ff=24, n_experts=8, moe_held=[0, 4], moe_top_k=3,
+        moe_capacity_factor=None,
+        moe_router={"scoring": "sigmoid", "selection_bias": True,
+                    "routed_scale": 5.0},
+        moe_shared_d_ff=40, moe_expert_act="relu2", moe_latent=16,
+        attn_impl="pallas_interpret")
+    for scope in ("ssm", "ssm/in_proj", "ssm/conv", "ssm/scan",
+                  "ssm/gate_norm", "ssm/out_proj", "moe/latent", "moe/shared",
+                  "moe/router", "moe/dispatch", "moe/experts", "attention",
+                  "flash_fwd", "flash_bwd"):
+        assert f"/{scope}/" in text, scope
+        backward = [line for line in text.split("jit(step)")
+                    if f"/{scope}/" in line and "transpose(" in line]
+        assert backward or scope in ("flash_fwd", "flash_bwd"), scope
+    for name in ("latent_down", "latent_up"):
+        assert f"/moe/latent/{name}/" in text, name
+    # the scan is no loop over positions: its one loop is the chunk states'
+    assert "/ssm/scan/" in text and "/ssd/state/" in text
+    for part in ("decay", "intra", "inter"):
+        assert f"/ssd/{part}/while" not in text, part
+    # a mixer a layer: no layer has attention AND an FFN
+    assert "/block_0/ssm/" in text and "/block_0/attn/" not in text
+    assert "/block_1/moe/" in text and "/block_1/attn/" not in text
+    assert "/block_2/attn/" in text and "/block_2/mlp/" not in text
+    assert "/cos" not in text         # nothing turns: no rotation anywhere
+
+
 def test_a_dense_step_has_no_moe_scope():
     text = _lm_step_text(attn_impl="xla")
     assert "/moe/" not in text and "/qk_norm/" not in text
-    assert "/mla/" not in text
+    assert "/mla/" not in text and "/ssm/" not in text
 
 
 def test_fused_head_loss_is_named_too():
