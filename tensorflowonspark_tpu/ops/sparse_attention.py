@@ -27,21 +27,27 @@ there.  Four steps, each a function here, one row ``[L, ...]`` at a time
 pair, 268 MB at 16k, alive inside its layer only).
 
 The selection is exact and a function of the data, so which tiles of the
-score matrix hold a kept pair is known on the device only: the three
-attention kernels and the loss kernel walk a RUNTIME visit table
-(``_visit_table``, as ``grouped_matmul._visits`` is built from sizes), the
-mask decides inside a tile.  A selection that is scattered over the past, as
-seeded weights give, leaves every causal tile live: the walk then skips
-nothing and each tile costs a whole tile (PERF.md §7).
+score matrix hold a kept pair is known on the device only: the two attention
+kernels (the forward, and ONE backward that takes dq, dk and dv from a tile's
+scores computed once) and the loss kernel walk the same RUNTIME visit table
+(``_visit_table``, as ``grouped_matmul._visits`` is built from sizes), query
+block by query block; the mask decides inside a tile.  A selection that is
+scattered over the past, as seeded weights give, leaves every causal tile
+live: the walk then skips nothing and each tile costs a whole tile (PERF.md
+§7).
 
 A VISIT of an attention kernel is one live tile for one K/V head and ALL the
 query heads of its group (grid ``(K/V heads, visits)``): K, V and the int8
 mask tile are fetched once, the mask is decoded once into a float32 bias
 that every head of the group adds, and dk / dv sum over the group inside the
-visit.  The group is read from the shapes (32 over 4 heads: 8; one query head
-a K/V head: 1, the same path); ``dsa.visit_heads`` over the attention kernels
-built says which it was.  The loss kernel's visit holds every head of every
-group.
+visit.  A visit of the backward adds its tile's share to dk and dv of the
+K/V head's WHOLE row, which stay in VMEM from the head's first visit to its
+last (float32, beside the blocks they are written to then): a row too long
+for that (past 40k at 128 + 128 wide) is refused when the backward is
+traced.  The group is read from the shapes (32 over 4 heads: 8; one query
+head a K/V head: 1, the same path); ``dsa.visit_heads`` over the attention
+kernels built says which it was.  The loss kernel's visit holds every head of
+every group.
 
 The exact threshold of a row is the ``topk``-th largest score, found by 32
 counting passes over the order-preserving integer image of the float32 scores
@@ -68,7 +74,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tensorflowonspark_tpu import telemetry
-from tensorflowonspark_tpu.ops.attention import NEG_INF, _NT
+from tensorflowonspark_tpu.ops.attention import (
+    NEG_INF, _NT, _TN, _VMEM_BODY)
 
 Impl = Literal["pallas", "pallas_interpret", "xla"]
 
@@ -79,7 +86,8 @@ INT_MIN = -2 ** 31
 # walk).  A caller that recomputes the layer (``jax.checkpoint``) and saves
 # these names runs none of the kernels a second time.
 SAVED_NAMES = ("dsa_mask", "dsa_lse_i", "dsa_out", "dsa_lse", "dsa_kl")
-_FIRST, _LAST = 1, 2        # a visit's flags: first / last of its output block
+# a visit's flags: first / last of its output block, of the whole walk
+_FIRST, _LAST, _OPEN, _CLOSE = 1, 2, 4, 8
 _VMEM_LIMIT = 96 << 20      # of a v5e's 128 MiB; the default scope is 16
 
 
@@ -433,11 +441,13 @@ def selection_stats(mask):
 
 def _visit_table(live):
     """The walk of one kernel's sequential axis over the live tiles of a
-    causal ``[n, n]`` tile map (or its transpose), built on the device:
-    ``(block, tile, flags, count)`` — for every output block (a row of
-    ``live``) its live tiles in ascending order.  The diagonal tile is always
+    causal ``[n, n]`` tile map, built on the device: ``(block, tile, flags,
+    count)`` — for every output block (a row of ``live``: a block of
+    queries) its live tiles in ascending order.  The diagonal tile is always
     visited, so every block has a first and a last visit; the table has room
-    for every causal tile and ``count`` says how many visits it holds."""
+    for every causal tile and ``count`` says how many visits it holds.  The
+    walk's own first and last visit carry ``_OPEN`` and ``_CLOSE`` too, for
+    the kernel that keeps something over the whole walk."""
     n = live.shape[0]
     visit = live | jnp.eye(n, dtype=bool)
     room = n * (n + 1) // 2
@@ -450,6 +460,7 @@ def _visit_table(live):
     prev = jnp.where(index > 0, jnp.roll(block, 1), -1)
     nxt = jnp.where(index + 1 < count, jnp.roll(block, -1), -1)
     flags = ((block != prev) * _FIRST + (block != nxt) * _LAST) * real
+    flags += (index == 0) * _OPEN + (index == count - 1) * _CLOSE
     return block, tile, flags.astype(jnp.int32), count
 
 
@@ -483,19 +494,23 @@ def _kept(mask_ref):
 
 
 # ---------------------------------------------------------------------------
-# Kernels 3-5: attention over the kept pairs, forward and both backward
-# passes.  A VISIT is one live tile for one K/V head and ALL the query heads
-# of its group: the grid is ``(K/V heads, visits)``, the K/V tiles and the
-# int8 mask tile are fetched once a visit, the mask is decoded once (into an
-# additive float32 bias, 0 on a kept pair and ``NEG_INF`` elsewhere:
-# ``logits + bias`` is ``where(kept, logits, NEG_INF)`` to the bit for finite
-# logits) and the group's heads are a loop inside the visit over the
-# ``[group, tile, d]`` blocks of the query side.  ``group`` is read from the
-# shapes; with one query head a K/V head the loop has one turn.
+# Kernels 3-4: attention over the kept pairs, the forward and the one-pass
+# backward, over ONE q-major visit table.  A VISIT is one live tile for one
+# K/V head and ALL the query heads of its group: the grid is ``(K/V heads,
+# visits)``, the K/V tiles and the int8 mask tile are fetched once a visit,
+# the mask is decoded once (into an additive float32 bias, 0 on a kept pair
+# and ``NEG_INF`` elsewhere: ``logits + bias`` is ``where(kept, logits,
+# NEG_INF)`` to the bit for finite logits) and the group's heads are a loop
+# inside the visit over the ``[group, tile, d]`` blocks of the query side.
+# ``group`` is read from the shapes; with one query head a K/V head the loop
+# has one turn.  A visit of the backward holds resident, beside its blocks:
+# dq's float32 accumulator of the query block, and dk and dv of the K/V head
+# over the whole row (float32, written once, on the head's last visit).
 # ---------------------------------------------------------------------------
 
-def _decode(mask_ref, bias_ref):
-    bias_ref[...] = jnp.where(_kept(mask_ref), 0.0, NEG_INF)
+def _decode(mask_ref, bias_ref, turned: bool = False):
+    bias = jnp.where(_kept(mask_ref), 0.0, NEG_INF)
+    bias_ref[...] = bias.T if turned else bias
 
 
 def _lanes(x, width: int):
@@ -546,46 +561,27 @@ def _fwd_kernel(iq_ref, ik_ref, flags_ref, q_ref, k_ref, v_ref, mask_ref,
     _on(flags, _LAST, finalize)
 
 
-def _dkv_kernel(ik_ref, iq_ref, flags_ref, q_ref, do_ref, lse_ref, delta_ref,
-                k_ref, v_ref, mask_t_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                bias_ref, *, sm_scale: float):
-    # the transposed tile [keys, queries], as ops/attention.py's dk/dv pass;
-    # dk and dv sum over the group's heads inside the visit
-    del ik_ref, iq_ref
-    flags = flags_ref[pl.program_id(1)]
+def _bwd_kernel(iq_ref, ik_ref, flags_ref, q_ref, do_ref, lse_ref, delta_ref,
+                k_ref, v_ref, mask_ref, dq_ref, dk_ref, dv_ref, dq_acc,
+                dk_acc, dv_acc, bias_ref, *, sm_scale: float):
+    # ONE pass, as ops/attention.py's ``_flash_bwd_kernel``: for every head of
+    # the visit the tile's scores and dP ONCE, on the TRANSPOSED tile [keys,
+    # queries], and the three gradients from them: lse and delta are rows,
+    # lane-dense as they lie in memory, dv += p_t dO and dk += ds_t q are
+    # plain matmuls, dq += ds_t^T k is the one product over the tile's other
+    # side.  The mask tile is the forward's: its bias is turned once a visit.
+    # dq accumulates over a query block's visits; dk and dv accumulate over
+    # the WHOLE key length of the grid row's K/V head, at the tile's rows:
+    # zeroed on the walk's first visit, scaled, cast and written on its last.
+    del iq_ref
+    visit = pl.program_id(1)
+    flags = flags_ref[visit]
+    tile = k_ref.shape[1]
+    keys = pl.ds(pl.multiple_of(ik_ref[visit] * tile, tile), tile)
 
-    def init():
+    def open_row():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    def finalize():
-        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
-
-    _on(flags, _FIRST, init)
-    _decode(mask_t_ref, bias_ref)
-    k, v = k_ref[0], v_ref[0]
-    for h in range(q_ref.shape[0]):
-        q, do = q_ref[h], do_ref[h]
-        logits_t = lax.dot_general(
-            k, q, _NT,
-            preferred_element_type=jnp.float32) * sm_scale + bias_ref[...]
-        p_t = jnp.exp(logits_t - lse_ref[h])
-        dv_acc[...] += jnp.dot(p_t.astype(do.dtype), do,
-                               preferred_element_type=jnp.float32)
-        dp_t = lax.dot_general(v, do, _NT,
-                               preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - delta_ref[h])
-        dk_acc[...] += jnp.dot(ds_t.astype(q.dtype), q,
-                               preferred_element_type=jnp.float32)
-    _on(flags, _LAST, finalize)
-
-
-def _dq_kernel(iq_ref, ik_ref, flags_ref, q_ref, do_ref, lse_ref, delta_ref,
-               k_ref, v_ref, mask_ref, dq_ref, dq_acc, bias_ref, *,
-               sm_scale: float):
-    del iq_ref, ik_ref
-    flags = flags_ref[pl.program_id(1)]
 
     def init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
@@ -593,21 +589,30 @@ def _dq_kernel(iq_ref, ik_ref, flags_ref, q_ref, do_ref, lse_ref, delta_ref,
     def finalize():
         dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
+    def close_row():
+        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    _on(flags, _OPEN, open_row)
     _on(flags, _FIRST, init)
-    _decode(mask_ref, bias_ref)
+    _decode(mask_ref, bias_ref, turned=True)
     k, v = k_ref[0], v_ref[0]
-    group, tile, _ = q_ref.shape
-    for h in range(group):
-        logits = lax.dot_general(
-            q_ref[h], k, _NT,
+    for h in range(q_ref.shape[0]):
+        q, do = q_ref[h], do_ref[h]
+        logits_t = lax.dot_general(
+            k, q, _NT,
             preferred_element_type=jnp.float32) * sm_scale + bias_ref[...]
-        p = jnp.exp(logits - _lanes(lse_ref[h], tile))
-        dp = lax.dot_general(do_ref[h], v, _NT,
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - _lanes(delta_ref[h], tile))
-        dq_acc[h] += jnp.dot(ds.astype(k.dtype), k,
-                             preferred_element_type=jnp.float32)
+        p_t = jnp.exp(logits_t - lse_ref[h])
+        dv_acc[keys] += jnp.dot(p_t.astype(do.dtype), do,
+                                preferred_element_type=jnp.float32)
+        dp_t = lax.dot_general(v, do, _NT,
+                               preferred_element_type=jnp.float32)
+        ds_t = (p_t * (dp_t - delta_ref[h])).astype(q.dtype)
+        dk_acc[keys] += jnp.dot(ds_t, q, preferred_element_type=jnp.float32)
+        dq_acc[h] += lax.dot_general(ds_t, k, _TN,
+                                     preferred_element_type=jnp.float32)
     _on(flags, _LAST, finalize)
+    _on(flags, _CLOSE, close_row)
 
 
 def _head_major(x):
@@ -650,67 +655,58 @@ def _fwd_pallas(qt, kt, vt, mask, table, *, sm_scale, tile, interpret):
     return out, lse[:, 0]
 
 
-def _bwd_pallas(qt, kt, vt, do_t, lse, delta, mask, table, table_t, *,
-                sm_scale, tile, interpret):
+def _bwd_bytes(group: int, tile: int, length: int, d: int,
+               itemsize: int) -> int:
+    """VMEM of the one-pass backward: dk and dv of a K/V head over the whole
+    row (float32 accumulators and the two buffers of each output block), and
+    a visit's blocks and scratch: q, dO and dq twice, dq's accumulator, K
+    and V, the mask tile and its bias."""
+    resident = 2 * length * d * (4 + 2 * itemsize)
+    heads = group * tile * d * (6 * itemsize + 4)
+    return (resident + heads + 4 * tile * d * itemsize
+            + tile * tile * (2 + 4))
+
+
+def _bwd_pallas(qt, kt, vt, do_t, lse, delta, mask, table, *, sm_scale,
+                tile, interpret):
     heads, length, d = qt.shape
     group = heads // kt.shape[0]
-    rows = [x[:, None, :] for x in (lse, delta)]                # [H, 1, L]
-    cols = [jnp.broadcast_to(x[:, :, None], (heads, length, 128))
-            for x in (lse, delta)]
-    q_block, k_block = (group, tile, d), (1, tile, d)
-    bias = pltpu.VMEM((tile, tile), jnp.float32)
-
-    block, col, flags, count = table_t           # blocks: K/V tiles
-    q_at = lambda g, v, ik, iq, f: (g, iq[v], 0)                # noqa: E731
-    row_at = lambda g, v, ik, iq, f: (g, 0, iq[v])              # noqa: E731
-    k_at = lambda g, v, ik, iq, f: (g, ik[v], 0)                # noqa: E731
-    dk, dv = _walk_call(
-        functools.partial(_dkv_kernel, sm_scale=sm_scale), "attend_dkv",
-        (heads // group, count), (block, col, flags),
-        in_specs=[
-            pl.BlockSpec(q_block, q_at),
-            pl.BlockSpec(q_block, q_at),
-            pl.BlockSpec((group, 1, tile), row_at),
-            pl.BlockSpec((group, 1, tile), row_at),
-            pl.BlockSpec(k_block, k_at),
-            pl.BlockSpec(k_block, k_at),
-            pl.BlockSpec((tile, tile),
-                         lambda g, v, ik, iq, f: (ik[v], iq[v])),
-        ],
-        out_specs=[pl.BlockSpec(k_block, k_at)] * 2,
-        out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
-                   jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
-        scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)] * 2 + [bias],
-        interpret=interpret, visit_heads=group,
-    )(qt, do_t, *rows, kt, vt, mask.T)
-
+    held = _bwd_bytes(group, tile, length, d, qt.dtype.itemsize)
+    if held > _VMEM_LIMIT - _VMEM_BODY:
+        raise ValueError(
+            f"sparse attention's backward keeps dk and dv of a row of "
+            f"{length} positions in VMEM: {held:,} bytes beside the tile "
+            f"body's {_VMEM_BODY:,}, over the limit of {_VMEM_LIMIT:,}")
     block, col, flags, count = table
+    q_block, whole = (group, tile, d), (1, length, d)
     q_at = lambda g, v, iq, ik, f: (g, iq[v], 0)                # noqa: E731
+    row_at = lambda g, v, iq, ik, f: (g, 0, iq[v])              # noqa: E731
     kv_at = lambda g, v, iq, ik, f: (g, ik[v], 0)               # noqa: E731
-    dq = _walk_call(
-        functools.partial(_dq_kernel, sm_scale=sm_scale), "attend_dq",
+    row_of = lambda g, v, iq, ik, f: (g, 0, 0)                  # noqa: E731
+    return _walk_call(
+        functools.partial(_bwd_kernel, sm_scale=sm_scale), "attend_bwd",
         (heads // group, count), (block, col, flags),
         in_specs=[
             pl.BlockSpec(q_block, q_at),
             pl.BlockSpec(q_block, q_at),
-            pl.BlockSpec((group, tile, 128), q_at),
-            pl.BlockSpec((group, tile, 128), q_at),
-            pl.BlockSpec(k_block, kv_at),
-            pl.BlockSpec(k_block, kv_at),
+            pl.BlockSpec((group, 1, tile), row_at),
+            pl.BlockSpec((group, 1, tile), row_at),
+            pl.BlockSpec((1, tile, d), kv_at),
+            pl.BlockSpec((1, tile, d), kv_at),
             pl.BlockSpec((tile, tile),
                          lambda g, v, iq, ik, f: (iq[v], ik[v])),
         ],
-        out_specs=pl.BlockSpec(q_block, q_at),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
-        scratch_shapes=[pltpu.VMEM((group, tile, d), jnp.float32), bias],
+        out_specs=[pl.BlockSpec(q_block, q_at),
+                   pl.BlockSpec(whole, row_of), pl.BlockSpec(whole, row_of)],
+        out_shape=[jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+                   jax.ShapeDtypeStruct(kt.shape, kt.dtype),
+                   jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
+        scratch_shapes=[pltpu.VMEM(q_block, jnp.float32),
+                        pltpu.VMEM((length, d), jnp.float32),
+                        pltpu.VMEM((length, d), jnp.float32),
+                        pltpu.VMEM((tile, tile), jnp.float32)],
         interpret=interpret, visit_heads=group,
-    )(qt, do_t, *cols, kt, vt, mask)
-    return dq, dk, dv
-
-
-def _tables(mask, tile: int):
-    live = live_tiles(mask, tile)
-    return _visit_table(live), _visit_table(live.T)
+    )(qt, do_t, lse[:, None, :], delta[:, None, :], kt, vt, mask)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -721,21 +717,21 @@ def _attend_tpu(q, k, v, mask, sm_scale, interpret):
 def _attend_fwd(q, k, v, mask, sm_scale, interpret):
     tile = _tile(q.shape[0])
     qt, kt, vt = (_head_major(x) for x in (q, k, v))
-    tables = _tables(mask, tile)
-    ot, lse = _fwd_pallas(qt, kt, vt, mask, tables[0], sm_scale=sm_scale,
+    table = _visit_table(live_tiles(mask, tile))
+    ot, lse = _fwd_pallas(qt, kt, vt, mask, table, sm_scale=sm_scale,
                           tile=tile, interpret=interpret)
     out = checkpoint_name(ot.transpose(1, 0, 2), "dsa_out")
     lse = checkpoint_name(lse, "dsa_lse")
-    return (out, lse), (qt, kt, vt, out, lse, mask, tables)
+    return (out, lse), (qt, kt, vt, out, lse, mask, table)
 
 
 def _attend_bwd(sm_scale, interpret, res, cotangents):
     g, _g_lse = cotangents      # the lse feeds a constant of the step only
-    qt, kt, vt, out, lse, mask, (table, table_t) = res
+    qt, kt, vt, out, lse, mask, table = res
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).T                                   # [H, L]
     dq, dk, dv = _bwd_pallas(
-        qt, kt, vt, _head_major(g), lse, delta, mask, table, table_t,
+        qt, kt, vt, _head_major(g), lse, delta, mask, table,
         sm_scale=sm_scale, tile=_tile(qt.shape[1]), interpret=interpret)
     return (*(x.transpose(1, 0, 2) for x in (dq, dk, dv)),
             np.zeros(mask.shape, jax.dtypes.float0))
@@ -766,7 +762,7 @@ def sparse_attention(q, k, v, mask, *, sm_scale: float | None = None,
 
 
 # ---------------------------------------------------------------------------
-# Kernel 6: the indexer's loss and, in the same walk, its gradient.
+# Kernel 5: the indexer's loss and, in the same walk, its gradient.
 # ---------------------------------------------------------------------------
 
 def _kl_kernel(iq_ref, ik_ref, flags_ref, q_ref, k_ref, lse_ref, a_ref,

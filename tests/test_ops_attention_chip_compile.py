@@ -154,8 +154,12 @@ def test_the_sparse_attention_kernels_lower_at_keye_widths(one_chip, step):
     query heads over 4 K/V heads).  What interpret mode cannot show: that
     Mosaic takes the int8 mask tiles, the visit tables built on the device
     with a grid whose length is a value of the run, 4 MiB of one block's
-    scores and keys in VMEM under the raised limit, and the loss kernel's
-    resident gradient of the one index key."""
+    scores and keys in VMEM under the raised limit, the loss kernel's
+    resident gradient of the one index key, and the one-pass backward's dk
+    and dv of a K/V head resident over the whole row (16 MiB of float32
+    accumulators beside 16 MiB of output buffers: 43 MiB in all by Mosaic's
+    count) with the mask tile's bias turned in VMEM and dq's product over the
+    tile's other side."""
     import jax
     import jax.numpy as jnp
     from jax.experimental.compilation_cache import compilation_cache
@@ -176,11 +180,11 @@ def test_the_sparse_attention_kernels_lower_at_keye_widths(one_chip, step):
     if step == "select":        # score tiles and the exact threshold
         fn, args, calls = (lambda a, b, c: dsa.lightning_select(
             a, b, c, 2048, impl="pallas")), (a, b, c), 2
-    elif step == "attend":      # forward, dk/dv pass, dq pass
+    elif step == "attend":      # forward, the one-pass backward
         fn = jax.value_and_grad(lambda q, k, v, mask: jnp.sum(
             dsa.sparse_attention(q, k, v, mask, impl="pallas")[0].astype(
                 jnp.float32)), argnums=(0, 1, 2))
-        args, calls = (q, k, k, mask), 3
+        args, calls = (q, k, k, mask), 2
     else:                       # the loss walk with the indexer's gradient
         fn = jax.value_and_grad(lambda a, b, c, *rest: dsa.index_kl(
             a, b, c, *rest, impl="pallas"), argnums=(0, 1, 2))

@@ -1,7 +1,7 @@
 """Learned sparse attention (``ops/sparse_attention.py``): the dense path and
 the kernels in interpret mode against a dense masked softmax written here, at
 grouped-query heads 4 over 2 (attention itself: 4 over 4, 2 and 1 too, a
-visit of its kernels serving 1, 2 or 4 query heads)."""
+visit of its two kernels serving 1, 2 or 4 query heads)."""
 
 import jax
 import jax.numpy as jnp
@@ -112,7 +112,8 @@ def _select(row, topk, impl):
 def assert_attention_and_its_three_gradients(row, mask, impl, seed):
     """``sparse_attention`` under ``mask`` against the dense softmax: the
     output, the log-sum-exp and the gradients in q, k and v."""
-    w = jnp.asarray(np.random.RandomState(seed).randn(L, H, D), jnp.float32)
+    w = jnp.asarray(np.random.RandomState(seed).randn(*row["q"].shape),
+                    jnp.float32)
 
     def loss(fn):
         return lambda q, k, v: jnp.sum(fn(q, k, v)[0] * w)
@@ -165,10 +166,77 @@ def test_rows_that_keep_nothing_inside_a_visited_tile(group, impl,
 
 
 @pytest.mark.parametrize("group", GROUPS)
+def test_dk_and_dv_of_a_tile_one_visit_touches_and_of_one_all_visit(
+        group, tiles_of_16):
+    """Tiles of 16, four a side.  Every query keeps itself and key 3, so the
+    first column of tiles is visited by every query block and the K/V tiles
+    1, 2 and 3 by their diagonal visit alone: K/V tile 0 takes its first
+    share on the walk's opening visit and more on visits of every block
+    after, K/V tile 3 takes its only share on the walk's closing visit."""
+    mask = np.eye(L, dtype=np.int8)
+    mask[3:, 3] = 1
+    live = np.asarray(dsa.live_tiles(jnp.asarray(mask), 16))
+    assert live.tolist() == [[True, False, False, False],
+                             [True, True, False, False],
+                             [True, False, True, False],
+                             [True, False, False, True]]
+    _block, tile, flags, count = dsa._visit_table(jnp.asarray(live))
+    assert np.asarray(tile)[:int(count)].tolist() == [0, 0, 1, 0, 2, 0, 3]
+    assert np.flatnonzero(np.asarray(flags) & 4).tolist() == [0]
+    assert np.flatnonzero(np.asarray(flags) & 8).tolist() == [6]
+    assert_attention_and_its_three_gradients(
+        make_row(seed=15, kv_heads=H // group), jnp.asarray(mask),
+        "pallas_interpret", seed=16)
+
+
+@pytest.mark.parametrize("tiles", [1, 2, 3])
+def test_a_walk_whose_first_visit_is_a_k_v_heads_last(tiles, tiles_of_16):
+    """Rows of 1, 2 and 3 tiles a side whose queries keep themselves alone:
+    only diagonal tiles are live.  In the row of one tile visit 0 opens and
+    closes the walk; in the longer ones K/V tile 0 is complete after visit 0
+    and must still be there, with every other tile's share, when the last
+    visit writes dk and dv."""
+    length = 16 * tiles
+    row = {name: x[:length] for name, x in make_row(seed=17).items()}
+    mask = jnp.eye(length, dtype=jnp.int8)
+    *_, flags, count = dsa._visit_table(dsa.live_tiles(mask, 16))
+    assert int(count) == tiles
+    assert (int(flags[0]) & 8 != 0) == (tiles == 1)
+    assert_attention_and_its_three_gradients(row, mask, "pallas_interpret",
+                                             seed=18)
+
+
+@pytest.mark.parametrize("length,fits", [
+    (16384, True), (32768, True), (40960, True), (41472, False),
+    (65536, False)])
+def test_a_row_too_long_for_resident_dk_and_dv_is_refused_at_trace_time(
+        length, fits):
+    """Keye's widths in bf16 (32 x 128 query heads over 4 K/V heads): the
+    backward keeps dk and dv of a K/V head's whole row in VMEM.  Rows up to
+    80 tiles trace; a longer one raises with the byte count (nothing is run
+    or allocated: shapes only)."""
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype)
+    q, k = shape(length, 32, 128), shape(length, 4, 128)
+    grad = jax.grad(lambda q, k, v, mask: jnp.sum(dsa.sparse_attention(
+        q, k, v, mask, impl="pallas_interpret")[0].astype(jnp.float32)),
+        argnums=(0, 1, 2))
+    trace = lambda: jax.eval_shape(  # noqa: E731
+        grad, q, k, k, shape(length, length, dtype=jnp.int8))
+    if fits:
+        assert [g.shape for g in trace()] == [q.shape, k.shape, k.shape]
+    else:
+        held = dsa._bwd_bytes(8, 512, length, 128, 2)
+        with pytest.raises(ValueError, match=f"{length} positions.*{held:,}"):
+            trace()
+
+
+@pytest.mark.parametrize("group", GROUPS)
 def test_a_visit_serves_the_whole_group_of_query_heads(group):
-    """The three attention kernels have the grid ``(K/V heads, visits)`` and
-    count the query heads a visit serves: ``dsa.visit_heads`` over the
-    attention kernels built reads the group."""
+    """The two attention kernels (the forward and the one-pass backward) have
+    the grid ``(K/V heads, visits)`` and count the query heads a visit
+    serves: ``dsa.visit_heads`` over the attention kernels built reads the
+    group.  The two passes' counters are gone."""
     row = make_row(seed=14, kv_heads=H // group)
     mask, _ = _select(row, 12, "xla")
     before = telemetry.snapshot()["counters"]
@@ -179,11 +247,11 @@ def test_a_visit_serves_the_whole_group_of_query_heads(group):
     after = telemetry.snapshot()["counters"]
     counted = {name.removeprefix("dsa."): after[name] - before.get(name, 0)
                for name in after if name.startswith("dsa.")}
-    built = sum(counted[f"kernels.attend_{name}"]
-                for name in ("fwd", "dkv", "dq"))
-    assert built == counted["kernels"] == 3
-    assert counted["visit_heads"] == group * built
-    assert [grid[0] for grid in _pallas_grids(jaxpr.jaxpr)] == [H // group] * 3
+    assert counted["kernels.attend_fwd"] == counted["kernels.attend_bwd"] == 1
+    assert counted["kernels"] == 2
+    assert not {"kernels.attend_dkv", "kernels.attend_dq"} & set(counted)
+    assert counted["visit_heads"] == group * 2
+    assert [grid[0] for grid in _pallas_grids(jaxpr.jaxpr)] == [H // group] * 2
 
 
 @pytest.mark.parametrize("impl", IMPLS)
@@ -254,8 +322,9 @@ def test_a_selection_that_leaves_tiles_empty_walks_the_live_ones(impl,
     assert int(count) == 4 + 3          # and the three other diagonal tiles
     visits = list(zip(*(np.asarray(x)[:int(count)]
                         for x in (block, tile, flags))))
-    assert visits == [(0, 0, 3), (1, 0, 1), (1, 1, 2), (2, 0, 1), (2, 2, 2),
-                      (3, 0, 1), (3, 3, 2)]
+    # first / last of a block: 1 / 2; of the whole walk: 4 / 8
+    assert visits == [(0, 0, 3 + 4), (1, 0, 1), (1, 1, 2), (2, 0, 1),
+                      (2, 2, 2), (3, 0, 1), (3, 3, 2 + 8)]
     qkv = (row["q"], row["k"], row["v"])
     w = jnp.asarray(np.random.RandomState(9).randn(L, H, D), jnp.float32)
     got = jax.value_and_grad(lambda q, k, v: jnp.sum(dsa.sparse_attention(

@@ -120,19 +120,19 @@ def test_the_cache_and_the_ring_refuse_sparse_attention():
 
 
 def test_the_second_forward_of_a_remat_block_runs_no_sparse_kernel():
-    """Six kernels a layer in a step's program (score tiles, selection,
-    attention forward, the loss walk with the indexer's gradient, dk/dv
-    pass, dq pass): remat saves what ``ops/sparse_attention.py`` names and
-    recomputes the rest of the block.  Without the policy the forward's four
-    would be there twice."""
+    """Five kernels a layer in a step's program (score tiles, selection,
+    attention forward, the loss walk with the indexer's gradient, the
+    one-pass attention backward): remat saves what
+    ``ops/sparse_attention.py`` names and recomputes the rest of the block.
+    Without the policy the forward's four would be there twice."""
     model, params = build(remat=True, attn_impl="pallas_interpret")
     loss = tfm.make_sparse_loss_fn(model, aux_loss_coef=0.01, vocab_chunk=32)
     batch = {"input_ids": IDS[:1]}
     program = str(jax.make_jaxpr(jax.grad(lambda p: loss(p, batch)[0]))(
         params))
-    assert program.count("pallas_call[") == 6 * CONFIG["n_layers"]
+    assert program.count("pallas_call[") == 5 * CONFIG["n_layers"]
     plain, _ = build(attn_impl="pallas_interpret")
     loss = tfm.make_sparse_loss_fn(plain, aux_loss_coef=0.01, vocab_chunk=32)
     program = str(jax.make_jaxpr(jax.grad(lambda p: loss(p, batch)[0]))(
         params))
-    assert program.count("pallas_call[") == 6 * CONFIG["n_layers"]
+    assert program.count("pallas_call[") == 5 * CONFIG["n_layers"]
