@@ -54,18 +54,29 @@ share, a static size): the first piece always, the further ones by a loop
 whose trip count follows the pairs that came (``_held_experts``): none on
 even routing, ``n·k / piece - 1`` if every pair lands here, the same program
 either way.  Within a piece the pairs are sorted by expert for the grouped
-matmul, whose tiles past the piece's held rows are not visited, and taken
-back to token order, where a token's pairs lie next to each other and its
-sum is one more gather.  ``moe_stats/held_pairs`` is the held pairs' share
-of ``n·k``, ``moe_stats/held_max_load`` the pairs at the busiest held expert
-over the held experts' mean.
+matmul, whose tiles past the piece's held rows are not visited.  That sort
+and its inverse are the piece's ONE index plan (``_Plan``, built once under
+``moe/dispatch``): tokens go to the expert-ordered rows by one gather
+(``_take``: row r is token ``src[r]``'s), and the combine's cotangent goes
+the same way by the same index, weighted by the routing weights in expert
+order; rows go back to tokens by ``ops/sum_tokens.py`` (``_give``, and
+``_take``'s transpose): one gather to token order, where a token's pairs
+lie next to each other, and one kernel that reads the tiles that hold a pair
+and writes each token's weighted sum once.  The routing weights' cotangent
+is the row sum of the experts' output times that gathered cotangent, taken
+back to the pairs by a piece-long scatter.  ``moe_stats/held_pairs`` is the
+held pairs' share of ``n·k``, ``moe_stats/held_max_load`` the pairs at the
+busiest held expert over the held experts' mean, ``moe_stats/moved_rows``
+the share of the first piece's rows that the sum back to tokens reads (whole
+tiles; about a half on even routing, 1 off the TPU, where the specification
+passes over the whole piece).
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -74,6 +85,7 @@ from jax.sharding import PartitionSpec as P
 
 from tensorflowonspark_tpu.ops.grouped_matmul import (executed_rows,
                                                        grouped_matmul)
+from tensorflowonspark_tpu.ops.sum_tokens import moved_rows, sum_tokens
 from tensorflowonspark_tpu.parallel.tp import constrain
 
 
@@ -182,52 +194,75 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 # A chip's share of the experts: the held pairs, a piece at a time.
 # ---------------------------------------------------------------------------
 
+class _Plan(NamedTuple):
+    """One piece's index plan, built once (under ``moe/dispatch``): every
+    way a ``[piece, d]`` array is read or written, forward and backward.
+    Row ``r`` of the piece in EXPERT order (the grouped matmul's) is token
+    ``src[r]``'s and lies at position ``order[r]`` of the piece in TOKEN
+    order, where position ``s`` is row ``inverse[s]`` and a token's
+    positions end at ``end[t]`` (its run: ``end[t - 1] .. end[t] - 1``; the
+    positions from ``end[-1]`` on hold no pair)."""
+
+    src: jax.Array
+    order: jax.Array
+    inverse: jax.Array
+    end: jax.Array
+
+
+# The piece's two maps.  Each is the other's transpose up to the weights, so
+# both directions of both are ONE gather by ``src`` (tokens to rows) or one
+# ``sum_tokens`` (rows to tokens): autodiff would scatter-add rows, and a
+# gather to token order and back is two where one does.  ``how``: the
+# longest run, and the mesh's axes that GSPMD partitions over (``(run,
+# axes)``, static).
+
+def _token_sums(how, rows, plan: "_Plan", *gates):
+    run, axes = how
+    sums = functools.partial(sum_tokens, run=run)
+    if axes:    # as the experts' kernels: every rank sums all the rows
+        sums = jax.shard_map(sums, in_specs=P(), out_specs=P(),
+                             axis_names=frozenset(axes), check_vma=False)
+    return sums(rows, plan.inverse, plan.end, *gates)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _rows(transpose, x, idx, aux):
-    """``x[idx]`` whose cotangent is ``transpose(g, *aux)``: where the
-    indices' inverse is known the transpose is a gather too (autodiff would
-    scatter-add rows)."""
-    return x[idx]
+def _take(how, x, plan: _Plan):
+    """``[n, d]`` tokens -> ``[piece, d]``: row r holds its pair's token."""
+    return x[plan.src]
 
 
-def _rows_fwd(transpose, x, idx, aux):
-    return x[idx], aux
+def _take_fwd(how, x, plan):
+    return x[plan.src], plan
 
 
-def _rows_bwd(transpose, aux, g):
-    return transpose(g, *aux), None, None
+def _take_bwd(how, plan, g):
+    return _token_sums(how, g, plan), None
 
 
-_rows.defvjp(_rows_fwd, _rows_bwd)
+_take.defvjp(_take_fwd, _take_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _sum_runs(z, tok, last, has, run: int):
-    """``[r, d]`` rows in token order (row s is token ``tok[s]``'s: a token's
-    rows lie next to each other, at most ``run`` of them) -> ``[n, d]``,
-    each token's rows summed: ``last[t]`` is the row its run ends at and
-    ``has[t]`` whether it has one."""
-    rows = z.shape[0]
-    # one pass: the run's earlier rows are shifted views of one padded copy
-    z_pad = jnp.pad(z, ((run - 1, 0), (0, 0)))
-    tok_pad = jnp.pad(tok, (run - 1, 0), constant_values=-1)
-    total = z.astype(jnp.float32)
-    for i in range(1, run):
-        at = slice(run - 1 - i, run - 1 - i + rows)
-        total = total + jnp.where((tok_pad[at] == tok)[:, None],
-                                  z_pad[at].astype(jnp.float32), 0.0)
-    return jnp.where(has[:, None], total.astype(z.dtype)[last], 0)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _give(how, out, gates, plan: _Plan):
+    """``[piece, d]`` expert outputs -> ``[n, d]``: each token's rows,
+    weighted by its routing weights (``gates`` ``[piece]``, in token order,
+    0 where a position holds no pair), summed."""
+    return _token_sums(how, out, plan, gates)
 
 
-def _sum_runs_fwd(z, tok, last, has, run):
-    return _sum_runs(z, tok, last, has, run), tok
+def _give_fwd(how, out, gates, plan):
+    return _give(how, out, gates, plan), (out, gates, plan)
 
 
-def _sum_runs_bwd(run, tok, g):
-    return g[tok], None, None, None
+def _give_bwd(how, res, dy):
+    out, gates, plan = res
+    g = dy[plan.src]                        # the dispatch's gather
+    d_out = g * gates[plan.order][:, None].astype(g.dtype)
+    d_gates = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), -1)
+    return d_out, d_gates[plan.inverse].astype(gates.dtype), None
 
 
-_sum_runs.defvjp(_sum_runs_fwd, _sum_runs_bwd)
+_give.defvjp(_give_fwd, _give_bwd)
 
 
 def _piece_rows(pairs: int, share: float) -> int:
@@ -237,49 +272,38 @@ def _piece_rows(pairs: int, share: float) -> int:
 
 
 def _held_piece(c, ints, xf, top_p, *weights, ffn, first: int, n_held: int,
-                piece: int):
+                piece: int, axes: tuple):
     """What the held pairs ``c·piece .. (c+1)·piece`` (in token order) add to
     the layer's output ``[n, d]``; ``weights``: the experts' matrices, as
     ``ffn`` takes them."""
-    flat_idx, cum, starts, ends, held_first, held = ints
-    k = flat_idx.shape[0] // xf.shape[0]
+    flat_idx, ends, held_first = ints
+    pairs = flat_idx.shape[0]
+    k = pairs // xf.shape[0]
     # a token's held rows: at most one an expert held (8 of a top 22)
-    run = min(k, n_held)
+    how = (min(k, n_held), axes)
     lo = c * piece
     with jax.named_scope("moe/dispatch"):
-        valid = lo + jnp.arange(piece, dtype=jnp.int32) < cum[-1]
+        at = jnp.arange(piece, dtype=jnp.int32)
+        valid = lo + at < ends[-1]
         # the pairs that are the piece's held ones, their tokens and experts
         pair = jax.lax.dynamic_slice(held_first, (lo,), (piece,))
-        tok = pair // k
         expert = jnp.where(valid, flat_idx[pair] - first, n_held)
         order = jnp.argsort(expert, stable=True)      # the piece's one sort
-        inverse = (jnp.zeros((piece,), jnp.int32)
-                   .at[order].set(jnp.arange(piece, dtype=jnp.int32)))
         sizes = jnp.sum(jax.nn.one_hot(expert, n_held, dtype=jnp.int32), 0)
-        # where each token's run ends within the piece, if it has rows here
-        last = jnp.minimum(ends, lo + piece) - 1 - lo
-        has = last >= jnp.maximum(starts, lo) - lo
-        last = jnp.clip(last, 0, piece - 1)
-
-        def sum_tokens(g, inverse, tok, valid, last, has):
-            # rows past the held ones were never written: a select
-            return _sum_runs(jnp.where(valid[:, None], g[inverse], 0),
-                             tok, last, has, run)
-
-        rows = _rows(sum_tokens, xf, tok[order],
-                     (inverse, tok, valid, last, has))
+        plan = _Plan(src=(pair // k)[order], order=order,
+                     inverse=jnp.zeros((piece,), jnp.int32).at[order].set(at),
+                     end=jnp.clip(ends - lo, 0, piece))
+        rows = _take(how, xf, plan)
     with jax.named_scope("moe/experts"):
         out = ffn(rows, *weights, sizes)
     with jax.named_scope("moe/combine"):
-        out = _rows(lambda g, order: g[order], out, inverse, (order,))
-        out = jnp.where(valid[:, None], out, 0)
-        # this piece's pairs' routing weights; the others get no cotangent
-        here = held & (cum > lo) & (cum <= lo + piece)
-        gates = _rows(lambda g, here, at: jnp.where(here, g[at], 0),
-                      top_p.reshape(-1), pair,
-                      (here, jnp.clip(cum - 1 - lo, 0, piece - 1)))
-        return _sum_runs(out * gates[:, None].astype(out.dtype),
-                         tok, last, has, run)
+        # this piece's pairs' routing weights, in token order; a position
+        # past the held ones reads none and sends no cotangent (the indices
+        # ascend and none comes twice: the transpose is a piece-long scatter)
+        gates = top_p.reshape(-1).at[jnp.where(valid, pair, pairs + at)].get(
+            mode="fill", fill_value=0, indices_are_sorted=True,
+            unique_indices=True)
+        return _give(how, out, gates, plan)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -523,7 +547,8 @@ class MoEMLP(nn.Module):
         with jax.named_scope("moe/experts"):    # the casts are the experts'
             weights = tuple(w.astype(cdt) for w in weights)
         if self.held:
-            return self._held(xf.astype(cdt), top_idx, top_p, weights, ffn)
+            return self._held(xf.astype(cdt), top_idx, top_p, weights, ffn,
+                              tuple(auto))
         with jax.named_scope("moe/dispatch"):
             order, row_of_pair = _sorted_layout(top_idx)
             rows = _dispatch(xf.astype(cdt), order, row_of_pair)
@@ -532,28 +557,30 @@ class MoEMLP(nn.Module):
         with jax.named_scope("moe/combine"):
             return _combine(out, top_p, order, row_of_pair)
 
-    def _held(self, xf, top_idx, top_p, weights, ffn):
-        """The held experts' part of the output (module docstring)."""
+    def _held(self, xf, top_idx, top_p, weights, ffn, axes: tuple):
+        """The held experts' part of the output (module docstring); ``axes``:
+        the mesh's, that ``ffn`` is mapped over."""
         n, k = top_idx.shape
         first, end = self.held
         with jax.named_scope("moe/dispatch"):
             flat_idx = top_idx.reshape(-1)
-            # pairs in token order: how many held ones up to each, and the
-            # run of each token's held pairs among them
+            # pairs in token order: how many held ones up to and with each
+            # token's (where its run among them ends)
             held = (flat_idx >= first) & (flat_idx < end)
-            cum = jnp.cumsum(held, dtype=jnp.int32)
-            ends = cum.reshape(n, k)[:, -1]
-            starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+            ends = jnp.cumsum(held, dtype=jnp.int32).reshape(n, k)[:, -1]
             piece = _piece_rows(n * k, (end - first) / self.n_experts)
             # the one sort over all the pairs: the held ones first, in token
             # order (padded to whole pieces; what follows them is masked)
             held_first = jnp.pad(
                 jnp.argsort(jnp.logical_not(held), stable=True)
                 .astype(jnp.int32), (0, -(n * k) % piece))
-        ints = (flat_idx, cum, starts, ends, held_first, held)
+            self.sow("moe_stats", "moved_rows", moved_rows(
+                piece, jnp.minimum(ends, piece)) / piece)
+        ints = (flat_idx, ends, held_first)
         args = (xf, top_p) + weights
         piece_fn = functools.partial(_held_piece, ffn=ffn, first=first,
-                                     n_held=end - first, piece=piece)
+                                     n_held=end - first, piece=piece,
+                                     axes=axes)
         # the first piece by plain autodiff, which keeps its residuals; the
         # further ones, which only uneven routing fills, are run again
         # backward (their number is not a shape)

@@ -187,3 +187,129 @@ def test_the_kernels_serve_a_piece_as_they_serve_all_the_rows(monkeypatch):
     y = _layer((0, 4)).apply({"params": _share(params, 0, 4)}, x)
     np.testing.assert_allclose(y, _dense_reference(params, x, 0, 4),
                                atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 43: one index plan a piece; a token's sum by the ``sum_tokens`` kernel.
+# ---------------------------------------------------------------------------
+
+def _kernel_form(monkeypatch, impl="pallas_interpret"):
+    """``sum_tokens`` as the chip runs it (here in interpreter mode)."""
+    from tensorflowonspark_tpu.ops import sum_tokens as st
+
+    monkeypatch.setattr(eplib, "sum_tokens",
+                        functools.partial(st.sum_tokens, impl=impl))
+    monkeypatch.setattr(eplib, "moved_rows",
+                        functools.partial(st.moved_rows, impl=impl))
+
+
+# routing, experts held, choices a token, tokens, rows a piece, width, latent
+_SUM_CASES = {
+    # one choice a token, all on ONE held expert: two pieces, runs of 1 (= k)
+    "all_on_one_held": ([5], (4, 8), 1, 512, 256, D, 0),
+    # four choices, all held: eight pieces, runs of 4 (= k = held experts)
+    "every_pair_held": ([4, 5, 6, 7], (4, 8), K, 512, 256, D, 0),
+    # no pair held: no piece holds a row, every block of tokens reads zeros
+    "none_held": ([0, 1, 2, 3], (4, 8), K, 512, 256, D, 0),
+    # a piece of 250 rows cuts a token's run of 4 in two (and is no whole
+    # tile: the kernel's last tile is padded)
+    "run_at_a_piece_s_edge": ([4, 5, 6, 7], (4, 8), K, 128, 250, D, 0),
+    # seeded routing over 1/8 of the experts in pieces of half the even
+    # share: a second and a third piece, partly filled
+    "a_second_piece": (None, (8, 12), K, 512, 128, D, 0),
+    # rows as wide as lanes come (2,048 = 16 x 128; here 2 x 128)
+    "lane_wide_rows": (None, (8, 12), K, 256, 256, 256, 0),
+    # six choices over four held experts: runs of 4 (= held experts < k),
+    # relu2 experts in a latent of 128
+    "latent_run_of_the_held": (None, (0, 4), 6, 256, 256, 32, 128),
+}
+
+
+@pytest.mark.parametrize("case", list(_SUM_CASES))
+def test_the_sum_tokens_kernel_is_the_run_sum_whatever_the_routing(
+        case, monkeypatch):
+    """ISSUE 43.  The held layer with ``sum_tokens`` as the Pallas kernel
+    (interpreter mode) against the same layer with the specification
+    (``sum_runs``: shifted adds over the piece, a gather of each token's last
+    row): the output, and the gradient to the input, to the router (which is
+    the routing weights': the choice is no function of it) and to every
+    expert matrix, within this file's limits."""
+    favoured, held, k, n, piece, d, latent = _SUM_CASES[case]
+    kwargs = dict(norm_topk_prob=True, held=held)
+    if latent:
+        kwargs.update(expert_act="relu2", latent=latent)
+    layer = eplib.MoEMLP(d, F, E, k, None, **kwargs)
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.standard_normal((1, n, d)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal(x.shape), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(11), x)["params"]
+    if favoured:
+        x, params = jnp.abs(x), _forced(params, favoured)
+    monkeypatch.setattr(eplib, "_piece_rows", lambda pairs, share: piece)
+
+    def run():
+        def loss(p, x):
+            y, sown = layer.apply({"params": p}, x, mutable=["moe_stats"])
+            return jnp.sum(y * w), (y, sown["moe_stats"])
+
+        (_, (y, stats)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(params, x)
+        return y, grads, stats
+
+    want_y, want_grads, want_stats = run()
+    _kernel_form(monkeypatch)
+    from tensorflowonspark_tpu import telemetry
+
+    built = telemetry.counter("moe.kernels.sum_tokens").value()
+    got_y, got_grads, got_stats = run()
+    # the first piece's combine forward and dispatch backward; the loop of
+    # the further pieces: its forward's combine, and both of its backward,
+    # which runs the piece again
+    assert telemetry.counter(
+        "moe.kernels.sum_tokens").value() - built == 2 + 3 * (piece < n * k)
+    np.testing.assert_allclose(got_y, want_y, atol=5e-6)
+    flat = lambda t: jax.tree_util.tree_leaves_with_path(t)  # noqa: E731
+    for (path, a), (_, b) in zip(flat(got_grads), flat(want_grads)):
+        np.testing.assert_allclose(a, b, atol=5e-5, err_msg=str(path))
+    assert float(want_stats["moved_rows"][0]) == 1.0    # the whole piece
+    held_pairs = float(got_stats["held_pairs"][0]) * n * k
+    tile = min(256, -(-piece // 128) * 128)
+    assert float(got_stats["moved_rows"][0]) == pytest.approx(
+        min(piece, -(-min(held_pairs, piece) // tile) * tile) / piece)
+    if case == "none_held":
+        assert not np.asarray(got_y).any()
+
+
+def test_the_kernel_reads_the_tiles_that_hold_a_pair_and_no_other(
+        monkeypatch):
+    """On seeded routing a piece (twice the even share) is about half full:
+    ``moe_stats/moved_rows`` reads the share of its rows that the sum reads,
+    whole tiles of 256, well under 1; what lies past the held pairs in the
+    experts' output (never written on the chip: here NaN) reaches nothing."""
+    from tensorflowonspark_tpu.ops import grouped_matmul as gm
+
+    n = 2048
+    params, x = _whole(seed=9, n=n)
+    _kernel_form(monkeypatch)
+
+    def unwritten(rows, w, sizes):
+        out = gm.grouped_matmul(rows, w, sizes, impl="xla")
+        past = jnp.arange(rows.shape[0]) >= jnp.sum(sizes)
+        return jnp.where(past[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(eplib, "grouped_matmul", unwritten)
+    layer = _layer((8, 12))
+    share = _share(params, 8, 12)
+    (y, sown), vjp = jax.vjp(
+        lambda x: layer.apply({"params": share}, x, mutable=["moe_stats"]),
+        x)
+    dx = vjp((jnp.ones_like(y), jax.tree.map(jnp.zeros_like, sown)))[0]
+    assert np.isfinite(np.asarray(y)).all()
+    assert np.isfinite(np.asarray(dx)).all()
+    np.testing.assert_allclose(y, _dense_reference(params, x, 8, 12),
+                               atol=5e-6)
+    piece = eplib._piece_rows(n * K, 4 / E)
+    held = float(sown["moe_stats"]["held_pairs"][0]) * n * K
+    moved = float(sown["moe_stats"]["moved_rows"][0])
+    assert piece == 2048 and moved == -(-held // 256) * 256 / piece
+    assert 0.25 < moved <= 0.75

@@ -5,6 +5,8 @@ refactor.  Metadata only: nothing here runs a step."""
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import optax
@@ -124,6 +126,64 @@ def test_mixer_step_names_the_state_space_mixer_and_the_latent_maps():
     assert "/block_1/moe/" in text and "/block_1/attn/" not in text
     assert "/block_2/attn/" in text and "/block_2/mlp/" not in text
     assert "/cos" not in text         # nothing turns: no rotation anywhere
+
+
+def _sum_tokens_as_on_the_chip(monkeypatch):
+    """``sum_tokens`` as the Pallas kernel (interpreter mode), counted."""
+    import functools
+
+    from tensorflowonspark_tpu import telemetry
+    from tensorflowonspark_tpu.ops import sum_tokens as st
+    from tensorflowonspark_tpu.parallel import ep
+
+    monkeypatch.setattr(ep, "sum_tokens", functools.partial(
+        st.sum_tokens, impl="pallas_interpret"))
+    return telemetry.counter("moe.kernels.sum_tokens")
+
+
+def _sum_kernel_ops(text: str) -> list:
+    """The op_names of the ``sum_tokens`` kernel's instructions (an op_name
+    runs to the first byte that is no text; the module's table of source
+    files names whatever the process has traced before)."""
+    return [name for name in (re.match(r"[\x20-\x7e]*", chunk).group()
+                              for chunk in text.split("jit(step)"))
+            if "_sum_tokens_pallas" in name]
+
+
+def test_the_held_sum_is_the_combine_s_forward_and_the_dispatch_s_backward(
+        monkeypatch):
+    """ISSUE 43: the ``sum_tokens`` kernel's device time stays under the
+    scopes of what it replaced, ``moe/combine`` forward and ``moe/dispatch``
+    backward, and nothing of it lands under ``moe/experts``
+    (``moe_dispatch_ms`` and ``moe_experts_ms`` go on reading the same
+    work)."""
+    built = _sum_tokens_as_on_the_chip(monkeypatch)
+    before = built.value()
+    text = _lm_step_text(n_experts=8, moe_held=[0, 4], moe_top_k=2,
+                         moe_capacity_factor=None, attn_impl="xla")
+    # one layer, one piece: the forward traced to shape the parameters, then
+    # the step's forward and backward
+    assert built.value() - before == 3
+    ops = _sum_kernel_ops(text)
+    forward = [line for line in ops if "transpose(" not in line]
+    backward = [line for line in ops if "transpose(" in line]
+    assert forward and backward
+    assert all("/moe/combine/" in line for line in forward)
+    assert all("/moe/dispatch/" in line for line in backward)
+    assert not any("/moe/experts/" in line for line in ops)
+
+
+@pytest.mark.parametrize("capacity", [None, 1.25],
+                         ids=["dropless", "capacity"])
+def test_the_other_routing_rules_build_no_sum_kernel(capacity, monkeypatch):
+    """ISSUE 43: without ``held`` the dropless path (``_dispatch`` /
+    ``_combine``) and the capacity rule are the programs they were: no
+    ``sum_tokens`` in them, nothing counted."""
+    built = _sum_tokens_as_on_the_chip(monkeypatch)
+    before = built.value()
+    text = _lm_step_text(n_experts=4, moe_top_k=2,
+                         moe_capacity_factor=capacity, attn_impl="xla")
+    assert not _sum_kernel_ops(text) and built.value() == before
 
 
 def test_a_dense_step_has_no_moe_scope():
