@@ -78,7 +78,7 @@ def _make_partition(rows: int, row_bytes: int, seed: int) -> list[bytes]:
 def run_fanout(num_nodes: int, *, row_bytes: int, rows_per_part: int,
                parts_per_node: int, wire: int, send_window: int | None,
                chunk_rows: int, capacity: int = 1024,
-               use_ring: bool = False, metrics: bool | None = None) -> dict:
+               metrics: bool | None = None) -> dict:
     """One fan-out run; returns {mb_per_s, rows_per_s, seconds, ...}.
 
     ``metrics`` pins ``TOS_METRICS`` for this run (None = leave the
@@ -94,7 +94,7 @@ def run_fanout(num_nodes: int, *, row_bytes: int, rows_per_part: int,
                            rows_per_part=rows_per_part,
                            parts_per_node=parts_per_node, wire=wire,
                            send_window=send_window, chunk_rows=chunk_rows,
-                           capacity=capacity, use_ring=use_ring)
+                           capacity=capacity)
     prev = os.environ.get("TOS_METRICS")
     os.environ["TOS_METRICS"] = "1" if metrics else "0"
     telemetry.reset()
@@ -103,7 +103,7 @@ def run_fanout(num_nodes: int, *, row_bytes: int, rows_per_part: int,
                            rows_per_part=rows_per_part,
                            parts_per_node=parts_per_node, wire=wire,
                            send_window=send_window, chunk_rows=chunk_rows,
-                           capacity=capacity, use_ring=use_ring)
+                           capacity=capacity)
     finally:
         if prev is None:
             os.environ.pop("TOS_METRICS", None)
@@ -114,8 +114,7 @@ def run_fanout(num_nodes: int, *, row_bytes: int, rows_per_part: int,
 
 def _run_fanout(num_nodes: int, *, row_bytes: int, rows_per_part: int,
                 parts_per_node: int, wire: int, send_window: int | None,
-                chunk_rows: int, capacity: int = 1024,
-                use_ring: bool = False) -> dict:
+                chunk_rows: int, capacity: int = 1024) -> dict:
     from tensorflowonspark_tpu.dataserver import DataClient
 
     authkey = b"bench"
@@ -135,20 +134,9 @@ def _run_fanout(num_nodes: int, *, row_bytes: int, rows_per_part: int,
     parts = [[_make_partition(rows_per_part, row_bytes, seed=n * 100 + i)
               for i in range(parts_per_node)] for n in range(num_nodes)]
 
-    # clients read TOS_SHM_RING at construction; restore it afterwards so an
-    # in-process caller (the tier-1 smoke test) doesn't leak forced-transport
-    # state into the rest of its session
-    prev_ring = os.environ.get("TOS_SHM_RING")
-    os.environ["TOS_SHM_RING"] = "1" if use_ring else "0"
-    try:
-        clients = [DataClient("127.0.0.1", port, authkey,
-                              chunk_size=chunk_rows, send_window=send_window)
-                   for port in ports]
-    finally:
-        if prev_ring is None:
-            os.environ.pop("TOS_SHM_RING", None)
-        else:
-            os.environ["TOS_SHM_RING"] = prev_ring
+    clients = [DataClient("127.0.0.1", port, authkey,
+                          chunk_size=chunk_rows, send_window=send_window)
+               for port in ports]
     if wire == 1:
         for c in clients:
             c._wire = 1  # force the legacy frame format

@@ -188,16 +188,8 @@ def _run_mode(mode: str, num_nodes: int, shard_paths: list[str],
         payload = [[list(tfrecord.read_records(p)) for p in share]
                    for share in shares]
 
-    prev_ring = os.environ.get("TOS_SHM_RING")
-    os.environ["TOS_SHM_RING"] = "0"  # apples-to-apples TCP on both legs
-    try:
-        clients = [DataClient("127.0.0.1", port, authkey, chunk_size=64)
-                   for port in ports]
-    finally:
-        if prev_ring is None:
-            os.environ.pop("TOS_SHM_RING", None)
-        else:
-            os.environ["TOS_SHM_RING"] = prev_ring
+    clients = [DataClient("127.0.0.1", port, authkey, chunk_size=64)
+               for port in ports]
 
     errors: list[BaseException] = []
 
@@ -425,16 +417,8 @@ def _run_direct_items(work_items: list, num_nodes: int, expect_rows: int,
                 pass
 
     shares = [work_items[i::num_nodes] for i in range(num_nodes)]
-    prev_ring = os.environ.get("TOS_SHM_RING")
-    os.environ["TOS_SHM_RING"] = "0"
-    try:
-        clients = [DataClient("127.0.0.1", port, authkey, chunk_size=64)
-                   for port in ports]
-    finally:
-        if prev_ring is None:
-            os.environ.pop("TOS_SHM_RING", None)
-        else:
-            os.environ["TOS_SHM_RING"] = prev_ring
+    clients = [DataClient("127.0.0.1", port, authkey, chunk_size=64)
+               for port in ports]
 
     errors: list[BaseException] = []
 
@@ -730,118 +714,110 @@ def _run_tier(shard_paths: list, num_trainers: int, num_workers: int,
 
     authkey = b"bench"
     ctx = mp.get_context("fork")
-    prev_ring = os.environ.get("TOS_SHM_RING")
-    os.environ["TOS_SHM_RING"] = "0"  # the cross-process wire on both legs
     procs, tconns, tports = [], [], []
-    try:
-        for i in range(num_trainers):
-            parent, child = ctx.Pipe()
-            if num_workers:
-                args = (child, authkey, capacity, i, count_col)
-                target = _disagg_trainer_main
-            else:
-                args = (child, authkey, capacity, i,
-                        {"schema": schema_json, "chunk_records": chunk_records},
-                        count_col)
-                target = _node_local_trainer_main
-            p = ctx.Process(target=target, args=args, daemon=True)
-            p.start()
-            procs.append(p)
-            tconns.append(parent)
-            tports.append(parent.recv())
-        wconns, wports = [], []
-        for j in range(num_workers):
-            parent, child = ctx.Pipe()
-            p = ctx.Process(target=_ingest_worker_proc_main,
-                            args=(child, authkey, capacity,
-                                  num_trainers + j, tports,
-                                  {"schema": schema_json,
-                                   "chunk_records": chunk_records}),
-                            daemon=True)
-            p.start()
-            procs.append(p)
-            wconns.append(parent)
-            wports.append(parent.recv())
-
-        for path in shard_paths:  # page-cache pre-warm, outside the clock
-            with open(path, "rb") as f:  # toslint: disable=shard-io-discipline
-                while f.read(1 << 22):
-                    pass
-
-        feed_ports = wports if num_workers else tports
-        shares = [shard_paths[i::len(feed_ports)]
-                  for i in range(len(feed_ports))]
-        clients = [DataClient("127.0.0.1", port, authkey, chunk_size=64)
-                   for port in feed_ports]
-        errors: list[BaseException] = []
-
-        def _feed(i: int) -> None:
-            try:
-                clients[i].feed_partition(shares[i], task_key=(0, i))
-                clients[i].send_eof()
-            except BaseException as e:  # noqa: BLE001 - surfaced below
-                errors.append(e)
-
-        threads = [threading.Thread(target=_feed, args=(i,))
-                   for i in range(len(feed_ports))]
-        t0 = time.perf_counter()
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        if errors:
-            # surface NOW: a failed feed skipped its send_eof, so the
-            # recv()s below would block forever on children that never
-            # finish — kill them and raise the real failure instead
-            for p in procs:
-                if p.is_alive():
-                    p.terminate()
-            raise errors[0]
+    for i in range(num_trainers):
+        parent, child = ctx.Pipe()
         if num_workers:
-            # worker EOFs end their service loops; the trainers then get
-            # theirs so EndOfFeed queues BEHIND every forwarded chunk
-            for conn in wconns:
-                conn.recv()
-                conn.send(None)  # lets the child go: see _report
-            eofs = [DataClient("127.0.0.1", port, authkey)
-                    for port in tports]
-            for c in eofs:
-                c.send_eof()
-                c.close()
-        totals = [conn.recv() for conn in tconns]
-        for conn in tconns:
-            conn.send(None)      # lets the child go: see _report
-        elapsed = time.perf_counter() - t0
-        for c in clients:
-            c.close()
+            args = (child, authkey, capacity, i, count_col)
+            target = _disagg_trainer_main
+        else:
+            args = (child, authkey, capacity, i,
+                    {"schema": schema_json, "chunk_records": chunk_records},
+                    count_col)
+            target = _node_local_trainer_main
+        p = ctx.Process(target=target, args=args, daemon=True)
+        p.start()
+        procs.append(p)
+        tconns.append(parent)
+        tports.append(parent.recv())
+    wconns, wports = [], []
+    for j in range(num_workers):
+        parent, child = ctx.Pipe()
+        p = ctx.Process(target=_ingest_worker_proc_main,
+                        args=(child, authkey, capacity,
+                              num_trainers + j, tports,
+                              {"schema": schema_json,
+                               "chunk_records": chunk_records}),
+                        daemon=True)
+        p.start()
+        procs.append(p)
+        wconns.append(parent)
+        wports.append(parent.recv())
+
+    for path in shard_paths:  # page-cache pre-warm, outside the clock
+        with open(path, "rb") as f:  # toslint: disable=shard-io-discipline
+            while f.read(1 << 22):
+                pass
+
+    feed_ports = wports if num_workers else tports
+    shares = [shard_paths[i::len(feed_ports)]
+              for i in range(len(feed_ports))]
+    clients = [DataClient("127.0.0.1", port, authkey, chunk_size=64)
+               for port in feed_ports]
+    errors: list[BaseException] = []
+
+    def _feed(i: int) -> None:
+        try:
+            clients[i].feed_partition(shares[i], task_key=(0, i))
+            clients[i].send_eof()
+        except BaseException as e:  # noqa: BLE001 - surfaced below
+            errors.append(e)
+
+    threads = [threading.Thread(target=_feed, args=(i,))
+               for i in range(len(feed_ports))]
+    t0 = time.perf_counter()
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    if errors:
+        # surface NOW: a failed feed skipped its send_eof, so the
+        # recv()s below would block forever on children that never
+        # finish — kill them and raise the real failure instead
         for p in procs:
-            p.join(timeout=30)
             if p.is_alive():
                 p.terminate()
-        if errors:
-            raise errors[0]
-        rows = sum(t[0] for t in totals)
-        trainer_cpu = sum(t[1] for t in totals)
-        if rows != expect_rows:
-            raise RuntimeError(f"trainer-side rows {rows} != exact "
-                               f"{expect_rows}")
-        return {"num_trainers": num_trainers, "num_workers": num_workers,
-                "seconds": round(elapsed, 4),
-                "mb_per_s": round(total_bytes / elapsed / 1e6, 1),
-                "rows_per_s": round(rows / elapsed, 1),
-                # what the tier actually moves OFF the trainer: CPU seconds
-                # the trainer cores spent per row (recv+unpickle+slice in
-                # disaggregated mode vs read+CRC+columnar decode+slice
-                # node-locally) — the per-core entitlement number that
-                # holds on any box, spare cores or not
-                "trainer_cpu_secs": round(trainer_cpu, 4),
-                "rows_per_trainer_cpu_s": (round(rows / trainer_cpu, 1)
-                                           if trainer_cpu > 0 else None)}
-    finally:
-        if prev_ring is None:
-            os.environ.pop("TOS_SHM_RING", None)
-        else:
-            os.environ["TOS_SHM_RING"] = prev_ring
+        raise errors[0]
+    if num_workers:
+        # worker EOFs end their service loops; the trainers then get
+        # theirs so EndOfFeed queues BEHIND every forwarded chunk
+        for conn in wconns:
+            conn.recv()
+            conn.send(None)  # lets the child go: see _report
+        eofs = [DataClient("127.0.0.1", port, authkey)
+                for port in tports]
+        for c in eofs:
+            c.send_eof()
+            c.close()
+    totals = [conn.recv() for conn in tconns]
+    for conn in tconns:
+        conn.send(None)      # lets the child go: see _report
+    elapsed = time.perf_counter() - t0
+    for c in clients:
+        c.close()
+    for p in procs:
+        p.join(timeout=30)
+        if p.is_alive():
+            p.terminate()
+    if errors:
+        raise errors[0]
+    rows = sum(t[0] for t in totals)
+    trainer_cpu = sum(t[1] for t in totals)
+    if rows != expect_rows:
+        raise RuntimeError(f"trainer-side rows {rows} != exact "
+                           f"{expect_rows}")
+    return {"num_trainers": num_trainers, "num_workers": num_workers,
+            "seconds": round(elapsed, 4),
+            "mb_per_s": round(total_bytes / elapsed / 1e6, 1),
+            "rows_per_s": round(rows / elapsed, 1),
+            # what the tier actually moves OFF the trainer: CPU seconds
+            # the trainer cores spent per row (recv+unpickle+slice in
+            # disaggregated mode vs read+CRC+columnar decode+slice
+            # node-locally) — the per-core entitlement number that
+            # holds on any box, spare cores or not
+            "trainer_cpu_secs": round(trainer_cpu, 4),
+            "rows_per_trainer_cpu_s": (round(rows / trainer_cpu, 1)
+                                       if trainer_cpu > 0 else None)}
 
 
 def _run_cache_epochs(shard_paths: list, schema_json: str, cache_bytes: int,

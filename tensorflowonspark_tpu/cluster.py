@@ -704,8 +704,8 @@ class TPUCluster:
                     # (and every surviving worker spin-waiting on it) for the
                     # full ~11-minute socket budget; the worker's own later
                     # requeue is then a safe no-op.  The client teardown
-                    # matters for the same reason: a worker blocked inside a
-                    # dead ring peer (no RST) is woken instead of waited on.
+                    # matters for the same reason: a worker blocked on a
+                    # dead peer (no RST) is woken instead of waited on.
                     self._requeue_dead_slot(eid)
                     self.supervisor.handle_death(eid)
                 continue
@@ -777,7 +777,7 @@ class TPUCluster:
 
     def _drop_client(self, executor_id: int, *, abort: bool = False) -> None:
         """Discard (and best-effort close) the slot's cached data client —
-        its socket/ring died with the failure that led here.  ``abort=True``
+        its socket died with the failure that led here.  ``abort=True``
         (the monitor's death declaration) tears the socket down WITHOUT the
         per-client lock, so a feed worker wedged mid-call on the dead peer is
         woken instead of waited on."""
@@ -1762,11 +1762,9 @@ class TPUCluster:
 
         One-attempt dials throughout: the default 3x60s backoff would
         stack ~185s per queue against a blackholed host, all outside the
-        caller's timeout budget.  The retry client skips shm-ring
-        negotiation — no ring handshake just to deliver a ~20-byte EOF
-        frame.  A node whose process already exited is a normal teardown
-        race (its map_fun finished and closed its data plane first), not
-        a failure."""
+        caller's timeout budget.  A node whose process already exited is a
+        normal teardown race (its map_fun finished and closed its data
+        plane first), not a failure."""
         try:
             self._client(executor_id, connect_timeout=5.0,
                          connect_attempts=1).send_eof(qname)
@@ -1784,7 +1782,7 @@ class TPUCluster:
             try:
                 meta = self._fresh_meta(executor_id)
                 retry = DataClient(meta["host"], meta["data_port"],
-                                   self.authkey, prefer_ring=False,
+                                   self.authkey,
                                    call_timeout=30.0, stall_timeout=30.0,
                                    connect_timeout=5.0, connect_attempts=1)
                 try:

@@ -297,10 +297,10 @@ def _rebuild_array_column(dtype_str, shape, *bufs) -> "_ArrayColumn":
     for b in bufs:
         arr = np.frombuffer(b, dtype=np.dtype(dtype_str)).reshape(shape)
         if not arr.flags.writeable:
-            # read-only receive buffers (bytes-backed ring records, in-band
-            # fallback) must not leak into user code: pickled ndarrays were
-            # always writable, and whether a map_fun may normalize in place
-            # must not depend on which transport delivered the batch
+            # read-only receive buffers (the in-band fallback's bytes) must
+            # not leak into user code: pickled ndarrays were always
+            # writable, and whether a map_fun may normalize in place must
+            # not depend on how the wire framed the batch
             arr = arr.copy()
         rows.append(arr)
     return _ArrayColumn(rows)

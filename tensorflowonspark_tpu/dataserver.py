@@ -5,7 +5,8 @@ Replaces the Spark RDD partition-delivery path of the reference
 closures inside pyspark workers that pushed items into ``TFManager`` remote
 queues (``TFSparkNode.py:~430-580``), here the driver streams partitions over
 a socket directly into the node's in-process ``FeedQueues``.  One hop, no
-manager proxy.
+manager proxy, and one transport: a ``DataClient`` is one authenticated TCP
+connection (loopback when driver and node share a host).
 
 Wire format: length-framed pickle, **after** an HMAC-SHA256
 challenge-response handshake on the shared cluster ``authkey`` (mirroring the
@@ -72,9 +73,6 @@ _MAX_SECTIONS = 1 << 20
 #: schema extension that appends a trace context to ``infer_round``/
 #: ``end_partition`` — a v2 peer never sees the extra element.
 WIRE_VERSION = 3
-# shm-ring v2 records carry an explicit magic (ring records are pickled blobs
-# otherwise, which always start with b"\x80")
-_RING_VEC_MAGIC = b"TOSVEC2\x00"
 
 from tensorflowonspark_tpu.utils.net import (  # noqa: E402
     hmac_handshake_client as _hmac_handshake_client,
@@ -213,61 +211,6 @@ def _recv(sock: socket.socket) -> Any:
     return _recv_frame(sock)[0]
 
 
-# -- shm-ring record framing (same two formats over ring records) -------------
-
-
-def _ring_vec_record(obj: Any) -> list:
-    """Buffer list for ONE segmented ring record carrying a v2 frame
-    (pushed join-free via ``ShmRing.put_buffers``)."""
-    body, raws = _vec_parts(obj)
-    header = bytearray(_RING_VEC_MAGIC)
-    header += _LEN.pack(len(raws) + 1)
-    header += _LEN.pack(len(body))
-    for r in raws:
-        header += _LEN.pack(r.nbytes)
-    return [header, body, *raws]
-
-
-def _ring_loads(blob: bytes) -> tuple[Any, bool]:
-    """Decode one ring record -> (object, was_vectorized); buffer sections
-    resolve to zero-copy views of the record blob."""
-    if blob[:8] == _RING_VEC_MAGIC:
-        view = memoryview(blob)
-        (nsec,) = _LEN.unpack(view[8:16])
-        if not 1 <= nsec <= _MAX_SECTIONS:
-            raise ValueError(f"corrupt vectorized ring record ({nsec} sections)")
-        lens = struct.unpack(f">{nsec}Q", view[16:16 + 8 * nsec])
-        off = 16 + 8 * nsec
-        body = view[off:off + lens[0]]
-        off += lens[0]
-        bufs = []
-        for ln in lens[1:]:
-            bufs.append(view[off:off + ln])
-            off += ln
-        return pickle.loads(body, buffers=bufs), True
-    return pickle.loads(blob), False
-
-
-def _ring_send(ring, obj: Any, wire: int, timeout: float | None) -> None:
-    if wire >= 2:
-        bufs = _ring_vec_record(obj)
-        ring.put_buffers(bufs, timeout=timeout)
-        telemetry.counter("dataplane.tx_bytes").inc(
-            sum(b.nbytes if isinstance(b, memoryview) else len(b)
-                for b in bufs))
-        telemetry.counter("dataplane.tx_frames").inc()
-        return
-    ring.put(obj, timeout=timeout)
-    telemetry.counter("dataplane.tx_frames").inc()
-
-
-def _ring_recv(ring, timeout: float | None) -> tuple[Any, bool]:
-    blob = ring.get_bytes(timeout=timeout)
-    telemetry.counter("dataplane.rx_bytes").inc(len(blob))
-    telemetry.counter("dataplane.rx_frames").inc()
-    return _ring_loads(blob)
-
-
 class DataServer:
     """Accepts driver feed/inference connections for one node process."""
 
@@ -281,7 +224,6 @@ class DataServer:
         self.port: int = self._sock.getsockname()[1]
         self._stopped = threading.Event()
         self._thread = threading.Thread(target=self._accept_loop, daemon=True, name="dataserver")
-        self._ring_threads: list[threading.Thread] = []
 
     def start(self) -> int:
         self._thread.start()
@@ -293,14 +235,6 @@ class DataServer:
             self._sock.close()
         except OSError:  # toslint: allow-silent(closing the listener is what unblocks the accept loop; a second close racing it is fine)
             pass
-        # Wait briefly for ring threads to run their cleanup (close_write):
-        # they are daemons, and if the node process exits before a ring's
-        # close_write, a driver blocked in ring.get() waits out its FULL call
-        # timeout (~minutes) instead of seeing RingClosed immediately — the
-        # teardown race behind sporadic 600s shutdown stalls.  The threads
-        # wake from their bounded waits within a few seconds.
-        for t in self._ring_threads:
-            t.join(timeout=8.0)
 
     # -- server internals ----------------------------------------------------
 
@@ -569,143 +503,32 @@ class DataServer:
             except queue.Empty:  # toslint: allow-silent(collect drains what is already there; empty just ends this round-trip)
                 pass
             return ("ok", results)
-        if op == "ring_setup":
-            # Same-host fast path: move the request/reply stream onto a pair
-            # of native shared-memory rings (shm_ring.py).  Only offered
-            # after the TCP HMAC handshake has already authenticated the
-            # peer; the rings themselves are 0600 same-user segments.
-            try:
-                from tensorflowonspark_tpu import shm_ring
-
-                capacity = int(msg[1]) if len(msg) > 1 else 64 * 1024 * 1024
-                c2s = shm_ring.ShmRing.create(capacity=capacity)
-                s2c = shm_ring.ShmRing.create(capacity=capacity)
-            except Exception as e:  # noqa: BLE001 - no compiler/shm: stay on TCP
-                return ("err", f"ring unavailable: {e}")
-            t = threading.Thread(target=self._serve_ring, args=(c2s, s2c),
-                                 daemon=True, name="dataserver-ring")
-            # prune finished threads so repeated ring setups (driver
-            # reconnects/downgrades) don't accumulate dead Thread objects
-            self._ring_threads = [r for r in self._ring_threads if r.is_alive()]
-            self._ring_threads.append(t)
-            t.start()
-            return ("ok", c2s.name, s2c.name)
         if op == "close":
             return ("ok",)
         return ("err", f"unknown op {op!r}")
-
-    def _serve_ring(self, c2s, s2c) -> None:
-        from tensorflowonspark_tpu.shm_ring import RingClosed, RingTimeout
-
-        unlinked = False
-        try:
-            while not self._stopped.is_set():
-                try:
-                    msg, was_vec = _ring_recv(c2s, timeout=1.0)
-                except RingTimeout:
-                    continue
-                except RingClosed:
-                    return
-                if not unlinked:
-                    # First message proves the client has mmap'd both rings:
-                    # unlink the names eagerly so the segments can never
-                    # outlive the processes (POSIX shm persists past process
-                    # death until unlinked — 2x capacity leaked per abandoned
-                    # pair otherwise).
-                    c2s.unlink()
-                    s2c.unlink()
-                    unlinked = True
-                try:
-                    reply = self._handle(msg)
-                except faultinject.FaultInjected:
-                    # `sever` on the ring path: abandon the ring with no
-                    # reply (finally runs close_write, so the driver sees a
-                    # dead data plane, mirroring the TCP sever).
-                    logger.warning("fault injection: severing ring data plane")
-                    return
-                except Exception as e:  # noqa: BLE001 - mirror TCP behaviour
-                    logger.exception("dataserver ring op failed")
-                    reply = ("err", f"{type(e).__name__}: {e}")
-                # Bounded reply put: a client that detached without draining
-                # would otherwise pin this thread (and the finally-cleanup)
-                # forever.  Retry-with-short-timeout is only safe for a
-                # single WHOLE record (a timed-out push commits nothing);
-                # a segmented put that times out mid-stream leaves partial
-                # segments in flight (shm_ring contract) — one bounded
-                # attempt, then abandon the ring.
-                vec_bufs = _ring_vec_record(reply) if was_vec else None
-                if vec_bufs is not None and len(vec_bufs) > 2:
-                    # buffer-carrying v2 reply: join-free segmented push,
-                    # single bounded attempt (mid-stream timeout is fatal)
-                    try:
-                        s2c.put_buffers(vec_bufs, timeout=self.feed_timeout)
-                    except RingTimeout:
-                        logger.warning("ring client not draining a vectorized "
-                                       "reply; abandoning ring")
-                        return
-                    if msg[0] == "close":
-                        return
-                    continue
-                if vec_bufs is not None:
-                    data = b"".join(vec_bufs)  # header+body only: tiny
-                else:
-                    data = pickle.dumps(reply, protocol=pickle.HIGHEST_PROTOCOL)
-                if len(data) + 1 <= s2c.capacity // 2:
-                    sent = False
-                    deadline = _monotonic() + self.feed_timeout
-                    while not sent and not self._stopped.is_set():
-                        try:
-                            s2c.put_bytes(data, timeout=5.0)
-                            sent = True
-                        except RingTimeout:
-                            if _monotonic() > deadline:
-                                logger.warning(
-                                    "ring client not draining replies for "
-                                    "%.0fs; abandoning ring", self.feed_timeout)
-                                return
-                    if not sent:
-                        return
-                else:
-                    try:
-                        s2c.put_bytes(data, timeout=self.feed_timeout)
-                    except RingTimeout:
-                        logger.warning("ring client not draining a segmented "
-                                       "reply; abandoning ring")
-                        return
-                if msg[0] == "close":
-                    return
-        except (RingClosed, OSError):
-            return
-        finally:
-            s2c.close_write()
-            for ring in (c2s, s2c):
-                ring.detach()
-                if not unlinked:
-                    ring.unlink()
 
 
 class DataClient:
     """Driver-side connection to one node's DataServer."""
 
     def __init__(self, host: str, port: int, authkey: bytes, chunk_size: int = 512,
-                 prefer_ring: bool = True, ring_capacity: int = 64 * 1024 * 1024,
                  call_timeout: float = 660.0, stall_timeout: float = 600.0,
                  connect_timeout: float = 60.0, connect_attempts: int | None = None,
                  send_window: int | None = None):
         self.chunk_size = chunk_size
-        self.ring_capacity = ring_capacity
         # Inference stall budget: infer_partition raises when no item was
         # accepted AND no result arrived for this long (the reference's
         # feed_timeout semantics, applied driver-side now that individual
         # round-trips are short).
         self.stall_timeout = stall_timeout
-        # Ring-path request/reply timeout.  Must exceed the server's
+        # Request/reply timeout on the socket.  Must exceed the server's
         # feed_timeout (its puts can legitimately block that long under
-        # backpressure) but must be finite: if the node process is SIGKILLed
-        # the ring's closed flag is never set, and an infinite wait would
-        # wedge the whole driver data plane inside self._lock.
+        # backpressure) but must be finite: a node that is wedged but alive
+        # (or a host that went dark without a FIN) never closes the
+        # connection, and an infinite wait would pin the whole driver data
+        # plane inside self._lock.
         self.call_timeout = call_timeout
-        from tensorflowonspark_tpu.utils.envtune import env_bool, env_int
+        from tensorflowonspark_tpu.utils.envtune import env_int
         from tensorflowonspark_tpu.utils.net import connect_with_backoff
 
         # Backoff on the dial (TOS_CONNECT_ATTEMPTS): a node mid-restart has
@@ -735,27 +558,6 @@ class DataClient:
         # every other connection.
         self.sender_gate = contextlib.nullcontext
         self._wire = self._negotiate_wire()
-        self._c2s = self._s2c = None
-        if prefer_ring:
-            # TOS_SHM_RING: unset -> one-shot measured probe decides
-            # (utils.net.ring_beats_loopback); "1"/"0" force either way.
-            # A junk value must degrade to the documented default (probe),
-            # never silently force a transport: env_bool falls back to its
-            # default on junk, so two reads with opposite defaults agreeing
-            # is the "parsed cleanly" signal.
-            from tensorflowonspark_tpu.utils.envtune import env_str
-
-            forced: bool | None = None
-            if env_str("TOS_SHM_RING", ""):
-                as_true = env_bool("TOS_SHM_RING", True)
-                forced = as_true if as_true == env_bool("TOS_SHM_RING", False) \
-                    else None
-            if forced is not False:
-                self._try_ring_setup(host, probe=forced is None)
-        # transport selection, one count per client connection (the ring
-        # probe decision is otherwise invisible outside debug logs)
-        telemetry.counter("dataplane.clients_ring" if self.using_ring
-                          else "dataplane.clients_tcp").inc()
 
     def _negotiate_wire(self) -> int:
         """Probe the server's wire version with a v1 ``hello``: a current
@@ -779,37 +581,6 @@ class DataClient:
                          exc_info=True)
         return 1
 
-    def _try_ring_setup(self, host: str, probe: bool = False) -> None:
-        """Upgrade to shared-memory rings when the node is on this host."""
-        from tensorflowonspark_tpu.utils.net import local_ip, ring_beats_loopback
-
-        if host not in ("127.0.0.1", "localhost", local_ip()):
-            return
-        try:
-            from tensorflowonspark_tpu import shm_ring
-
-            if not shm_ring.available():
-                return
-            if probe and not ring_beats_loopback():
-                # measured slower than loopback TCP on this host: never
-                # silently pick the slower transport (VERDICT r5 weak #5)
-                return
-            with self._lock:
-                _send(self._sock, ("ring_setup", self.ring_capacity), self._wire)
-                reply = _recv(self._sock)
-            if not (isinstance(reply, tuple) and reply[0] == "ok"):
-                return
-            self._c2s = shm_ring.ShmRing.attach(reply[1])
-            self._s2c = shm_ring.ShmRing.attach(reply[2])
-            logger.info("data plane upgraded to shm ring (%s)", reply[1])
-        except Exception:  # noqa: BLE001 - any failure: stay on TCP
-            logger.debug("shm ring setup failed; using TCP", exc_info=True)
-            self._c2s = self._s2c = None
-
-    @property
-    def using_ring(self) -> bool:
-        return self._c2s is not None
-
     def _check(self, reply: tuple) -> tuple:
         if not (isinstance(reply, tuple) and reply and reply[0] == "ok"):
             raise RuntimeError(f"data plane error: {reply[1] if len(reply) > 1 else reply!r}")
@@ -818,28 +589,8 @@ class DataClient:
     def _call(self, msg: tuple, timeout: float | None = None) -> tuple:
         timeout = self.call_timeout if timeout is None else timeout
         with self._lock:
-            if self._c2s is not None:
-                try:
-                    _ring_send(self._c2s, msg, self._wire, timeout)
-                except (EOFError, TimeoutError, OSError, ValueError):
-                    # Send failed ⇒ the server never saw the request: safe to
-                    # downgrade to the healthy TCP socket and retry there.
-                    logger.warning("shm ring send failed; downgrading to TCP",
-                                   exc_info=True)
-                    self._teardown_ring()
-                else:
-                    try:
-                        return self._check(_ring_recv(self._s2c, timeout)[0])
-                    except (EOFError, TimeoutError, OSError, ValueError) as e:
-                        # Reply path failed AFTER the server may have acted:
-                        # retrying could double-feed, so surface the error.
-                        # Future calls use TCP.
-                        self._teardown_ring()
-                        raise RuntimeError(
-                            f"data plane error: ring reply lost ({e})") from e
-            # TCP path honors the same bound: the socket is otherwise
-            # blocking, and e.g. a short-timeout EOF must not wait forever
-            # on a wedged (but alive) node.
+            # the socket is otherwise blocking, and e.g. a short-timeout EOF
+            # must not wait forever on a wedged (but alive) node
             self._sock.settimeout(timeout)
             try:
                 _send(self._sock, msg, self._wire)
@@ -847,23 +598,13 @@ class DataClient:
             except (TimeoutError, OSError):
                 # the stream may now hold a partial frame or a late reply;
                 # reusing it would hand a future call the WRONG response —
-                # poison the socket (mirror of _teardown_ring)
+                # poison the socket
                 with contextlib.suppress(OSError):
                     self._sock.close()
                 raise
             finally:
                 with contextlib.suppress(OSError):
                     self._sock.settimeout(None)
-
-    def _teardown_ring(self) -> None:
-        if self._c2s is not None:
-            telemetry.counter("dataplane.ring_downgrades").inc()
-            for ring in (self._c2s, self._s2c):
-                try:
-                    ring.detach()
-                except Exception:  # noqa: BLE001  # toslint: allow-silent(downgrade path: the ring is already failed, TCP takes over either way)
-                    pass
-            self._c2s = self._s2c = None
 
     def _pack_items(self, chunk: list) -> Any:
         """Columnar-pack a chunk for the v2 wire (``data.pack_chunk``); v1
@@ -909,29 +650,9 @@ class DataClient:
 
     def _stream_chunks(self, items: Iterable[Any], qname: str) -> str:
         with self._lock:
-            if self._c2s is not None:
-                try:
-                    return self._pump_chunks(
-                        lambda m: _ring_send(self._c2s, m, self._wire,
-                                             self.call_timeout),
-                        lambda: _ring_recv(self._s2c, self.call_timeout)[0],
-                        items, qname)
-                except (EOFError, TimeoutError, OSError, ValueError,
-                        RuntimeError) as e:
-                    # A pipelined burst cannot tell a lost send from a lost
-                    # reply, and an err reply leaves unread acks behind: the
-                    # ring state is unknown either way — drop to TCP for
-                    # future calls and let the ledger re-feed the partition.
-                    self._teardown_ring()
-                    if isinstance(e, RuntimeError):
-                        raise
-                    raise RuntimeError(
-                        f"data plane error: ring feed failed ({e})") from e
             self._sock.settimeout(self.call_timeout)
             try:
-                return self._pump_chunks(
-                    lambda m: _send(self._sock, m, self._wire),
-                    lambda: _recv(self._sock), items, qname)
+                return self._pump_chunks(items, qname)
             except (TimeoutError, OSError, RuntimeError):
                 # mid-burst failure (or an err reply with acks still unread):
                 # the stream holds frames a future call would misread —
@@ -943,7 +664,7 @@ class DataClient:
                 with contextlib.suppress(OSError):
                     self._sock.settimeout(None)
 
-    def _pump_chunks(self, send, recv, items: Iterable[Any], qname: str) -> str:
+    def _pump_chunks(self, items: Iterable[Any], qname: str) -> str:
         window = max(1, int(self.send_window))
         outstanding = 0
         state = "running"
@@ -952,34 +673,34 @@ class DataClient:
 
         def drain_one() -> None:
             nonlocal outstanding, state
-            reply = self._check(recv())
+            reply = self._check(_recv(self._sock))
             outstanding -= 1
             occupancy.set(outstanding)
             if len(reply) > 1 and reply[1] == "terminating":
                 state = "terminating"
 
+        def send_chunk(chunk: list) -> None:
+            nonlocal outstanding, chunks_sent, rows_sent
+            with self.sender_gate():
+                _send(self._sock,
+                      ("feed", qname, self._pack_items(chunk)), self._wire)
+            chunks_sent += 1
+            rows_sent += len(chunk)
+            outstanding += 1
+            occupancy.set(outstanding)
+
         chunk: list = []
         for item in items:
             chunk.append(item)
             if len(chunk) >= self.chunk_size:
-                with self.sender_gate():
-                    send(("feed", qname, self._pack_items(chunk)))
-                chunks_sent += 1
-                rows_sent += len(chunk)
+                send_chunk(chunk)
                 chunk = []
-                outstanding += 1
-                occupancy.set(outstanding)
                 while outstanding >= window:
                     drain_one()
                 if state == "terminating":
                     break  # consumer is done; drop the rest fast
         if chunk and state != "terminating":
-            with self.sender_gate():
-                send(("feed", qname, self._pack_items(chunk)))
-            chunks_sent += 1
-            rows_sent += len(chunk)
-            outstanding += 1
-            occupancy.set(outstanding)
+            send_chunk(chunk)
         while outstanding:
             drain_one()
         telemetry.counter("dataplane.chunks_sent").inc(chunks_sent)
@@ -1100,29 +821,14 @@ class DataClient:
         """Lockless immediate teardown (the monitor's death path): wake any
         thread wedged inside ``_call`` by shutting the socket down under it.
         ``close()`` would first wait on the per-client lock that thread holds
-        for its full call timeout (~11 min against a dead ring peer) —
+        for its full call timeout (~11 min against a peer that went dark) —
         exactly the stall a death declaration exists to cut short."""
-        c2s, s2c = self._c2s, self._s2c
-        self._c2s = self._s2c = None
-        if c2s is not None:
-            with contextlib.suppress(Exception):
-                c2s.close_write()
-                c2s.detach()
-                s2c.detach()
         with contextlib.suppress(OSError):
             self._sock.shutdown(socket.SHUT_RDWR)
         with contextlib.suppress(OSError):
             self._sock.close()
 
     def close(self) -> None:
-        if self._c2s is not None:
-            try:
-                self._c2s.close_write()  # ring server drains, then cleans up
-                self._c2s.detach()
-                self._s2c.detach()
-            except Exception:  # noqa: BLE001
-                logger.debug("ring teardown failed during close", exc_info=True)
-            self._c2s = self._s2c = None
         try:
             with self._lock:
                 # Bounded, unlike the old bare blocking recv: the lockgraph

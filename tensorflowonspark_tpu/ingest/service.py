@@ -179,7 +179,7 @@ class TrainerForwarder:
     ``endpoints`` is ``[(executor_id, host, data_port), ...]`` of every
     trainer (the worker reads them off ``ctx.cluster_info``).  Transport is
     the ordinary :class:`~tensorflowonspark_tpu.dataserver.DataClient`
-    (authkey handshake, v2/v3 wire, ring upgrade where same-host) — the
+    (authkey handshake, v2/v3 wire) — the
     dial-discipline transport home; this class never opens a raw socket.
 
     Target selection: ``shuffle`` on (``TOS_INGEST_SHUFFLE``, the default)

@@ -199,9 +199,6 @@ _ALL = (
     Knob("TOS_RESTART_BACKOFF_MAX", "float", "10.0",
          "Supervised-restart backoff: cap on the per-restart delay "
          "(seconds)."),
-    Knob("TOS_RING_PROBE_BYTES", "int", "65536",
-         "Payload size for the one-shot ring-vs-loopback transport probe "
-         "(cached per process; see TOS_SHM_RING)."),
     Knob("TOS_SEND_WINDOW", "int", "4",
          "Pipelined feed: max unacknowledged chunk frames in flight per "
          "node connection (1 = strict request/reply ping-pong)."),
@@ -259,10 +256,6 @@ _ALL = (
     Knob("TOS_SERVE_TIMEOUT", "float", "30",
          "Default per-request deadline (seconds) for gateway predict "
          "calls; expired requests are answered with ServeTimeout."),
-    Knob("TOS_SHM_RING", "str", "(unset: measured probe decides)",
-         "Same-host shared-memory ring for the data plane: 1 forces it on, "
-         "0 forces TCP, unset lets a one-shot ring-vs-loopback probe pick "
-         "the faster transport."),
     Knob("TOS_SHUTDOWN_TIMEOUT", "float", "120",
          "Budget for shutdown() to join node processes before escalating "
          "to terminate/kill."),

@@ -237,132 +237,6 @@ def hmac_handshake_server(sock: socket.socket, authkey: bytes) -> bool:
     return ok
 
 
-# -- same-host transport probe ------------------------------------------------
-#
-# PERF_NOTES round 5 measured the shm ring ~3x SLOWER than loopback TCP on a
-# 1-core box (its request/reply ping-pong pays scheduler wakeups the kernel
-# TCP path amortizes) yet it used to be selected unconditionally.  The probe
-# below settles ring-vs-TCP empirically, once per process: a short measured
-# round-trip exchange on each transport, cached.  ``TOS_SHM_RING`` still
-# forces either way (1 = always ring, 0 = never); unset means "probe".
-
-_ring_probe_cache: dict[int, bool] = {}
-
-
-def _probe_tcp_loopback(payload: bytes, rounds: int) -> float:
-    """Seconds for ``rounds`` loopback-TCP round-trips of ``payload``."""
-    import threading
-    import time
-
-    srv = bound_socket("127.0.0.1")
-    port = srv.getsockname()[1]
-    n = len(payload)
-
-    def _echo() -> None:
-        try:
-            conn, _ = srv.accept()
-            with conn:
-                buf = bytearray(n)
-                for _ in range(rounds):
-                    recv_exact_into(conn, buf)
-                    conn.sendall(buf)
-        except OSError:
-            return
-
-    t = threading.Thread(target=_echo, daemon=True, name="tcp-probe-echo")
-    t.start()
-    try:
-        with socket.create_connection(("127.0.0.1", port), timeout=5.0) as c:
-            c.settimeout(5.0)
-            buf = bytearray(n)
-            t0 = time.perf_counter()
-            for _ in range(rounds):
-                c.sendall(payload)
-                recv_exact_into(c, buf)
-            return time.perf_counter() - t0
-    finally:
-        srv.close()
-        t.join(timeout=5.0)
-
-
-def _probe_shm_ring(payload: bytes, rounds: int) -> float:
-    """Seconds for ``rounds`` shm-ring round-trips of ``payload``; raises
-    when the native ring is unavailable."""
-    import contextlib
-    import threading
-    import time
-
-    from tensorflowonspark_tpu import shm_ring
-
-    # both creates INSIDE the cleanup scope: if the second one fails (shm
-    # quota, /dev/shm full) the first segment must still be unlinked —
-    # POSIX shm persists past process death until someone unlinks it
-    c2s = s2c = None
-    t = None
-    try:
-        c2s = shm_ring.ShmRing.create(capacity=max(1 << 20, 4 * len(payload)))
-        s2c = shm_ring.ShmRing.create(capacity=max(1 << 20, 4 * len(payload)))
-
-        def _echo() -> None:
-            try:
-                for _ in range(rounds):
-                    s2c.put_bytes(c2s.get_bytes(timeout=5.0), timeout=5.0)
-            except Exception:  # noqa: BLE001 - probe peer: any failure ends it
-                return
-
-        t = threading.Thread(target=_echo, daemon=True, name="ring-probe-echo")
-        t.start()
-        t0 = time.perf_counter()
-        for _ in range(rounds):
-            c2s.put_bytes(payload, timeout=5.0)
-            s2c.get_bytes(timeout=5.0)
-        return time.perf_counter() - t0
-    finally:
-        if t is not None:
-            t.join(timeout=5.0)
-        for ring in (c2s, s2c):
-            if ring is not None:
-                for cleanup in (ring.close_write, ring.unlink, ring.detach):
-                    with contextlib.suppress(Exception):
-                        cleanup()
-
-
-def ring_beats_loopback(payload_bytes: int | None = None,
-                        rounds: int = 16) -> bool:
-    """Measured once per process (then cached): is the same-host shm ring
-    actually faster than loopback TCP for data-plane-sized messages?
-
-    Called by ``DataClient`` on the first same-host dial when ``TOS_SHM_RING``
-    is unset — the slower transport is never silently selected again
-    (VERDICT r5 weak #5).  Payload size defaults from ``TOS_RING_PROBE_BYTES``.
-    """
-    import logging
-
-    from tensorflowonspark_tpu.utils.envtune import env_int
-
-    if payload_bytes is None:
-        payload_bytes = env_int("TOS_RING_PROBE_BYTES", 64 * 1024)
-    cached = _ring_probe_cache.get(payload_bytes)
-    if cached is not None:
-        return cached
-    payload = b"\x5a" * payload_bytes
-    try:
-        ring_s = _probe_shm_ring(payload, rounds)
-        tcp_s = _probe_tcp_loopback(payload, rounds)
-        verdict = ring_s < tcp_s
-        logging.getLogger(__name__).info(
-            "transport probe (%d x %d B round-trips): ring %.1f ms, "
-            "loopback TCP %.1f ms -> %s", rounds, payload_bytes,
-            ring_s * 1e3, tcp_s * 1e3, "ring" if verdict else "TCP")
-    except Exception:  # noqa: BLE001 - no compiler/shm: TCP is the only option
-        logging.getLogger(__name__).debug(
-            "transport probe could not run the ring side; staying on TCP",
-            exc_info=True)
-        verdict = False
-    _ring_probe_cache[payload_bytes] = verdict
-    return verdict
-
-
 def hmac_handshake_client(sock: socket.socket, authkey: bytes) -> bool:
     import hmac
     import os

@@ -1085,13 +1085,13 @@ def test_env_str_passthrough_and_default(monkeypatch):
     ("junk", True),  # junk degrades to the default, never flips silently
 ])
 def test_env_bool_values(monkeypatch, raw, expect):
-    monkeypatch.setenv("TOS_SHM_RING", raw)
-    assert envtune.env_bool("TOS_SHM_RING", True) is expect
+    monkeypatch.setenv("TOS_INGEST_SHUFFLE", raw)
+    assert envtune.env_bool("TOS_INGEST_SHUFFLE", True) is expect
 
 
 def test_env_bool_unset_returns_default(monkeypatch):
-    monkeypatch.delenv("TOS_SHM_RING", raising=False)
-    assert envtune.env_bool("TOS_SHM_RING", False) is False
+    monkeypatch.delenv("TOS_INGEST_SHUFFLE", raising=False)
+    assert envtune.env_bool("TOS_INGEST_SHUFFLE", False) is False
 
 
 def test_unregistered_knob_read_warns_once(monkeypatch, caplog):

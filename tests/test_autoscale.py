@@ -254,12 +254,11 @@ def test_default_barrier_count_tracks_retirement():
 # -- end-to-end: scale-out mid-train ------------------------------------------
 
 
-def test_scale_out_mid_train_picks_up_ledger_partitions(tmp_path, monkeypatch):
+def test_scale_out_mid_train_picks_up_ledger_partitions(tmp_path):
     """1-node STREAMING train with a slow consumer; resize(2) mid-feed.
     The newcomer must be admitted through rendezvous, receive rebalanced
     ledger partitions, and the union of consumed records must cover the
     fed records exactly (duplicates allowed, loss not)."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     telemetry.reset()
     items = list(range(120))
     parts = [items[i * 10:(i + 1) * 10] for i in range(12)]
@@ -318,13 +317,12 @@ def _serve_cluster(tmp_path, *, num_executors=2, elastic=True,
     return cluster, export
 
 
-def test_scale_in_drains_serving_exactly_once(tmp_path, monkeypatch):
+def test_scale_in_drains_serving_exactly_once(tmp_path):
     """2-replica serving cluster under continuous load; resize(1) mid-flight.
     Every accepted request is answered exactly once with the right result
     (in-flight batches on the victim finish or retry on the survivor), the
     victim exits cleanly, and retirement is classified as intentional: no
     respawn, no restart budget, no elastic.restarts_total."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     telemetry.reset()
     cluster, export = _serve_cluster(tmp_path)
     base = np.arange(4, dtype=np.float32)
@@ -385,13 +383,12 @@ def test_scale_in_drains_serving_exactly_once(tmp_path, monkeypatch):
     assert cluster.coordinator.errors() == []
 
 
-def test_scale_in_refused_during_live_inference(tmp_path, monkeypatch):
+def test_scale_in_refused_during_live_inference(tmp_path):
     """Inference partitions are statically assigned at call start (no live
     re-feed session like train()), so a scale-in landing mid-call would
     EOF a worker that still owns partitions and fail the whole call on a
     healthy cluster — resize() refuses instead, and the shrink succeeds
     the moment the call completes."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     telemetry.reset()
     cluster = tcluster.run(
         mapfuns.echo_inference, {},
@@ -417,13 +414,12 @@ def test_scale_in_refused_during_live_inference(tmp_path, monkeypatch):
     assert cluster.coordinator.errors() == []
 
 
-def test_scale_in_non_elastic_drains_promptly(tmp_path, monkeypatch):
+def test_scale_in_non_elastic_drains_promptly(tmp_path):
     """resize() needs no supervisor: on an ``elastic=False`` cluster the
     retired slot's feed worker still polls the victim's consumption
     watermark, so scale-in completes as soon as the backlog is consumed —
     instead of burning the whole drain_timeout and then terminating a
     perfectly healthy node (exit code 0 pins the clean-EOF path)."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     telemetry.reset()
     items = list(range(80))
     parts = [items[i * 10:(i + 1) * 10] for i in range(8)]
@@ -470,7 +466,6 @@ def test_kill_during_drain_does_not_wedge_resize(tmp_path, monkeypatch):
     partitions: the resize must complete (the ledger re-feed owns its
     partitions — survivors deliver them), coverage must hold, and the death
     mid-drain must still count as retirement (no respawn, no budget)."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")  # a SIGKILL leaves rings wedged
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     telemetry.reset()
     items = list(range(120))
@@ -543,12 +538,11 @@ class _QpsStepPolicy(Policy):
         return 2 if qps > self.threshold_qps else 1
 
 
-def test_serving_replicas_follow_load_step(tmp_path, monkeypatch):
+def test_serving_replicas_follow_load_step(tmp_path):
     """The closed loop: a 1-replica serving cluster under a load step must
     scale out through the REAL autoscaler tick loop (spawn, rendezvous,
     router admission), serve from both replicas, then scale back in once
     the load stops — with zero non-503 failures throughout."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     telemetry.reset()
     cluster, export = _serve_cluster(tmp_path, num_executors=1)
     stop = threading.Event()

@@ -9,8 +9,7 @@ from __future__ import annotations
 import pytest
 
 
-def test_bench_autoscale_quick_runs_and_tracks_step(monkeypatch):
-    monkeypatch.setenv("TOS_SHM_RING", "0")
+def test_bench_autoscale_quick_runs_and_tracks_step():
     import bench_autoscale  # repo root is on sys.path via conftest
 
     results = bench_autoscale.bench(quick=True)

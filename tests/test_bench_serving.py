@@ -10,8 +10,7 @@ from __future__ import annotations
 import pytest
 
 
-def test_bench_serving_quick_config_runs(monkeypatch):
-    monkeypatch.setenv("TOS_SHM_RING", "0")
+def test_bench_serving_quick_config_runs():
     import bench_serving  # repo root is on sys.path via conftest
 
     results = bench_serving.bench(quick=True)

@@ -402,7 +402,6 @@ def test_kill_coordinator_mid_streaming_train_recovers(tmp_path, monkeypatch,
     from tensorflowonspark_tpu.telemetry import trace as ttrace
 
     ttrace.collect_final()  # earlier tests' driver events must not pollute
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     arm_driver_faults("kill_coordinator:after_ops=15")
     items = list(range(120))
@@ -442,7 +441,6 @@ def test_kill_coordinator_mid_direct_train_recovers(tmp_path, monkeypatch,
     from tensorflowonspark_tpu.telemetry import trace as ttrace
 
     ttrace.collect_final()
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     arm_driver_faults("kill_coordinator:after_ops=15")
     shard_dir = tmp_path / "shards"
@@ -500,7 +498,6 @@ def test_kill_coordinator_mid_serve_zero_failed_requests(tmp_path, monkeypatch,
     from tensorflowonspark_tpu.telemetry import trace as ttrace
 
     ttrace.collect_final()
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     arm_driver_faults("kill_coordinator:after_ops=40")
     config = {"model": "linear", "in_dim": 4, "out_dim": 4}
@@ -558,7 +555,6 @@ def test_kill_coordinator_mid_sync_train_reforms_exact(tmp_path, monkeypatch,
     from tensorflowonspark_tpu.telemetry import trace as ttrace
 
     ttrace.collect_final()
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     total_steps = 12
     cluster = tcluster.run(
@@ -637,7 +633,6 @@ def test_self_fence_parks_node_until_readmitted(tmp_path, monkeypatch,
     from tensorflowonspark_tpu.telemetry import trace as ttrace
 
     ttrace.collect_final()
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     # coordinator restore waits ~3-5s (jittered); nodes park at 2s of
     # silence and would give up at 8s — recovery lands inside the window
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "4.0")
@@ -679,7 +674,6 @@ def test_flap_and_delay_soak_completes_exact(tmp_path, monkeypatch,
     heartbeats, 3ms injected latency per send) for a whole train — the
     ledger re-feed, reconnecting heartbeats, and (if the flap outlasts the
     death window) incarnation fencing must still deliver every record."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "6")
     # ~8s of paced consumption: the degraded node lives through SEVERAL
     # 1s flap windows (multiple severs + heartbeat-swallowing phases), not

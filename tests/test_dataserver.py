@@ -2,7 +2,9 @@
 feeding paths, SURVEY.md §3.2/§3.3)."""
 
 import threading
+import time
 
+import numpy as np
 import pytest
 
 from tensorflowonspark_tpu.dataserver import DataClient, DataServer
@@ -11,22 +13,52 @@ from tensorflowonspark_tpu.feeding import DataFeed, FeedQueues
 AUTH = b"secret"
 
 
-def start_pair(feed_timeout=5.0, capacity=1024):
+def start_pair(feed_timeout=5.0, capacity=1024, **client_opts):
     queues = FeedQueues(capacity=capacity)
     server = DataServer(queues, AUTH, feed_timeout=feed_timeout)
     port = server.start()
-    client = DataClient("127.0.0.1", port, AUTH, chunk_size=8,
-                        stall_timeout=feed_timeout)
+    client = DataClient("127.0.0.1", port, AUTH,
+                        **{"chunk_size": 8, "stall_timeout": feed_timeout,
+                           **client_opts})
     return queues, server, client
 
 
-def test_feed_partition_and_markers():
-    queues, server, client = start_pair()
+def serve_model(queues, fn):
+    """A map_fun's inference loop on a thread: results are ``fn`` of each
+    row, in order."""
+    def model():
+        feed = DataFeed(queues, train_mode=False)
+        while not feed.should_stop():
+            batch = feed.next_batch(4)
+            if batch:
+                feed.batch_results([fn(x) for x in batch])
+
+    t = threading.Thread(target=model, daemon=True)
+    t.start()
+    return t
+
+
+# 200 KiB rows: one chunk of four is far past a socket buffer, so a frame
+# is sent and received in many pieces, in both directions
+BIG = b"B" * (200 * 1024)
+BIG_ROWS = [BIG, BIG, b"small", BIG, BIG]
+PARTITIONS = [
+    pytest.param(list(range(20)), {}, id="ints"),
+    pytest.param(BIG_ROWS, {"chunk_size": 4, "send_window": 1},
+                 id="200KiB-rows-window1"),
+    pytest.param(BIG_ROWS, {"chunk_size": 4, "send_window": 4},
+                 id="200KiB-rows-window4"),
+]
+
+
+@pytest.mark.parametrize("rows,client_opts", PARTITIONS)
+def test_feed_partition_and_markers(rows, client_opts):
+    queues, server, client = start_pair(**client_opts)
     feed = DataFeed(queues)
-    state = client.feed_partition(range(20))
+    state = client.feed_partition(rows)
     assert state == "running"
     client.send_eof()
-    assert feed.next_batch(100) == list(range(20))
+    assert feed.next_batch(100) == rows
     assert feed.next_batch(1) == []
     assert feed.should_stop()
     client.close()
@@ -42,20 +74,15 @@ def test_auth_rejected():
     server.stop()
 
 
-def test_infer_exactly_count_ordered():
-    queues, server, client = start_pair()
-
-    def model():
-        feed = DataFeed(queues, train_mode=False)
-        while not feed.should_stop():
-            batch = feed.next_batch(4)
-            if batch:
-                feed.batch_results([x * x for x in batch])
-
-    t = threading.Thread(target=model, daemon=True)
-    t.start()
-    results = client.infer_partition(list(range(30)))
-    assert results == [x * x for x in range(30)]
+@pytest.mark.parametrize("rows,client_opts", [
+    pytest.param(list(range(30)), {}, id="ints"),
+    *PARTITIONS[1:],          # replies of 600 KiB a row
+])
+def test_infer_exactly_count_ordered(rows, client_opts):
+    queues, server, client = start_pair(**client_opts)
+    t = serve_model(queues, lambda x: x * 3)
+    results = client.infer_partition(rows)
+    assert results == [x * 3 for x in rows]
     client.send_eof()
     t.join(5)
     client.close()
@@ -95,140 +122,18 @@ def test_infer_timeout_when_model_absent():
     server.stop()
 
 
-def test_ring_upgrade_engages_on_localhost(monkeypatch):
-    from tensorflowonspark_tpu import shm_ring
-
-    if not shm_ring.available():
-        pytest.skip("native shm ring not buildable")
-    # TOS_SHM_RING=1 forces the ring regardless of what the transport probe
-    # measures on this box (unset means probe-decides; see utils.net)
-    monkeypatch.setenv("TOS_SHM_RING", "1")
-    queues, server, client = start_pair()
-    assert client.using_ring
-    feed = DataFeed(queues)
-    client.feed_partition(range(50))
-    client.send_eof()
-    assert feed.next_batch(100) == list(range(50))
-    client.close()
-    server.stop()
-
-
-def test_tcp_path_still_works_when_ring_disabled():
-    queues = FeedQueues(capacity=1024)
+def test_tcp_path_works_with_the_client_defaults():
+    """No option given: the default chunk (512 rows) and send window carry a
+    partition of several chunks and a short tail."""
+    queues = FeedQueues(capacity=4096)
     server = DataServer(queues, AUTH, feed_timeout=5.0)
-    port = server.start()
-    client = DataClient("127.0.0.1", port, AUTH, chunk_size=8, prefer_ring=False)
-    assert not client.using_ring
+    client = DataClient("127.0.0.1", server.start(), AUTH)
     feed = DataFeed(queues)
-    client.feed_partition(range(10))
+    assert client.feed_partition(range(1300)) == "running"
     client.send_eof()
-    assert feed.next_batch(100) == list(range(10))
+    assert feed.next_batch(2000) == list(range(1300))
     client.close()
     server.stop()
-
-
-def test_oversized_messages_stream_through_ring(monkeypatch):
-    # Chunks (and replies) larger than the ring are segmented transparently
-    # in both directions; the client stays on the ring throughout.
-    from tensorflowonspark_tpu import shm_ring
-
-    if not shm_ring.available():
-        pytest.skip("native shm ring not buildable")
-    monkeypatch.setenv("TOS_SHM_RING", "1")
-    queues = FeedQueues(capacity=1024)
-    server = DataServer(queues, AUTH, feed_timeout=5.0)
-    port = server.start()
-    client = DataClient("127.0.0.1", port, AUTH, chunk_size=4,
-                        ring_capacity=64 * 1024)
-    assert client.using_ring
-    feed = DataFeed(queues)
-    big = b"B" * (200 * 1024)  # one chunk of these exceeds the 64k ring
-    client.feed_partition([big, big, b"small"])
-    client.send_eof()
-    got = feed.next_batch(10)
-    assert got == [big, big, b"small"]
-    assert client.using_ring  # never downgraded
-    client.close()
-    server.stop()
-
-    # Fresh pair for the reply direction (the EOF above still sits in the
-    # old input queue): replies larger than the ring segment too.
-    queues2 = FeedQueues(capacity=1024)
-    server2 = DataServer(queues2, AUTH, feed_timeout=5.0)
-    client2 = DataClient("127.0.0.1", server2.start(), AUTH, chunk_size=4,
-                         ring_capacity=64 * 1024)
-    assert client2.using_ring
-
-    def model():
-        f = DataFeed(queues2, train_mode=False)
-        while not f.should_stop():
-            batch = f.next_batch(4)
-            if batch:
-                f.batch_results([x * 3 for x in batch])  # replies > ring too
-
-    t = threading.Thread(target=model, daemon=True)
-    t.start()
-    assert client2.infer_partition([big, b"x"]) == [big * 3, b"xxx"]
-    assert client2.using_ring
-    client2.send_eof()
-    t.join(5)
-    client2.close()
-    server2.stop()
-
-
-def test_ring_inference_roundtrip(monkeypatch):
-    from tensorflowonspark_tpu import shm_ring
-
-    if not shm_ring.available():
-        pytest.skip("native shm ring not buildable")
-    monkeypatch.setenv("TOS_SHM_RING", "1")
-    queues, server, client = start_pair()
-    assert client.using_ring
-
-    def model():
-        feed = DataFeed(queues, train_mode=False)
-        while not feed.should_stop():
-            batch = feed.next_batch(4)
-            if batch:
-                feed.batch_results([x + 1 for x in batch])
-
-    t = threading.Thread(target=model, daemon=True)
-    t.start()
-    assert client.infer_partition(list(range(40))) == [x + 1 for x in range(40)]
-    client.send_eof()
-    t.join(5)
-    client.close()
-    server.stop()
-
-
-def test_send_eof_after_server_stop_fails_fast(monkeypatch):
-    """Teardown race regression: a node can stop its data plane before the
-    driver's EOF arrives.  On the shm-ring transport that used to block for
-    the FULL call timeout (~minutes) because nothing closed the rings before
-    process exit; server.stop() now joins ring threads (rings close) and
-    send_eof carries its own short timeout.  The driver must see an error
-    within seconds either way."""
-    import time
-
-    from tensorflowonspark_tpu import shm_ring
-
-    if not shm_ring.available():
-        # TCP-only: established connections outlive stop() by design (the
-        # node process exit closes them); the fast-fail contract under test
-        # is specific to the ring transport.
-        pytest.skip("native shm ring not buildable")
-    monkeypatch.setenv("TOS_SHM_RING", "1")
-    queues, server, client = start_pair(feed_timeout=600.0)
-    assert client.using_ring
-    client.send_eof("input")  # healthy path works
-    server.stop()
-    t0 = time.monotonic()
-    with pytest.raises(Exception):
-        client.send_eof("input")
-        # ring path may downgrade to TCP and fail there; either way:
-        client.send_eof("input")
-    assert time.monotonic() - t0 < 30.0
-    client.close()
 
 
 # -- zero-copy wire format (ISSUE 3 tentpole) ---------------------------------
@@ -237,8 +142,6 @@ def test_send_eof_after_server_stop_fails_fast(monkeypatch):
 def test_wire_negotiates_v2_and_packs_chunks():
     """Current client x current server negotiate the vectorized wire and
     round-trip packed bytes/ndarray/tuple/dict chunks bit-identically."""
-    import numpy as np
-
     queues, server, client = start_pair()
     assert client._wire >= 2  # vectorized wire (v3 = v2 frames + trace ops)
     feed = DataFeed(queues)
@@ -266,8 +169,6 @@ def test_wire_negotiates_v2_and_packs_chunks():
 
 
 def test_wire_v2_roundtrip_values_exact():
-    import numpy as np
-
     queues, server, client = start_pair()
     feed = DataFeed(queues)
     rows = [bytes([i]) * 1000 for i in range(16)]
@@ -303,8 +204,7 @@ def test_old_server_negotiates_down_to_v1():
 
     server._handle = legacy_handle.__get__(server)
     port = server.start()
-    client = DataClient("127.0.0.1", port, AUTH, chunk_size=8,
-                        prefer_ring=False)
+    client = DataClient("127.0.0.1", port, AUTH, chunk_size=8)
     assert client._wire == 1
     feed = DataFeed(queues)
     rows = [bytes([i]) * 256 for i in range(20)]
@@ -385,113 +285,171 @@ def test_feed_timeout_error_surfaces_through_pipeline():
     server.stop()
 
 
-def test_ring_forced_off_via_knob(monkeypatch):
-    monkeypatch.setenv("TOS_SHM_RING", "0")
-    queues, server, client = start_pair()
-    assert not client.using_ring
-    feed = DataFeed(queues)
-    client.feed_partition(range(10))
-    assert feed.next_batch(20) == list(range(10))
-    client.close()
-    server.stop()
+def _packed_rows():
+    return [np.full((64, 64), i, np.float32) for i in range(6)]  # >= 4KB: packed
 
 
-def test_ring_probe_gates_auto_selection(monkeypatch):
-    """Unset TOS_SHM_RING: the measured probe decides.  Forcing the cached
-    probe verdict both ways must flip the selected transport."""
-    from tensorflowonspark_tpu import shm_ring
-    from tensorflowonspark_tpu.utils import net as unet
-
-    if not shm_ring.available():
-        pytest.skip("native shm ring not buildable")
-    monkeypatch.delenv("TOS_SHM_RING", raising=False)
-    monkeypatch.setattr(unet, "_ring_probe_cache", {64 * 1024: False})
-    queues, server, client = start_pair()
-    assert not client.using_ring  # probe said TCP: ring never selected
-    client.close()
-    server.stop()
-
-    monkeypatch.setattr(unet, "_ring_probe_cache", {64 * 1024: True})
-    queues2, server2, client2 = start_pair()
-    assert client2.using_ring  # probe said ring
-    feed = DataFeed(queues2)
-    client2.feed_partition([b"r" * 2048] * 10)
-    assert feed.next_batch(20) == [b"r" * 2048] * 10
-    client2.close()
-    server2.stop()
-
-
-def test_junk_shm_ring_value_degrades_to_probe(monkeypatch):
-    """A TOS_SHM_RING typo must degrade to the documented default (the
-    probe), never silently force a transport off (or on)."""
-    from tensorflowonspark_tpu import shm_ring
-    from tensorflowonspark_tpu.utils import net as unet
-
-    if not shm_ring.available():
-        pytest.skip("native shm ring not buildable")
-    monkeypatch.setenv("TOS_SHM_RING", "auto")  # junk: not a bool value
-    monkeypatch.setattr(unet, "_ring_probe_cache", {64 * 1024: True})
-    queues, server, client = start_pair()
-    assert client.using_ring  # probe (True) decided, not the junk value
-    client.close()
-    server.stop()
-
-
-def test_received_ndarrays_are_writable_on_both_transports(monkeypatch):
-    """Pickled ndarrays were always writable; the zero-copy receive path
-    must not hand user code read-only arrays — and writability must not
-    depend on which transport delivered the batch."""
-    import numpy as np
-
-    from tensorflowonspark_tpu import shm_ring
-
-    rows = [np.full((64, 64), i, np.float32) for i in range(6)]  # >= 4KB: packed
-    configs = [("0", False)]
-    if shm_ring.available():
-        configs.append(("1", True))
+def _mixed_rows():
     # mixed shapes >= 4KB: pack_chunk refuses, so numpy's OWN protocol-5
-    # reduce puts these out-of-band — the plain-row receive path must be
-    # writable too (it reconstructs from views of the receive blob)
-    mixed = [np.full((64, 64), 1.0, np.float32),
-             np.full((32, 64), 2.0, np.float32)]
-    for knob, expect_ring in configs:
-        monkeypatch.setenv("TOS_SHM_RING", knob)
-        queues, server, client = start_pair()
-        assert client.using_ring == expect_ring
-        feed = DataFeed(queues)
-        for batch in (rows, mixed):
-            client.feed_partition(batch)
-            got = feed.next_batch(10)
-            for a, b in zip(batch, got):
-                assert np.array_equal(a, b)
-                assert b.flags.writeable, \
-                    f"read-only array over ring={expect_ring}"
-                b += 1.0  # in-place mutation (the map_fun normalize idiom)
-        client.close()
-        server.stop()
+    # reduce puts these out-of-band — the plain-row receive path
+    # reconstructs them from views of the receive blob
+    return [np.full((64, 64), 1.0, np.float32),
+            np.full((32, 64), 2.0, np.float32)]
 
 
-def test_structured_dtype_rows_round_trip():
-    """Structured dtypes must survive the wire with field names intact —
-    they are excluded from columnar packing (dtype.str would collapse them
-    to raw void) and travel via numpy's own reduce."""
-    import numpy as np
-
+def _structured_rows():
+    # structured dtypes are excluded from columnar packing (dtype.str would
+    # collapse them to raw void) and travel via numpy's own reduce
     dt = np.dtype([("a", "<f4"), ("b", "<i4")])
     rows = [np.zeros(2048, dtype=dt) for _ in range(3)]  # >= 4KB each
     for i, r in enumerate(rows):
         r["a"] += i
         r["b"] += 10 * i
-    from tensorflowonspark_tpu.data import pack_chunk
+    return rows
 
-    assert pack_chunk(rows) is None  # never packed
+
+@pytest.mark.parametrize("make_rows", [_packed_rows, _mixed_rows])
+def test_received_ndarrays_are_writable(make_rows):
+    """Pickled ndarrays were always writable; the zero-copy receive path
+    must not hand user code read-only arrays, packed or not."""
+    batch = make_rows()
     queues, server, client = start_pair()
     feed = DataFeed(queues)
-    client.feed_partition(rows)
+    client.feed_partition(batch)
     got = feed.next_batch(10)
-    for a, b in zip(rows, got):
-        assert b.dtype == dt
-        np.testing.assert_array_equal(a["a"], b["a"])
-        np.testing.assert_array_equal(a["b"], b["b"])
+    for a, b in zip(batch, got):
+        assert np.array_equal(a, b)
+        assert b.flags.writeable
+        b += 1.0  # in-place mutation (the map_fun normalize idiom)
     client.close()
     server.stop()
+
+
+def _same_row(a, b) -> bool:
+    if isinstance(a, bytes):
+        return a == b
+    return a.dtype == b.dtype and a.shape == b.shape and a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("op", ["feed_partition", "infer_partition"])
+@pytest.mark.parametrize("make_rows,packs", [
+    pytest.param(lambda: [bytes([i]) * 4096 for i in range(10)], True,
+                 id="bytes"),
+    pytest.param(_packed_rows, True, id="packed-ndarrays"),
+    pytest.param(_mixed_rows, False, id="mixed-ndarrays"),
+    pytest.param(_structured_rows, False, id="structured-dtype"),
+])
+def test_payload_kinds_round_trip(make_rows, packs, op):
+    """Every kind of row the wire frames differently (columnar-packed or
+    not, out-of-band buffers or in-band) comes back value- and dtype-exact,
+    towards the node (``feed_partition``) and, as results, from it
+    (``infer_partition`` through a model that echoes its rows)."""
+    from tensorflowonspark_tpu.data import pack_chunk
+
+    rows = make_rows()
+    assert (pack_chunk(rows) is not None) == packs
+    queues, server, client = start_pair()
+    if op == "feed_partition":
+        client.feed_partition(rows)
+        got = DataFeed(queues).next_batch(100)
+    else:
+        t = serve_model(queues, lambda x: x)
+        got = client.infer_partition(rows)
+        client.send_eof()
+        t.join(5)
+    assert len(got) == len(rows)
+    assert all(_same_row(a, b) for a, b in zip(rows, got))
+    client.close()
+    server.stop()
+
+
+# -- bounded waits on the socket ----------------------------------------------
+
+
+def _wedge(server, op: str) -> threading.Event:
+    """Make ``server`` sit on every ``op`` request until the returned event
+    is set: a node that is alive and holds the connection, but never
+    answers."""
+    release = threading.Event()
+    handle = server._handle
+
+    def wedged(msg):
+        if msg[0] == op:
+            release.wait(30.0)
+        return handle(msg)
+
+    server._handle = wedged
+    return release
+
+
+def test_send_eof_to_a_wedged_node_fails_within_its_own_timeout():
+    """EOF is a teardown message: a node that holds the connection open and
+    never answers costs ``send_eof`` its own short timeout, not the
+    connection's ``call_timeout``; the socket is then poisoned, so a late
+    reply can never be read as the answer to a later call."""
+    queues, server, client = start_pair(call_timeout=600.0)
+    client.send_eof("input")  # healthy path works
+    release = _wedge(server, "eof")
+    t0 = time.monotonic()
+    with pytest.raises((TimeoutError, OSError)):
+        client.send_eof("input", timeout=0.5)
+    assert time.monotonic() - t0 < 5.0
+    release.set()
+    with pytest.raises(OSError):
+        client.poll_consumed("input", timeout=0.5)
+    client.close()
+    server.stop()
+
+
+def test_close_is_bounded_against_a_wedged_node():
+    """``cluster.resize`` and ``gateway.reload`` reach ``close()`` under
+    their own locks: it waits ``min(10 s, call_timeout)`` for the close ack
+    and then drops the socket, whatever the node does."""
+    queues, server, client = start_pair(call_timeout=0.5)
+    release = _wedge(server, "close")
+    t0 = time.monotonic()
+    client.close()
+    assert time.monotonic() - t0 < 5.0
+    release.set()
+    server.stop()
+
+
+def test_abort_wakes_a_call_blocked_on_the_socket():
+    """The monitor's death path: ``abort()`` takes no lock, so it cuts a call
+    that would otherwise ride out its whole ``call_timeout`` under it."""
+    queues, server, client = start_pair(call_timeout=600.0)
+    release = _wedge(server, "consumed")
+    errors: list[BaseException] = []
+
+    def call():
+        try:
+            client.poll_consumed("input", timeout=600.0)
+        except BaseException as e:  # noqa: BLE001
+            errors.append(e)
+
+    t = threading.Thread(target=call, daemon=True)
+    t.start()
+    time.sleep(0.3)  # let the request land and the reply wait begin
+    t0 = time.monotonic()
+    client.abort()
+    t.join(5.0)
+    assert not t.is_alive() and time.monotonic() - t0 < 5.0
+    assert errors and isinstance(errors[0], (OSError, EOFError)), errors
+    release.set()
+    server.stop()
+
+
+def test_stop_returns_at_once_with_a_client_connected():
+    """``stop()`` closes the listener and waits for nothing; a connection
+    that is already up is served until its peer closes it or the node
+    process exits (so a late EOF still lands)."""
+    queues, server, client = start_pair()
+    client.feed_partition(range(5))
+    t0 = time.monotonic()
+    server.stop()
+    assert time.monotonic() - t0 < 1.0
+    client.send_eof("input", timeout=5.0)
+    feed = DataFeed(queues)
+    assert feed.next_batch(10) == list(range(5))
+    assert feed.next_batch(1) == [] and feed.should_stop()
+    client.close()

@@ -456,7 +456,6 @@ def test_elastic_restart_resumes_from_checkpoint_and_completes(tmp_path, monkeyp
     without raising, every item must be delivered (at-least-once), and the
     restarted worker must have resumed from the latest committed checkpoint
     under a bumped incarnation."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")  # a SIGKILL leaves rings wedged
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     items = list(range(120))
@@ -497,13 +496,12 @@ def test_elastic_restart_resumes_from_checkpoint_and_completes(tmp_path, monkeyp
 
 
 @pytest.mark.chaos
-def test_severed_data_socket_is_refed_without_restart(tmp_path, monkeypatch):
+def test_severed_data_socket_is_refed_without_restart(tmp_path):
     """`sever` drops the data connection mid-stream with the node healthy:
     the driver must requeue the unacknowledged partition and re-feed it over
     a fresh connection — no supervisor involved, no item lost, and (because
     the sever fires before any of that partition's items were queued) none
     duplicated."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     items = list(range(80))
     parts = [items[i * 20:(i + 1) * 20] for i in range(4)]
     per_node_env = [{}, {"TOS_FAULTINJECT": "sever:after_data_ops=2"}]
@@ -527,7 +525,6 @@ def test_elastic_inference_retries_exactly_once_on_restarted_node(tmp_path, monk
     results: the in-flight partition is retried ONLY against the restarted
     node (fresh queues), and the partition-index dedupe keeps the output
     ordered exactly-count."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     import tensorflowonspark_tpu as tos
@@ -554,7 +551,6 @@ def test_feed_failure_names_executor_and_partition(tmp_path, monkeypatch):
     """Satellite: a feed failure that exhausts its retry budget surfaces a
     RuntimeError naming the executor AND partition (the old code collected
     bare exceptions with no identity)."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_MAX_PARTITION_ATTEMPTS", "1")  # fail on first sever
     items = list(range(80))
     parts = [items[i * 20:(i + 1) * 20] for i in range(4)]
@@ -638,7 +634,6 @@ def test_node_death_mid_pipelined_vote_unblocks_survivor(tmp_path, monkeypatch):
     consensus vote is in flight.  The survivor must see the monitor's abort
     within seconds (not the 120s vote timeout), survive the abandoned-vote
     reset, and exit; the driver must surface the death instead of hanging."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")  # a SIGKILL leaves rings wedged
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     items = list(range(120))
     parts = [items[i * 20:(i + 1) * 20] for i in range(6)]

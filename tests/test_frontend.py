@@ -187,8 +187,7 @@ def _handshaked_raw_conn(endpoint, authkey):
     return sock
 
 
-def test_pipelined_clients_pool_and_wire_compat(tmp_path, monkeypatch):
-    monkeypatch.setenv("TOS_SHM_RING", "0")
+def test_pipelined_clients_pool_and_wire_compat(tmp_path):
     telemetry.reset()
     cluster, export = _serve_cluster(tmp_path, scale=2.0, max_batch=4)
     try:
@@ -289,11 +288,10 @@ def test_pipelined_clients_pool_and_wire_compat(tmp_path, monkeypatch):
         cluster.shutdown(timeout=120.0)
 
 
-def test_adversarial_connections_do_not_stall_the_reactor(tmp_path, monkeypatch):
+def test_adversarial_connections_do_not_stall_the_reactor(tmp_path):
     """Slow-loris partial frames, malformed frames, handshake stalls, and
     disconnects with requests in flight: one reactor survives all four with
     a healthy client round-tripping throughout."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     telemetry.reset()
     cluster, export = _serve_cluster(tmp_path, scale=2.0, max_batch=4)
     try:
@@ -376,10 +374,9 @@ def test_adversarial_connections_do_not_stall_the_reactor(tmp_path, monkeypatch)
         cluster.shutdown(timeout=120.0)
 
 
-def test_per_connection_outstanding_cap_fast_fails(tmp_path, monkeypatch):
+def test_per_connection_outstanding_cap_fast_fails(tmp_path):
     """The per-connection pipelining cap answers 'unavailable' (503)
     synchronously on the reactor — no thread handoff, connection intact."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     telemetry.reset()
     cluster, export = _serve_cluster(tmp_path, scale=2.0, max_batch=4)
     try:
@@ -419,7 +416,6 @@ def test_chaos_replica_kill_mid_pipelined_burst_answers_every_request(
     every request accepted on the multiplexed connection is answered
     exactly once with the right result (retry-on-survivor underneath), and
     the slot recovers."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")  # a SIGKILL leaves rings wedged
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     telemetry.reset()

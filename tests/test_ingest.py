@@ -465,12 +465,11 @@ def test_shards_as_partitioned_grouping(tmp_path):
 # -- cluster end-to-end -------------------------------------------------------
 
 
-def test_direct_train_e2e_exact_accounting(tmp_path, monkeypatch):
+def test_direct_train_e2e_exact_accounting(tmp_path):
     """2-node DIRECT train over a real cluster: the ledger streams shard
     paths, nodes ingest the bytes, and the epoch's record coverage comes
     out exact (happy path: no duplicates either).  Mode-mismatch APIs
     raise errors that name the supported mode."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     shard_dir = tmp_path / "shards"
     paths, ids = _write_shards(shard_dir, 6, 40, gzip_last=True)
     cluster = tcluster.run(
@@ -525,7 +524,6 @@ def test_direct_kill_mid_subshard_rereads_lost_span(tmp_path, monkeypatch):
     epoch's DISTINCT record coverage must come out exact — duplicates
     allowed (a re-fed span is re-read from its start offset), loss
     never."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")  # a SIGKILL leaves rings wedged
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     monkeypatch.setenv("TOS_INGEST_SPAN_BYTES", "2048")
@@ -572,7 +570,6 @@ def test_direct_kill_mid_shard_reassigns_to_survivor(tmp_path, monkeypatch):
     supervised restart), train() must complete with no node error, and the
     epoch's DISTINCT record coverage must come out exact — duplicates
     allowed (a re-assigned shard is re-READ from the top), loss never."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")  # a SIGKILL leaves rings wedged
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     shard_dir = tmp_path / "shards"

@@ -53,12 +53,6 @@ from tensorflowonspark_tpu.marker import EndOfFeed, EndPartition
 from tests import mapfuns
 
 
-@pytest.fixture(autouse=True)
-def _tcp_data_plane(monkeypatch):
-    # apples-to-apples plumbing for every test here: no shm-ring probes
-    monkeypatch.setenv("TOS_SHM_RING", "0")
-
-
 def _write_shards(dirpath, num_shards=3, per_shard=40, prefix="rec"):
     os.makedirs(dirpath, exist_ok=True)
     expected = set()

@@ -449,14 +449,13 @@ class TestMergePredictionRows:
         assert results[1]["b"] == 1
 
 
-def test_fit_direct_feeds_ledger_ingest(tmp_path, monkeypatch):
+def test_fit_direct_feeds_ledger_ingest(tmp_path):
     """TPUEstimator.fit in DIRECT mode drives the ledger-backed ingest
     feed (the ISSUE 10 satellite): a shard-spec dataset goes through
     cluster.train, nodes consume ctx.get_data_feed(), and every record is
     delivered exactly once on the happy path — no self-service reads."""
     from tensorflowonspark_tpu import tfrecord
 
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     shard_dir = tmp_path / "shards"
     os.makedirs(shard_dir)
     total = 0

@@ -294,7 +294,7 @@ def _candidate(tmp_path, scale):
 
 @pytest.mark.chaos
 def test_bad_model_canary_auto_rolls_back_with_zero_failed_requests(
-        tmp_path, monkeypatch):
+        tmp_path):
     """The headline acceptance: stage a candidate that the ``bad_model``
     chaos hook corrupts (NaN outputs on CANDIDATE bundles only); the
     governor must detect it and roll the canaries back within one window,
@@ -302,7 +302,6 @@ def test_bad_model_canary_auto_rolls_back_with_zero_failed_requests(
     and the rollback journaled.  The same boot pins the tenant wire
     compatibility: tenant-tagged pipelined frames and the id-less legacy
     client share the gateway."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     telemetry.reset()
     chaos = {"TOS_FAULTINJECT": "bad_model:nan=1"}
     cluster, export = _serve_cluster(
@@ -381,7 +380,6 @@ def test_rollout_survives_coordinator_kill_then_promotes(
     rollout keeps governing, and the journal replay restores the in-flight
     rollout state across the failover — after which promotion converges
     the fleet onto the candidate."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     telemetry.reset()
     cluster, export = _serve_cluster(tmp_path, scale=2.0,
@@ -444,7 +442,6 @@ def test_canary_replica_sigkill_no_spurious_rollback_and_cohort_rejoin(
     and the supervised restart must rejoin the replica into the CANARY
     cohort serving the CANDIDATE bundle (recovery replays the cohort's
     reload ctl)."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     telemetry.reset()
@@ -522,7 +519,6 @@ def test_hot_tenant_flood_sheds_only_the_hot_tenant(tmp_path, monkeypatch,
     tenant sees shed (``ServeThrottled``) responses, every other tenant's
     request stream stays error-free with p99 within 2x its uncontended
     baseline."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_SERVE_TENANT_RATE", "400")
     telemetry.reset()
     # the chaos hook multiplies the HOT tenant's bucket charge by 10

@@ -372,8 +372,7 @@ def _serve_cluster(tmp_path, *, scale=2.0, elastic=False, per_node_env=None,
     return cluster, export
 
 
-def test_gateway_round_trip_and_tcp_endpoint_and_coalescing(tmp_path, monkeypatch):
-    monkeypatch.setenv("TOS_SHM_RING", "0")
+def test_gateway_round_trip_and_tcp_endpoint_and_coalescing(tmp_path):
     telemetry.reset()
     cluster, export = _serve_cluster(tmp_path, scale=2.0, max_batch=4)
     try:
@@ -425,11 +424,10 @@ def test_gateway_round_trip_and_tcp_endpoint_and_coalescing(tmp_path, monkeypatc
     assert reg.histogram("serve.queue_wait_secs").count >= 2
 
 
-def test_gateway_hot_reload_swaps_bundle(tmp_path, monkeypatch):
+def test_gateway_hot_reload_swaps_bundle(tmp_path):
     """Re-exporting into the same export_dir must swap predictions on every
     replica without restarting anything (version watch -> drain -> reload
     control round)."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     telemetry.reset()
     cluster, export = _serve_cluster(tmp_path, scale=2.0, max_batch=4)
     try:
@@ -454,13 +452,12 @@ def test_gateway_hot_reload_swaps_bundle(tmp_path, monkeypatch):
 
 
 @pytest.mark.chaos
-def test_severed_live_replica_is_resynced_and_readmitted(tmp_path, monkeypatch):
+def test_severed_live_replica_is_resynced_and_readmitted(tmp_path):
     """``TOS_FAULTINJECT=sever`` drops a serving replica's data connection
     with the NODE STILL ALIVE (no restart, no incarnation bump): the failed
     batch retries on the peer, and the router must re-admit the live
     process after the order-fenced resync — not quarantine it forever
     waiting for a restart that will never come."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     telemetry.reset()
     cluster, export = _serve_cluster(
         tmp_path, scale=2.0, max_batch=4,
@@ -520,7 +517,6 @@ def test_serving_survives_replica_kill_with_exactly_one_answer_each(
     3rd consumed batch): the in-flight batch retries once on the survivor,
     every accepted request is answered exactly once with the right result,
     and the elastic supervisor brings the slot back."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")  # a SIGKILL leaves rings wedged
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     telemetry.reset()

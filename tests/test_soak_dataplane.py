@@ -1,5 +1,5 @@
 """Bounded soak of the data plane: many small partitions through feed and
-inference round-trips — shakes ring/TCP framing, EndPartition bookkeeping,
+inference round-trips — shakes the TCP framing, EndPartition bookkeeping,
 and the ordered exactly-count invariant at a partition count well above what
 the e2e tests use (reference regime: hundreds of Spark partitions)."""
 
@@ -66,7 +66,6 @@ def test_randomized_chaos_soak(tmp_path, monkeypatch):
     # + in-flight put < items-per-partition
     kill_after = rng.randint(2, 6)        # 3*6 + 4 + 1 < 25
     sever_after = rng.randint(1, 6)       # each node feeds 6 partitions
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     items = list(range(300))

@@ -267,12 +267,11 @@ def _poll_metrics(cluster, want_nodes, want_rows=None, timeout=30.0):
     return snap
 
 
-def test_cluster_metrics_aggregates_every_node_and_writes_run_report(tmp_path, monkeypatch):
+def test_cluster_metrics_aggregates_every_node_and_writes_run_report(tmp_path):
     """The acceptance scenario: an in-process 2-node STREAMING cluster's
     ``cluster.metrics()`` returns an aggregated snapshot holding data-plane
     byte/chunk counters from EVERY node, plus the map_fun's own
     ``ctx.metrics`` entries; shutdown writes the JSON run report."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     telemetry.reset()  # isolate the driver-side registry from earlier tests
     items = list(range(80))
     parts = [items[i * 20:(i + 1) * 20] for i in range(4)]
@@ -323,7 +322,6 @@ def test_cluster_metrics_aggregates_every_node_and_writes_run_report(tmp_path, m
 def test_metrics_disabled_cluster_still_trains(tmp_path, monkeypatch):
     """TOS_METRICS=0 must be a pure kill switch: the cluster runs, metrics
     come back empty, and no run report is written."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_METRICS", "0")
     telemetry.reset()
     try:
@@ -359,7 +357,6 @@ def test_restart_counter_increments_under_injected_kill(tmp_path, monkeypatch):
     """``TOS_FAULTINJECT=kill`` + elastic=True: the supervised restart must
     show up as ``elastic.restarts_total`` >= 1 in the aggregated snapshot
     and in the run report (the ISSUE 4 acceptance criterion)."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")  # a SIGKILL leaves rings wedged
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     telemetry.reset()  # isolate this test's driver-side counters

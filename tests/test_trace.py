@@ -417,7 +417,6 @@ def test_traced_serving_run_assembles_cross_process_traces(tmp_path, monkeypatch
     the gateway and node processes under ONE trace id, the stage spans
     account for >= 90% of its measured end-to-end latency, and shutdown
     writes a validating, Perfetto-loadable trace.json."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")
     monkeypatch.setenv("TOS_TRACE", "1")
     monkeypatch.setenv("TOS_TRACE_SAMPLE", "1")
     telemetry.reset()
@@ -504,7 +503,6 @@ def test_chaos_kill_leaves_flight_timeline_kill_retry_resync(tmp_path,
     resync re-admission.  Tracing is ON (sampled), so the same chaos run
     also yields a merged Perfetto-loadable trace.json — the full ISSUE-8
     chaos acceptance scenario."""
-    monkeypatch.setenv("TOS_SHM_RING", "0")  # a SIGKILL leaves rings wedged
     monkeypatch.setenv("TOS_DEAD_NODE_TIMEOUT", "4")
     monkeypatch.setenv("TOS_RESTART_BACKOFF_BASE", "0.2")
     monkeypatch.setenv("TOS_TRACE", "1")
