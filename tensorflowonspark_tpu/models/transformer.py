@@ -19,6 +19,7 @@ MXU-friendly recipe.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any, Optional
 
@@ -34,14 +35,84 @@ from tensorflowonspark_tpu.parallel.tp import constrain
 BATCH = ("dp", "fsdp")
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
-    """Rotary embedding, ``x: [B, S, H, D]``, ``positions: [S]``."""
-    d = x.shape[-1]
-    half = d // 2
-    freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention factor ``0.1 · mscale · ln(factor) + 1`` (1 where
+    nothing is stretched), as transformers' ``yarn_get_mscale``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_scaling_from_config(scaling: Optional[dict]) -> Optional[tuple]:
+    """A published ``rope_scaling`` group, by its own keys, as the tuple
+    ``rope_frequencies`` takes (a module's attribute has to hash)."""
+    if scaling is None:
+        return None
+    return (str(scaling.get("type", scaling.get("rope_type"))),
+            float(scaling["factor"]),
+            int(scaling["original_max_position_embeddings"]),
+            float(scaling.get("beta_fast", 32)),
+            float(scaling.get("beta_slow", 1)),
+            float(scaling.get("mscale", 0)),
+            float(scaling.get("mscale_all_dim", 0)))
+
+
+def _yarn_only(rope_scaling: Optional[tuple]) -> None:
+    if rope_scaling is not None and rope_scaling[0] != "yarn":
+        raise NotImplementedError(
+            f"rope_scaling type {rope_scaling[0]!r}: rope_frequencies "
+            "computes 'yarn' and no other stretch of the rotary frequencies")
+
+
+def rope_frequencies(theta: float, rope_scaling: Optional[tuple], width: int):
+    """``(inverse frequencies [width // 2] float32, factor on cos and sin)``
+    of a rotation over ``width`` columns: the ONE place that says how fast a
+    pair turns.  ``rope_scaling`` None: ``theta^(-i / half)`` and 1.
+
+    ``("yarn", factor, original positions, beta_fast, beta_slow, mscale,
+    mscale_all_dim)`` is YaRN (arXiv:2309.00071) as transformers'
+    ``_compute_yarn_parameters`` computes it: pairs that turn more than
+    ``beta_fast`` times over the original context keep their frequency, pairs
+    that turn less than ``beta_slow`` times have it divided by ``factor``,
+    and a linear ramp over the pair index lies between (the correction range,
+    floored and ceiled).  All of it is arithmetic on Python floats and numpy
+    at trace time: the program holds constants."""
+    half = width // 2
+    if rope_scaling is None:
+        return jnp.exp(-math.log(theta)
+                       * jnp.arange(half, dtype=jnp.float32) / half), 1.0
+    import numpy as np
+
+    _yarn_only(rope_scaling)
+    _kind, factor, original, beta_fast, beta_slow, mscale, all_dim = \
+        rope_scaling
+
+    def correction_dim(rotations):     # the pair that turns so many times
+        return (width * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), width - 1)
+    if low == high:
+        high += 0.001
+    inv = theta ** (-np.arange(0, width, 2, dtype=np.float64) / width)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    inv = inv / factor * ramp + inv * (1.0 - ramp)
+    on_cos_sin = (yarn_mscale(factor, mscale) / yarn_mscale(factor, all_dim)
+                  if mscale and all_dim else yarn_mscale(factor))
+    return jnp.asarray(inv, jnp.float32), float(on_cos_sin)
+
+
+def apply_rope(x, positions, theta: float = 10000.0,
+               rope_scaling: Optional[tuple] = None):
+    """Rotary embedding, ``x: [B, S, H, D]``, ``positions: [S]``; the
+    frequencies are ``rope_frequencies``'."""
+    half = x.shape[-1] // 2
+    freqs, factor = rope_frequencies(theta, rope_scaling, x.shape[-1])
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, half]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
@@ -96,6 +167,13 @@ class Attention(nn.Module):
     # False: no rotation and no position input at all (``nemotron_h``'s
     # attention layers: the state-space layers around them carry the order).
     rope: bool = True
+    # A query latent beside ``latent`` (DeepSeek-V3's ``q_lora_rank``): ``q =
+    # RMSNorm(u W_qa) W_qb`` through a latent of this width (0: ``u W_q``).
+    q_lora_rank: int = 0
+    # How the rotary frequencies are stretched (``rope_frequencies``; None:
+    # not at all).  Under latent attention YaRN also scales the SOFTMAX, by
+    # ``yarn_mscale(factor, mscale_all_dim)²`` (``_latent_attention``).
+    rope_scaling: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, x, positions=None, block_diffusion=None):
@@ -110,6 +188,10 @@ class Attention(nn.Module):
                 "the indexer and the cache path all turn their keys")
         if self.latent:
             return self._latent_attention(x, positions, block_diffusion)
+        if self.q_lora_rank:
+            raise NotImplementedError(
+                f"q_lora_rank={self.q_lora_rank} without latent=: the query "
+                "latent is latent attention's (Attention._latent_attention)")
         b, s, _ = x.shape
         h, dh = self.n_heads, self.d_head
         h_kv = self.n_kv_heads or h
@@ -152,8 +234,10 @@ class Attention(nn.Module):
             if self.rope:
                 if positions is None:
                     positions = jnp.arange(s)
-                q = apply_rope(q, positions, self.rope_theta)
-                k = apply_rope(k, positions, self.rope_theta)
+                q = apply_rope(q, positions, self.rope_theta,
+                               self.rope_scaling)
+                k = apply_rope(k, positions, self.rope_theta,
+                               self.rope_scaling)
             q = constrain(q, P(BATCH, "sp", "tp", None))
             k = constrain(k, P(BATCH, "sp", "tp", None))
             v = constrain(v, P(BATCH, "sp", "tp", None))
@@ -174,7 +258,8 @@ class Attention(nn.Module):
 
     def _latent_attention(self, x, positions, block_diffusion):
         """Latent attention on the training path.  From the layer's normed
-        hidden state ``u``: ``q = u W_q`` = per head ``[q_nope | q_rope]``;
+        hidden state ``u``: ``q = u W_q`` (under ``q_lora_rank``: ``RMSNorm(u
+        W_qa) W_qb``, a query latent) = per head ``[q_nope | q_rope]``;
         ``[c | k_r] = u W_kva``, ``c`` RMS-normed (``kv_a_norm``), ``k_r`` ONE
         rotary key head for all query heads; ``[k_nope | v] = c W_kvb`` per
         head.  RoPE turns ``q_rope`` and ``k_r`` only (this file's half-split
@@ -183,7 +268,12 @@ class Attention(nn.Module):
         sees).  ``score = (q_nope · k_nope + q_rope · k_r) / sqrt(nope +
         rope)``: the kernels take ``k_r`` as their shared key, so no copy of
         it a head exists, forward or backward.  Autodiff keeps ``k_nope``
-        and ``v`` whole for the backward (the kernels' residuals)."""
+        and ``v`` whole for the backward (the kernels' residuals).  Under
+        YaRN (``rope_scaling``) the frequencies are ``rope_frequencies``' and
+        the softmax scale is ``(nope + rope)^-1/2 · m²``, ``m = yarn_mscale(
+        factor, mscale_all_dim)`` (transformers' ``deepseek_v3``), a constant
+        that reaches the kernels as their static ``sm_scale``; without it the
+        argument stays None and the kernels are compiled as they were."""
         if self.decode:
             raise NotImplementedError(
                 "latent attention (Attention.latent) has no cache path: "
@@ -196,9 +286,20 @@ class Attention(nn.Module):
         rank, nope, rope, dv = self.latent
         h, s = self.n_heads, x.shape[1]
         cdt = self.compute_dtype
+        sm_scale = None
+        if self.rope_scaling is not None and self.rope_scaling[6]:
+            _kind, factor, *_rest, all_dim = self.rope_scaling
+            sm_scale = (nope + rope) ** -0.5 * yarn_mscale(factor, all_dim) ** 2
         with jax.named_scope("mla/project"):
-            q = nn.DenseGeneral((h, nope + rope), use_bias=False,
-                                name="q_proj", dtype=cdt)(x)
+            if self.q_lora_rank:
+                q_a = nn.Dense(self.q_lora_rank, use_bias=False,
+                               name="q_a_proj", dtype=cdt)(x)
+                q = nn.DenseGeneral((h, nope + rope), use_bias=False,
+                                    name="q_b_proj", dtype=cdt)(
+                    RMSNorm(self.norm_eps, name="q_a_norm")(q_a))
+            else:
+                q = nn.DenseGeneral((h, nope + rope), use_bias=False,
+                                    name="q_proj", dtype=cdt)(x)
             kv_a = nn.Dense(rank + rope, use_bias=False, name="kv_a_proj",
                             dtype=cdt)(x)
             c = RMSNorm(self.norm_eps, name="kv_a_norm")(kv_a[..., :rank])
@@ -208,15 +309,17 @@ class Attention(nn.Module):
                 positions = jnp.arange(s)
             q = jnp.concatenate(
                 [q[..., :nope],
-                 apply_rope(q[..., nope:], positions, self.rope_theta)], -1)
+                 apply_rope(q[..., nope:], positions, self.rope_theta,
+                            self.rope_scaling)], -1)
             k_r = apply_rope(kv_a[:, :, None, rank:], positions,
-                             self.rope_theta)[:, :, 0]
+                             self.rope_theta, self.rope_scaling)[:, :, 0]
             q = constrain(q, P(BATCH, "sp", "tp", None))
             k = constrain(kv[..., :nope], P(BATCH, "sp", "tp", None))
             v = constrain(kv[..., nope:], P(BATCH, "sp", "tp", None))
         with jax.named_scope("attention"):
             out = flash_attention(
                 q, k, v, k_shared=k_r, causal=not block_diffusion,
+                sm_scale=sm_scale,
                 impl=None if self.attn_impl == "auto" else self.attn_impl,
                 block_diffusion=block_diffusion)
         with jax.named_scope("mla/project"):
@@ -250,8 +353,8 @@ class Attention(nn.Module):
                             lambda: jnp.zeros((), jnp.int32))
         cur = idx.value
         pos = cur + jnp.arange(s)  # RoPE positions of this slab
-        q = apply_rope(q, pos, self.rope_theta)
-        k = apply_rope(k, pos, self.rope_theta)
+        q = apply_rope(q, pos, self.rope_theta, self.rope_scaling)
+        k = apply_rope(k, pos, self.rope_theta, self.rope_scaling)
         ck.value = jax.lax.dynamic_update_slice(ck.value, k, (0, cur, 0, 0))
         cv.value = jax.lax.dynamic_update_slice(cv.value, v, (0, cur, 0, 0))
         idx.value = cur + s
@@ -303,13 +406,14 @@ class Attention(nn.Module):
                                dtype=f32)(key.astype(f32))
             c = nn.Dense(heads, use_bias=False, name="index_w", dtype=f32)(
                 u.astype(f32)) * (heads * dim) ** -0.5
-            a = apply_rope(a.astype(f32), positions, self.rope_theta).astype(
-                self.compute_dtype)
-            key = apply_rope(key[:, :, None], positions, self.rope_theta)[
-                :, :, 0].astype(self.compute_dtype)
+            a = apply_rope(a.astype(f32), positions, self.rope_theta,
+                           self.rope_scaling).astype(self.compute_dtype)
+            key = apply_rope(key[:, :, None], positions, self.rope_theta,
+                             self.rope_scaling)[:, :, 0].astype(
+                                 self.compute_dtype)
         with jax.named_scope("dsa/attend"):
-            q = apply_rope(q, positions, self.rope_theta)
-            k = apply_rope(k, positions, self.rope_theta)
+            q = apply_rope(q, positions, self.rope_theta, self.rope_scaling)
+            k = apply_rope(k, positions, self.rope_theta, self.rope_scaling)
 
         def row(a, key, c, q, k, v):
             mask, lse_i = dsa.lightning_select(a, key, c, topk, impl=impl)
@@ -522,6 +626,130 @@ class MixerBlock(nn.Module):
         return constrain(x + y, P(BATCH, "sp", None))
 
 
+class HyperConnection(nn.Module):
+    """The maps of one manifold-constrained hyper-connection (mHC,
+    arXiv:2512.24880; hyper-connections, arXiv:2409.19606) from the ``n``
+    residual streams of a token, which the carry holds side by side on the
+    feature axis (``x``: ``[B, S, n·C]``, stream ``i`` the columns ``i·C ..
+    (i+1)·C``: whole 128-lane tiles, where a ``[.., n, C]`` array would pad
+    its rows of ``n`` to a tile).  With ``x̃ = RMSNorm(x)`` over all ``n·C``
+    values (its own weight, ``norm_scale``) and ``phi`` ``[n·C, n + n + n²]``:
+
+        H_pre  = σ(α_pre · x̃ φ_pre + b_pre)                  [B, S, n]
+        H_post = 2 σ(α_post · x̃ φ_post + b_post)             [B, S, n]
+        H_res  = SK(exp(clip(α_res · mat(x̃ φ_res) + b_res)))  [B, S, n, n]
+
+    ``SK``: ``iters`` rounds of (rows over their sum + ``eps``, columns over
+    theirs), so ``H_res`` is doubly stochastic to within what the rounds
+    leave (sown: ``hc_stats/res_row_err``, ``res_col_err``, the largest
+    deviation of a row's and of a column's sum from 1, and ``pre_mean``).  A
+    sub-layer ``F`` then reads ``hc_read(x, H_pre) = H_pre x`` and the
+    streams become ``hc_write(x, F(..), H_post, H_res) = H_res x + H_postᵀ
+    F(..)``.  Returned ``(H_pre [n, B, S], H_post [n, B, S], H_res [n, n, B,
+    S])``: tokens on the minor axes, so that the rounds are elementwise work
+    on whole vectors of tokens and no reduction over a padded axis of 4.
+
+    Everything here is ``dtype`` (float32; a check's control sets bf16) but
+    the operands of the ONE product ``x φ``, which are the streams as they
+    are and ``φ`` scaled by the norm's weight in the streams' dtype, summed
+    in float32: ``x̃ φ = (x (w ⊙ φ)) / rms(x)``, so the normed streams are
+    never written."""
+
+    n: int
+    iters: int = 20
+    eps: float = 1e-6
+    clamp: tuple = (-30.0, 30.0)
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        n, dt = self.n, self.dtype
+        width = x.shape[-1]
+        maps = 2 * n + n * n
+        # small maps at the start (x̃ φ ~ N(0, 1) a map, α 0.01): near their
+        # static part σ(b), which a checkpoint then moves
+        phi = self.param("phi", nn.initializers.lecun_normal(), (width, maps))
+        bias = self.param("bias", nn.initializers.zeros, (maps,))
+        alpha = self.param("alpha", nn.initializers.constant(0.01), (3,))
+        scale = self.param("norm_scale", nn.initializers.ones, (width,))
+        with jax.named_scope("hc/maps"):
+            x32 = x.astype(dt)
+            rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1)
+                                + self.norm_eps)                   # [B, S]
+            z = jnp.einsum("bsk,kj->jbs", x,
+                           (scale[:, None] * phi).astype(x.dtype),
+                           preferred_element_type=dt)
+            # α_pre over the first n maps, α_post the next n, α_res the n²
+            of = [0] * n + [1] * n + [2] * (n * n)
+            z = (z * rms[None] * alpha.astype(dt)[jnp.array(of)][:, None, None]
+                 + bias.astype(dt)[:, None, None])
+            h_pre = jax.nn.sigmoid(z[:n])
+            h_post = 2.0 * jax.nn.sigmoid(z[n:2 * n])
+            h_res = jnp.exp(jnp.clip(z[2 * n:], *self.clamp)).reshape(
+                (n, n) + z.shape[1:])               # [row j, column i, B, S]
+            for _ in range(self.iters):
+                h_res = h_res / (jnp.sum(h_res, axis=1, keepdims=True)
+                                 + self.eps)
+                h_res = h_res / (jnp.sum(h_res, axis=0, keepdims=True)
+                                 + self.eps)
+            f32 = jnp.float32
+            self.sow("hc_stats", "res_row_err", jnp.max(jnp.abs(
+                jnp.sum(h_res.astype(f32), axis=1) - 1.0)))
+            self.sow("hc_stats", "res_col_err", jnp.max(jnp.abs(
+                jnp.sum(h_res.astype(f32), axis=0) - 1.0)))
+            self.sow("hc_stats", "pre_mean", jnp.mean(h_pre.astype(f32)))
+        return h_pre, h_post, h_res
+
+
+def _streams(x, n: int):
+    """The ``n`` streams of the carry ``[B, S, n·C]``, each ``[B, S, C]``."""
+    width = x.shape[-1] // n
+    return [x[..., i * width:(i + 1) * width] for i in range(n)]
+
+
+def hc_read(x, h_pre):
+    """``H_pre x``: the ONE mixed stream a sub-layer reads, ``[B, S, C]`` in
+    the carry's dtype, summed in the maps' dtype."""
+    with jax.named_scope("hc/pre"):
+        parts = _streams(x, h_pre.shape[0])
+        return sum(h_pre[i][..., None] * part.astype(h_pre.dtype)
+                   for i, part in enumerate(parts)).astype(x.dtype)
+
+
+def hc_write(x, y, h_post, h_res):
+    """``H_res x + H_postᵀ y``: every stream ``j`` becomes the ``H_res[j]``
+    mix of the streams plus ``H_post[j]`` times the sub-layer's output ``y``
+    ``[B, S, C]``; the carry's dtype, summed in the maps' dtype."""
+    with jax.named_scope("hc/post"):
+        n, dt = h_post.shape[0], h_post.dtype
+        parts = [part.astype(dt) for part in _streams(x, n)]
+        y = y.astype(dt)
+        return jnp.concatenate(
+            [(sum(h_res[j, i][..., None] * parts[i] for i in range(n))
+              + h_post[j][..., None] * y).astype(x.dtype)
+             for j in range(n)], axis=-1)
+
+
+def _to_streams(x, n: int):
+    """The embedding ``[B, S, C]`` copied into the ``n`` streams (one stream:
+    as it is, and no op)."""
+    if n == 1:
+        return x
+    with jax.named_scope("hc/ends"):
+        return jnp.tile(x, (1, 1, n))
+
+
+def _from_streams(x, n: int):
+    """The SUM of the ``n`` streams (hyper-connections, arXiv:2409.19606),
+    summed in float32 (one stream: as it is, and no op)."""
+    if n == 1:
+        return x
+    with jax.named_scope("hc/ends"):
+        return sum(part.astype(jnp.float32)
+                   for part in _streams(x, n)).astype(x.dtype)
+
+
 class Block(nn.Module):
     n_heads: int
     d_head: int
@@ -545,42 +773,82 @@ class Block(nn.Module):
     latent: Optional[tuple] = None
     moe_router: Optional[tuple] = None      # see Transformer
     moe_shared_d_ff: int = 0
+    q_lora_rank: int = 0
+    rope_scaling: Optional[tuple] = None
+    hyper: Optional[tuple] = None
+    hyper_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x, positions=None, block_diffusion=None):
         norm = lambda name: RMSNorm(self.norm_eps, name=name)  # noqa: E731
-        x = x + Attention(self.n_heads, self.d_head, self.rope_theta,
-                          self.attn_impl, self.mesh, self.compute_dtype,
-                          self.decode, self.max_decode_len, self.qk_norm,
-                          self.norm_eps, self.n_kv_heads,
-                          self.qk_norm_per_head, self.sparse, self.latent,
-                          name="attn")(
-                              norm("attn_norm")(x), positions,
-                              block_diffusion)
+        attn = Attention(self.n_heads, self.d_head, self.rope_theta,
+                         self.attn_impl, self.mesh, self.compute_dtype,
+                         self.decode, self.max_decode_len, self.qk_norm,
+                         self.norm_eps, self.n_kv_heads,
+                         self.qk_norm_per_head, self.sparse, self.latent,
+                         q_lora_rank=self.q_lora_rank,
+                         rope_scaling=self.rope_scaling, name="attn")
+        if self.hyper:
+            return self._hyper_connected(x, attn, positions, block_diffusion)
+        x = x + attn(norm("attn_norm")(x), positions, block_diffusion)
         x = constrain(x, P(BATCH, "sp", None))
-        if self.n_experts:
-            from tensorflowonspark_tpu.parallel.ep import MoEMLP
-
-            scoring, bias, scale = self.moe_router or ("softmax", False, 1.0)
-            ffn = MoEMLP(x.shape[-1], self.d_ff, self.n_experts,
-                         self.moe_top_k, self.moe_capacity_factor,
-                         compute_dtype=self.compute_dtype,
-                         norm_topk_prob=self.moe_norm_topk_prob,
-                         held=self.moe_held, scoring=scoring,
-                         selection_bias=bias, routed_scale=scale, name="moe")
-        else:
-            ffn = SwiGLU(self.d_ff, self.compute_dtype, name="mlp")
+        ffn, shared = self._ffn(x.shape[-1])
         y = norm("mlp_norm")(x)
         x = x + ffn(y)
-        if self.n_experts and self.moe_shared_d_ff:
-            # the shared experts, as ONE SwiGLU of their summed width that
-            # every token passes beside its routed experts: every chip of an
-            # expert-parallel stage computes it whole, so under ``moe_held``
-            # it is in the layer's output once, as it is without
+        if shared is not None:
             with jax.named_scope("moe/shared"):
-                x = x + SwiGLU(self.moe_shared_d_ff, self.compute_dtype,
-                               name="shared")(y)
+                x = x + shared(y)
         return constrain(x, P(BATCH, "sp", None))
+
+    def _ffn(self, d_model: int):
+        """``(the layer's FFN, the shared experts or None)``: the experts of
+        ``parallel/ep.MoEMLP`` or a dense SwiGLU; the shared experts are ONE
+        SwiGLU of their summed width that every token passes beside its
+        routed experts: every chip of an expert-parallel stage computes it
+        whole, so under ``moe_held`` it is in the layer's output once, as it
+        is without."""
+        if not self.n_experts:
+            return SwiGLU(self.d_ff, self.compute_dtype, name="mlp"), None
+        from tensorflowonspark_tpu.parallel.ep import MoEMLP
+
+        scoring, bias, scale = self.moe_router or ("softmax", False, 1.0)
+        ffn = MoEMLP(d_model, self.d_ff, self.n_experts,
+                     self.moe_top_k, self.moe_capacity_factor,
+                     compute_dtype=self.compute_dtype,
+                     norm_topk_prob=self.moe_norm_topk_prob,
+                     held=self.moe_held, scoring=scoring,
+                     selection_bias=bias, routed_scale=scale, name="moe")
+        shared = (SwiGLU(self.moe_shared_d_ff, self.compute_dtype,
+                         name="shared") if self.moe_shared_d_ff else None)
+        return ffn, shared
+
+    def _hyper_connected(self, x, attn, positions, block_diffusion):
+        """The layer over ``n`` residual streams (``hyper`` = ``(n, Sinkhorn
+        rounds, eps, clamp min, clamp max)``; ``x``: ``[B, S, n·C]``): each
+        of the two sub-layers, attention and the FFN (the experts AND the
+        shared expert: one sub-layer), with the pre-norm it has, reads ONE
+        mix of the streams and writes to all of them, ``x <- H_res x +
+        H_postᵀ F(H_pre x)``, through maps of its own (``hc_attn``,
+        ``hc_mlp``: ``HyperConnection``).  Same modules under the same names
+        as the plain layer's, so the two parameter trees differ by the two
+        ``hc_*`` entries alone."""
+        n, iters, eps, *clamp = self.hyper
+        norm = lambda name: RMSNorm(self.norm_eps, name=name)  # noqa: E731
+        maps = lambda name: HyperConnection(  # noqa: E731
+            int(n), int(iters), eps, tuple(clamp), self.norm_eps,
+            self.hyper_dtype, name=name)
+        h_pre, h_post, h_res = maps("hc_attn")(x)
+        y = attn(norm("attn_norm")(hc_read(x, h_pre)), positions,
+                 block_diffusion)
+        x = constrain(hc_write(x, y, h_post, h_res), P(BATCH, "sp", None))
+        h_pre, h_post, h_res = maps("hc_mlp")(x)
+        u = norm("mlp_norm")(hc_read(x, h_pre))
+        ffn, shared = self._ffn(u.shape[-1])
+        y = ffn(u)
+        if shared is not None:
+            with jax.named_scope("moe/shared"):
+                y = y + shared(u)
+        return constrain(hc_write(x, y, h_post, h_res), P(BATCH, "sp", None))
 
 
 class Transformer(nn.Module):
@@ -655,6 +923,50 @@ class Transformer(nn.Module):
     rope: bool = True
     moe_expert_act: str = "swiglu"
     moe_latent: int = 0
+    # Latent attention's query latent and the stretch of the rotary
+    # frequencies (see ``Attention``; ``rope_scaling`` as ``rope_frequencies``
+    # takes it).
+    q_lora_rank: int = 0
+    rope_scaling: Optional[tuple] = None
+    # A residual path of ``n`` streams mixed by doubly-stochastic maps (mHC):
+    # ``(n, Sinkhorn rounds, eps, clamp min, clamp max)``, see
+    # ``Block._hyper_connected``.  The embedding is copied into the ``n``
+    # streams and the final norm reads their SUM.  None: ``x + F(norm(x))``.
+    # ``hyper_dtype``: what the maps are computed in (a check's control).
+    hyper: Optional[tuple] = None
+    hyper_dtype: Any = jnp.float32
+    # Multi-token prediction (DeepSeek-V3, arXiv:2412.19437 §2.2) at depth
+    # ``mtp_layers`` (0 or 1): a module ``mtp`` after the trunk, of ONE more
+    # layer of the model's last kind between a projection and a norm of its
+    # own, that predicts the token after next from the trunk's hidden state
+    # and the SHARED embedding of the next token; the model then returns
+    # ``(main, mtp)`` and ``make_loss_fn`` adds the second loss through the
+    # shared head.  Training path only.
+    mtp_layers: int = 0
+
+    def __post_init__(self):
+        super().__post_init__()
+        # what the code cannot compute says so where the model is BUILT
+        if self.hyper and (self.decode or self.layer_mixer or self.sparse
+                           or self.attn_impl == "ring"):
+            raise NotImplementedError(
+                f"hyper={self.hyper} with decode={self.decode}, layer_mixer="
+                f"{self.layer_mixer}, sparse={self.sparse}, attn_impl="
+                f"{self.attn_impl!r}: the residual streams run through "
+                "Block on the training path (no cache of n streams, no "
+                "MixerBlock, no indexer, no ring attention)")
+        _yarn_only(self.rope_scaling)
+        if self.q_lora_rank and not self.latent:
+            raise NotImplementedError(
+                f"q_lora_rank={self.q_lora_rank} without latent=: the query "
+                "latent is latent attention's (Attention._latent_attention)")
+        if self.mtp_layers and (self.mtp_layers > 1 or self.decode
+                                or self.layer_mixer or self.sparse):
+            raise NotImplementedError(
+                f"mtp_layers={self.mtp_layers} with decode={self.decode}, "
+                f"layer_mixer={self.layer_mixer}, sparse={self.sparse}: ONE "
+                "multi-token-prediction module of a Block, in the training "
+                "loss of make_loss_fn (no drafting on the cache path)")
 
     @nn.compact
     def __call__(self, input_ids, positions=None, block_diffusion=None):
@@ -674,10 +986,16 @@ class Transformer(nn.Module):
         give (``_remat_policy``)."""
         dh = self.d_head or self.d_model // self.n_heads
         dff = self.d_ff or 4 * self.d_model
+        if block_diffusion and (self.hyper or self.mtp_layers):
+            raise NotImplementedError(
+                f"block_diffusion={block_diffusion} with hyper={self.hyper}, "
+                f"mtp_layers={self.mtp_layers}: the block-diffusion loss runs "
+                "one residual stream and one head pass")
+        streams = int(self.hyper[0]) if self.hyper else 1
         emb = nn.Embed(self.vocab_size, self.d_model, name="embed",
                        dtype=self.compute_dtype)
         x = emb(input_ids)
-        x = constrain(x, P(BATCH, "sp", None))
+        x = _to_streams(constrain(x, P(BATCH, "sp", None)), streams)
         if self.layer_mixer:
             if len(self.layer_mixer) != self.n_layers or (
                     self.decode or self.sparse or self.latent
@@ -716,8 +1034,8 @@ class Transformer(nn.Module):
             block_cls = (nn.remat(Block, static_argnums=(3,),
                                   policy=self._remat_policy())
                          if self.remat else Block)
-            for i, dense in enumerate(layer_ffn):
-                x = block_cls(
+            def block(dense, name):
+                return block_cls(
                     self.n_heads, dh, dense or dff,
                     0 if dense else self.n_experts, self.moe_top_k,
                     self.rope_theta, self.attn_impl, self.mesh,
@@ -726,14 +1044,42 @@ class Transformer(nn.Module):
                     self.moe_norm_topk_prob, self.n_kv_heads,
                     self.qk_norm_per_head, self.moe_held, self.sparse,
                     self.latent, self.moe_router, self.moe_shared_d_ff,
-                    name=f"block_{i}")(x, positions, block_diffusion)
-        x = RMSNorm(self.norm_eps, name="final_norm")(x)
-        if self.return_hidden:
-            return x
-        with jax.named_scope("lm_head_loss"):   # the loss half: make_loss_fn
-            logits = nn.Dense(self.vocab_size, use_bias=False, name="lm_head",
-                              dtype=self.compute_dtype)(x)
-            return constrain(logits.astype(jnp.float32), P(BATCH, "sp", None))
+                    self.q_lora_rank, self.rope_scaling, self.hyper,
+                    self.hyper_dtype, name=name)
+
+            for i, dense in enumerate(layer_ffn):
+                x = block(dense, f"block_{i}")(x, positions, block_diffusion)
+        x = _from_streams(x, streams)
+        head = nn.Dense(self.vocab_size, use_bias=False, name="lm_head",
+                        dtype=self.compute_dtype)
+
+        def out(hidden):
+            if self.return_hidden:
+                return hidden
+            with jax.named_scope("lm_head_loss"):   # the loss half: make_loss_fn
+                logits = head(hidden)
+                return constrain(logits.astype(jnp.float32),
+                                 P(BATCH, "sp", None))
+
+        main = out(RMSNorm(self.norm_eps, name="final_norm")(x))
+        if not self.mtp_layers:
+            return main
+        # position i reads the trunk's state h_i (before the final norm) and
+        # the embedding of token i + 1 and predicts token i + 2.  The row is
+        # rolled, not cut, so the module runs the trunk's shapes: its last
+        # position reads token 0 and has no target, and under the causal mask
+        # no other position sees it.
+        with jax.named_scope("mtp"):
+            nxt = emb(jnp.roll(input_ids, -1, axis=1))
+            both = jnp.concatenate(
+                [RMSNorm(self.norm_eps, name="mtp_hnorm")(x),
+                 RMSNorm(self.norm_eps, name="mtp_enorm")(nxt)], axis=-1)
+            y = nn.Dense(self.d_model, use_bias=False, name="mtp_eh_proj",
+                         dtype=self.compute_dtype)(both)
+            y = _to_streams(constrain(y, P(BATCH, "sp", None)), streams)
+            y = block(layer_ffn[-1], "mtp_block")(y, positions, None)
+            return main, out(RMSNorm(self.norm_eps, name="mtp_norm")(
+                _from_streams(y, streams)))
 
     def _remat_policy(self):
         """What a rematerialised block keeps besides its input: nothing, or,
@@ -759,6 +1105,8 @@ def build_transformer(config: dict) -> Transformer:
     layer_ffn = config.get("layer_ffn")
     layer_mixer = config.get("layer_mixer")
     ssm = config.get("ssm")
+    scaling = config.get("rope_scaling")
+    hyper = config.get("hyper_connections")
     if router is not None and int(router.get("n_group", 1)) > 1:
         raise NotImplementedError(
             f"group-limited routing (n_group {router['n_group']}): "
@@ -808,6 +1156,14 @@ def build_transformer(config: dict) -> Transformer:
         rope=bool(config.get("rope", True)),
         moe_expert_act=str(config.get("moe_expert_act", "swiglu")),
         moe_latent=int(config.get("moe_latent", 0)),
+        q_lora_rank=int(config.get("q_lora_rank") or 0),
+        rope_scaling=rope_scaling_from_config(scaling),
+        hyper=None if hyper is None else (
+            int(hyper["hc_mult"]), int(hyper["hc_sinkhorn_iters"]),
+            float(hyper["hc_eps"]), float(hyper["mhc_h_res_clamp_min"]),
+            float(hyper["mhc_h_res_clamp_max"])),
+        hyper_dtype=jnp.dtype(config.get("hyper_dtype", "float32")),
+        mtp_layers=int(config.get("num_nextn_predict_layers", 0)),
     )
 
 
@@ -970,7 +1326,8 @@ def greedy_generate(model: Transformer, params, prompt_ids, max_new_tokens: int,
 
 
 def _sown_collections(model: Transformer) -> list:
-    return ["aux_loss", "moe_stats"] if model.n_experts else ["aux_loss"]
+    return (["aux_loss"] + (["moe_stats"] if model.n_experts else [])
+            + (["hc_stats"] if model.hyper else []))
 
 
 def _with_sown_terms(loss, updates, aux_loss_coef: float,
@@ -988,17 +1345,34 @@ def _with_sown_terms(loss, updates, aux_loss_coef: float,
             aux = aux + leaf
     total = loss + aux_loss_coef * aux + router_z_coef * z
     metrics = {"lm_loss": loss, "aux_loss": aux, "router_z_loss": z}
-    stats: dict[str, list] = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(
-            updates.get("moe_stats", {}))[0]:
-        name = [p.key for p in path if hasattr(p, "key")][-1]
-        stats.setdefault(f"moe_{name}", []).append(leaf)
-    metrics.update({k: jnp.mean(jnp.stack(v)) for k, v in stats.items()})
+    # a collection's leaves by name over the layers: an error is the worst
+    # of them (``hc_stats/res_*_err``), anything else their mean
+    for collection, prefix in (("moe_stats", "moe_"), ("hc_stats", "hc_")):
+        stats: dict[str, list] = {}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+                updates.get(collection, {}))[0]:
+            name = [p.key for p in path if hasattr(p, "key")][-1]
+            stats.setdefault(prefix + name, []).append(leaf)
+        metrics.update({k: (jnp.max if k.endswith("_err") else jnp.mean)(
+            jnp.stack(v)) for k, v in stats.items()})
     return total, metrics
 
 
+@contextlib.contextmanager
+def _mtp_head_scope():
+    """The scopes of the MTP module's pass of the head and the loss: ``mtp``
+    and ``lm_head_loss`` as whole components.  A transform names itself
+    around the OUTERMOST scope it meets in a differentiated function
+    (``jvp(mtp_loss)/mtp/lm_head_loss/...``), and a reader of device time
+    looks for whole components: so there is a scope around ``mtp``."""
+    with jax.named_scope("mtp_loss"), jax.named_scope("mtp"), \
+            jax.named_scope("lm_head_loss"):
+        yield
+
+
 def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
-                 vocab_chunk: int = 0, router_z_coef: float = 1e-3):
+                 vocab_chunk: int = 0, router_z_coef: float = 1e-3,
+                 mtp_coef: float = 0.1):
     """Next-token LM loss.  Batch: ``{"input_ids": [B, S] int32}`` (targets
     are inputs shifted left; final position predicts a discarded token).
     MoE auxiliary losses are collected from the ``aux_loss`` sow:
@@ -1019,22 +1393,40 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
     selection bias, ``parallel/ep.MoEMLP``) is handed them as a third
     argument, ``loss_fn(params, batch, buffers)``: what
     ``parallel/dp.make_train_step`` calls where the train state carries
-    them.  The loss is not differentiated by them."""
+    them.  The loss is not differentiated by them.
+
+    A model with a multi-token-prediction module (``Transformer.mtp_layers``)
+    returns a second hidden state (or logits) a position: the SAME head and
+    cross-entropy a second time, over targets shifted by TWO (position i
+    predicts token i + 2; the row's last two positions have none), under the
+    scope ``mtp``, and ``total = main + mtp_coef · mtp_loss`` (DeepSeek-V3
+    §2.2, one depth: its λ).  The gradients of the two uses of the embedding
+    and of the head are summed by the one ``value_and_grad``.  Metrics carry
+    ``mtp_loss``."""
 
     sown = _sown_collections(model)
+    mtp = bool(model.mtp_layers)
 
     def _variables(params, buffers):
         return ({"params": params} if buffers is None
                 else {"params": params, "buffers": buffers})
 
-    def _reduce(nll, batch, updates):
+    def _mean(nll, batch, shift: int):
+        """The mean of ``nll`` ``[B, S - shift]`` over the real targets."""
         mask = batch.get("loss_mask")
-        if mask is not None:
-            mask = mask[:, 1:].astype(jnp.float32)
-            loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-        else:
-            loss = jnp.mean(nll)
-        return _with_sown_terms(loss, updates, aux_loss_coef, router_z_coef)
+        if mask is None:
+            return jnp.mean(nll)
+        mask = mask[:, shift:].astype(jnp.float32)
+        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+    def _reduce(nll, batch, updates):
+        return _with_sown_terms(_mean(nll, batch, 1), updates, aux_loss_coef,
+                                router_z_coef)
+
+    def _with_mtp(total, metrics, nll, batch):
+        """The second loss: ``nll`` ``[B, S - 2]`` of targets 2 .. S - 1."""
+        loss = _mean(nll, batch, 2)
+        return total + mtp_coef * loss, {**metrics, "mtp_loss": loss}
 
     if vocab_chunk:
         from tensorflowonspark_tpu.ops.xent import blockwise_cross_entropy
@@ -1045,6 +1437,8 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
             ids = batch["input_ids"]
             h, updates = hidden_model.apply(_variables(params, buffers), ids,
                                             mutable=sown)
+            if mtp:
+                h, h_mtp = h
             b, s, d = h.shape
             h = h[:, :-1].reshape(b * (s - 1), d)
             targets = ids[:, 1:].reshape(-1)
@@ -1052,7 +1446,15 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
                 nll = blockwise_cross_entropy(
                     h, params["lm_head"]["kernel"].astype(h.dtype), targets,
                     chunk=vocab_chunk)
-            return _reduce(nll.reshape(b, s - 1), batch, updates)
+            out = _reduce(nll.reshape(b, s - 1), batch, updates)
+            if not mtp:
+                return out
+            with _mtp_head_scope():
+                nll = blockwise_cross_entropy(
+                    h_mtp[:, :-2].reshape(b * (s - 2), d),
+                    params["lm_head"]["kernel"].astype(h.dtype),
+                    ids[:, 2:].reshape(-1), chunk=vocab_chunk)
+            return _with_mtp(*out, nll.reshape(b, s - 2), batch)
 
         return fused_loss_fn
 
@@ -1060,14 +1462,30 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
         ids = batch["input_ids"]
         logits, updates = model.apply(_variables(params, buffers), ids,
                                       mutable=sown)
+        if mtp:
+            logits, logits_mtp = logits
         with jax.named_scope("lm_head_loss"):
             logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
             targets = ids[:, 1:]
             nll = -jnp.take_along_axis(logp, targets[..., None],
                                        axis=-1)[..., 0]
-        return _reduce(nll, batch, updates)
+        out = _reduce(nll, batch, updates)
+        if not mtp:
+            return out
+        with _mtp_head_scope():
+            logp = jax.nn.log_softmax(logits_mtp[:, :-2].astype(jnp.float32))
+            nll = -jnp.take_along_axis(logp, ids[:, 2:, None], axis=-1)[..., 0]
+        return _with_mtp(*out, nll, batch)
 
     return loss_fn
+
+
+def _one_stream_one_head(model: Transformer, loss: str) -> None:
+    if model.hyper or model.mtp_layers:
+        raise NotImplementedError(
+            f"{loss} with hyper={model.hyper}, mtp_layers={model.mtp_layers}: "
+            "the block-diffusion and the sparse losses run one residual "
+            "stream and one pass of the head (make_loss_fn has both)")
 
 
 def corrupt_blocks(ids, noise_seed, block: int, mask_id: int,
@@ -1111,6 +1529,7 @@ def make_block_diffusion_loss_fn(model: Transformer, block: int, mask_id: int,
     carry ``masked_share``: masked tokens over all."""
     from tensorflowonspark_tpu.ops.xent import blockwise_cross_entropy
 
+    _one_stream_one_head(model, "make_block_diffusion_loss_fn")
     sown = _sown_collections(model)
     hidden_model = model.clone(return_hidden=True)
 
@@ -1170,6 +1589,7 @@ def make_sparse_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
 
     from tensorflowonspark_tpu.ops.xent import blockwise_cross_entropy
 
+    _one_stream_one_head(model, "make_sparse_loss_fn")
     sown = _sown_collections(model) + ["dsa_stats"]
     hidden_model = model.clone(return_hidden=True)
 

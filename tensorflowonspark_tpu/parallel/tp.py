@@ -125,4 +125,12 @@ TRANSFORMER_TP_RULES: Rules = (
     (r"ssm/(in_proj|out_proj)/kernel$", P()),
     (r"ssm/(conv_kernel|conv_bias|A_log|D|dt_bias|norm_scale)$", P()),
     (r"moe/latent_(down|up)/kernel$", P()),
+    # residual streams (``Transformer.hyper``): a hyper-connection's maps
+    # read all ``n·C`` values of a token and are replicated over tp, as the
+    # carry ``[B, S, n·C]`` is (``P(BATCH, "sp", None)``); so is the
+    # multi-token-prediction module's ``[2·C, C]`` projection, and latent
+    # attention's query latent follows no rule (``q_a_proj``, ``q_b_proj``:
+    # replicated, as ``kv_a_proj`` / ``kv_b_proj`` are)
+    (r"hc_(attn|mlp)/(phi|bias|alpha|norm_scale)$", P()),
+    (r"mtp_eh_proj/kernel$", P()),
 )

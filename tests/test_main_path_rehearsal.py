@@ -15,8 +15,10 @@ the block-diffusion loss over a chip's share of the experts with
 grouped-query heads (SDAR), learned sparse attention with its indexer's
 loss under remat (Keye), latent attention over a leading dense layer and
 bias-corrected sigmoid routing with a buffer the optimizer leaves alone
-(Kanana-2), and a mixer a layer: the Mamba-2 scan, attention without
-rotation and relu² experts in a latent (Nemotron-3): a crash on that cell's
+(Kanana-2), a mixer a layer: the Mamba-2 scan, attention without
+rotation and relu² experts in a latent (Nemotron-3), and four residual
+streams under hyper-connections with a query latent, YaRN and a
+multi-token-prediction module in the loss (Xing4.0): a crash on that cell's
 first step shows here.
 
 The ResNet-50 case also reads the rehearsal's own ``logs/run_report.json``
@@ -75,7 +77,8 @@ def _missing(path: str) -> list[str]:
                                       "sdar_30b_a3b_d4_ep8_train_bd4k",
                                       "keye_vl2_30b_a3b_d4_ep8_train_16k",
                                       "kanana2_30b_a3b_d5_ep8_train_8k",
-                                      "nemotron3_super_d11_tp8_ep64_train_8k"])
+                                      "nemotron3_super_d11_tp8_ep64_train_8k",
+                                      "xing4_29b_a4b_d5_tp8_ep8_train_4k"])
 def test_cell_rehearses_on_cpu(workload):
     cell = common.resolve_cell(workload)
     # run.py's work directory is not configurable and a DIRECT cell keeps one

@@ -90,6 +90,58 @@ def test_latent_step_names_its_projections_the_shared_expert_and_router():
     assert "/block_1/moe/" in text
 
 
+def test_hyper_connected_step_names_maps_mixing_and_the_mtp_module():
+    """ISSUE 45: a model with residual streams names a hyper-connection's
+    maps (``hc/maps``: norm, product, sigmoid, exp, Sinkhorn), the mix a
+    sub-layer reads (``hc/pre``) and what it writes back (``hc/post``),
+    forward and backward, and the multi-token-prediction module (``mtp``):
+    its projection, its layer with the layer's own scopes inside, and its
+    pass of the head and the loss, dense and fused (the readers ``hc_mix_ms``,
+    ``hc_maps_ms``, ``hc_mix_roofline`` and ``mtp_ms`` sum device time by
+    these); the query latent's two projections are latent attention's."""
+    config = dict(
+        n_layers=2, n_heads=2, n_experts=4, moe_top_k=2,
+        moe_capacity_factor=None, attn_impl="pallas_interpret",
+        latent_attention={"kv_lora_rank": 16, "qk_nope_head_dim": 8,
+                          "qk_rope_head_dim": 4, "v_head_dim": 8},
+        q_lora_rank=12, rope_scaling={
+            "type": "yarn", "factor": 64, "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 8},
+        moe_router={"scoring": "sigmoid", "selection_bias": True,
+                    "routed_scale": 2.0},
+        moe_shared_d_ff=32, layer_ffn=[48, 0],
+        hyper_connections={"hc_mult": 4, "hc_sinkhorn_iters": 3,
+                           "hc_eps": 1e-6, "mhc_h_res_clamp_min": -30,
+                           "mhc_h_res_clamp_max": 30},
+        num_nextn_predict_layers=1, remat=True)
+    text = _lm_step_text(**config)
+    for scope in ("hc/maps", "hc/pre", "hc/post", "mtp", "mla/project",
+                  "flash_fwd", "flash_bwd", "moe/shared", "moe/experts"):
+        assert f"/{scope}/" in text, scope
+        backward = [line for line in text.split("jit(step)")
+                    if f"/{scope}/" in line and "transpose(" in line]
+        assert backward or scope in ("flash_fwd", "flash_bwd"), scope
+    for name in ("q_a_proj", "q_b_proj", "kv_a_proj", "kv_b_proj", "o_proj"):
+        assert f"/mla/project/{name}/" in text, name
+    # the module's layer keeps the scopes a layer has, inside ``mtp``
+    for inner in ("hc/maps", "hc/pre", "hc/post", "mla/project",
+                  "moe/experts", "lm_head_loss", "mtp_eh_proj"):
+        assert re.search(rf"/mtp/[^\s\"]*{inner}/", text), inner
+    assert re.search(r'/mtp/[^\s"]*mtp_block[^\s"]*/hc_mlp/hc/maps/', text)
+    assert re.search(r'/block_1/[^\s"]*hc_attn/hc/maps/', text)
+    # the fused loss's second pass of the head is the module's too: a scope
+    # opened directly in the differentiated function is wrapped in one more
+    model = registry.build({"model": "transformer", "vocab_size": 64,
+                            "d_model": 32, "d_ff": 64, **config})
+    ids = jnp.zeros((2, 16), jnp.int32)
+    variables = jax.eval_shape(lambda: model.init(jax.random.key(0), ids))
+    loss_fn = transformer.make_loss_fn(model, vocab_chunk=32)
+    fused = _hlo_op_names(jax.jit(jax.grad(
+        lambda p, b: loss_fn(p, {"input_ids": ids}, b)[0])).lower(
+            variables["params"], variables["buffers"]))
+    assert "(mtp_loss)/mtp/lm_head_loss/" in fused
+
+
 def test_mixer_step_names_the_state_space_mixer_and_the_latent_maps():
     """ISSUE 41: a model of one mixer a layer names the Mamba-2 mixer
     (``ssm``) and its five parts, LatentMoE's two maps (``moe/latent``), the
