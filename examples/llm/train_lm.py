@@ -253,11 +253,10 @@ def _train_pipelined(args) -> None:
         tgt = tgt_ids[:, 1:]
         if args.vocab_chunk:
             # fused blockwise head: never materializes [mb, S, V] logits
-            nll = xent.blockwise_cross_entropy(
+            return xent.blockwise_cross_entropy(
                 final[:, :-1].reshape(-1, args.d_model),
                 hp["lm_head"]["kernel"], tgt.reshape(-1),
-                chunk=args.vocab_chunk)
-            return jnp.mean(nll)
+                chunk=args.vocab_chunk) / tgt.size
         logits = nn.Dense(args.vocab_size, use_bias=False, dtype=dtype).apply(
             {"params": hp["lm_head"]}, final).astype(jnp.float32)
         logp = jax.nn.log_softmax(logits[:, :-1])

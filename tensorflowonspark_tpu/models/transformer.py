@@ -1419,13 +1419,8 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
         mask = mask[:, shift:].astype(jnp.float32)
         return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
-    def _reduce(nll, batch, updates):
-        return _with_sown_terms(_mean(nll, batch, 1), updates, aux_loss_coef,
-                                router_z_coef)
-
-    def _with_mtp(total, metrics, nll, batch):
-        """The second loss: ``nll`` ``[B, S - 2]`` of targets 2 .. S - 1."""
-        loss = _mean(nll, batch, 2)
+    def _with_mtp(total, metrics, loss):
+        """The second loss: the mean over targets 2 .. S - 1."""
         return total + mtp_coef * loss, {**metrics, "mtp_loss": loss}
 
     if vocab_chunk:
@@ -1433,28 +1428,35 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
 
         hidden_model = model.clone(return_hidden=True)
 
+        def _fused_mean(params, h, batch, shift: int):
+            """``_mean`` of the cross-entropy of ``h``'s position i against
+            token i + shift, the head fused in (``ops/xent.py``)."""
+            b, s, d = h.shape
+            ids, mask = batch["input_ids"], batch.get("loss_mask")
+            if mask is not None:
+                mask = mask[:, shift:].reshape(-1).astype(jnp.float32)
+            total = blockwise_cross_entropy(
+                h[:, :-shift].reshape(b * (s - shift), d),
+                params["lm_head"]["kernel"].astype(h.dtype),
+                ids[:, shift:].reshape(-1), mask, chunk=vocab_chunk)
+            if mask is None:
+                return total / (b * (s - shift))
+            return total / jnp.maximum(jnp.sum(mask), 1.0)
+
         def fused_loss_fn(params, batch, buffers=None):
-            ids = batch["input_ids"]
-            h, updates = hidden_model.apply(_variables(params, buffers), ids,
-                                            mutable=sown)
+            h, updates = hidden_model.apply(_variables(params, buffers),
+                                            batch["input_ids"], mutable=sown)
             if mtp:
                 h, h_mtp = h
-            b, s, d = h.shape
-            h = h[:, :-1].reshape(b * (s - 1), d)
-            targets = ids[:, 1:].reshape(-1)
             with jax.named_scope("lm_head_loss"):
-                nll = blockwise_cross_entropy(
-                    h, params["lm_head"]["kernel"].astype(h.dtype), targets,
-                    chunk=vocab_chunk)
-            out = _reduce(nll.reshape(b, s - 1), batch, updates)
+                loss = _fused_mean(params, h, batch, 1)
+            out = _with_sown_terms(loss, updates, aux_loss_coef,
+                                   router_z_coef)
             if not mtp:
                 return out
             with _mtp_head_scope():
-                nll = blockwise_cross_entropy(
-                    h_mtp[:, :-2].reshape(b * (s - 2), d),
-                    params["lm_head"]["kernel"].astype(h.dtype),
-                    ids[:, 2:].reshape(-1), chunk=vocab_chunk)
-            return _with_mtp(*out, nll.reshape(b, s - 2), batch)
+                loss = _fused_mean(params, h_mtp, batch, 2)
+            return _with_mtp(*out, loss)
 
         return fused_loss_fn
 
@@ -1469,13 +1471,14 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
             targets = ids[:, 1:]
             nll = -jnp.take_along_axis(logp, targets[..., None],
                                        axis=-1)[..., 0]
-        out = _reduce(nll, batch, updates)
+        out = _with_sown_terms(_mean(nll, batch, 1), updates, aux_loss_coef,
+                               router_z_coef)
         if not mtp:
             return out
         with _mtp_head_scope():
             logp = jax.nn.log_softmax(logits_mtp[:, :-2].astype(jnp.float32))
             nll = -jnp.take_along_axis(logp, ids[:, 2:, None], axis=-1)[..., 0]
-        return _with_mtp(*out, nll, batch)
+        return _with_mtp(*out, _mean(nll, batch, 2))
 
     return loss_fn
 
@@ -1556,10 +1559,10 @@ def make_block_diffusion_loss_fn(model: Transformer, block: int, mask_id: int,
                 mutable=sown)
             h = h[:, :length].reshape(rows * length, h.shape[-1])
             with jax.named_scope("lm_head_loss"):
-                nll = blockwise_cross_entropy(
+                loss = blockwise_cross_entropy(
                     h, params["lm_head"]["kernel"].astype(h.dtype),
-                    ids.reshape(-1), chunk=vocab_chunk)
-                loss = jnp.sum(nll * weight) / (rows * length)
+                    ids.reshape(-1), weight,
+                    chunk=vocab_chunk) / (rows * length)
             total, metrics = _with_sown_terms(loss, updates, aux_loss_coef,
                                               router_z_coef)
         return total, {**metrics, "masked_share": jnp.mean(masked)}
@@ -1602,11 +1605,11 @@ def make_sparse_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
                                             mutable=sown)
             b, s, d = h.shape
             with jax.named_scope("lm_head_loss"):
-                nll = blockwise_cross_entropy(
+                loss = blockwise_cross_entropy(
                     h[:, :-1].reshape(b * (s - 1), d),
                     params["lm_head"]["kernel"].astype(h.dtype),
-                    ids[:, 1:].reshape(-1), chunk=vocab_chunk)
-                loss = jnp.mean(nll)
+                    ids[:, 1:].reshape(-1),
+                    chunk=vocab_chunk) / (b * (s - 1))
             aux = traverse_util.flatten_dict(dict(updates.get("aux_loss", {})))
             index = [v for k, v in aux.items() if "index_kl" in k]
             rest = {k: v for k, v in aux.items() if "index_kl" not in k}

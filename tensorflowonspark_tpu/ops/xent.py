@@ -1,21 +1,38 @@
-"""Blockwise (vocab-chunked) softmax cross-entropy for large-vocab LM heads.
+"""Blockwise softmax cross-entropy fused with a large-vocabulary LM head.
 
-The dense LM loss path materializes ``[B, S, V]`` float32 logits twice
-(forward activations + backward cotangents) — at the bench shape
-(8×2048×32000) that is ~2 GB of HBM traffic per direction for a loss whose
-useful output is one scalar per token.  This op never materializes more
-than ``[N, chunk]`` logits: the head matmul, online logsumexp, and the
-softmax-minus-onehot backward are streamed over vocabulary chunks with
-``lax.scan``, recomputing chunk logits in the backward instead of saving
-them (the same recompute-over-residuals trade the flash-attention kernel
-makes — SURVEY.md §5.7 is the design's cousin).
+The dense LM loss materializes ``[N, V]`` float32 logits and their
+cotangent.  This op walks CHUNKS OF ROWS over the whole vocabulary, one
+after the other: a chunk's logits ``[rows, V]`` are whole, so its log-sum-exp,
+its targets' logits and its softmax all come from ONE head product, and
+the gradient is taken where the logits are.  The differentiated call
+(``_fwd``) makes three products a chunk — the logits, ``dh_chunk = dlogits ·
+Wᵀ`` (a whole block: nothing accumulates over chunks) and ``dw += h_chunkᵀ ·
+dlogits`` (the one carry, float32) — and keeps ``dh``, ``dw`` and the rows'
+``nll`` for the backward rule, which only scales them by its scalar
+cotangent.  No logits are kept and none are computed twice; the head is used
+as ``[D, V]`` as it stands.
+
+That needs each row's loss weight in the forward, so the op is the WEIGHTED
+SUM ``Σ w·nll`` (a mean's denominator is the caller's, outside).  The
+undifferentiated call (an evaluation) makes the one logits product a chunk
+and no gradient.
+
+The row chunk follows from the shapes: ``chunk`` is the width of the
+``[N, chunk]`` float32 logits block the op may hold, spent as ``[rows, V]``,
+but no fewer than 2,048 rows: every chunk reads and writes the carried ``dw``
+once.  Rows are padded with weight 0 up to whole chunks.
 
 No counterpart exists in the reference (its models are CNNs/wide-and-deep;
 losses are delegated to TF) — this exists because the LM family is
-first-class here.  XLA-level implementation (``lax.scan`` + dot_general with
-f32 accumulation), so it runs on TPU and CPU alike and GSPMD shards the
-token axis; for tensor-parallel vocab sharding use the dense path instead
-(the chunk scan would fight the tp partitioning of the head kernel).
+first-class here.  XLA-level implementation (an unrolled loop of dot_general
+with f32 accumulation), so it runs on TPU and CPU alike.  Under a data-parallel
+mesh the carried ``dw`` is summed over the devices once a chunk, and the
+row walk would fight a tensor-parallel sharding of the head's vocabulary:
+use the dense path there.
+
+Trace-time counters (``logs/run_report.json``): ``xent.calls`` a traced call
+of the op, ``xent.grad_in_forward`` a traced ``_fwd``; a differentiated call
+counts in both, a forward-only program in the first alone.
 """
 
 from __future__ import annotations
@@ -26,117 +43,130 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_NEG_INF = -1e30
+from tensorflowonspark_tpu import telemetry
+
+_ROW_MULTIPLE = 8  # a float32 tile's sublanes
+# A chunk reads and writes the carried ``dw`` once for ``rows / 4`` FLOP a
+# byte of it: 2,048 rows are twice the v5e's ridge (197 TFLOP/s over 819
+# GB/s = 240).  Below it the carry shows: 1.3 ms a chunk at OLMoE's shapes.
+_MIN_ROWS = 2048
 
 
-def _pad_vocab(kernel: jax.Array, chunk: int) -> tuple[jax.Array, int]:
-    """Reshape ``[D, V]`` → ``[n_chunks, D, chunk]``, zero-padding V up."""
-    d, v = kernel.shape
-    n_chunks = -(-v // chunk)
-    pad = n_chunks * chunk - v
-    if pad:
-        kernel = jnp.pad(kernel, ((0, 0), (0, pad)))
-    return kernel.reshape(d, n_chunks, chunk).transpose(1, 0, 2), n_chunks
-
-
-def _chunk_logits(h: jax.Array, w_c: jax.Array, first_col: jax.Array,
-                  vocab: int) -> jax.Array:
-    """f32 ``[N, chunk]`` logits for one kernel chunk; padded cols → -inf."""
-    logits = jax.lax.dot_general(
-        h, w_c, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    cols = first_col + jnp.arange(w_c.shape[1])
-    return jnp.where(cols[None, :] < vocab, logits, _NEG_INF)
+def _row_chunk(n: int, vocab: int, chunk: int) -> int:
+    """Rows a chunk: as many chunks as ``chunk`` columns go into the
+    vocabulary, so that ``[rows, vocab]`` is the block ``[n, chunk]`` was,
+    and no fewer rows than pay for the carry."""
+    n_chunks = -(-vocab // min(chunk, vocab))
+    rows = max(-(-n // n_chunks), min(_MIN_ROWS, n))
+    return -(-rows // _ROW_MULTIPLE) * _ROW_MULTIPLE
 
 
 def blockwise_cross_entropy(hidden: jax.Array, kernel: jax.Array,
-                            targets: jax.Array, chunk: int = 4096) -> jax.Array:
-    """Per-token ``-log softmax(hidden @ kernel)[target]`` without the
-    ``[N, V]`` materialization.
+                            targets: jax.Array,
+                            weights: jax.Array | None = None,
+                            chunk: int = 4096) -> jax.Array:
+    """``Σ_i weights_i · -log softmax(hidden_i @ kernel)[targets_i]`` without
+    the ``[N, V]`` materialization.
 
     Args:
       hidden: ``[N, D]`` final hidden states (any float dtype; matmuls
         accumulate in f32).
       kernel: ``[D, V]`` LM-head kernel.
       targets: ``[N]`` int32 target ids in ``[0, V)``.
-      chunk: vocab tile width (V is zero-padded up to a multiple).
+      weights: ``[N]`` loss weight a row (default: ones).  Its cotangent is
+        the rows' negative log-likelihoods.
+      chunk: width of the ``[N, chunk]`` float32 logits block the op may
+        hold; it holds ``[max(N · chunk / V, 2048), V]``.
 
-    Returns: ``[N]`` float32 negative log-likelihoods.
+    Returns: the float32 scalar weighted sum.
     """
-    chunk = min(chunk, kernel.shape[1])
-    return _blockwise_xent(hidden, kernel, targets, chunk, kernel.shape[1])
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _blockwise_xent(hidden, kernel, targets, chunk, vocab):
-    nll, _ = _forward(hidden, kernel, targets, chunk, vocab)
-    return nll
-
-
-def _forward(hidden, kernel, targets, chunk, vocab):
     n = hidden.shape[0]
-    w_chunks, n_chunks = _pad_vocab(kernel, chunk)
-
-    def body(carry, scan_in):
-        m, s, tgt = carry
-        ci, w_c = scan_in
-        first = ci * chunk
-        logits = _chunk_logits(hidden, w_c, first, vocab)  # [N, chunk]
-        m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
-        s = s * jnp.exp(m - m_new) + jnp.sum(
-            jnp.exp(logits - m_new[:, None]), axis=-1)
-        local = targets - first
-        in_chunk = (local >= 0) & (local < chunk)
-        picked = jnp.take_along_axis(
-            logits, jnp.clip(local, 0, chunk - 1)[:, None], axis=-1)[:, 0]
-        tgt = jnp.where(in_chunk, picked, tgt)
-        return (m_new, s, tgt), None
-
-    init = (jnp.full((n,), _NEG_INF, jnp.float32),
-            jnp.zeros((n,), jnp.float32),
-            jnp.full((n,), _NEG_INF, jnp.float32))
-    (m, s, tgt), _ = jax.lax.scan(
-        body, init, (jnp.arange(n_chunks), w_chunks))
-    lse = m + jnp.log(s)
-    return lse - tgt, lse
+    if weights is None:
+        weights = jnp.ones((n,), jnp.float32)
+    return _weighted_xent(hidden, kernel, targets,
+                          weights.astype(jnp.float32),
+                          _row_chunk(n, kernel.shape[1], chunk))
 
 
-def _fwd(hidden, kernel, targets, chunk, vocab):
-    nll, lse = _forward(hidden, kernel, targets, chunk, vocab)
-    return nll, (hidden, kernel, targets, lse)
+def _walk(body, carry, rows: int, *per_row: jax.Array):
+    """``lax.scan`` of ``body(carry, chunk) -> (carry, outs)`` over chunks of
+    ``rows`` rows of the ``[N, ...]`` arrays (zero-padded to whole chunks),
+    the outputs joined back to ``N`` rows.  Unrolled, with each chunk held
+    behind the carry of the one before by an optimization barrier, so one
+    chunk's logits are alive at a time: as a ``while`` loop whose carried
+    ``dw`` is a temporary, the step's program holds a further logits block
+    by the compiler's count (PERF.md §6, PR 46)."""
+    n = per_row[0].shape[0]
+    pad = -n % rows
+    chunks = [jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+              .reshape(-1, rows, *x.shape[1:]) for x in per_row]
+    outs = []
+    for chunk in zip(*chunks):
+        chunk, carry = jax.lax.optimization_barrier((chunk, carry))
+        carry, out = body(carry, chunk)
+        outs.append(out)
+    return carry, jax.tree.map(lambda *xs: jnp.concatenate(xs)[:n], *outs)
 
 
-def _bwd(chunk, vocab, residuals, g):
-    hidden, kernel, targets, lse = residuals
-    w_chunks, n_chunks = _pad_vocab(kernel, chunk)
+def _chunk_nll(h_c, kernel, t_c):
+    """One chunk's f32 logits ``[rows, V]``, log-sum-exp and nll ``[rows]``."""
+    logits = jax.lax.dot_general(
+        h_c, kernel, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m = jnp.max(logits, axis=-1)
+    lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[:, None]), axis=-1))
+    picked = jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
+    return logits, lse, lse - picked
 
-    def body(dh, scan_in):
-        ci, w_c = scan_in
-        first = ci * chunk
-        logits = _chunk_logits(hidden, w_c, first, vocab)
-        # d nll / d logits = softmax - onehot(target); scale by the incoming
-        # per-token cotangent.  Padded columns have softmax exactly 0.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _weighted_xent(hidden, kernel, targets, weights, rows):
+    telemetry.counter("xent.calls").inc()
+
+    def body(total, chunk):
+        h_c, t_c, w_c = chunk
+        nll = _chunk_nll(h_c, kernel, t_c)[2]
+        return total + jnp.sum(nll * w_c), ()
+
+    return _walk(body, jnp.zeros((), jnp.float32), rows,
+                 hidden, targets, weights)[0]
+
+
+def _fwd(hidden, kernel, targets, weights, rows):
+    telemetry.counter("xent.calls").inc()
+    telemetry.counter("xent.grad_in_forward").inc()
+
+    def body(carry, chunk):
+        total, dw = carry
+        h_c, t_c, w_c = chunk
+        logits, lse, nll = _chunk_nll(h_c, kernel, t_c)
+        # d nll / d logits = softmax - onehot(target), times the row's weight
         p = jnp.exp(logits - lse[:, None])
-        local = targets - first
-        onehot = ((local[:, None] == jnp.arange(chunk)[None, :])
+        onehot = ((t_c[:, None] == jnp.arange(kernel.shape[1])[None, :])
                   .astype(jnp.float32))
-        dlogits = (p - onehot) * g[:, None].astype(jnp.float32)
-        dh = dh + jax.lax.dot_general(
-            dlogits, w_c, (((1,), (1,)), ((), ())),
+        dlogits = (p - onehot) * w_c[:, None]
+        dh_c = jax.lax.dot_general(
+            dlogits, kernel, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         dw_c = jax.lax.dot_general(
-            hidden, dlogits, (((0,), (0,)), ((), ())),
+            h_c, dlogits, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return dh, dw_c
+        dw = dw_c if dw is None else dw + dw_c     # no zeros to write and read
+        return (total + jnp.sum(nll * w_c), dw), (dh_c, nll)
 
-    dh, dw_chunks = jax.lax.scan(
-        body, jnp.zeros(hidden.shape, jnp.float32),
-        (jnp.arange(n_chunks), w_chunks))
-    d = kernel.shape[0]
-    dw = dw_chunks.transpose(1, 0, 2).reshape(d, n_chunks * chunk)
-    dw = dw[:, : kernel.shape[1]]
+    (total, dw), (dh, nll) = _walk(body, (jnp.zeros((), jnp.float32), None),
+                                   rows, hidden, targets, weights)
+    # the empty slices carry the operands' dtypes to the backward rule
+    return total, (dh, dw, nll, targets, hidden[:0], kernel[:0])
+
+
+def _bwd(rows, residuals, g):
+    del rows
+    dh, dw, nll, targets, hidden, kernel = residuals
+    # dh and dw are float32 up to here: each rounds once, after the scale
     dtargets = np.zeros(targets.shape, jax.dtypes.float0)  # int arg: float0
-    return dh.astype(hidden.dtype), dw.astype(kernel.dtype), dtargets
+    return ((g * dh).astype(hidden.dtype), (g * dw).astype(kernel.dtype),
+            dtargets, g * nll)
 
 
-_blockwise_xent.defvjp(_fwd, _bwd)
+_weighted_xent.defvjp(_fwd, _bwd)

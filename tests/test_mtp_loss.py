@@ -132,11 +132,19 @@ def _loss_fn_of_the_parent(model, aux_loss_coef=0.01, vocab_chunk=0,
             b, s, d = h.shape
             h = h[:, :-1].reshape(b * (s - 1), d)
             targets = ids[:, 1:].reshape(-1)
+            mask = batch.get("loss_mask")
+            if mask is not None:
+                mask = mask[:, 1:].reshape(-1).astype(jnp.float32)
             with jax.named_scope("lm_head_loss"):
-                nll = blockwise_cross_entropy(
+                total = blockwise_cross_entropy(
                     h, params["lm_head"]["kernel"].astype(h.dtype), targets,
-                    chunk=vocab_chunk)
-            return _reduce(nll.reshape(b, s - 1), batch, updates)
+                    mask, chunk=vocab_chunk)
+            if mask is None:
+                loss = total / (b * (s - 1))
+            else:
+                loss = total / jnp.maximum(jnp.sum(mask), 1.0)
+            return tfm._with_sown_terms(loss, updates, aux_loss_coef,
+                                        router_z_coef)
 
         return fused_loss_fn
 
