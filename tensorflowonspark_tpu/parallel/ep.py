@@ -83,6 +83,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from tensorflowonspark_tpu import telemetry
 from tensorflowonspark_tpu.ops.grouped_matmul import (executed_rows,
                                                        grouped_matmul)
 from tensorflowonspark_tpu.ops.sum_tokens import moved_rows, sum_tokens
@@ -337,6 +338,28 @@ def _held_experts_bwd(piece_fn, piece, res, g):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+def _pick(probs, top_idx):
+    """``(take_along_axis(probs, top_idx, -1), hit)`` with no gather: ``hit
+    [n, k, e]`` is the one-hot of the choice, and the chosen scores are a
+    compare, a select and a sum over the experts' lanes.  The chip has no
+    fast element gather: XLA's moves a value at a time, on a v5e 1.84 ms a
+    layer for 22 of 512 a token of 8,192 tokens, and 1.70 for its transpose
+    (a sort of the indices and a scatter-add, which carry no scope).  A
+    dense pass over every (token, choice, expert) triple takes 0.15 ms, and
+    its transpose, the same pass summed over the choices, 0.11: no
+    scatter-add in the backward either.  The values are the gather's to the
+    bit both ways (a token's chosen experts are distinct: every sum has ONE
+    term that is not 0).  XLA fuses each direction into one reduce, so
+    ``[n, k, e]`` is in no memory.  The result stands behind a barrier: the
+    renormalisation's sum over the choices would merge into the pick's
+    reduce, one over ``(k, e)`` that adds in another order (a weight's last
+    bit) and is five times slower at 8 of 128."""
+    hit = top_idx[:, :, None] == jnp.arange(probs.shape[-1],
+                                            dtype=top_idx.dtype)
+    top_p = jnp.sum(jnp.where(hit, probs[:, None, :], 0), -1)
+    return jax.lax.optimization_barrier(top_p), hit
+
+
 class MoEMLP(nn.Module):
     """Top-k routed SwiGLU MoE FFN, ``[B, S, D] -> [B, S, D]``.
 
@@ -352,6 +375,16 @@ class MoEMLP(nn.Module):
     define it, where the capacity rule counts the first (Switch eq. 4).
     ``norm_topk_prob`` renormalises the k routing weights to sum to 1
     (HF's key of that name; OLMoE publishes ``false``).
+
+    The router (scope ``moe/router``): a float32 product, the scores,
+    ``lax.top_k`` for the INDICES of the choice alone, then ONE one-hot of
+    the choice, ``hit [n, k, e]``, which gives the chosen scores (``_pick``:
+    a select and a sum over the experts, with or without a selection bias;
+    ``top_k``'s own values and their JVP are not used) and the pairs an
+    expert is sent (``sum(hit, (0, 1))``).  Neither direction holds a gather
+    or a scatter-add over ``[n, e]``, ``hit`` is fused into the passes that
+    read it, and the counter ``moe.router.picks`` goes up by ``n·k`` for
+    each router traced.
 
     ``held = (first, end)`` is this chip's share of the layer's experts
     (module docstring): the router and the routing stay ``n_experts`` wide,
@@ -431,14 +464,16 @@ class MoEMLP(nn.Module):
                     "buffers", "e_score_correction_bias",
                     lambda: jnp.zeros((e,), jnp.float32)).value
                 _, top_idx = jax.lax.top_k(probs + bias, self.top_k)
-                top_p = jnp.take_along_axis(probs, top_idx, axis=-1)
+            else:
+                _, top_idx = jax.lax.top_k(probs, self.top_k)      # [n, k]
+            top_p, hit = _pick(probs, top_idx)
+            telemetry.counter("moe.router.picks").inc(n * self.top_k)
+            if self.selection_bias:
                 # a choice the unbiased scores would not have made: k or
                 # more experts score higher than the chosen one
                 higher = jnp.sum(probs[:, None, :] > top_p[:, :, None], -1)
                 self.sow("moe_stats", "bias_moved",
                          jnp.mean(higher >= self.top_k))
-            else:
-                top_p, top_idx = jax.lax.top_k(probs, self.top_k)  # [n, k]
             if self.norm_topk_prob:
                 top_p = top_p / (
                     jnp.sum(top_p, -1, keepdims=True) + 1e-20
@@ -447,14 +482,13 @@ class MoEMLP(nn.Module):
             if self.routed_scale != 1.0:
                 top_p = top_p * self.routed_scale
 
-            pairs = jnp.sum(jax.nn.one_hot(top_idx, e, dtype=jnp.int32),
-                            axis=(0, 1))                           # [e]
+            pairs = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)     # [e]
             if not self.selection_bias:
                 # Load-balancing aux loss, e · Σ_e f_e · P_e: f_e the share
                 # of tokens whose FIRST choice is e (Switch eq. 4) or,
                 # dropless, the pairs routed to e per token (all k choices).
-                counted = pairs if dropless else jnp.sum(jax.nn.one_hot(
-                    top_idx[:, 0], e, dtype=jnp.float32), axis=0)
+                counted = pairs if dropless else jnp.sum(
+                    hit[:, 0], axis=0, dtype=jnp.float32)
                 frac_probs = jnp.mean(probs, axis=0)
                 self.sow("aux_loss", "load_balance",
                          e * jnp.sum(counted / n * frac_probs))

@@ -106,5 +106,11 @@ def test_the_held_layer_lowers_at_the_cells_shapes(one_chip, case,
     # gather as long as the layer's pairs is left (the parent's was 6.7 ms a
     # step in Nemotron-3's cell)
     assert not re.search(rf"\[{n * k}\]\S* gather\(", hlo)
+    # ... and the router picks its weights by a one-hot of the choice: the
+    # compiled scope holds no gather and no scatter (9.3 ms a step there)
+    router = [line for line in hlo.splitlines() if "/moe/router/" in line]
+    assert not [line for line in router
+                if re.search(r" (gather|scatter)\(", line)]
+    assert any(" select(" in line for line in router)
     assert len(kernels) - len(sums) >= 3 * (3 if "expert_act" in further
                                             else 2)
