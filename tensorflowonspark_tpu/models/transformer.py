@@ -174,6 +174,11 @@ class Attention(nn.Module):
     # not at all).  Under latent attention YaRN also scales the SOFTMAX, by
     # ``yarn_mscale(factor, mscale_all_dim)²`` (``_latent_attention``).
     rope_scaling: Optional[tuple] = None
+    # A window on the causal mask: a query sees itself and the ``window - 1``
+    # keys before it (0: every key before it).  A field, so static under
+    # ``remat`` by construction: the flash kernels build the band's visit
+    # tables from it at trace time (``ops/attention.py``).
+    window: int = 0
 
     @nn.compact
     def __call__(self, x, positions=None, block_diffusion=None):
@@ -186,6 +191,13 @@ class Attention(nn.Module):
             raise NotImplementedError(
                 "rope=False is the plain training path's: latent attention, "
                 "the indexer and the cache path all turn their keys")
+        if self.window and (self.latent or self.sparse or self.decode
+                            or self.attn_impl == "ring"):
+            raise NotImplementedError(
+                f"window={self.window} is the plain training path's, one "
+                "whole sequence through flash_attention: latent and sparse "
+                "attention mask otherwise, the cache path holds every key, "
+                "and ring attention's chunks take no window")
         if self.latent:
             return self._latent_attention(x, positions, block_diffusion)
         if self.q_lora_rank:
@@ -251,7 +263,8 @@ class Attention(nn.Module):
                 impl = None if self.attn_impl == "auto" else self.attn_impl
                 out = flash_attention(q, k, v, causal=not block_diffusion,
                                       impl=impl,
-                                      block_diffusion=block_diffusion)
+                                      block_diffusion=block_diffusion,
+                                      window=self.window or None)
         out = nn.DenseGeneral(x.shape[-1], axis=(-2, -1), use_bias=False,
                               name="o_proj", dtype=self.compute_dtype)(out)
         return out
@@ -777,24 +790,34 @@ class Block(nn.Module):
     rope_scaling: Optional[tuple] = None
     hyper: Optional[tuple] = None
     hyper_dtype: Any = jnp.float32
+    attention: tuple = (0, True)            # see Transformer.layer_attention
+    moe_expert_act: str = "swiglu"
+    moe_router_input: str = "ffn"
 
     @nn.compact
     def __call__(self, x, positions=None, block_diffusion=None):
         norm = lambda name: RMSNorm(self.norm_eps, name=name)  # noqa: E731
+        window, rope = self.attention
         attn = Attention(self.n_heads, self.d_head, self.rope_theta,
                          self.attn_impl, self.mesh, self.compute_dtype,
                          self.decode, self.max_decode_len, self.qk_norm,
                          self.norm_eps, self.n_kv_heads,
                          self.qk_norm_per_head, self.sparse, self.latent,
-                         q_lora_rank=self.q_lora_rank,
-                         rope_scaling=self.rope_scaling, name="attn")
+                         rope=rope, q_lora_rank=self.q_lora_rank,
+                         rope_scaling=self.rope_scaling, window=window,
+                         name="attn")
         if self.hyper:
             return self._hyper_connected(x, attn, positions, block_diffusion)
+        layer_input = x
         x = x + attn(norm("attn_norm")(x), positions, block_diffusion)
         x = constrain(x, P(BATCH, "sp", None))
         ffn, shared = self._ffn(x.shape[-1])
         y = norm("mlp_norm")(x)
-        x = x + ffn(y)
+        if self.n_experts and self.moe_router_input == "layer":
+            # the router reads the residual stream as the layer found it
+            x = x + ffn(y, router_input=layer_input)
+        else:
+            x = x + ffn(y)
         if shared is not None:
             with jax.named_scope("moe/shared"):
                 x = x + shared(y)
@@ -817,7 +840,8 @@ class Block(nn.Module):
                      compute_dtype=self.compute_dtype,
                      norm_topk_prob=self.moe_norm_topk_prob,
                      held=self.moe_held, scoring=scoring,
-                     selection_bias=bias, routed_scale=scale, name="moe")
+                     selection_bias=bias, routed_scale=scale,
+                     expert_act=self.moe_expert_act, name="moe")
         shared = (SwiGLU(self.moe_shared_d_ff, self.compute_dtype,
                          name="shared") if self.moe_shared_d_ff else None)
         return ffn, shared
@@ -852,7 +876,14 @@ class Block(nn.Module):
 
 
 class Transformer(nn.Module):
-    """Decoder-only LM.  ``__call__(input_ids: [B, S]) -> logits [B, S, V]``."""
+    """Decoder-only LM.  ``__call__(input_ids: [B, S]) -> logits [B, S, V]``.
+
+    Layers may differ inside one model, each by ONE field with an entry a
+    layer: ``layer_ffn`` (a dense FFN in place of the experts),
+    ``layer_mixer`` (the layer's one mixer) and ``layer_attention`` (the
+    width of the window on a layer's causal mask, and whether it rotates).
+    What the experts' router reads is ``moe_router_input``, their form
+    ``moe_expert_act``.  The fields below say the rest."""
 
     vocab_size: int
     d_model: int
@@ -907,6 +938,15 @@ class Transformer(nn.Module):
     # layer the model's own.  DeepSeek-V3's ``first_k_dense_replace`` 1 over
     # 5 layers is ``(6144, 0, 0, 0, 0)``.
     layer_ffn: Optional[tuple] = None
+    # What each layer's ATTENTION is, one entry a layer beside ``layer_ffn``:
+    # ``(window, rope)``, the width of the window on its causal mask (0: the
+    # full causal mask) and whether it turns its queries and keys.  None:
+    # every layer ``(0, True)``.  SmallThinker's ``sliding_window_layout`` =
+    # ``rope_layout`` = 0, 1, 1, 1 with ``sliding_window_size`` 4096 is ``((0,
+    # False), (4096, True), (4096, True), (4096, True))``: a global layer
+    # without rotation, then three that rotate inside a band.  Plain and
+    # grouped-query attention on the training path (``__post_init__``).
+    layer_attention: Optional[tuple] = None
     # A MIXER a layer (``nemotron_h``'s ``hybrid_override_pattern``): one
     # entry a layer, ``"M"`` a Mamba-2 mixer (``ssm`` = (heads, head dim,
     # groups, state size, conv taps, chunk, dt min, max, floor)), ``"*"``
@@ -917,12 +957,18 @@ class Transformer(nn.Module):
     # latent``: the experts' form and the latent they live in
     # (``parallel/ep.MoEMLP``), the shared expert in the same form.  Dropless
     # routing, training path (no cache for the recurrent and conv state).
+    # A ``Block``'s experts take ``moe_expert_act`` too (``"swiglu"`` or
+    # ``"reglu"``), and ``moe_router_input`` says what their ROUTER reads:
+    # ``"ffn"`` the normed state the experts read, or ``"layer"`` the layer's
+    # input as it comes, un-normed, before attention (SmallThinker: the
+    # experts are known while attention runs; ``MoEMLP``'s ``router_input``).
     layer_mixer: Optional[tuple] = None
     ssm: Optional[tuple] = None
     ssm_state_dtype: Any = jnp.float32      # ``ops/ssd.py``: a check's control
     rope: bool = True
     moe_expert_act: str = "swiglu"
     moe_latent: int = 0
+    moe_router_input: str = "ffn"
     # Latent attention's query latent and the stretch of the rotary
     # frequencies (see ``Attention``; ``rope_scaling`` as ``rope_frequencies``
     # takes it).
@@ -955,6 +1001,30 @@ class Transformer(nn.Module):
                 f"{self.attn_impl!r}: the residual streams run through "
                 "Block on the training path (no cache of n streams, no "
                 "MixerBlock, no indexer, no ring attention)")
+        if self.layer_attention is not None and (
+                self.decode or self.sparse or self.latent or self.hyper
+                or self.layer_mixer or self.mtp_layers
+                or self.attn_impl == "ring"
+                or len(self.layer_attention) != self.n_layers):
+            raise NotImplementedError(
+                f"layer_attention={self.layer_attention} over {self.n_layers} "
+                f"layers with decode={self.decode}, sparse={self.sparse}, "
+                f"latent={self.latent}, hyper={self.hyper}, layer_mixer="
+                f"{self.layer_mixer}, mtp_layers={self.mtp_layers}, attn_impl="
+                f"{self.attn_impl!r}: a (window, rope) a layer is plain or "
+                "grouped-query attention of a Block on the training path (no "
+                "cache in which window layers hold a window, no window "
+                "beside a latent, an indexer or ring attention's chunks)")
+        if self.moe_router_input not in ("ffn", "layer") or (
+                self.moe_router_input == "layer"
+                and (self.hyper or self.layer_mixer
+                     or self.moe_capacity_factor is not None)):
+            raise NotImplementedError(
+                f"moe_router_input={self.moe_router_input!r} with hyper="
+                f"{self.hyper}, layer_mixer={self.layer_mixer}, "
+                f"moe_capacity_factor={self.moe_capacity_factor}: the router "
+                "reads a Block's input ('layer') or what its experts read "
+                "('ffn'), one residual stream, dropless routing")
         _yarn_only(self.rope_scaling)
         if self.q_lora_rank and not self.latent:
             raise NotImplementedError(
@@ -983,7 +1053,11 @@ class Transformer(nn.Module):
         indexer's weights.  A recomputation would make it again, and the same
         (the selection draws nothing and breaks its ties by position); the
         block's policy saves it instead, with what else the sparse kernels
-        give (``_remat_policy``)."""
+        give (``_remat_policy``).  A layer's WINDOW (``layer_attention``) is
+        static in another way: it is a field of the layer's ``Block``, and a
+        rematerialised module's fields are part of the module, never traced,
+        so the band's tables are built from it at trace time with no
+        ``static_argnums`` of their own."""
         dh = self.d_head or self.d_model // self.n_heads
         dff = self.d_ff or 4 * self.d_model
         if block_diffusion and (self.hyper or self.mtp_layers):
@@ -1034,7 +1108,10 @@ class Transformer(nn.Module):
             block_cls = (nn.remat(Block, static_argnums=(3,),
                                   policy=self._remat_policy())
                          if self.remat else Block)
-            def block(dense, name):
+            layer_attention = self.layer_attention or (
+                (0, True),) * self.n_layers
+
+            def block(dense, name, attention=(0, True)):
                 return block_cls(
                     self.n_heads, dh, dense or dff,
                     0 if dense else self.n_experts, self.moe_top_k,
@@ -1045,10 +1122,12 @@ class Transformer(nn.Module):
                     self.qk_norm_per_head, self.moe_held, self.sparse,
                     self.latent, self.moe_router, self.moe_shared_d_ff,
                     self.q_lora_rank, self.rope_scaling, self.hyper,
-                    self.hyper_dtype, name=name)
+                    self.hyper_dtype, attention, self.moe_expert_act,
+                    self.moe_router_input, name=name)
 
             for i, dense in enumerate(layer_ffn):
-                x = block(dense, f"block_{i}")(x, positions, block_diffusion)
+                x = block(dense, f"block_{i}", layer_attention[i])(
+                    x, positions, block_diffusion)
         x = _from_streams(x, streams)
         head = nn.Dense(self.vocab_size, use_bias=False, name="lm_head",
                         dtype=self.compute_dtype)
@@ -1104,6 +1183,7 @@ def build_transformer(config: dict) -> Transformer:
     router = config.get("moe_router")
     layer_ffn = config.get("layer_ffn")
     layer_mixer = config.get("layer_mixer")
+    layer_attention = config.get("layer_attention")
     ssm = config.get("ssm")
     scaling = config.get("rope_scaling")
     hyper = config.get("hyper_connections")
@@ -1147,6 +1227,8 @@ def build_transformer(config: dict) -> Transformer:
         layer_ffn=None if layer_ffn is None else tuple(
             int(width) for width in layer_ffn),
         layer_mixer=None if layer_mixer is None else tuple(layer_mixer),
+        layer_attention=None if layer_attention is None else tuple(
+            (int(window), bool(rope)) for window, rope in layer_attention),
         ssm=None if ssm is None else (
             *(int(ssm[key]) for key in (
                 "n_heads", "head_dim", "n_groups", "state_size",
@@ -1156,6 +1238,7 @@ def build_transformer(config: dict) -> Transformer:
         rope=bool(config.get("rope", True)),
         moe_expert_act=str(config.get("moe_expert_act", "swiglu")),
         moe_latent=int(config.get("moe_latent", 0)),
+        moe_router_input=str(config.get("moe_router_input", "ffn")),
         q_lora_rank=int(config.get("q_lora_rank") or 0),
         rope_scaling=rope_scaling_from_config(scaling),
         hyper=None if hyper is None else (

@@ -37,7 +37,14 @@ dk and dv are summed over it inside the visit.
 Masks: ``causal``, or ``block_diffusion=(length, block)``, the training mask
 of block diffusion (BD3-LM, arXiv:2503.09573; SDAR, arXiv:2510.06303) over
 the concatenation of a noised and a clean copy of one row
-(``block_diffusion_visible``).
+(``block_diffusion_visible``).  ``window=W`` narrows ``causal`` to a band: a
+query sees itself and the ``W - 1`` keys before it (``0 <= i - j < W``,
+transformers' sliding window).  It is static like the other two, so the
+kernels' tables hold the BAND's tiles alone: a tile wholly older than the
+window is dead, as one wholly in the future is, and a query block's run of
+live tiles has masked tiles at both ends (the diagonal and the band's far
+edge) around interior ones.  A window that reaches over the whole row is no
+window: the tables, and the programs, are plain causal's.
 """
 
 from __future__ import annotations
@@ -101,10 +108,12 @@ def _repeat_kv(q, k, v):
 
 
 def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None,
-                  kv_offset: int = 0, block_diffusion=None):
+                  kv_offset: int = 0, block_diffusion=None,
+                  window: int | None = None):
     """Dense O(S²) attention.  ``kv_offset`` is the global position of
     ``k[:, 0]`` relative to ``q[:, 0]`` (ring attention passes non-zero
-    offsets so causal masks stay globally consistent across chunks)."""
+    offsets so causal masks stay globally consistent across chunks);
+    ``window`` hides the keys ``window`` or more places before their query."""
     *_, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     k, v = _repeat_kv(q, k, v)
@@ -114,6 +123,8 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None
     kpos = kv_offset + jnp.arange(sk)[None, :]
     if causal:
         logits = jnp.where(kpos <= qpos, logits, NEG_INF)
+    if window is not None:
+        logits = jnp.where(qpos - kpos < window, logits, NEG_INF)
     if block_diffusion:
         logits = jnp.where(block_diffusion_visible(qpos, kpos,
                                                    *block_diffusion),
@@ -127,22 +138,25 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None
 # ---------------------------------------------------------------------------
 
 def chunk_attention(q, k, v, *, causal: bool = True, sm_scale: float | None = None,
-                    kv_offset=0):
+                    kv_offset=0, window: int | None = None):
     """Attend q over one KV chunk; return ``(out, lse)``.
 
     ``out`` is the softmax-normalised output **for this chunk alone** and
     ``lse`` its log-sum-exp (``[B, Sq, H]``, float32).  Two chunk results
     combine exactly via ``merge_attention`` — the online-softmax identity
-    ring attention is built on.  ``kv_offset`` may be a traced scalar.
+    ring attention is built on.  ``kv_offset`` may be a traced scalar;
+    ``window`` as ``mha_reference`` takes it.
     """
     *_, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     sq, sk = q.shape[1], k.shape[1]
+    qpos = jnp.arange(sq)[:, None]
+    kpos = kv_offset + jnp.arange(sk)[None, :]
     if causal:
-        qpos = jnp.arange(sq)[:, None]
-        kpos = kv_offset + jnp.arange(sk)[None, :]
         logits = jnp.where(kpos <= qpos, logits, NEG_INF)
+    if window is not None:
+        logits = jnp.where(qpos - kpos < window, logits, NEG_INF)
     m = jnp.max(logits, axis=-1)                                   # [B,H,Sq]
     # Rows with every position masked (pure-future chunk): exp underflows to
     # 0 row-wise; guard the max so exp(NEG_INF - NEG_INF) doesn't become 1.
@@ -175,12 +189,14 @@ def merge_attention(o1, lse1, o2, lse2):
 def blockwise_attention(q, k, v, *, causal: bool = True,
                         sm_scale: float | None = None, block_k: int = 512,
                         kv_offset: int = 0, block_diffusion=None,
-                        k_shared=None):
+                        k_shared=None, window: int | None = None):
     """Flash-style attention as a ``lax.scan`` over KV blocks.
 
     Differentiable, runs on every backend, and with the per-block
     ``jax.checkpoint`` memory is O(Sq·block_k) — ``impl="xla"``: the path off
-    the TPU.  ``k_shared`` as ``flash_attention`` takes it.
+    the TPU.  ``k_shared`` and ``window`` as ``flash_attention`` takes them
+    (every KV block is scanned, a window's dead ones too: the specification,
+    not the kernels).
     """
     b, sq, h, d = q.shape
     sk, d_k = k.shape[1], k.shape[-1]
@@ -214,6 +230,8 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
         mask = kpos < kv_offset + sk  # padded tail
         if causal:
             mask = mask & (kpos <= qpos)
+        if window is not None:
+            mask = mask & (qpos - kpos < window)
         if block_diffusion:
             mask = mask & block_diffusion_visible(qpos, kpos,
                                                   *block_diffusion)
@@ -281,16 +299,21 @@ def _block_diffusion_tile(q_lo, q_hi, k_lo, k_hi, length: int, block: int):
 
 
 def _tile_live(q_start, k_start, *, causal: bool, kv_offset: int,
-               block_q: int, block_k: int, sk: int, block_diffusion=None):
+               block_q: int, block_k: int, sk: int, block_diffusion=None,
+               window=None):
     """Whether the tile of queries from ``q_start`` and keys from ``k_start``
     (a global position) holds any visible pair: not wholly padding and, under
-    ``causal``, not wholly in the future.  No kernel visits a dead tile
-    (``_walk``); a further mask adds its condition here, as
-    ``block_diffusion`` does.  Judged at trace time, on integers or numpy
-    arrays of them, as ``_tile_interior`` is."""
+    ``causal``, not wholly in the future, nor under ``window`` wholly older
+    than its first query's window (its last real key against that query).
+    No kernel visits a dead tile (``_walk``); a further mask adds its
+    condition here, as ``block_diffusion`` does.  Judged at trace time, on
+    integers or numpy arrays of them, as ``_tile_interior`` is."""
     live = k_start < kv_offset + sk
     if causal:
         live = live & (k_start <= q_start + block_q - 1)
+    if window is not None:
+        last_key = np.minimum(k_start + block_k, kv_offset + sk) - 1
+        live = live & (q_start - last_key < window)
     if block_diffusion:
         live = live & _block_diffusion_tile(
             q_start, q_start + block_q - 1, k_start,
@@ -300,13 +323,16 @@ def _tile_live(q_start, k_start, *, causal: bool, kv_offset: int,
 
 def _tile_interior(q_start, k_start, *, causal: bool, kv_offset: int,
                    block_q: int, block_k: int, sk: int,
-                   block_diffusion=None):
+                   block_diffusion=None, window=None):
     """Whether EVERY pair of the tile is visible (it holds no padding and,
-    under ``causal``, lies wholly in the past): the kernels build no mask
+    under ``causal``, lies wholly in the past, and under ``window`` its
+    first key is inside its last query's window): the kernels build no mask
     there.  A further mask narrows this as it narrows ``_tile_live``."""
     interior = k_start + block_k <= kv_offset + sk
     if causal:
         interior = interior & (k_start + block_k - 1 <= q_start)
+    if window is not None:
+        interior = interior & (q_start + block_q - 1 - k_start < window)
     if block_diffusion:
         interior = interior & _block_diffusion_tile(
             q_start, q_start + block_q - 1, k_start, k_start + block_k - 1,
@@ -315,16 +341,19 @@ def _tile_interior(q_start, k_start, *, causal: bool, kv_offset: int,
 
 
 def _tile_visible(shape, q_dim: int, *, q_start, k_start, causal: bool,
-                  kv_offset: int, sk: int, block_diffusion=None):
+                  kv_offset: int, sk: int, block_diffusion=None, window=None):
     """The visible pairs of one live tile, queries along ``q_dim`` of
     ``shape`` and keys along the other: the padded tail of the keys is never
-    visible, nor under ``causal`` a key after its query."""
+    visible, nor under ``causal`` a key after its query, nor under ``window``
+    one that many places or more before it."""
     kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
     mask = kpos < kv_offset + sk
-    if causal or block_diffusion:
+    if causal or block_diffusion or window is not None:
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
     if causal:
         mask = jnp.logical_and(mask, kpos <= qpos)
+    if window is not None:
+        mask = jnp.logical_and(mask, qpos - kpos < window)
     if block_diffusion:
         mask = jnp.logical_and(mask, block_diffusion_visible(
             qpos, kpos, *block_diffusion))
@@ -443,7 +472,7 @@ class _Plan(NamedTuple):
 
 
 def _plan(qt, kt, vt, kr=None, *, causal, kv_offset, block_q, block_k,
-          block_diffusion) -> _Plan:
+          block_diffusion, window=None) -> _Plan:
     """The plan of the kernels over head-major ``qt``, ``kt`` and ``vt``:
     numpy and integers only, worked out in each rule of the VJP (which counts
     by it).
@@ -460,13 +489,23 @@ def _plan(qt, kt, vt, kr=None, *, causal, kv_offset, block_q, block_k,
     float32 too where several grid rows share a K/V head and their shares
     are added outside).  With K and V of its own a head, fewer heads a visit
     hold less: the largest divisor of the forward's that fits.  Nothing fits
-    a 128k row: ``fused`` is 0 and the two passes run."""
+    a 128k row: ``fused`` is 0 and the two passes run.
+
+    Under a ``window`` the rule is the same one, over the WHOLE key length,
+    though a key's queries span only the window: the walk is q-major and an
+    output block is written where its index changes, so dk and dv of a key
+    tile could leave VMEM early only in a kernel whose outputs follow the
+    key tile, and that kernel is the dk/dv pass of the two.  One pass over
+    the band keeps the scores computed once a tile, which is worth more than
+    the memory while the row fits (a 16k row of 128 + 128 under a group of
+    7: 14 + 32 MiB); where it does not, the two passes walk the band."""
     bh, sq, d_p = qt.shape
     sk = kt.shape[1]
     group = bh // kt.shape[0]
     block_q, block_k, sq_p, sk_p = _blocks(sq, sk, block_q, block_k)
     tile = dict(causal=causal, kv_offset=kv_offset, block_q=block_q,
-                block_k=block_k, sk=sk, block_diffusion=block_diffusion)
+                block_k=block_k, sk=sk, block_diffusion=block_diffusion,
+                window=window)
     kinds = _tile_kinds(sq_p // block_q, sk_p // block_k, **tile)
     rows = lambda table: tuple(map(tuple, table.tolist()))      # noqa: E731
     size = qt.dtype.itemsize
@@ -523,6 +562,22 @@ def _count(plan: _Plan, latent: bool, heads: int, *tables) -> None:
         if latent:
             telemetry.counter("flash.latent_kernels").inc()
             telemetry.counter("flash.latent_visit_heads").inc(heads)
+    tile = dict(plan.tile)
+    if tile["window"] is None:
+        return
+    # the band's kernels again, on their own: ``flash.window.visits`` over
+    # ``flash.window.causal_visits`` is the share of plain causal's visits
+    # (same shapes, same tiles) that the band keeps, and
+    # ``flash.window.masked_tiles`` its visits that build a mask, on the
+    # diagonal and on the band's far edge
+    causal = int(np.count_nonzero(_tile_kinds(
+        plan.sq_p // tile["block_q"], plan.sk_p // tile["block_k"],
+        **{**tile, "window": None})))
+    for table in tables:
+        telemetry.counter("flash.window.visits").inc(len(table[0]))
+        telemetry.counter("flash.window.causal_visits").inc(causal)
+        telemetry.counter("flash.window.masked_tiles").inc(
+            sum(1 for flags in table[2] if flags & _MASKED))
 
 
 def _walk_call(kernel, table, rows: int, vmem_limit: int | None, *,
@@ -1103,19 +1158,26 @@ def _split_shared(q, k, k_shared, interpret: bool):
             _head_major(k_shared[:, :, None], interpret))
 
 
+def _kind(window) -> str:
+    """What a window's kernels add to their scopes' names (``flash_fwd_window``,
+    ``flash_bwd_window``): a reader of device time by scope tells the band's
+    kernels from the full mask's, which keep ``flash_fwd`` and ``flash_bwd``."""
+    return "" if window is None else "_window"
+
+
 def _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, kv_offset, block_q,
-                    block_k, interpret, block_diffusion):
+                    block_k, interpret, block_diffusion, window):
     # q, k, v stay head-major and lane-padded, as both backward kernels read
     # them (the backward lays out only the cotangent); the output stays as
     # the caller holds it anyway, and the kernel's log-sum-exp is kept.
     b = q.shape[0]
-    with jax.named_scope("flash_fwd"):
+    with jax.named_scope("flash_fwd" + _kind(window)):
         qt, kt, vt = (_head_major(x, interpret)
                       for x in (q[..., :k.shape[-1]], k, v))
         qr, kr = _split_shared(q, k, k_shared, interpret)
         plan = _plan(qt, kt, vt, kr, causal=causal, kv_offset=kv_offset,
                      block_q=block_q, block_k=block_k,
-                     block_diffusion=block_diffusion)
+                     block_diffusion=block_diffusion, window=window)
         _count(plan, kr is not None, plan.heads, plan.walk)
         ot, lse = _flash_fwd_pallas(qt, kt, vt, qr, kr, plan=plan,
                                     sm_scale=_scale(sm_scale, q.shape[-1]),
@@ -1128,24 +1190,27 @@ def _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, kv_offset, block_q,
         return out, (qt, kt, vt, qr, kr, out, lse, widths)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_attention_tpu(q, k, v, k_shared, causal, sm_scale, kv_offset,
-                         block_q, block_k, interpret, block_diffusion):
+                         block_q, block_k, interpret, block_diffusion,
+                         window):
     return _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, kv_offset,
-                           block_q, block_k, interpret, block_diffusion)[0]
+                           block_q, block_k, interpret, block_diffusion,
+                           window)[0]
 
 
 def _flash_bwd_rule(causal, sm_scale, kv_offset, block_q, block_k, interpret,
-                    block_diffusion, res, g):
+                    block_diffusion, window, res, g):
     qt, kt, vt, qr, kr, out, lse, widths = res
     b, sq, h, d_v = g.shape
     d_k, d_r = (x.shape[1] for x in widths)
-    with jax.named_scope("flash_bwd"):
+    with jax.named_scope("flash_bwd" + _kind(window)):
         delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1).transpose(0, 2, 1).reshape(b * h, sq)
         plan = _plan(qt, kt, vt, kr, causal=causal, kv_offset=kv_offset,
                      block_q=block_q, block_k=block_k,
-                     block_diffusion=block_diffusion)
+                     block_diffusion=block_diffusion, window=window)
         # the share of backward calls traced that took the one-pass kernel
         telemetry.counter("flash.bwd_calls").inc()
         if plan.fused:
@@ -1180,7 +1245,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: int = 512, block_k: int = 512,
                     impl: Impl | None = None,
                     block_diffusion: tuple[int, int] | None = None,
-                    k_shared=None):
+                    k_shared=None, window: int | None = None):
     """Attention, ``[B, S, H, D]`` queries in, ``[B, S, H, D_v]`` out; ``k``
     and ``v`` carry ``H`` heads or a divisor of it (grouped-query heads), and
     ``v`` may be of another width than ``q`` and ``k``.
@@ -1196,6 +1261,15 @@ def flash_attention(q, k, v, *, causal: bool = True,
     ``block_diffusion=(length, block)`` replaces ``causal`` by the training
     mask of block diffusion over ``2 * length`` positions
     (``block_diffusion_visible``).
+
+    ``window=W`` (static, with ``causal``): query ``i`` sees key ``j`` iff ``0
+    <= i - j < W``, itself and the ``W - 1`` before it.  The kernels visit
+    the band's tiles and no other, under the scopes ``flash_fwd_window`` and
+    ``flash_bwd_window``; a window that reaches over the whole row is dropped
+    here, and the call is plain causal's, table for table.  Not built, and
+    refused by name: a window beside ``block_diffusion`` (another mask in
+    causal's place) or beside ``k_shared`` (latent attention), and one
+    without ``causal``.  Ring attention (``parallel/sp.py``) takes none.
 
     ``impl=None`` auto-selects: Pallas kernel on TPU, blockwise XLA scan
     elsewhere.  ``pallas_interpret`` runs the kernel in interpreter mode (CPU
@@ -1220,19 +1294,29 @@ def flash_attention(q, k, v, *, causal: bool = True,
                 f"clean copy of {length} tokens in whole blocks, queries and "
                 f"keys alike, in place of causal: got {q.shape[1]} queries, "
                 f"{k.shape[1]} keys, causal={causal}, kv_offset={kv_offset}")
+    if window is not None:
+        window = int(window)
+        if window < 1 or not causal or block_diffusion or k_shared is not None:
+            raise NotImplementedError(
+                f"window={window} narrows the causal mask of plain or "
+                f"grouped-query attention: got causal={causal}, "
+                f"block_diffusion={block_diffusion}, k_shared="
+                f"{getattr(k_shared, 'shape', None)}")
+        if window > q.shape[1] - 1 - kv_offset:     # no query looks that far
+            window = None
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "xla":
         return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                                    block_k=block_k, kv_offset=kv_offset,
                                    block_diffusion=block_diffusion,
-                                   k_shared=k_shared)
+                                   k_shared=k_shared, window=window)
     if impl in ("pallas", "pallas_interpret"):
         def kernel(q, k, v, k_shared=None):
             return _flash_attention_tpu(q, k, v, k_shared, causal, sm_scale,
                                         kv_offset, block_q, block_k,
                                         impl == "pallas_interpret",
-                                        block_diffusion)
+                                        block_diffusion, window)
 
         shared = () if k_shared is None else (k_shared,)
 

@@ -361,7 +361,8 @@ def _pick(probs, top_idx):
 
 
 class MoEMLP(nn.Module):
-    """Top-k routed SwiGLU MoE FFN, ``[B, S, D] -> [B, S, D]``.
+    """Top-k routed gated (SwiGLU, ReGLU) or relu² MoE FFN, ``[B, S, D] ->
+    [B, S, D]``.
 
     Param layout (matched by ``tp.TRANSFORMER_TP_RULES``): ``router/kernel``
     replicated; ``experts_gate``/``experts_up`` ``[E, D, F]`` and
@@ -412,7 +413,18 @@ class MoEMLP(nn.Module):
     ``moe/latent``; the ROUTER still reads ``x``.  ``expert_act="relu2"``
     gives an expert two matrices and no gate, ``relu(ℓ·U_e)²·V_e``
     (``experts_up``, ``experts_down``; ``"swiglu"``: the three of a SwiGLU).
-    Both under dropless routing only.
+    ``expert_act="reglu"`` is the SwiGLU's three matrices with ``relu`` on the
+    gate, ``(relu(x·G_e) ⊙ (x·U_e))·D_e`` (SmallThinker's "sparse ReGLU",
+    arXiv:2507.20984).  All under dropless routing only.
+
+    ``__call__(x, router_input=None)``: the router may read ANOTHER array of
+    the tokens than the experts do (SmallThinker takes the logits from the
+    layer's input, before attention, and feeds the experts the normed state
+    after it): logits, scores, choice, auxiliary terms and statistics are
+    ``router_input``'s, the rows that are dispatched are ``x``'s, and each
+    gets its own cotangent.  The scope stays ``moe/router``; XLA is free to
+    run that product as early as its operand is there.  None: ``x`` itself,
+    the program as it was.
     """
 
     d_model: int
@@ -430,7 +442,7 @@ class MoEMLP(nn.Module):
     latent: int = 0
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_input=None):
         b, s, d = x.shape
         n = b * s
         e = self.n_experts
@@ -443,19 +455,26 @@ class MoEMLP(nn.Module):
                 "layer's experts under dropless routing")
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring={self.scoring!r}")
-        gated = self.expert_act == "swiglu"
-        if self.expert_act not in ("swiglu", "relu2") or (
-                not dropless and (self.latent or not gated)):
+        gated = self.expert_act in ("swiglu", "reglu")
+        if self.expert_act not in ("swiglu", "reglu", "relu2") or (
+                not dropless and (self.latent or self.expert_act != "swiglu"
+                                  or router_input is not None)):
             raise ValueError(
                 f"expert_act={self.expert_act!r}, latent={self.latent}, "
                 f"capacity_factor={self.capacity_factor}: relu2 experts and "
-                "a latent are the dropless path's")
+                "a latent, reglu experts and a router input of its own are "
+                "the dropless path's")
+        if router_input is not None and router_input.shape != x.shape:
+            raise ValueError(f"router_input {router_input.shape} beside "
+                             f"tokens {x.shape}")
         xf = x.reshape(n, d)
+        router_rows = (xf if router_input is None
+                       else router_input.reshape(n, d))
 
         with jax.named_scope("moe/router"):
             router = nn.Dense(e, use_bias=False, name="router",
                               dtype=jnp.float32)  # routing always f32
-            router_logits = router(xf.astype(jnp.float32))
+            router_logits = router(router_rows.astype(jnp.float32))
             probs = (jax.nn.sigmoid(router_logits)
                      if self.scoring == "sigmoid"
                      else jax.nn.softmax(router_logits, axis=-1))
@@ -551,14 +570,16 @@ class MoEMLP(nn.Module):
                                       if a not in mesh.manual_axes]
         tp = "tp" if "tp" in auto else None
 
-        def swiglu(rows, w_gate, w_up, w_down, sizes):
+        gate_act = jax.nn.relu if self.expert_act == "reglu" else jax.nn.silu
+
+        def glu(rows, w_gate, w_up, w_down, sizes):
             if self.held is None:
-                h = (jax.nn.silu(grouped_matmul(rows, w_gate, sizes))
+                h = (gate_act(grouped_matmul(rows, w_gate, sizes))
                      * grouped_matmul(rows, w_up, sizes))
             else:   # gate and up as ONE product: one cotangent for the rows
                 gate_up = grouped_matmul(
                     rows, jnp.concatenate([w_gate, w_up], axis=-1), sizes)
-                h = (jax.nn.silu(gate_up[:, :w_gate.shape[-1]])
+                h = (gate_act(gate_up[:, :w_gate.shape[-1]])
                      * gate_up[:, w_gate.shape[-1]:])
             out = grouped_matmul(h, w_down, sizes)
             return jax.lax.psum(out, tp) if tp else out
@@ -568,7 +589,7 @@ class MoEMLP(nn.Module):
             out = grouped_matmul(h, w_down, sizes)
             return jax.lax.psum(out, tp) if tp else out
 
-        ffn = swiglu if self.expert_act == "swiglu" else relu2
+        ffn = relu2 if self.expert_act == "relu2" else glu
         if auto:
             # GSPMD cannot partition a Mosaic kernel (see
             # ``flash_attention``): every rank runs the kernels on all the

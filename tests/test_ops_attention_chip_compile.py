@@ -68,6 +68,17 @@ def _two_passes(monkeypatch):
     # a group too large for one visit: four visits of 8 heads a tile, whose
     # float32 shares of dk and dv are resident and added outside
     pytest.param((2048, 32, 1, 128, dict(causal=True), 1), id="32-over-1"),
+    # SmallThinker's 16k row (ISSUE 48): 28 query heads over 4 K/V heads, a
+    # group of 7 in one visit (no power of two: blocks of 7 x 512 x 128), the
+    # band of a 4,096 window (252 visits a K/V head, masked tiles at both
+    # ends of a run) with dk and dv of the whole row resident (32 MiB), the
+    # band through the two passes, and its global layers' causal mask
+    pytest.param((16384, 28, 4, 128, dict(causal=True, window=4096), 1),
+                 id="window-4096-group-7-16k-row"),
+    pytest.param((16384, 28, 4, 128, dict(causal=True, window=4096), 2),
+                 id="window-4096-group-7-16k-row-two-passes"),
+    pytest.param((16384, 28, 4, 128, dict(causal=True), 1),
+                 id="group-7-16k-row"),
 ])
 def test_the_flash_kernels_lower_at_sdar_widths(one_chip, case, monkeypatch):
     import jax
@@ -105,6 +116,9 @@ def test_the_flash_kernels_lower_at_sdar_widths(one_chip, case, monkeypatch):
     assert len(kernels) == 1 + backward
     assert sum("flash_bwd" in line for line in kernels) == backward
     assert sum("flash_fwd" in line for line in kernels) == 1
+    # a window's kernels carry scopes of their own, the full mask's do not
+    assert sum("flash_fwd_window" in line or "flash_bwd_window" in line
+               for line in kernels) == ("window" in mask) * (1 + backward)
 
 
 @pytest.mark.parametrize("backward", [
