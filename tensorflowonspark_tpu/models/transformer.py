@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from tensorflowonspark_tpu import telemetry
 from tensorflowonspark_tpu.models.registry import register
 from tensorflowonspark_tpu.ops.attention import flash_attention
 from tensorflowonspark_tpu.parallel.tp import constrain
@@ -875,6 +876,22 @@ class Block(nn.Module):
         return constrain(hc_write(x, y, h_post, h_res), P(BATCH, "sp", None))
 
 
+def _run(remat: bool, block, *args):
+    """``block(*args)``, counted for the run report where it is
+    rematerialised (trace time, as ``flash.*`` is): ``remat.blocks``, and of
+    them ``remat.flash_kept``, the blocks whose trace ran the flash forward
+    rule, which names what ``Transformer._remat_policy`` keeps.  No method
+    of the model: flax would put its name into every scope below."""
+    if not remat:
+        return block(*args)
+    named = telemetry.counter("flash.fwd_calls")
+    before = named.value()
+    out = block(*args)
+    telemetry.counter("remat.blocks").inc()
+    telemetry.counter("remat.flash_kept").inc(int(named.value() > before))
+    return out
+
+
 class Transformer(nn.Module):
     """Decoder-only LM.  ``__call__(input_ids: [B, S]) -> logits [B, S, V]``.
 
@@ -906,7 +923,8 @@ class Transformer(nn.Module):
     # Rematerialize each block's activations in the backward pass
     # (jax.checkpoint): activation memory drops from O(n_layers) residuals
     # to O(1) per block at ~1/3 extra FLOPs — the standard long-context /
-    # large-batch trade on HBM-bound TPUs.
+    # large-batch trade on HBM-bound TPUs.  Kept beside a block's input:
+    # what its attention kernels name (``_remat_policy``).
     remat: bool = False
     # What a published config states beyond the sizes; the defaults are the
     # model this class built before it had the keys.
@@ -1053,11 +1071,17 @@ class Transformer(nn.Module):
         indexer's weights.  A recomputation would make it again, and the same
         (the selection draws nothing and breaks its ties by position); the
         block's policy saves it instead, with what else the sparse kernels
-        give (``_remat_policy``).  A layer's WINDOW (``layer_attention``) is
-        static in another way: it is a field of the layer's ``Block``, and a
-        rematerialised module's fields are part of the module, never traced,
-        so the band's tables are built from it at trace time with no
-        ``static_argnums`` of their own."""
+        give (``_remat_policy``).  What a recomputation makes again is the
+        rest of the block: the norms, ``q/k/v_proj``, RoPE, the head-major
+        layouts, ``o_proj``, the router and the experts.  What an attention
+        KERNEL alone can make it does not: the policy keeps the flash
+        forward's output and log-sum-exp too (``S * H * D_v * 2 B + S * H * 4
+        B`` a layer beside the block's input), so the second forward of a
+        layer runs no kernel of either family.  A layer's WINDOW
+        (``layer_attention``) is static in another way: it is a field of the
+        layer's ``Block``, and a rematerialised module's fields are part of
+        the module, never traced, so the band's tables are built from it at
+        trace time with no ``static_argnums`` of their own."""
         dh = self.d_head or self.d_model // self.n_heads
         dff = self.d_ff or 4 * self.d_model
         if block_diffusion and (self.hyper or self.mtp_layers):
@@ -1085,7 +1109,7 @@ class Transformer(nn.Module):
                                   policy=self._remat_policy())
                          if self.remat else MixerBlock)
             for i, kind in enumerate(self.layer_mixer):
-                x = block_cls(
+                x = _run(self.remat, block_cls(
                     kind, self.n_heads, dh, dff, n_kv_heads=self.n_kv_heads,
                     rope=self.rope, rope_theta=self.rope_theta,
                     attn_impl=self.attn_impl, mesh=self.mesh,
@@ -1097,8 +1121,8 @@ class Transformer(nn.Module):
                     moe_held=self.moe_held, moe_router=self.moe_router,
                     moe_shared_d_ff=self.moe_shared_d_ff,
                     moe_expert_act=self.moe_expert_act,
-                    moe_latent=self.moe_latent, name=f"block_{i}")(
-                        x, positions, block_diffusion)
+                    moe_latent=self.moe_latent, name=f"block_{i}"),
+                    x, positions, block_diffusion)
         else:
             layer_ffn = self.layer_ffn or (0,) * self.n_layers
             if len(layer_ffn) != self.n_layers:
@@ -1126,8 +1150,9 @@ class Transformer(nn.Module):
                     self.moe_router_input, name=name)
 
             for i, dense in enumerate(layer_ffn):
-                x = block(dense, f"block_{i}", layer_attention[i])(
-                    x, positions, block_diffusion)
+                x = _run(self.remat,
+                         block(dense, f"block_{i}", layer_attention[i]),
+                         x, positions, block_diffusion)
         x = _from_streams(x, streams)
         head = nn.Dense(self.vocab_size, use_bias=False, name="lm_head",
                         dtype=self.compute_dtype)
@@ -1156,22 +1181,25 @@ class Transformer(nn.Module):
             y = nn.Dense(self.d_model, use_bias=False, name="mtp_eh_proj",
                          dtype=self.compute_dtype)(both)
             y = _to_streams(constrain(y, P(BATCH, "sp", None)), streams)
-            y = block(layer_ffn[-1], "mtp_block")(y, positions, None)
+            y = _run(self.remat, block(layer_ffn[-1], "mtp_block"), y,
+                     positions, None)
             return main, out(RMSNorm(self.norm_eps, name="mtp_norm")(
                 _from_streams(y, streams)))
 
     def _remat_policy(self):
-        """What a rematerialised block keeps besides its input: nothing, or,
-        under ``sparse``, what ``ops/sparse_attention.py`` names (the
-        selection, attention's output and the indexer's loss with its
-        gradient: 0.4 GB a layer at 16k), so that the second forward runs
-        the projections, norms and experts again and none of the sparse
-        kernels."""
-        if not self.sparse:
-            return None
-        from tensorflowonspark_tpu.ops.sparse_attention import SAVED_NAMES
+        """What a rematerialised block keeps besides its input: what its
+        attention KERNELS name, of either family.  A sparse layer emits
+        ``ops/sparse_attention.py``'s names (the selection, attention's
+        output and the indexer's loss with its gradient: 0.4 GB a layer at
+        16k), a flash layer ``ops/attention.py``'s (the output and its
+        log-sum-exp: ``S * H * D_v * 2 B + S * H * 4 B``, 0.12 GB a layer at
+        16k rows of 28 heads of 128), the XLA scan neither, and then nothing
+        is kept.  The second forward runs the projections, norms and experts
+        again and none of those kernels."""
+        from tensorflowonspark_tpu.ops import attention, sparse_attention
 
-        return jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
+        return jax.checkpoint_policies.save_only_these_names(
+            *sparse_attention.SAVED_NAMES, *attention.SAVED_NAMES)
 
 
 @register("transformer")

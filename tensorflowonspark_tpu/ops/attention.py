@@ -56,6 +56,7 @@ from typing import Literal, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -1165,12 +1166,26 @@ def _kind(window) -> str:
     return "" if window is None else "_window"
 
 
+# What the forward KERNEL alone can make of a layer's residuals, by
+# ``checkpoint_name``: attention's output ``[B, S, H, D_v]`` and its
+# log-sum-exp ``[B * H, S]`` float32 (``S * H * D_v * 2 B + S * H * 4 B`` a
+# row in bf16).  A caller that recomputes the layer (``jax.checkpoint``) and
+# saves these names runs the backward kernels on them and the forward kernel
+# once; the head-major layouts of q, k and v it makes again from the
+# projections.  Outside a checkpoint a name is an identity and lowers to
+# nothing.
+SAVED_NAMES = ("flash_out", "flash_lse")
+
+
 def _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, kv_offset, block_q,
                     block_k, interpret, block_diffusion, window):
     # q, k, v stay head-major and lane-padded, as both backward kernels read
     # them (the backward lays out only the cotangent); the output stays as
     # the caller holds it anyway, and the kernel's log-sum-exp is kept.
     b = q.shape[0]
+    # forward rules traced: each names SAVED_NAMES (Transformer reads it to
+    # tell the rematerialised blocks whose policy keeps them)
+    telemetry.counter("flash.fwd_calls").inc()
     with jax.named_scope("flash_fwd" + _kind(window)):
         qt, kt, vt = (_head_major(x, interpret)
                       for x in (q[..., :k.shape[-1]], k, v))
@@ -1182,7 +1197,9 @@ def _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, kv_offset, block_q,
         ot, lse = _flash_fwd_pallas(qt, kt, vt, qr, kr, plan=plan,
                                     sm_scale=_scale(sm_scale, q.shape[-1]),
                                     interpret=interpret)
-        out = _from_head_major(ot, b, v.shape[-1])
+        out = checkpoint_name(_from_head_major(ot, b, v.shape[-1]),
+                              "flash_out")
+        lse = checkpoint_name(lse, "flash_lse")
         # the widths of the keys and of the shared key, as empty arrays: the
         # backward reads them off shapes (the operands are lane-padded)
         widths = (jnp.zeros((0, k.shape[-1])),

@@ -93,10 +93,11 @@ def test_latent_step_names_its_projections_the_shared_expert_and_router():
 def test_window_layers_kernels_carry_scopes_of_their_own():
     """ISSUE 48: a global layer's kernels under ``flash_fwd`` / ``flash_bwd``
     and a window layer's under ``flash_fwd_window`` / ``flash_bwd_window``,
-    layer by layer and under ``remat`` too (the recomputed forward keeps the
-    scope; ``swa_flash_*`` and ``flash_bwd_ms`` / ``bd_flash_fwd_ms`` tell
-    band from full by these), beside the expert layer's ``moe/router`` (which
-    reads the layer's input here), ``moe/dispatch`` and ``moe/experts``."""
+    layer by layer and under ``remat`` too (the recomputed block keeps the
+    scope and runs no forward kernel under it, ISSUE 49; ``swa_flash_*`` and
+    ``flash_bwd_ms`` / ``bd_flash_fwd_ms`` tell band from full by these),
+    beside the expert layer's ``moe/router`` (which reads the layer's input
+    here), ``moe/dispatch`` and ``moe/experts``."""
     text = _lm_step_text(
         n_layers=2, n_heads=14, n_kv_heads=2, d_head=4, n_experts=4,
         moe_top_k=2, moe_capacity_factor=None, moe_held=[0, 2],
@@ -111,10 +112,16 @@ def test_window_layers_kernels_carry_scopes_of_their_own():
     assert re.search(r'/block_1/[^\s"]*/flash_fwd_window/', text)
     assert not re.search(r'/block_0/[^\s"]*/flash_(fwd|bwd)_window/', text)
     assert not re.search(r'/block_1/[^\s"]*/flash_(fwd|bwd)/', text)
-    # the forward runs again in the backward pass, under the same scope
-    again = [line for line in text.split("jit(step)")
-             if "/flash_fwd_window/" in line and "transpose(" in line]
-    assert again
+    # the block runs again in the backward pass and keeps the scope for the
+    # layouts of q, k and v it makes again; the KERNEL is not there a second
+    # time (the policy kept its output and log-sum-exp), under either scope
+    for scope in ("flash_fwd", "flash_fwd_window"):
+        again = [line for line in text.split("jit(step)")
+                 if f"/{scope}/" in line and "transpose(" in line]
+        assert again, scope
+        assert not [line for line in again if "_flash_fwd_pallas" in line]
+        assert [line for line in text.split("jit(step)")
+                if f"/{scope}/" in line and "_flash_fwd_pallas" in line]
 
 
 def test_hyper_connected_step_names_maps_mixing_and_the_mtp_module():

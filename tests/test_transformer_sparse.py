@@ -1,6 +1,8 @@
 """Learned sparse attention in ``models/transformer.py``: the two losses'
 gradients stay apart, with and without ``remat``; the selection decides what
-attention sees; ``remat`` with a static and with a data-dependent mask."""
+attention sees; ``remat`` with a static and with a data-dependent mask, and
+what its policy keeps of either family of kernels (the sparse and, ISSUE 49,
+the flash trio)."""
 
 import jax
 import jax.numpy as jnp
@@ -136,3 +138,78 @@ def test_the_second_forward_of_a_remat_block_runs_no_sparse_kernel():
     program = str(jax.make_jaxpr(jax.grad(lambda p: loss(p, batch)[0]))(
         params))
     assert program.count("pallas_call[") == 5 * CONFIG["n_layers"]
+
+
+FLASH = {**CONFIG, "sparse_attention": None, "attn_impl": "pallas_interpret"}
+LATENT = {"kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 4,
+          "v_head_dim": 8}
+
+
+def _kernels(jaxpr) -> int:
+    """The ``pallas_call``s of a jaxpr, those of the jaxprs its equations
+    hold included (two layers of one shape share ONE printed jaxpr)."""
+    return sum((eqn.primitive.name == "pallas_call")
+               + sum(map(_kernels, jax.core.jaxprs_in_params(eqn.params)))
+               for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("overrides,block_diffusion", [
+    ({"layer_attention": [[0, False], [0, False]]}, False),
+    ({"layer_attention": [[8, True], [8, True]]}, False),
+    ({"latent_attention": LATENT, "qk_norm": False, "n_kv_heads": 0}, False),
+    ({}, True),
+], ids=["global", "window", "latent_shared_key", "block_diffusion"])
+def test_the_second_forward_of_a_remat_block_runs_no_flash_kernel(
+        overrides, block_diffusion):
+    """The sparse test's sibling for the flash trio: two kernels a layer in
+    a step's program (the forward, the one-pass backward), with ``remat`` as
+    without: the policy keeps what ``ops/attention.py`` names (the forward
+    kernel's output and log-sum-exp) and the block recomputes the rest.
+    Without the names the forward kernel would be there twice a layer.  Loss
+    and every gradient leaf are the plain model's."""
+    if block_diffusion:
+        make = lambda model: tfm.make_block_diffusion_loss_fn(  # noqa: E731
+            model, block=4, mask_id=63, aux_loss_coef=0.01, vocab_chunk=32)
+        batch = {"input_ids": IDS, "noise_seed": jnp.asarray([3, 4],
+                                                             jnp.uint32)}
+    else:
+        make = lambda model: tfm.make_loss_fn(  # noqa: E731
+            model, aux_loss_coef=0.01, vocab_chunk=32)
+        batch = {"input_ids": IDS}
+    plain = tfm.build_transformer({**FLASH, **overrides})
+    remat = tfm.build_transformer({**FLASH, **overrides, "remat": True})
+    params = plain.init(jax.random.PRNGKey(0), IDS)["params"]
+    results = []
+    for model in (plain, remat):
+        step = jax.value_and_grad(lambda p: make(model)(p, batch)[0])
+        assert _kernels(jax.make_jaxpr(step)(params).jaxpr) == 2 * FLASH[
+            "n_layers"]
+        results.append(jax.jit(step)(params))
+    for a, b in zip(*map(jax.tree.leaves, results)):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("overrides,blocks,kept", [
+    ({"sparse_attention": None, "attn_impl": "pallas_interpret"}, 2, 2),
+    ({"sparse_attention": None, "attn_impl": "pallas_interpret",
+      "num_nextn_predict_layers": 1}, 3, 3),
+    ({"attn_impl": "pallas_interpret"}, 2, 0),
+    ({"sparse_attention": None}, 2, 0),
+    ({"sparse_attention": None, "attn_impl": "pallas_interpret",
+      "remat": False}, 0, 0),
+], ids=["flash", "flash_and_mtp", "sparse", "xla_scan", "no_remat"])
+def test_the_run_report_counts_the_remat_blocks_that_keep_a_flash_output(
+        overrides, blocks, kept):
+    """``remat.blocks`` and ``remat.flash_kept``, counted as a model is
+    traced: the policy's effect follows the names a block's attention emits
+    (the MTP module's block is one more; a sparse layer and the XLA scan
+    emit no flash name; without ``remat`` no block is counted)."""
+    from tensorflowonspark_tpu import telemetry
+
+    model = tfm.build_transformer({**CONFIG, "remat": True, **overrides})
+    before = telemetry.snapshot()["counters"]
+    jax.eval_shape(model.init, jax.random.PRNGKey(0), IDS)
+    after = telemetry.snapshot()["counters"]
+    counted = [after.get(name, 0) - before.get(name, 0)
+               for name in ("remat.blocks", "remat.flash_kept")]
+    assert counted == [blocks, kept]
