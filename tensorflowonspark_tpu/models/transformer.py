@@ -637,7 +637,9 @@ class MixerBlock(nn.Module):
                                    name="shared")(u)
         else:
             raise ValueError(f"layer kind {self.kind!r}: M, * or E")
-        return constrain(x + y, P(BATCH, "sp", None))
+        with jax.named_scope("residual"):
+            x = x + y
+        return _constrained(x)
 
 
 class HyperConnection(nn.Module):
@@ -714,6 +716,13 @@ class HyperConnection(nn.Module):
                 jnp.sum(h_res.astype(f32), axis=0) - 1.0)))
             self.sow("hc_stats", "pre_mean", jnp.mean(h_pre.astype(f32)))
         return h_pre, h_post, h_res
+
+
+def _constrained(x):
+    """The residual carry ``[B, S, ·]`` under its sharding constraint, named
+    as the carry's own work (``residual``: the layers' adds and this)."""
+    with jax.named_scope("residual"):
+        return constrain(x, P(BATCH, "sp", None))
 
 
 def _streams(x, n: int):
@@ -810,19 +819,22 @@ class Block(nn.Module):
         if self.hyper:
             return self._hyper_connected(x, attn, positions, block_diffusion)
         layer_input = x
-        x = x + attn(norm("attn_norm")(x), positions, block_diffusion)
-        x = constrain(x, P(BATCH, "sp", None))
+        y = attn(norm("attn_norm")(x), positions, block_diffusion)
+        with jax.named_scope("residual"):
+            x = x + y
+        x = _constrained(x)
         ffn, shared = self._ffn(x.shape[-1])
         y = norm("mlp_norm")(x)
-        if self.n_experts and self.moe_router_input == "layer":
-            # the router reads the residual stream as the layer found it
-            x = x + ffn(y, router_input=layer_input)
-        else:
-            x = x + ffn(y)
+        # the router may read the residual stream as the layer found it
+        routed = (ffn(y, router_input=layer_input)
+                  if self.n_experts and self.moe_router_input == "layer"
+                  else ffn(y))
+        with jax.named_scope("residual"):
+            x = x + routed
         if shared is not None:
             with jax.named_scope("moe/shared"):
                 x = x + shared(y)
-        return constrain(x, P(BATCH, "sp", None))
+        return _constrained(x)
 
     def _ffn(self, d_model: int):
         """``(the layer's FFN, the shared experts or None)``: the experts of
@@ -865,7 +877,7 @@ class Block(nn.Module):
         h_pre, h_post, h_res = maps("hc_attn")(x)
         y = attn(norm("attn_norm")(hc_read(x, h_pre)), positions,
                  block_diffusion)
-        x = constrain(hc_write(x, y, h_post, h_res), P(BATCH, "sp", None))
+        x = _constrained(hc_write(x, y, h_post, h_res))
         h_pre, h_post, h_res = maps("hc_mlp")(x)
         u = norm("mlp_norm")(hc_read(x, h_pre))
         ffn, shared = self._ffn(u.shape[-1])
@@ -873,7 +885,7 @@ class Block(nn.Module):
         if shared is not None:
             with jax.named_scope("moe/shared"):
                 y = y + shared(u)
-        return constrain(hc_write(x, y, h_post, h_res), P(BATCH, "sp", None))
+        return _constrained(hc_write(x, y, h_post, h_res))
 
 
 def _run(remat: bool, block, *args):
@@ -1092,8 +1104,7 @@ class Transformer(nn.Module):
         streams = int(self.hyper[0]) if self.hyper else 1
         emb = nn.Embed(self.vocab_size, self.d_model, name="embed",
                        dtype=self.compute_dtype)
-        x = emb(input_ids)
-        x = _to_streams(constrain(x, P(BATCH, "sp", None)), streams)
+        x = _to_streams(_constrained(emb(input_ids)), streams)
         if self.layer_mixer:
             if len(self.layer_mixer) != self.n_layers or (
                     self.decode or self.sparse or self.latent
@@ -1160,7 +1171,7 @@ class Transformer(nn.Module):
         def out(hidden):
             if self.return_hidden:
                 return hidden
-            with jax.named_scope("lm_head_loss"):   # the loss half: make_loss_fn
+            with jax.named_scope("lm_head_loss"):
                 logits = head(hidden)
                 return constrain(logits.astype(jnp.float32),
                                  P(BATCH, "sp", None))
@@ -1180,7 +1191,7 @@ class Transformer(nn.Module):
                  RMSNorm(self.norm_eps, name="mtp_enorm")(nxt)], axis=-1)
             y = nn.Dense(self.d_model, use_bias=False, name="mtp_eh_proj",
                          dtype=self.compute_dtype)(both)
-            y = _to_streams(constrain(y, P(BATCH, "sp", None)), streams)
+            y = _to_streams(_constrained(y), streams)
             y = _run(self.remat, block(layer_ffn[-1], "mtp_block"), y,
                      positions, None)
             return main, out(RMSNorm(self.norm_eps, name="mtp_norm")(
@@ -1441,11 +1452,13 @@ def _sown_collections(model: Transformer) -> list:
             + (["hc_stats"] if model.hyper else []))
 
 
+@jax.named_scope("loss_terms")
 def _with_sown_terms(loss, updates, aux_loss_coef: float,
                      router_z_coef: float):
     """``(total, metrics)``: the LM loss plus the weighted auxiliary terms
     the layers sowed (``aux_loss``), and their ``moe_stats`` averaged over
-    layers (see ``make_loss_fn``)."""
+    layers (see ``make_loss_fn``).  Under the scope ``loss_terms``, which the
+    caller keeps a whole component (``_loss_scope``)."""
     aux = jnp.asarray(0.0)
     z = jnp.asarray(0.0)
     for path, leaf in jax.tree_util.tree_flatten_with_path(
@@ -1470,14 +1483,16 @@ def _with_sown_terms(loss, updates, aux_loss_coef: float,
 
 
 @contextlib.contextmanager
-def _mtp_head_scope():
-    """The scopes of the MTP module's pass of the head and the loss: ``mtp``
-    and ``lm_head_loss`` as whole components.  A transform names itself
-    around the OUTERMOST scope it meets in a differentiated function
-    (``jvp(mtp_loss)/mtp/lm_head_loss/...``), and a reader of device time
-    looks for whole components: so there is a scope around ``mtp``."""
-    with jax.named_scope("mtp_loss"), jax.named_scope("mtp"), \
-            jax.named_scope("lm_head_loss"):
+def _loss_scope(outer: str, *scopes: str):
+    """``scopes`` as WHOLE components of the op names inside a
+    differentiated function, forward and backward.  A transform names itself
+    around the OUTERMOST scope it meets (``jvp(lm_loss)/lm_head_loss/...``,
+    ``transpose(jvp(mtp_loss))/mtp/lm_head_loss/...``), and a reader of
+    device time looks for whole components: so every scope a loss opens has
+    ``outer`` around it, which no reader reads."""
+    with contextlib.ExitStack() as stack:
+        for name in (outer, *scopes):
+            stack.enter_context(jax.named_scope(name))
         yield
 
 
@@ -1532,7 +1547,9 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
 
     def _with_mtp(total, metrics, loss):
         """The second loss: the mean over targets 2 .. S - 1."""
-        return total + mtp_coef * loss, {**metrics, "mtp_loss": loss}
+        with _loss_scope("mtp_loss", "loss_terms"):
+            total = total + mtp_coef * loss
+        return total, {**metrics, "mtp_loss": loss}
 
     if vocab_chunk:
         from tensorflowonspark_tpu.ops.xent import blockwise_cross_entropy
@@ -1559,13 +1576,14 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
                                             batch["input_ids"], mutable=sown)
             if mtp:
                 h, h_mtp = h
-            with jax.named_scope("lm_head_loss"):
+            with _loss_scope("lm_loss", "lm_head_loss"):
                 loss = _fused_mean(params, h, batch, 1)
-            out = _with_sown_terms(loss, updates, aux_loss_coef,
-                                   router_z_coef)
+            with _loss_scope("lm_loss"):
+                out = _with_sown_terms(loss, updates, aux_loss_coef,
+                                       router_z_coef)
             if not mtp:
                 return out
-            with _mtp_head_scope():
+            with _loss_scope("mtp_loss", "mtp", "lm_head_loss"):
                 loss = _fused_mean(params, h_mtp, batch, 2)
             return _with_mtp(*out, loss)
 
@@ -1577,19 +1595,22 @@ def make_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
                                       mutable=sown)
         if mtp:
             logits, logits_mtp = logits
-        with jax.named_scope("lm_head_loss"):
+        with _loss_scope("lm_loss", "lm_head_loss"):
             logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
             targets = ids[:, 1:]
             nll = -jnp.take_along_axis(logp, targets[..., None],
                                        axis=-1)[..., 0]
-        out = _with_sown_terms(_mean(nll, batch, 1), updates, aux_loss_coef,
-                               router_z_coef)
+            loss = _mean(nll, batch, 1)
+        with _loss_scope("lm_loss"):
+            out = _with_sown_terms(loss, updates, aux_loss_coef,
+                                   router_z_coef)
         if not mtp:
             return out
-        with _mtp_head_scope():
+        with _loss_scope("mtp_loss", "mtp", "lm_head_loss"):
             logp = jax.nn.log_softmax(logits_mtp[:, :-2].astype(jnp.float32))
             nll = -jnp.take_along_axis(logp, ids[:, 2:, None], axis=-1)[..., 0]
-        return _with_mtp(*out, _mean(nll, batch, 2))
+            loss = _mean(nll, batch, 2)
+        return _with_mtp(*out, loss)
 
     return loss_fn
 
@@ -1652,9 +1673,7 @@ def make_block_diffusion_loss_fn(model: Transformer, block: int, mask_id: int,
         rows, length = ids.shape
         if length % block:
             raise ValueError(f"rows of {length} tokens in blocks of {block}")
-        # one scope around the whole loss: a transform names itself around
-        # the OUTERMOST scope it meets ("jvp(block_diffusion)/diffusion/
-        # corrupt/..."), and the readers look for whole components
+        # one scope around the whole loss: _loss_scope's rule
         with jax.named_scope("block_diffusion"):
             with jax.named_scope("diffusion/corrupt"):
                 noised, masked, t = corrupt_blocks(
@@ -1709,8 +1728,7 @@ def make_sparse_loss_fn(model: Transformer, aux_loss_coef: float = 0.01,
 
     def loss_fn(params, batch):
         ids = batch["input_ids"]
-        # one scope around the whole loss, as block diffusion's has: the
-        # readers look for whole components of the dsa/* scopes
+        # one scope around the whole loss: _loss_scope's rule
         with jax.named_scope("sparse_lm"):
             h, updates = hidden_model.apply({"params": params}, ids,
                                             mutable=sown)
