@@ -31,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 from tensorflowonspark_tpu import telemetry
 from tensorflowonspark_tpu.models.registry import register
 from tensorflowonspark_tpu.ops.attention import flash_attention
+from tensorflowonspark_tpu.ops.qk_prep import engages, qk_prep
 from tensorflowonspark_tpu.parallel.tp import constrain
 
 BATCH = ("dp", "fsdp")
@@ -132,6 +133,32 @@ class RMSNorm(nn.Module):
         return (norm * scale).astype(x.dtype)
 
 
+def _token_major_dot(lhs, rhs, dimension_numbers, precision=None,
+                     preferred_element_type=None):
+    """``DenseGeneral``'s product ``[B, S, M] x [M, H, D]`` as the 2-D product
+    ``[B * S, M] x [M, H * D]``: its result is token-major as it is written,
+    which is how ``ops/qk_prep.py`` reads it, and its two backward products
+    read that op's cotangent as it was written (a product over ``[.., H, D]``
+    lays its result out head by head, and each way costs a copy)."""
+    if dimension_numbers != (((lhs.ndim - 1,), (0,)), ((), ())):
+        raise NotImplementedError(f"a projection's product: got "
+                                  f"{dimension_numbers}")
+    out = jax.lax.dot_general(
+        lhs.reshape(-1, lhs.shape[-1]), rhs.reshape(rhs.shape[0], -1),
+        (((1,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=preferred_element_type)
+    return out.reshape(lhs.shape[:-1] + rhs.shape[1:])
+
+
+class _NormScale(nn.Module):
+    """``RMSNorm``'s learned scale, under the name and the shape ``RMSNorm``
+    gives it, for a caller whose norm runs elsewhere (``ops/qk_prep.py``)."""
+
+    @nn.compact
+    def __call__(self, width: int):
+        return self.param("scale", nn.initializers.ones, (width,))
+
+
 class Attention(nn.Module):
     n_heads: int
     d_head: int
@@ -151,7 +178,9 @@ class Attention(nn.Module):
     n_kv_heads: int = 0
     # Where ``qk_norm`` sits: over each head's ``d_head`` with ONE learned
     # scale of that width for all heads (Qwen3's placement) instead of over
-    # the whole projection (OLMoE's).
+    # the whole projection (OLMoE's).  On the kernels' path this norm and
+    # RoPE are ONE op (``_prepare``, ``ops/qk_prep.py``); ``RMSNorm`` is the
+    # cache path's, a 96-wide head's and every other backend's.
     qk_norm_per_head: bool = False
     # Learned sparse attention (DeepSeek Sparse Attention, as Keye-VL-2.0
     # trains it): ``(index heads, index head dim, topk)``.  An indexer scores
@@ -167,6 +196,9 @@ class Attention(nn.Module):
     latent: Optional[tuple] = None
     # False: no rotation and no position input at all (``nemotron_h``'s
     # attention layers: the state-space layers around them carry the order).
+    # True: ``_prepare`` turns q and k, by ``ops/qk_prep.py`` on the kernels'
+    # path and by ``apply_rope`` elsewhere; latent attention, the indexer
+    # and the cache path call ``apply_rope`` themselves.
     rope: bool = True
     # A query latent beside ``latent`` (DeepSeek-V3's ``q_lora_rank``): ``q =
     # RMSNorm(u W_qa) W_qb`` through a latent of this width (0: ``u W_q``).
@@ -208,12 +240,26 @@ class Attention(nn.Module):
         b, s, _ = x.shape
         h, dh = self.n_heads, self.d_head
         h_kv = self.n_kv_heads or h
-        dense = lambda name, heads: nn.DenseGeneral(  # noqa: E731
+        per_head = self.qk_norm and self.qk_norm_per_head
+        # q and k take the fused pass where the kernels run and it can: the
+        # share of the sites traced that did is attn.qk_prep / attn.qk_sites
+        fused = (self._impl() != "xla" and not self.decode
+                 and self.attn_impl != "ring"
+                 and engages(dh, per_head, self.rope))
+        dense = lambda name, heads, dot=None: nn.DenseGeneral(  # noqa: E731
             (heads, dh), axis=-1, use_bias=False, name=name,
-            dtype=self.compute_dtype)
-        q, k, v = (dense("q_proj", h)(x), dense("k_proj", h_kv)(x),
+            dtype=self.compute_dtype, dot_general=dot)
+        dot = _token_major_dot if fused else None
+        q, k, v = (dense("q_proj", h, dot)(x), dense("k_proj", h_kv, dot)(x),
                    dense("v_proj", h_kv)(x))
-        if self.qk_norm:
+        telemetry.counter("attn.qk_sites").inc()
+        if fused:
+            telemetry.counter("attn.qk_prep").inc()
+        scales = (None, None)
+        if fused and per_head:
+            scales = (_NormScale(name="q_norm")(dh),
+                      _NormScale(name="k_norm")(dh))
+        elif self.qk_norm:
             # before the branch: the cache path computes the same model
             with jax.named_scope("qk_norm"):
                 if self.qk_norm_per_head:
@@ -231,26 +277,24 @@ class Attention(nn.Module):
                     "the cache path holds one K/V head per query head, at "
                     "the tokens' own places, under the causal mask")
             return self._decode_step(x, q, k, v)
+        if self.rope and positions is None:
+            positions = jnp.arange(s)
         if self.sparse:
             if block_diffusion or self.attn_impl == "ring":
                 raise NotImplementedError(
                     "learned sparse attention selects among the causal keys "
                     "of one whole sequence on one chip")
+            q, k = self._prepare(q, k, positions, scales, fused,
+                                 "dsa/attend")
             return self._sparse_attention(x, q, k, v, positions)
         if self.attn_impl == "ring" and (
                 self.mesh is None or h_kv != h or block_diffusion):
             raise ValueError("ring attention needs mesh=, as many K/V heads "
                              "as query heads and the causal mask")
-        # named scope: rope, layout and the kernel (both halves of its
-        # VJP) carry "attention" in their op names, whatever XLA fuses
+        q, k = self._prepare(q, k, positions, scales, fused, "attention")
+        # named scope: layout and the kernel (both halves of its VJP) carry
+        # "attention" in their op names, whatever XLA fuses
         with jax.named_scope("attention"):
-            if self.rope:
-                if positions is None:
-                    positions = jnp.arange(s)
-                q = apply_rope(q, positions, self.rope_theta,
-                               self.rope_scaling)
-                k = apply_rope(k, positions, self.rope_theta,
-                               self.rope_scaling)
             q = constrain(q, P(BATCH, "sp", "tp", None))
             k = constrain(k, P(BATCH, "sp", "tp", None))
             v = constrain(v, P(BATCH, "sp", "tp", None))
@@ -261,14 +305,44 @@ class Attention(nn.Module):
                 out = sequence_parallel_attention(
                     self.mesh, q, k, v, causal=True)
             else:
-                impl = None if self.attn_impl == "auto" else self.attn_impl
                 out = flash_attention(q, k, v, causal=not block_diffusion,
-                                      impl=impl,
+                                      impl=self._impl(),
                                       block_diffusion=block_diffusion,
                                       window=self.window or None)
         out = nn.DenseGeneral(x.shape[-1], axis=(-2, -1), use_bias=False,
                               name="o_proj", dtype=self.compute_dtype)(out)
         return out
+
+    @nn.nowrap
+    def _impl(self) -> str:
+        """Which kernels this module's attention runs: ``attn_impl``, and
+        under ``auto`` Pallas on the TPU and XLA's elsewhere."""
+        if self.attn_impl == "auto":
+            return "pallas" if jax.default_backend() == "tpu" else "xla"
+        return self.attn_impl
+
+    @nn.nowrap
+    def _prepare(self, q, k, positions, scales, fused: bool, scope: str):
+        """q and k from the projections' ``[B, S, H, D]`` to what the
+        kernels take.  ``fused``: per-head QK-norm (``scales``, where the
+        model has it) and RoPE in one pass each way, under the scope
+        ``qk_prep`` (``ops/qk_prep.py``); else q and k arrive normed and
+        ``apply_rope`` turns them under ``scope``, as the kernels' own
+        layout work is."""
+        if fused:
+            freqs, factor = (rope_frequencies(
+                self.rope_theta, self.rope_scaling, self.d_head)
+                if self.rope else (None, 1.0))
+            return tuple(
+                qk_prep(t, scale, positions, freqs, factor=factor,
+                        eps=self.norm_eps,
+                        interpret=self._impl() == "pallas_interpret")
+                for t, scale in zip((q, k), scales))
+        if not self.rope:
+            return q, k
+        with jax.named_scope(scope):
+            return tuple(apply_rope(t, positions, self.rope_theta,
+                                    self.rope_scaling) for t in (q, k))
 
     def _latent_attention(self, x, positions, block_diffusion):
         """Latent attention on the training path.  From the layer's normed
@@ -407,8 +481,6 @@ class Attention(nn.Module):
         heads, dim, topk = self.sparse
         impl = None if self.attn_impl == "auto" else self.attn_impl
         b, s, _ = x.shape
-        if positions is None:
-            positions = jnp.arange(s)
         f32 = jnp.float32
         with jax.named_scope("dsa/index"):
             u = jax.lax.stop_gradient(x)
@@ -425,9 +497,6 @@ class Attention(nn.Module):
             key = apply_rope(key[:, :, None], positions, self.rope_theta,
                              self.rope_scaling)[:, :, 0].astype(
                                  self.compute_dtype)
-        with jax.named_scope("dsa/attend"):
-            q = apply_rope(q, positions, self.rope_theta, self.rope_scaling)
-            k = apply_rope(k, positions, self.rope_theta, self.rope_scaling)
 
         def row(a, key, c, q, k, v):
             mask, lse_i = dsa.lightning_select(a, key, c, topk, impl=impl)

@@ -211,3 +211,54 @@ def test_the_sparse_attention_kernels_lower_at_keye_widths(one_chip, step):
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
     assert hlo.count('custom_call_target="tpu_custom_call"') == calls
+
+
+@pytest.mark.parametrize("case", [
+    # positions, heads, norm, rotation: SDAR's and Keye's q and k (per-head
+    # QK-norm and RoPE), SmallThinker's (28 heads in blocks of 4, rotation
+    # only), OLMoE's (the norm over the whole projection stays jnp in front)
+    pytest.param((8192, 32, True, True), id="sdar-q"),
+    pytest.param((8192, 4, True, True), id="sdar-k"),
+    pytest.param((16384, 32, True, True), id="keye-q"),
+    pytest.param((16384, 28, False, True), id="smallthinker-q"),
+    pytest.param((4096, 16, False, True), id="olmoe-q"),
+    pytest.param((8192, 32, True, False), id="norm-only"),
+])
+def test_the_qk_prep_kernels_lower_at_the_cells_widths(one_chip, case):
+    """``ops/qk_prep.py``'s two passes on a token-major ``[1, S, H, 128]``
+    bf16 array.  What interpret mode cannot show: that Mosaic takes the lane
+    rotation by half a head (``pltpu.roll``), the lane-aligned head slices
+    of a ``(512, 4 x 128)`` block, the scale's ``(1, 128)`` block and the
+    partial sums' ``(1, 128)`` block of a four-dimensional output."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from tensorflowonspark_tpu.models.transformer import rope_frequencies
+    from tensorflowonspark_tpu.ops.qk_prep import qk_prep
+
+    length, heads, norm, rope = case
+    x = jax.ShapeDtypeStruct((1, length, heads, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip)
+    freqs, factor = rope_frequencies(1e6, None, 128)
+
+    def loss(x, scale):
+        out = qk_prep(x, scale if norm else None,
+                      jnp.arange(length) if rope else None,
+                      freqs if rope else None, factor=factor)
+        return jnp.sum(out.astype(jnp.float32))
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            x, scale).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2
+    assert sum("qk_prep_fwd" in line for line in kernels) == 1
+    assert sum("qk_prep_bwd" in line for line in kernels) == 1
