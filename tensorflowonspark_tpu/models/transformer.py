@@ -196,9 +196,11 @@ class Attention(nn.Module):
     latent: Optional[tuple] = None
     # False: no rotation and no position input at all (``nemotron_h``'s
     # attention layers: the state-space layers around them carry the order).
-    # True: ``_prepare`` turns q and k, by ``ops/qk_prep.py`` on the kernels'
-    # path and by ``apply_rope`` elsewhere; latent attention, the indexer
-    # and the cache path call ``apply_rope`` themselves.
+    # Latent attention takes it too (``mla_use_nope``: the ``qk_rope_head_dim``
+    # columns keep their place in the shapes and are never turned).  True:
+    # ``_prepare`` turns q and k, by ``ops/qk_prep.py`` on the kernels' path
+    # and by ``apply_rope`` elsewhere; latent attention turns its rotary
+    # columns with ``apply_rope``, as the indexer and the cache path do theirs.
     rope: bool = True
     # A query latent beside ``latent`` (DeepSeek-V3's ``q_lora_rank``): ``q =
     # RMSNorm(u W_qa) W_qb`` through a latent of this width (0: ``u W_q``).
@@ -220,10 +222,10 @@ class Attention(nn.Module):
         that mask in place of the causal one (``ops/attention.py``).  The
         caller hands in both: what a sequence holds is the loss's business
         (``make_block_diffusion_loss_fn``)."""
-        if not self.rope and (self.latent or self.sparse or self.decode):
+        if not self.rope and (self.sparse or self.decode):
             raise NotImplementedError(
-                "rope=False is the plain training path's: latent attention, "
-                "the indexer and the cache path all turn their keys")
+                "rope=False is the training path's, plain or latent: the "
+                "indexer and the cache path turn their keys")
         if self.window and (self.latent or self.sparse or self.decode
                             or self.attn_impl == "ring"):
             raise NotImplementedError(
@@ -350,10 +352,12 @@ class Attention(nn.Module):
         W_qa) W_qb``, a query latent) = per head ``[q_nope | q_rope]``;
         ``[c | k_r] = u W_kva``, ``c`` RMS-normed (``kv_a_norm``), ``k_r`` ONE
         rotary key head for all query heads; ``[k_nope | v] = c W_kvb`` per
-        head.  RoPE turns ``q_rope`` and ``k_r`` only (this file's half-split
-        pairing: against the published interleaved one a fixed permutation
-        of the rotary columns of ``W_q`` and ``W_kva``, which no score
-        sees).  ``score = (q_nope · k_nope + q_rope · k_r) / sqrt(nope +
+        head.  Under ``rope`` ``apply_rope`` turns ``q_rope`` and ``k_r`` only
+        (this file's half-split pairing: against the published interleaved
+        one a fixed permutation of the rotary columns of ``W_q`` and
+        ``W_kva``, which no score sees); under ``rope=False`` nothing is
+        turned and ``positions`` is not read: the columns are a second,
+        shared part of the key (Kimi-Linear's ``mla_use_nope``).  ``score = (q_nope · k_nope + q_rope · k_r) / sqrt(nope +
         rope)``: the kernels take ``k_r`` as their shared key, so no copy of
         it a head exists, forward or backward.  Autodiff keeps ``k_nope``
         and ``v`` whole for the backward (the kernels' residuals).  Under
@@ -393,14 +397,16 @@ class Attention(nn.Module):
             c = RMSNorm(self.norm_eps, name="kv_a_norm")(kv_a[..., :rank])
             kv = nn.DenseGeneral((h, nope + dv), use_bias=False,
                                  name="kv_b_proj", dtype=cdt)(c)
-            if positions is None:
-                positions = jnp.arange(s)
-            q = jnp.concatenate(
-                [q[..., :nope],
-                 apply_rope(q[..., nope:], positions, self.rope_theta,
-                            self.rope_scaling)], -1)
-            k_r = apply_rope(kv_a[:, :, None, rank:], positions,
-                             self.rope_theta, self.rope_scaling)[:, :, 0]
+            k_r = kv_a[..., rank:]
+            if self.rope:
+                if positions is None:
+                    positions = jnp.arange(s)
+                q = jnp.concatenate(
+                    [q[..., :nope],
+                     apply_rope(q[..., nope:], positions, self.rope_theta,
+                                self.rope_scaling)], -1)
+                k_r = apply_rope(k_r[:, :, None], positions, self.rope_theta,
+                                 self.rope_scaling)[:, :, 0]
             q = constrain(q, P(BATCH, "sp", "tp", None))
             k = constrain(kv[..., :nope], P(BATCH, "sp", "tp", None))
             v = constrain(kv[..., nope:], P(BATCH, "sp", "tp", None))
@@ -550,6 +556,21 @@ class Relu2MLP(nn.Module):
             return dense(x.shape[-1], "down_proj")(h)
 
 
+def _dt_bias_init(dt_range: tuple):
+    """The initialiser of a ``dt_bias``: the inverse softplus of a
+    log-uniform draw from ``dt_range`` = (min, max, floor) (Mamba-2's, and
+    Kimi Delta Attention's)."""
+    lo, hi, floor = dt_range
+
+    def init(key, shape):
+        dt = jnp.exp(jax.random.uniform(key, shape)
+                     * (math.log(hi) - math.log(lo)) + math.log(lo))
+        dt = jnp.maximum(dt, floor)
+        return dt + jnp.log(-jnp.expm1(-dt))            # softplus^-1
+
+    return init
+
+
 class Mamba2(nn.Module):
     """Mamba-2's mixer (Dao & Gu, arXiv:2405.21060; transformers'
     ``nemotron_h``), ``[B, L, D] -> [B, L, D]``, ``L`` a multiple of
@@ -588,13 +609,6 @@ class Mamba2(nn.Module):
                       self.state_size)
         inner, f32 = h * p, jnp.float32
 
-        def dt_bias_init(key, shape):
-            lo, hi, floor = self.dt_range
-            dt = jnp.exp(jax.random.uniform(key, shape)
-                         * (math.log(hi) - math.log(lo)) + math.log(lo))
-            dt = jnp.maximum(dt, floor)
-            return dt + jnp.log(-jnp.expm1(-dt))        # softplus^-1
-
         with jax.named_scope("ssm"):
             with jax.named_scope("ssm/in_proj"):
                 zxbcdt = nn.Dense(2 * inner + 2 * g * n + h, use_bias=False,
@@ -615,7 +629,8 @@ class Mamba2(nn.Module):
                     "A_log", lambda key, shape: jnp.log(jax.random.uniform(
                         key, shape, minval=1.0, maxval=16.0)), (h,))
                 skip = self.param("D", nn.initializers.ones, (h,))
-                dt_bias = self.param("dt_bias", dt_bias_init, (h,))
+                dt_bias = self.param("dt_bias", _dt_bias_init(self.dt_range),
+                                     (h,))
                 y = ssd_scan(
                     x.reshape(b, length, h, p),
                     jax.nn.softplus(dt.astype(f32) + dt_bias),
@@ -634,6 +649,124 @@ class Mamba2(nn.Module):
             with jax.named_scope("ssm/out_proj"):
                 return nn.Dense(d, use_bias=False, name="out_proj",
                                 dtype=self.compute_dtype)(y)
+
+
+class KimiDeltaAttention(nn.Module):
+    """Kimi Delta Attention's mixer (Kimi Linear, arXiv:2510.26692 §3-4;
+    the public ``KimiDeltaAttention``), ``[B, L, D] -> [B, L, D]``, ``L`` a
+    multiple of ``chunk``.  From the layer's normed state ``u``, per head
+    ``h`` of ``H`` with ``d = head_dim`` key and value channels:
+
+        q = L2Norm(SiLU(Conv(u W_q)))_h · d^-1/2,  k = L2Norm(SiLU(Conv(u
+        W_k)))_h,  v = SiLU(Conv(u W_v))_h
+        g = -exp(A_log_h) · softplus((u W_f↓ W_f↑)_h + dt_bias_h)   [d]
+        β = sigmoid(u W_β)_h
+        S_t = (I - β_t k_t k_tᵀ) Diag(exp g_t) S_{t-1} + β_t k_t v_tᵀ,
+        o_t = S_tᵀ q_t                                   (``ops/kda.py``)
+        y = (RMSNorm_d(o) ⊙ sigmoid((u W_g↓ W_g↑)_h)) W_o
+
+    ``Conv``: a depthwise causal conv of ``conv`` taps over each of the
+    three ``H·d``-wide projections, no bias (``ops/ssd.causal_conv1d``); the
+    L2 norm over a head's ``d`` channels, ``x / sqrt(Σ x² + 1e-6)``; the
+    decay a vector over the head's ``d`` KEY channels through a low-rank
+    pair ``D -> d -> H·d`` (``f_a_proj``, ``f_b_proj``), ``A_log`` one value
+    a head, ``dt_bias`` one a channel; ``β`` one value a head; the output
+    norm one learned weight of width ``d`` (``o_norm``) for all heads, gated
+    through a second low-rank pair (``g_a_proj``, ``g_b_proj``).  No bias
+    anywhere.
+
+    ``g``, ``β``, the L2 norm, the op's running sums, decays and carried
+    state, the output norm's mean and the gate's sigmoid are float32; the
+    projections, the conv's operands, the op's products and the gate's
+    multiply ``compute_dtype``.  The L2 norm and the decay run under
+    ``jax.checkpoint``: a layer keeps the maps they are made from, not
+    float32 copies of ``[B, L, H·d]``.  Seeded as the public
+    layer is: ``A`` uniform in [1, 16], ``dt_bias`` the inverse softplus of
+    a log-uniform draw from ``dt_range`` = (min, max, floor), so a channel
+    loses between a thousandth and more than a whole ``e`` a step."""
+
+    n_heads: int
+    head_dim: int
+    conv: int = 4
+    chunk: int = 64
+    norm_eps: float = 1e-5
+    compute_dtype: Any = jnp.bfloat16
+    state_dtype: Any = jnp.float32      # ``ops/kda.py``: a check's control
+    dt_range: tuple = (0.001, 0.1, 1e-4)
+
+    @nn.compact
+    def __call__(self, u):
+        from tensorflowonspark_tpu.ops.kda import kda_scan
+        from tensorflowonspark_tpu.ops.ssd import causal_conv1d
+
+        b, length, d_model = u.shape
+        h, d = self.n_heads, self.head_dim
+        inner, f32, cdt = h * d, jnp.float32, self.compute_dtype
+        telemetry.counter("kda.layers").inc()
+        telemetry.counter("kda.chunks").inc(length // self.chunk)
+
+        heads = lambda name: nn.DenseGeneral(           # noqa: E731
+            (h, d), use_bias=False, name=name, dtype=cdt)
+        pair = lambda name, x: nn.Dense(                # noqa: E731
+            inner, use_bias=False, name=f"{name}_b_proj", dtype=cdt)(
+                nn.Dense(d, use_bias=False, name=f"{name}_a_proj",
+                         dtype=cdt)(x))
+        with jax.named_scope("kda"):
+            q, k, v = (heads(name)(u).reshape(b, length, inner)
+                       for name in ("q_proj", "k_proj", "v_proj"))
+            with jax.named_scope("kda/conv"):
+                def short_conv(name, x):
+                    kernel = self.param(
+                        name, lambda key, shape: jax.random.uniform(
+                            key, shape, minval=-1.0, maxval=1.0)
+                        / math.sqrt(self.conv), (self.conv, inner))
+                    x = jax.nn.silu(causal_conv1d(
+                        x, kernel, jnp.zeros((inner,), f32)))
+                    return x.reshape(b, length, h, d)
+
+                # the L2 norm (and below the decay) under ``jax.checkpoint``:
+                # what a layer keeps of them are the bf16 maps they are made
+                # from, not float32 copies (0.25 GB each at 16k rows)
+                @jax.checkpoint
+                def unit(x, scale):
+                    x32 = x.astype(f32)
+                    return (x32 * (scale * jax.lax.rsqrt(jnp.sum(
+                        x32 * x32, axis=-1, keepdims=True) + 1e-6))
+                            ).astype(cdt)
+
+                q = unit(short_conv("q_conv", q), d ** -0.5)
+                k = unit(short_conv("k_conv", k), 1.0)
+                v = short_conv("v_conv", v)
+            with jax.named_scope("kda/gates"):
+                a_log = self.param(
+                    "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                        key, shape, minval=1.0, maxval=16.0)), (h,))
+                dt_bias = self.param("dt_bias", _dt_bias_init(self.dt_range),
+                                     (inner,))
+
+                @jax.checkpoint
+                def decay(f, a_log, dt_bias):
+                    return (-jnp.exp(a_log.astype(f32))[:, None]
+                            * jax.nn.softplus(f.astype(f32) + dt_bias)
+                            .reshape(b, length, h, d))
+
+                g = decay(pair("f", u), a_log, dt_bias)
+                beta = jax.nn.sigmoid(nn.Dense(
+                    h, use_bias=False, name="b_proj", dtype=cdt)(u)
+                    .astype(f32))
+                gate = pair("g", u)
+            with jax.named_scope("kda/scan"):
+                o = kda_scan(q, k, v, g, beta, chunk=self.chunk,
+                             state_dtype=self.state_dtype)
+            with jax.named_scope("kda/gate_norm"):
+                scale = self.param("o_norm", nn.initializers.ones, (d,))
+                o = o.astype(f32)
+                o = o * jax.lax.rsqrt(
+                    jnp.mean(o * o, axis=-1, keepdims=True) + self.norm_eps)
+                o = ((o * scale).astype(cdt) * jax.nn.sigmoid(
+                    gate.astype(f32)).astype(cdt).reshape(b, length, h, d))
+            return nn.DenseGeneral(d_model, axis=(-2, -1), use_bias=False,
+                                   name="o_proj", dtype=cdt)(o)
 
 
 class MixerBlock(nn.Module):
@@ -842,6 +975,12 @@ def _from_streams(x, n: int):
                    for part in _streams(x, n)).astype(x.dtype)
 
 
+def _attention_entry(entry: tuple) -> tuple:
+    """``(window, rope, kind)`` of a ``Transformer.layer_attention`` entry,
+    which may leave the kind out (``"own"``)."""
+    return (*entry, "own")[:3]
+
+
 class Block(nn.Module):
     n_heads: int
     d_head: int
@@ -869,22 +1008,37 @@ class Block(nn.Module):
     rope_scaling: Optional[tuple] = None
     hyper: Optional[tuple] = None
     hyper_dtype: Any = jnp.float32
-    attention: tuple = (0, True)            # see Transformer.layer_attention
+    # ``(window, rope[, kind])``: see Transformer.layer_attention
+    attention: tuple = (0, True)
     moe_expert_act: str = "swiglu"
     moe_router_input: str = "ffn"
+    kda: Optional[tuple] = None             # see Transformer
+    kda_state_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x, positions=None, block_diffusion=None):
         norm = lambda name: RMSNorm(self.norm_eps, name=name)  # noqa: E731
-        window, rope = self.attention
-        attn = Attention(self.n_heads, self.d_head, self.rope_theta,
-                         self.attn_impl, self.mesh, self.compute_dtype,
-                         self.decode, self.max_decode_len, self.qk_norm,
-                         self.norm_eps, self.n_kv_heads,
-                         self.qk_norm_per_head, self.sparse, self.latent,
-                         rope=rope, q_lora_rank=self.q_lora_rank,
-                         rope_scaling=self.rope_scaling, window=window,
-                         name="attn")
+        window, rope, kind = _attention_entry(self.attention)
+        if kind == "kda":
+            if block_diffusion:
+                raise NotImplementedError(
+                    f"block_diffusion={block_diffusion} beside a KDA layer: "
+                    "a block-diffusion mask is attention's, the delta rule "
+                    "reads every position before its own")
+            heads, head_dim, conv, chunk = self.kda
+            mixer = KimiDeltaAttention(
+                heads, head_dim, conv, chunk, self.norm_eps,
+                self.compute_dtype, self.kda_state_dtype, name="attn")
+            attn = lambda u, _positions, _mask: mixer(u)    # noqa: E731
+        else:
+            attn = Attention(self.n_heads, self.d_head, self.rope_theta,
+                             self.attn_impl, self.mesh, self.compute_dtype,
+                             self.decode, self.max_decode_len, self.qk_norm,
+                             self.norm_eps, self.n_kv_heads,
+                             self.qk_norm_per_head, self.sparse, self.latent,
+                             rope=rope, q_lora_rank=self.q_lora_rank,
+                             rope_scaling=self.rope_scaling, window=window,
+                             name="attn")
         if self.hyper:
             return self._hyper_connected(x, attn, positions, block_diffusion)
         layer_input = x
@@ -961,15 +1115,18 @@ def _run(remat: bool, block, *args):
     """``block(*args)``, counted for the run report where it is
     rematerialised (trace time, as ``flash.*`` is): ``remat.blocks``, and of
     them ``remat.flash_kept``, the blocks whose trace ran the flash forward
-    rule, which names what ``Transformer._remat_policy`` keeps.  No method
-    of the model: flax would put its name into every scope below."""
+    rule, and ``remat.kda_kept``, the blocks whose trace ran the KDA op:
+    each names what ``Transformer._remat_policy`` keeps.  No method of the
+    model: flax would put its name into every scope below."""
     if not remat:
         return block(*args)
-    named = telemetry.counter("flash.fwd_calls")
-    before = named.value()
+    named = {"remat.flash_kept": telemetry.counter("flash.fwd_calls"),
+             "remat.kda_kept": telemetry.counter("kda.layers")}
+    before = {kept: counter.value() for kept, counter in named.items()}
     out = block(*args)
     telemetry.counter("remat.blocks").inc()
-    telemetry.counter("remat.flash_kept").inc(int(named.value() > before))
+    for kept, counter in named.items():
+        telemetry.counter(kept).inc(int(counter.value() > before[kept]))
     return out
 
 
@@ -978,8 +1135,10 @@ class Transformer(nn.Module):
 
     Layers may differ inside one model, each by ONE field with an entry a
     layer: ``layer_ffn`` (a dense FFN in place of the experts),
-    ``layer_mixer`` (the layer's one mixer) and ``layer_attention`` (the
-    width of the window on a layer's causal mask, and whether it rotates).
+    ``layer_mixer`` (the layer's one mixer) and ``layer_attention`` (what
+    a ``Block``'s attention slot holds: the width of the window on its
+    causal mask, whether it rotates, and its kind: the model's attention,
+    plain or latent, or a Kimi Delta Attention mixer).
     What the experts' router reads is ``moe_router_input``, their form
     ``moe_expert_act``.  The fields below say the rest."""
 
@@ -1037,14 +1196,24 @@ class Transformer(nn.Module):
     # layer the model's own.  DeepSeek-V3's ``first_k_dense_replace`` 1 over
     # 5 layers is ``(6144, 0, 0, 0, 0)``.
     layer_ffn: Optional[tuple] = None
-    # What each layer's ATTENTION is, one entry a layer beside ``layer_ffn``:
-    # ``(window, rope)``, the width of the window on its causal mask (0: the
-    # full causal mask) and whether it turns its queries and keys.  None:
-    # every layer ``(0, True)``.  SmallThinker's ``sliding_window_layout`` =
-    # ``rope_layout`` = 0, 1, 1, 1 with ``sliding_window_size`` 4096 is ``((0,
-    # False), (4096, True), (4096, True), (4096, True))``: a global layer
-    # without rotation, then three that rotate inside a band.  Plain and
-    # grouped-query attention on the training path (``__post_init__``).
+    # What each layer's ATTENTION slot holds, one entry a layer beside
+    # ``layer_ffn``: ``(window, rope)`` or ``(window, rope, kind)``.  The
+    # width of the window on the layer's causal mask (0: the full causal
+    # mask), whether it turns its queries and keys, and its KIND: ``"own"``
+    # (as without the third entry) the model's attention, plain or
+    # grouped-query, or latent where ``latent`` is set; ``"latent"`` the
+    # same, said by name (it needs ``latent``); ``"kda"`` a Kimi Delta
+    # Attention mixer (``KimiDeltaAttention``, sizes in ``kda``), which has
+    # neither window nor rotation and is written ``(0, False, "kda")``.
+    # None: every layer ``(0, True, "own")``.  SmallThinker's ``sliding_
+    # window_layout`` = ``rope_layout`` = 0, 1, 1, 1 with ``sliding_window_
+    # size`` 4096 is ``((0, False), (4096, True), (4096, True), (4096,
+    # True))``: a global layer without rotation, then three that rotate
+    # inside a band.  Kimi-Linear's ``kda_layers`` 1, 2, 3 and ``full_attn_
+    # layers`` 4 with ``mla_use_nope`` are three ``(0, False, "kda")`` and a
+    # ``(0, False, "latent")``: latent attention that turns nothing.  A
+    # window is plain attention's; all of it a ``Block``'s on the training
+    # path (``__post_init__``).
     layer_attention: Optional[tuple] = None
     # A MIXER a layer (``nemotron_h``'s ``hybrid_override_pattern``): one
     # entry a layer, ``"M"`` a Mamba-2 mixer (``ssm`` = (heads, head dim,
@@ -1068,6 +1237,10 @@ class Transformer(nn.Module):
     moe_expert_act: str = "swiglu"
     moe_latent: int = 0
     moe_router_input: str = "ffn"
+    # The sizes of the layers whose ``layer_attention`` kind is ``"kda"``:
+    # ``(heads, head dim, conv taps, chunk)`` (see ``KimiDeltaAttention``).
+    kda: Optional[tuple] = None
+    kda_state_dtype: Any = jnp.float32      # ``ops/kda.py``: a check's control
     # Latent attention's query latent and the stretch of the rotary
     # frequencies (see ``Attention``; ``rope_scaling`` as ``rope_frequencies``
     # takes it).
@@ -1101,19 +1274,41 @@ class Transformer(nn.Module):
                 "Block on the training path (no cache of n streams, no "
                 "MixerBlock, no indexer, no ring attention)")
         if self.layer_attention is not None and (
-                self.decode or self.sparse or self.latent or self.hyper
+                self.decode or self.sparse or self.hyper
                 or self.layer_mixer or self.mtp_layers
                 or self.attn_impl == "ring"
                 or len(self.layer_attention) != self.n_layers):
             raise NotImplementedError(
                 f"layer_attention={self.layer_attention} over {self.n_layers} "
                 f"layers with decode={self.decode}, sparse={self.sparse}, "
-                f"latent={self.latent}, hyper={self.hyper}, layer_mixer="
-                f"{self.layer_mixer}, mtp_layers={self.mtp_layers}, attn_impl="
-                f"{self.attn_impl!r}: a (window, rope) a layer is plain or "
-                "grouped-query attention of a Block on the training path (no "
-                "cache in which window layers hold a window, no window "
-                "beside a latent, an indexer or ring attention's chunks)")
+                f"hyper={self.hyper}, layer_mixer={self.layer_mixer}, "
+                f"mtp_layers={self.mtp_layers}, attn_impl="
+                f"{self.attn_impl!r}: an entry a layer is the attention "
+                "slot of a Block on the training path (no cache in which "
+                "window layers hold a window or a KDA layer its state and "
+                "its convs' last positions, no indexer, no residual "
+                "streams, no MTP module and no ring attention's chunks "
+                "beside a window or a KDA layer)")
+        for entry in self.layer_attention or ():
+            window, rope, kind = _attention_entry(entry)
+            if kind not in ("own", "latent", "kda") or (
+                    kind == "kda" and (self.kda is None or window or rope)
+                    ) or (kind == "latent" and self.latent is None
+                          ) or (window and kind != "kda" and self.latent):
+                raise NotImplementedError(
+                    f"layer_attention entry {tuple(entry)} with latent="
+                    f"{self.latent}, kda={self.kda}: a kind is 'own', "
+                    "'latent' (beside latent=) or 'kda' (beside kda=, "
+                    "written (0, False, 'kda'): the delta rule has neither "
+                    "window nor rotation), and latent attention takes no "
+                    "window")
+        if self.kda is not None and not any(
+                _attention_entry(entry)[2] == "kda"
+                for entry in self.layer_attention or ()):
+            raise NotImplementedError(
+                f"kda={self.kda} without a 'kda' entry in layer_attention="
+                f"{self.layer_attention}: the sizes are those of the layers "
+                "that layer_attention names")
         if self.moe_router_input not in ("ffn", "layer") or (
                 self.moe_router_input == "layer"
                 and (self.hyper or self.layer_mixer
@@ -1227,7 +1422,8 @@ class Transformer(nn.Module):
                     self.latent, self.moe_router, self.moe_shared_d_ff,
                     self.q_lora_rank, self.rope_scaling, self.hyper,
                     self.hyper_dtype, attention, self.moe_expert_act,
-                    self.moe_router_input, name=name)
+                    self.moe_router_input, self.kda, self.kda_state_dtype,
+                    name=name)
 
             for i, dense in enumerate(layer_ffn):
                 x = _run(self.remat,
@@ -1268,18 +1464,24 @@ class Transformer(nn.Module):
 
     def _remat_policy(self):
         """What a rematerialised block keeps besides its input: what its
-        attention KERNELS name, of either family.  A sparse layer emits
-        ``ops/sparse_attention.py``'s names (the selection, attention's
-        output and the indexer's loss with its gradient: 0.4 GB a layer at
-        16k), a flash layer ``ops/attention.py``'s (the output and its
-        log-sum-exp: ``S * H * D_v * 2 B + S * H * 4 B``, 0.12 GB a layer at
-        16k rows of 28 heads of 128), the XLA scan neither, and then nothing
-        is kept.  The second forward runs the projections, norms and experts
-        again and none of those kernels."""
-        from tensorflowonspark_tpu.ops import attention, sparse_attention
+        attention KERNELS name, of either family, and the KDA op's output.
+        A sparse layer emits ``ops/sparse_attention.py``'s names (the
+        selection, attention's output and the indexer's loss with its
+        gradient: 0.4 GB a layer at 16k), a flash layer ``ops/
+        attention.py``'s (the output and its log-sum-exp: ``S * H * D_v * 2 B
+        + S * H * 4 B``, 0.12 GB a layer at 16k rows of 28 heads of 128), a
+        KDA layer ``ops/kda.py``'s (the op's output, ``S * H * D_v * 2 B``:
+        0.13 GB a layer at 16k rows of 32 heads of 128; the op's own
+        backward runs its chunked form again from q, k, v, g and β, which
+        the second forward makes), the state-space scan none, and then
+        nothing is kept.  The second forward runs the projections, convs,
+        norms and experts again and none of those kernels, nor the KDA op
+        outside its own backward."""
+        from tensorflowonspark_tpu.ops import attention, kda, sparse_attention
 
         return jax.checkpoint_policies.save_only_these_names(
-            *sparse_attention.SAVED_NAMES, *attention.SAVED_NAMES)
+            *sparse_attention.SAVED_NAMES, *attention.SAVED_NAMES,
+            *kda.SAVED_NAMES)
 
 
 @register("transformer")
@@ -1295,6 +1497,7 @@ def build_transformer(config: dict) -> Transformer:
     ssm = config.get("ssm")
     scaling = config.get("rope_scaling")
     hyper = config.get("hyper_connections")
+    kda = config.get("kda")
     if router is not None and int(router.get("n_group", 1)) > 1:
         raise NotImplementedError(
             f"group-limited routing (n_group {router['n_group']}): "
@@ -1336,7 +1539,11 @@ def build_transformer(config: dict) -> Transformer:
             int(width) for width in layer_ffn),
         layer_mixer=None if layer_mixer is None else tuple(layer_mixer),
         layer_attention=None if layer_attention is None else tuple(
-            (int(window), bool(rope)) for window, rope in layer_attention),
+            (int(entry[0]), bool(entry[1]), *(str(k) for k in entry[2:]))
+            for entry in layer_attention),
+        kda=None if kda is None else tuple(int(kda[key]) for key in (
+            "n_heads", "head_dim", "conv_kernel", "chunk_size")),
+        kda_state_dtype=jnp.dtype(config.get("kda_state_dtype", "float32")),
         ssm=None if ssm is None else (
             *(int(ssm[key]) for key in (
                 "n_heads", "head_dim", "n_groups", "state_size",

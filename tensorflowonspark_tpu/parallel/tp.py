@@ -133,4 +133,13 @@ TRANSFORMER_TP_RULES: Rules = (
     # replicated, as ``kv_a_proj`` / ``kv_b_proj`` are)
     (r"hc_(attn|mlp)/(phi|bias|alpha|norm_scale)$", P()),
     (r"mtp_eh_proj/kernel$", P()),
+    # a Kimi Delta Attention layer (``Transformer.layer_attention`` kind
+    # ``"kda"``): its four big projections are ``[d_model, heads, d]`` /
+    # ``[heads, d, d_model]`` under attention's names and take attention's
+    # rules above; the low-rank pairs of the decay and of the output gate,
+    # ``β``'s map, the three short convs and the per-head and per-channel
+    # vectors are replicated over tp (a KDA layer whose heads' op, convs and
+    # gates are sharded over tp is not built: GSPMD gathers the heads)
+    (r"attn/(f_a_proj|f_b_proj|g_a_proj|g_b_proj|b_proj)/kernel$", P()),
+    (r"attn/(q_conv|k_conv|v_conv|A_log|dt_bias|o_norm)$", P()),
 )

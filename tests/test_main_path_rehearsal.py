@@ -20,8 +20,10 @@ rotation and relu² experts in a latent (Nemotron-3), and four residual
 streams under hyper-connections with a query latent, YaRN and a
 multi-token-prediction module in the loss (Xing4.0), and window and global
 attention mixed layer by layer over a group of 7 heads, a router on the
-layer's input and ReGLU experts under remat (SmallThinker): a crash on that
-cell's first step shows here.
+layer's input and ReGLU experts under remat (SmallThinker), and a ``Block``
+whose attention slot is Kimi Delta Attention's chunked gated delta rule in
+three layers and latent attention without rotation in the fourth, under
+remat (Kimi-Linear): a crash on that cell's first step shows here.
 
 The ResNet-50 case also reads the rehearsal's own ``logs/run_report.json``
 before the clean-up (ISSUE 35): the ``lifecycle`` block's stages in order
@@ -81,7 +83,8 @@ def _missing(path: str) -> list[str]:
                                       "kanana2_30b_a3b_d5_ep8_train_8k",
                                       "nemotron3_super_d11_tp8_ep64_train_8k",
                                       "xing4_29b_a4b_d5_tp8_ep8_train_4k",
-                                      "smallthinker_21b_a3b_d8_ep8_train_16k"])
+                                      "smallthinker_21b_a3b_d8_ep8_train_16k",
+                                      "kimi_linear_48b_a3b_d5_ep32_train_16k"])
 def test_cell_rehearses_on_cpu(workload):
     cell = common.resolve_cell(workload)
     # run.py's work directory is not configurable and a DIRECT cell keeps one

@@ -214,6 +214,50 @@ def test_mixer_step_names_the_state_space_mixer_and_the_latent_maps():
     assert "/cos" not in text         # nothing turns: no rotation anywhere
 
 
+def test_kda_step_names_the_mixer_s_parts_and_the_latent_layer_s_kernels():
+    """ISSUE 52: a ``Block`` model whose attention slot is Kimi Delta
+    Attention or latent attention names, under the flax path ``attn``, the
+    KDA mixer (``kda``) and its four parts, forward and backward, with the
+    four big projections under attention's names (the readers
+    ``kda_mixer_ms``, ``kda_scan_ms``, ``kda_conv_ms`` and
+    ``kda_scan_roofline`` sum device time by these, ``lm_attn_proj_ms`` the
+    projections), and the latent layer its projections and the flash
+    kernels; nothing turns."""
+    text = _lm_step_text(
+        n_layers=2, n_heads=2, d_ff=24,
+        layer_attention=[[0, False, "kda"], [0, False, "latent"]],
+        kda={"n_heads": 2, "head_dim": 8, "conv_kernel": 4, "chunk_size": 8},
+        latent_attention={"kv_lora_rank": 16, "qk_nope_head_dim": 8,
+                          "qk_rope_head_dim": 4, "v_head_dim": 8},
+        layer_ffn=[48, 0], n_experts=4, moe_held=[0, 2], moe_top_k=2,
+        moe_capacity_factor=None,
+        moe_router={"scoring": "sigmoid", "selection_bias": True,
+                    "routed_scale": 2.446},
+        moe_shared_d_ff=24, remat=True, attn_impl="pallas_interpret")
+    for scope in ("kda", "kda/conv", "kda/gates", "kda/scan",
+                  "kda/gate_norm", "mla/project", "attention", "flash_fwd",
+                  "flash_bwd", "moe/shared", "moe/router", "mlp"):
+        assert f"/{scope}/" in text, scope
+        backward = [line for line in text.split("jit(step)")
+                    if f"/{scope}/" in line and "transpose(" in line]
+        assert backward or scope in ("flash_fwd", "flash_bwd"), scope
+    for name in ("q_proj", "k_proj", "v_proj", "o_proj"):
+        assert f"/block_0/attn/kda/{name}/" in text, name
+    for name in ("f_a_proj", "f_b_proj", "g_a_proj", "g_b_proj", "b_proj"):
+        assert f"/block_0/attn/kda/kda/gates/{name}/" in text, name
+    assert "/block_1/attn/attn._latent_attention/mla/project/q_proj/" in text
+    # the op's parts; its loops are one over groups of chunks (what is in
+    # memory at once) and one over the chunk states: none over positions
+    for part in ("decay", "intra", "solve", "inter"):
+        assert f"/kda_op/{part}/" in text, part
+    assert "/kda_op/inter/while/body/" in text
+    for part in ("decay", "intra", "solve"):
+        assert f"/kda_op/{part}/while" not in text, part
+    assert "/block_0/attn/attention/" not in text   # no kernel in a KDA layer
+    assert "/block_1/attn/kda/" not in text
+    assert "/cos" not in text         # nothing turns: no rotation anywhere
+
+
 def _sum_tokens_as_on_the_chip(monkeypatch):
     """``sum_tokens`` as the Pallas kernel (interpreter mode), counted."""
     import functools
