@@ -680,10 +680,9 @@ class KimiDeltaAttention(nn.Module):
     projections, the conv's operands, the op's products and the gate's
     multiply ``compute_dtype``.  The L2 norm and the decay run under
     ``jax.checkpoint``: a layer keeps the maps they are made from, not
-    float32 copies of ``[B, L, H·d]``.  Seeded as the public
-    layer is: ``A`` uniform in [1, 16], ``dt_bias`` the inverse softplus of
-    a log-uniform draw from ``dt_range`` = (min, max, floor), so a channel
-    loses between a thousandth and more than a whole ``e`` a step."""
+    float32 copies of ``[B, L, H·d]``.  Seeded as the public layer is: ``A``
+    uniform in [1, 16], ``dt_bias`` the inverse softplus of a log-uniform draw
+    from ``dt_range`` (min, max, floor).  ``attn_impl``: the op's ``impl``."""
 
     n_heads: int
     head_dim: int
@@ -693,6 +692,7 @@ class KimiDeltaAttention(nn.Module):
     compute_dtype: Any = jnp.bfloat16
     state_dtype: Any = jnp.float32      # ``ops/kda.py``: a check's control
     dt_range: tuple = (0.001, 0.1, 1e-4)
+    attn_impl: str = "auto"         # auto | pallas | pallas_interpret | xla
 
     @nn.compact
     def __call__(self, u):
@@ -757,7 +757,7 @@ class KimiDeltaAttention(nn.Module):
                 gate = pair("g", u)
             with jax.named_scope("kda/scan"):
                 o = kda_scan(q, k, v, g, beta, chunk=self.chunk,
-                             state_dtype=self.state_dtype)
+                             state_dtype=self.state_dtype, impl=self.attn_impl)
             with jax.named_scope("kda/gate_norm"):
                 scale = self.param("o_norm", nn.initializers.ones, (d,))
                 o = o.astype(f32)
@@ -1025,10 +1025,10 @@ class Block(nn.Module):
                     f"block_diffusion={block_diffusion} beside a KDA layer: "
                     "a block-diffusion mask is attention's, the delta rule "
                     "reads every position before its own")
-            heads, head_dim, conv, chunk = self.kda
-            mixer = KimiDeltaAttention(
-                heads, head_dim, conv, chunk, self.norm_eps,
-                self.compute_dtype, self.kda_state_dtype, name="attn")
+            mixer = KimiDeltaAttention(     # heads, head_dim, conv, chunk
+                *self.kda, self.norm_eps, self.compute_dtype,
+                self.kda_state_dtype, attn_impl=self.attn_impl,
+                name="attn")
             attn = lambda u, _positions, _mask: mixer(u)    # noqa: E731
         else:
             attn = Attention(self.n_heads, self.d_head, self.rope_theta,
@@ -1464,19 +1464,19 @@ class Transformer(nn.Module):
 
     def _remat_policy(self):
         """What a rematerialised block keeps besides its input: what its
-        attention KERNELS name, of either family, and the KDA op's output.
+        attention KERNELS name, of either family, and what the KDA op names.
         A sparse layer emits ``ops/sparse_attention.py``'s names (the
         selection, attention's output and the indexer's loss with its
         gradient: 0.4 GB a layer at 16k), a flash layer ``ops/
         attention.py``'s (the output and its log-sum-exp: ``S * H * D_v * 2 B
         + S * H * 4 B``, 0.12 GB a layer at 16k rows of 28 heads of 128), a
         KDA layer ``ops/kda.py``'s (the op's output, ``S * H * D_v * 2 B``:
-        0.13 GB a layer at 16k rows of 32 heads of 128; the op's own
-        backward runs its chunked form again from q, k, v, g and β, which
-        the second forward makes), the state-space scan none, and then
-        nothing is kept.  The second forward runs the projections, convs,
-        norms and experts again and none of those kernels, nor the KDA op
-        outside its own backward."""
+        0.13 GB a layer at 16k rows of 32 heads of 128, and from the kernels
+        the state every chunk starts from, 0.5 GB; the backward kernel makes
+        a chunk's local part again from q, k, v, g and β, which the second
+        forward makes), the state-space scan none, and then nothing is kept.
+        The second forward runs the projections, convs, norms and experts
+        again and none of those kernels, nor the KDA op at all."""
         from tensorflowonspark_tpu.ops import attention, kda, sparse_attention
 
         return jax.checkpoint_policies.save_only_these_names(

@@ -258,6 +258,39 @@ def test_kda_step_names_the_mixer_s_parts_and_the_latent_layer_s_kernels():
     assert "/cos" not in text         # nothing turns: no rotation anywhere
 
 
+def test_the_kda_kernels_sit_under_kda_scan_once_a_direction():
+    """ISSUE 54: on the kernel path (a chunk of whole tiles under
+    ``attn_impl="pallas_interpret"``) the op's two kernels carry ``kda/scan``
+    (what ``kda_scan_ms`` and ``kda_scan_roofline`` sum by), ``kda_op/fwd``
+    in the forward pass and ``kda_op/bwd`` in the backward pass, in place of
+    the XLA form's four parts; under ``remat`` the recomputed block runs
+    neither again (the policy kept the output and the chunk states)."""
+    text = _lm_step_text(
+        n_layers=2, n_heads=2, d_ff=24,
+        layer_attention=[[0, False, "kda"], [0, False, "latent"]],
+        kda={"n_heads": 2, "head_dim": 8, "conv_kernel": 4, "chunk_size": 16},
+        latent_attention={"kv_lora_rank": 16, "qk_nope_head_dim": 8,
+                          "qk_rope_head_dim": 4, "v_head_dim": 8},
+        layer_ffn=[48, 0], n_experts=4, moe_held=[0, 2], moe_top_k=2,
+        moe_capacity_factor=None, moe_shared_d_ff=24, remat=True,
+        attn_impl="pallas_interpret")
+    ops = [line for line in text.split("jit(step)") if "/kda_op/" in line]
+    assert ops and all("/block_0/attn/kda/kda/scan/kda_op/" in line
+                       for line in ops)
+    forward = [line for line in ops if "/kda_op/fwd/kda_fwd/" in line]
+    backward = [line for line in ops if "/kda_op/bwd/kda_bwd/" in line]
+    assert forward and backward
+    assert not [line for line in forward if "transpose(" in line]
+    assert all("transpose(" in line and "/checkpoint/" in line
+               for line in backward)
+    for part in ("decay", "intra", "solve", "inter"):
+        assert f"/kda_op/{part}/" not in text, part
+    # the rest of the mixer keeps its scopes, both passes
+    for scope in ("kda/conv", "kda/gates", "kda/gate_norm"):
+        assert [line for line in text.split("jit(step)")
+                if f"/{scope}/" in line and "transpose(" in line], scope
+
+
 def _sum_tokens_as_on_the_chip(monkeypatch):
     """``sum_tokens`` as the Pallas kernel (interpreter mode), counted."""
     import functools

@@ -262,3 +262,52 @@ def test_the_qk_prep_kernels_lower_at_the_cells_widths(one_chip, case):
     assert len(kernels) == 2
     assert sum("qk_prep_fwd" in line for line in kernels) == 1
     assert sum("qk_prep_bwd" in line for line in kernels) == 1
+
+
+@pytest.mark.parametrize("state_dtype", ["float32", "bfloat16"])
+def test_the_kda_kernels_lower_at_kimi_linear_widths(one_chip, state_dtype):
+    """``ops/kda.py``'s two kernels on Kimi-Linear's 16,384-token row (ISSUE
+    54): 32 heads of 128 key and 128 value channels read as column blocks of
+    ``[1, 16384, 32 x 128]``, chunks of 64, four a grid step.  What
+    interpret mode cannot show: that Mosaic takes the ``(256, 128)`` blocks
+    of a head out of the token-major arrays, ``β``'s ``(4, 64)`` and the
+    chunk states' ``(4, 128, 128)`` float32 blocks, the ``[64, 128] -> [8, 8,
+    128]`` views of the diagonal blocks' pairwise decays, the products with a
+    transposed left operand and those in float32 proper, the dynamic chunk
+    offsets of a loop inside a grid step, and the backward's live set under
+    the raised VMEM limit; and the check's control, a state held in bf16."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from tensorflowonspark_tpu.ops import kda
+
+    length, heads, d = 16384, 32, 128
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+    q = shape((1, length, heads, d), jnp.bfloat16)
+    g = shape((1, length, heads, d), jnp.float32)
+    beta = shape((1, length, heads), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        out = kda.kda_scan(q, k, v, g, beta, chunk=64, impl="pallas",
+                           state_dtype=jnp.dtype(state_dtype))
+        return jnp.sum(out.astype(jnp.float32))
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4))).lower(q, q, q, g, beta).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    kernels = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2
+    assert sum("kda_fwd" in line for line in kernels) == 1
+    assert sum("kda_bwd" in line for line in kernels) == 1
+    # beside the operands and their cotangents the op holds the chunk
+    # states (0.54 GB) and little else: no stacked or transposed copies of
+    # q, k, v or g (134 MB and 268 MB each)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

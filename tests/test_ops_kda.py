@@ -1,6 +1,7 @@
 """``ops/kda.py``: the chunked gated delta rule with a decay a key channel
 against the recurrence itself, a ``lax.scan`` over positions, in float32:
-forward and every gradient, at decays from none to ``e^-20`` a step."""
+forward and every gradient, at decays from none to ``e^-20`` a step; the XLA
+form first, then the Pallas kernels in interpreter mode."""
 
 from __future__ import annotations
 
@@ -139,3 +140,149 @@ def test_shapes_that_disagree_are_refused():
     q, k, v, g, beta = _inputs(6, 32, "spread")
     with pytest.raises(ValueError, match="kda_scan"):
         kda.kda_scan(q, k, v, g[..., :4], beta, chunk=16, sub=4)
+
+
+# ---------------------------------------------------------------------------
+# The kernels (``impl="pallas_interpret"``): the same recurrence, the same
+# regimes.  Small ``_BLOCK``s make a row several grid steps, so the state and
+# its cotangent cross from one step to the next, forwards and backwards.
+# ---------------------------------------------------------------------------
+
+KERNEL_CASES = [
+    # length, chunk, positions a grid step, decay
+    (32, 16, 256, "spread"), (128, 64, 256, "spread"),
+    (64, 16, 32, "strong"), (128, 64, 64, "strong"),
+    (32, 16, 16, "none"), (128, 32, 64, "none"),
+    (64, 16, 32, "beta0"), (128, 64, 256, "beta0")]
+
+
+def _kernels(chunk, **kwargs):
+    return lambda *a: kda.kda_scan(*a, chunk=chunk, impl="pallas_interpret",
+                                   **kwargs)
+
+
+@pytest.mark.parametrize("length,chunk,block,decay", KERNEL_CASES)
+def test_the_kernels_forward_matches_the_recurrence(length, chunk, block,
+                                                    decay, monkeypatch):
+    monkeypatch.setattr(kda, "_BLOCK", block)
+    args = _inputs(0, length, decay)
+    got = _kernels(chunk)(*args)
+    want = recurrence(*args)
+    assert float(jnp.abs(want).max()) > 0 or decay == "beta0"
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("length,chunk,block,decay", KERNEL_CASES)
+def test_the_kernels_every_gradient_matches_the_recurrence(
+        length, chunk, block, decay, monkeypatch):
+    """The backward written by hand: dq, dk, dv, dg (the reverse running sum
+    of the decays' cotangents with the chunk-end term) and dβ."""
+    monkeypatch.setattr(kda, "_BLOCK", block)
+    args = _inputs(1, length, decay)
+    weight = jax.random.normal(jax.random.PRNGKey(7), args[2].shape)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a) * weight)
+
+    got = jax.grad(loss(_kernels(chunk)), argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(loss(recurrence), argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        scale = float(jnp.abs(b).max()) + 1e-30
+        np.testing.assert_allclose(a / scale, b / scale, atol=5e-5,
+                                   err_msg=name)
+
+
+def test_the_kernels_and_the_xla_form_agree_at_bf16_operands():
+    """Both take bf16 operands into float32 accumulators: each stays near
+    the float32 recurrence, and they are nearer each other than either is
+    to it, forward and in every gradient."""
+    q, k, v, g, beta = _inputs(3, 128, "spread", dk=32, dv=32)
+    low = (q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
+           v.astype(jnp.bfloat16), g, beta)
+    weight = jax.random.normal(jax.random.PRNGKey(8), v.shape)
+
+    def both(fn, args):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * weight),
+            argnums=(0, 1, 2, 3, 4))(*args)[1], fn(*args)
+
+    def err(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+    want, out = both(recurrence, (q, k, v, g, beta))
+    kernels, out_k = both(_kernels(64), low)
+    xla, out_x = both(lambda *a: kda.kda_scan(*a, chunk=64, sub=16,
+                                              impl="xla"), low)
+    assert out_k.dtype == jnp.bfloat16
+    assert err(out_k, out) < 2e-2 and err(out_x, out) < 2e-2
+    assert err(out_k, out_x) < 1e-2
+    for name, a, b, c in zip("q k v g beta".split(), kernels, xla, want):
+        assert err(a, c) < 3e-2 and err(b, c) < 3e-2, name
+        assert err(a, b) < 2e-2, name
+
+
+def test_a_state_held_in_bf16_reads_differently_in_the_kernels_too():
+    args = _inputs(2, 128, "spread")
+    want = recurrence(*args)
+    exact = _kernels(32)(*args)
+    rounded = _kernels(32, state_dtype=jnp.bfloat16)(*args)
+    err = lambda t: float(jnp.linalg.norm(t - want)     # noqa: E731
+                          / jnp.linalg.norm(want))
+    assert err(exact) < 1e-5
+    assert err(rounded) > 1e-3
+    # and it still has every gradient, finite
+    grads = jax.grad(lambda *a: jnp.sum(_kernels(
+        32, state_dtype=jnp.bfloat16)(*a)), argnums=(0, 1, 2, 3, 4))(*args)
+    assert all(bool(jnp.isfinite(t).all()) for t in grads)
+
+
+def test_the_kernels_name_the_output_and_the_chunk_states():
+    """What ``Transformer._remat_policy`` keeps of the kernel path: the
+    output and the state every chunk starts from, both by name."""
+    args = _inputs(4, 32, "spread")
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(_kernels(16)(*a))))(*args))
+    for name in kda.SAVED_NAMES:
+        assert f"name={name}" in jaxpr, name
+    assert "kda_fwd" in jaxpr and "kda_bwd" in jaxpr
+
+
+@pytest.mark.parametrize("length,chunk,sub,named", [
+    (40, 16, 4, "no multiple of chunk 16"),
+    (32, 16, 3, "powers of two"),
+    (48, 24, 8, "powers of two"),
+])
+def test_what_is_refused_by_name_stays_refused_on_the_kernel_path(
+        length, chunk, sub, named):
+    args = _inputs(5, length, "spread")
+    with pytest.raises(ValueError, match="kda_scan") as e:
+        kda.kda_scan(*args, chunk=chunk, sub=sub, impl="pallas_interpret")
+    assert named in str(e.value)
+
+
+def test_shapes_that_disagree_and_an_unknown_impl_are_refused():
+    q, k, v, g, beta = _inputs(6, 32, "spread")
+    with pytest.raises(ValueError, match="kda_scan"):
+        kda.kda_scan(q, k, v, g[..., :4], beta, chunk=16,
+                     impl="pallas_interpret")
+    with pytest.raises(ValueError, match="unknown impl 'mosaic'"):
+        kda.kda_scan(q, k, v, g, beta, chunk=16, impl="mosaic")
+
+
+@pytest.mark.parametrize("chunk,impl,kernels", [
+    (16, "pallas_interpret", 1), (8, "pallas_interpret", 0), (16, "xla", 0),
+    (16, None, 0), (16, "auto", 0)])
+def test_which_path_ran_is_counted_and_a_chunk_below_a_tile_takes_xla(
+        chunk, impl, kernels):
+    """``kda.kernel_layers``: calls traced that took the kernels.  A chunk
+    of 8 is half a bf16 tile: the XLA form takes it by its shape; off the
+    chip ``auto`` is the XLA form."""
+    from tensorflowonspark_tpu import telemetry
+
+    args = _inputs(7, 32, "spread")
+    counter = telemetry.counter("kda.kernel_layers")
+    before = counter.value()
+    got = kda.kda_scan(*args, chunk=chunk, impl=impl)
+    assert counter.value() - before == kernels
+    np.testing.assert_allclose(got, recurrence(*args), atol=2e-5, rtol=2e-5)

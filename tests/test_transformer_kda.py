@@ -172,6 +172,87 @@ def test_the_policy_names_the_op_s_output():
     assert "name=kda_out" in text and "name=flash_out" not in text
 
 
+# The op as Pallas kernels (interpreter mode; a chunk of 16 is a bf16 tile).
+KERNELS = {"attn_impl": "pallas_interpret", "kda": {**KDA, "chunk_size": 16}}
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_a_model_of_both_kinds_trains_a_step_on_the_kernels(remat):
+    model = tfm.build_transformer({**BASE, **EXPERTS, **KERNELS,
+                                   "remat": remat})
+    ids = _ids(3)
+    variables = model.init(jax.random.PRNGKey(0), ids)
+    optimizer = optax.adamw(1e-2)
+    start = jax.device_get(variables["params"])     # the step donates them
+    state = dplib.TrainState.create(variables["params"], optimizer,
+                                    variables["buffers"])
+    step = dplib.make_train_step(
+        tfm.make_loss_fn(model, aux_loss_coef=0.0, vocab_chunk=32,
+                         router_z_coef=0.0), optimizer)
+    losses = []
+    for _ in range(3):
+        state, metrics = step(state, {"input_ids": ids})
+        losses.append(float(metrics["loss"]))
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0], losses
+    moved = jax.tree.map(lambda a, b: float(np.abs(a - b).max()),
+                         jax.device_get(state.params), start)
+    for leaf in ("A_log", "dt_bias", "o_norm", "q_conv", "k_conv"):
+        assert moved["block_0"]["attn"][leaf] > 0, leaf
+
+
+def _kernel_grads(remat: bool, impl: str, ids):
+    model = tfm.build_transformer({**BASE, **EXPERTS, **KERNELS,
+                                   "attn_impl": impl, "remat": remat})
+    variables = model.init(jax.random.PRNGKey(0), ids)
+    loss = tfm.make_loss_fn(model, aux_loss_coef=0.0, router_z_coef=0.0)
+    before = telemetry.snapshot()["counters"]
+    grads = jax.grad(lambda p: loss(
+        p, {"input_ids": ids}, variables["buffers"])[0])(variables["params"])
+    after = telemetry.snapshot()["counters"]
+    return grads, {k: after.get(k, 0) - before.get(k, 0) for k in (
+        "kda.layers", "kda.kernel_layers", "remat.blocks", "remat.kda_kept")}
+
+
+def test_remat_changes_no_gradient_on_the_kernels_and_both_counters_count():
+    """``kda.kernel_layers``: the layers traced whose op took the kernels,
+    of ``kda.layers``; none under ``xla``.  ``remat`` moves no gradient, and
+    the kernels' gradients are the XLA form's."""
+    ids = _ids(4)
+    grads, counted = {}, {}
+    for remat, impl in ((False, "pallas_interpret"),
+                        (True, "pallas_interpret"), (False, "xla")):
+        grads[remat, impl], counted[remat, impl] = _kernel_grads(
+            remat, impl, ids)
+    worst = lambda a, b: max(jax.tree.leaves(jax.tree.map(  # noqa: E731
+        lambda x, y: float(jnp.abs(x - y).max() / (jnp.abs(y).max() + 1e-30)),
+        a, b)))
+    plain = grads[False, "pallas_interpret"]
+    assert worst(grads[True, "pallas_interpret"], plain) < 1e-4
+    assert worst(plain, grads[False, "xla"]) < 1e-3
+    assert counted[False, "pallas_interpret"] == {
+        "kda.layers": 2, "kda.kernel_layers": 2, "remat.blocks": 0,
+        "remat.kda_kept": 0}
+    assert counted[False, "xla"]["kda.kernel_layers"] == 0
+    kept = counted[True, "pallas_interpret"]
+    assert kept["remat.blocks"] == 3 and kept["remat.kda_kept"] == 2
+    assert kept["kda.kernel_layers"] == kept["kda.layers"]
+
+
+def test_the_policy_keeps_the_kernels_output_and_chunk_states():
+    """Both names reach the policy, and the rematerialised block's second
+    forward runs no KDA kernel: one ``kda_fwd`` and one ``kda_bwd`` a KDA
+    layer in the whole gradient."""
+    model = tfm.build_transformer({**BASE, **KERNELS, "remat": True})
+    ids = _ids(5)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    loss = tfm.make_loss_fn(model)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: loss(p, {"input_ids": ids})[0]))(params))
+    assert "name=kda_out" in text and "name=kda_states" in text
+    assert len(re.findall(r"name=kda_fwd\b", text)) == 2
+    assert len(re.findall(r"name=kda_bwd\b", text)) == 2
+
+
 def test_latent_attention_without_rotation_reads_no_position():
     """``rope=False`` under ``latent``: the logits are a function of the
     tokens alone; with rotation a shift of every position by a constant
